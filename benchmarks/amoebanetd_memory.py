@@ -44,4 +44,7 @@ def main(experiment, image, batch, chunks):
 
 
 if __name__ == "__main__":
+    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

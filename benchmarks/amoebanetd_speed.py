@@ -61,4 +61,7 @@ def main(experiment, epochs, steps, num_layers, num_filters, image, batch, bf16)
 
 
 if __name__ == "__main__":
+    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -298,4 +298,7 @@ def _load_rank_checkpoint(path, params, state, meta: str, ckpt_dir: str):
 
 
 if __name__ == "__main__":
+    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

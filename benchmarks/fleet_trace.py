@@ -592,4 +592,7 @@ def _pub(r: Dict) -> Dict:
 
 
 if __name__ == "__main__":
+    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

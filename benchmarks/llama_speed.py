@@ -46,7 +46,7 @@ PRESETS = {
     # (3.2-1B: 8192 = 2*6*2048/3).
     "tiny": (256, 8, 8, 4, 1024, 4.0),
     # ~200M params: big enough for meaningful attention/window timings at
-    # long seq, small enough to compile and fit beside HBM co-tenants.
+    # long seq, small enough to compile quickly.
     "small": (1024, 12, 16, 8, 32000, 4.0),
     "1b": (2048, 16, 32, 8, 128256, 6.0),
     "llama3-8b": (4096, 32, 32, 8, 128256, 5.25),
@@ -414,9 +414,9 @@ def _run_spmd(cfg, n, chunks, x, epochs, steps, checkpoint, label, moe=None,
     # MFU for the spmd engine too (same convention as the mpmd branches:
     # the numerator is the UN-pipELINED model's fwd+loss+bwd, costed from
     # a plain sequential step over the stacked block params).  Configs
-    # whose block graph needs mesh collectives at trace time (tp/sp/ep)
-    # fail the plain lowering — analytic_flops returns None there and
-    # print_mfu stays silent rather than publishing a wrong denominator.
+    # whose block graph needs mesh collectives at trace time (tp/ep)
+    # cannot lower as a plain step, so they print no MFU line rather
+    # than a wrong numerator.
     from benchmarks.common import analytic_flops, print_mfu
 
     def _plain_step(ps):
@@ -442,12 +442,13 @@ def _run_spmd(cfg, n, chunks, x, epochs, steps, checkpoint, label, moe=None,
 
         return jax.value_and_grad(loss_of)(ps)
 
-    print_mfu(
-        lambda: analytic_flops(_plain_step, carry["params"]),
-        tput, x.shape[0], label,
-        n_chips=int(mesh.devices.size),
-        device=mesh.devices.flat[0],
-    )
+    if tp == 1 and ep == 1:
+        print_mfu(
+            lambda: analytic_flops(_plain_step, carry["params"]),
+            tput, x.shape[0], label,
+            n_chips=int(mesh.devices.size),
+            device=mesh.devices.flat[0],
+        )
     if moe is not None and pre is not None:
         # Router balance of stage 0's first MoE block on the final batch.
         stage0 = jax.tree_util.tree_map(
@@ -460,4 +461,7 @@ def _run_spmd(cfg, n, chunks, x, epochs, steps, checkpoint, label, moe=None,
 
 
 if __name__ == "__main__":
+    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

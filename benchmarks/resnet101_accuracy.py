@@ -194,4 +194,7 @@ def main(experiment, epochs, data_dir, image, dataset_size, classes, lr,
 
 
 if __name__ == "__main__":
+    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -48,4 +48,7 @@ def main(experiment, epochs, steps, image, batch, base_width, bf16):
 
 
 if __name__ == "__main__":
+    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -407,4 +407,7 @@ def _pub(r: Dict[str, Any]) -> Dict[str, Any]:
 
 
 if __name__ == "__main__":
+    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

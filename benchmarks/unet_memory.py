@@ -46,4 +46,7 @@ def main(experiment, image, batch, chunks, depth, num_convs, base_channels):
 
 
 if __name__ == "__main__":
+    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -48,4 +48,7 @@ def main(experiment, epochs, steps, image, batch, depth, num_convs, base_channel
 
 
 if __name__ == "__main__":
+    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

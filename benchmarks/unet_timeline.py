@@ -82,4 +82,7 @@ def main(stages, chunks, image, batch, depth, num_convs, base_channels, steps):
 
 
 if __name__ == "__main__":
+    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -57,4 +57,7 @@ def main(experiment, epochs, steps, image, patch, dim, depth, heads,
 
 
 if __name__ == "__main__":
+    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -2,7 +2,10 @@
 
 Launches itself twice (two OS processes, 4 virtual CPU devices each) and
 joins them into ONE global 8-device mesh via ``jax.distributed`` — the
-same topology as two TPU hosts over DCN.  Each process then:
+same topology as two TPU hosts over DCN.  The self-launch is CPU ONLY:
+both ranks pin the CPU backend, because a chip belongs to one process at
+a time and two ranks on one host cannot share it (the launching parent
+never imports jax).  Each process then:
 
 * builds a dp-outermost ``(dp, pp)`` mesh so it owns a whole data slice,
 * feeds ONLY its own rows of the global batch

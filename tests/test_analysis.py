@@ -352,7 +352,7 @@ def test_host_sync_fires_inside_spmd_schedule(cpu_devices):
     # Inside the schedule scan: ERROR severity, anchored into spmd/train.
     assert found and found[0].severity == Severity.ERROR
     assert found[0].path == "spmd/train"
-    assert found[0].primitive == "debug_callback"
+    assert found[0].primitive == "debug_print"
 
 
 def test_host_sync_warns_in_mpmd_stage_program():

@@ -1,0 +1,130 @@
+"""The main path's kernels, compiled for a DESCRIBED TPU v5e.
+
+No chip is attached: the TPU compiler installed beside jax compiles for a
+topology description, so what Mosaic or XLA:TPU would refuse on the chip
+(untileable slices, too much VMEM, a program that does not fit HBM) is
+refused here, on the CPU, at no chip time.  Interpret mode enforces none
+of that.  Shapes are chip_smoke.py's: Mistral-7B widths, bf16.  A compile
+that passes is a compile, not a chip run — nothing executes.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from torchgpipe_tpu.models import generation
+from torchgpipe_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_decode_attention,
+)
+
+H, G, D = 32, 8, 128           # Mistral-7B: query heads, KV heads, head dim
+SEQ, WINDOW, MAX_LEN = 4096, 4096, 4096
+BF16 = jnp.bfloat16
+
+
+def _qkv(s, d=D):
+    return [((1, s, H, d), BF16), ((1, s, G, d), BF16), ((1, s, G, d), BF16)]
+
+
+@pytest.fixture(scope="module")
+def chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    return topo.devices[0]
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    # A described-chip executable is written to the persistent cache but
+    # cannot be read back without a chip: the next run would warn on
+    # every case.  Keep these compiles out of it.
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _flash(s, d=D, grad=False, **kw):
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, **kw)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    return (jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd), _qkv(s, d)
+
+
+def _decode(quant, window):
+    def fn(q, ck, cv, pos0, *scales):
+        ks, vs = scales if quant else (None, None)
+        return flash_decode_attention(
+            q, ck, cv, pos0, window=window, k_scale=ks, v_scale=vs
+        )
+
+    cache = ((1, MAX_LEN, G, D), jnp.int8 if quant else BF16)
+    shapes = [((1, 1, H, D), BF16), cache, cache, ((), jnp.int32)]
+    if quant:
+        shapes += [((1, G, MAX_LEN), jnp.float32)] * 2
+    return fn, shapes
+
+
+def _prefill_attention(s):
+    def fn(q, k, v):
+        return generation._attend_full(q, k, v, WINDOW)
+
+    return fn, _qkv(s)
+
+
+# name -> (fn, argument (shape, dtype)s, kernel expected in the executable)
+CASES = {
+    "flash-fwd": (*_flash(SEQ, window=WINDOW), True),
+    "flash-bwd-resident": (
+        *_flash(SEQ, grad=True, streaming=False, window=WINDOW), True),
+    "flash-bwd-streaming": (
+        *_flash(SEQ, grad=True, streaming=True, window=WINDOW), True),
+    # A window shorter than the sequence: the banded block ranges.
+    "flash-bwd-windowed": (*_flash(SEQ, grad=True, window=1024), True),
+    "flash-head64-padded-2048": (*_flash(2048, d=64, grad=True), True),
+    "decode-bf16": (*_decode(False, None), True),
+    "decode-bf16-window": (*_decode(False, WINDOW), True),
+    "decode-int8": (*_decode(True, None), True),
+    "decode-int8-window": (*_decode(True, WINDOW), True),
+    # prefill()/generate(): a prompt the 128-blocks do not divide must
+    # take the dense path (100 was refused by Mosaic, 200 compiled to a
+    # short grid that left the tail rows unwritten), an aligned one the
+    # kernel.
+    "prefill-attention-100": (*_prefill_attention(100), False),
+    "prefill-attention-200": (*_prefill_attention(200), False),
+    "prefill-attention-256": (*_prefill_attention(256), True),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_compiles_for_v5e(name, chip, monkeypatch):
+    fn, shapes, wants_kernel = CASES[name]
+    # generation.py asks jax.devices() for the platform; a described-chip
+    # compile still sees the CPU there, so the test answers for it.
+    monkeypatch.setattr(jax, "devices", lambda *a: [chip])
+    where = SingleDeviceSharding(chip)
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=where)
+        for shape, dtype in shapes
+    ]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert ("tpu_custom_call" in text) == wants_kernel

@@ -475,7 +475,7 @@ def test_classify_error():
     assert classify_error(TimeoutError("x")) == "transient"
     assert classify_error(ValueError("x")) == "fatal"
     assert classify_error(PeerDiedError(2, "w2")) == "fatal"
-    from jaxlib.xla_extension import XlaRuntimeError
+    from jax.errors import JaxRuntimeError as XlaRuntimeError
 
     assert classify_error(
         XlaRuntimeError("RESOURCE_EXHAUSTED: out of memory")

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from torchgpipe_tpu.spmd import shard_map_compat as shard_map
+from torchgpipe_tpu.spmd import _shard_map as shard_map
 from torchgpipe_tpu.parallel import full_attention, ring_attention
 from torchgpipe_tpu.spmd import SpmdGPipe, make_mesh
 from torchgpipe_tpu.models.transformer import (

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from torchgpipe_tpu import SpmdGPipe, make_mesh
@@ -257,7 +257,7 @@ def test_comm_bytes_allreduce_ring_model():
     def f(x):
         return shard_map(
             lambda v: lax.psum(v, "dp"),
-            mesh=AbstractMesh((("dp", 4),)),
+            mesh=AbstractMesh((4,), ("dp",)),
             in_specs=P(), out_specs=P(),
         )(x)
 
@@ -273,7 +273,7 @@ def test_comm_bytes_allreduce_ring_model():
 
 
 def test_comm_bytes_collectives_and_loop_structure():
-    mesh = AbstractMesh((("sp", 4),))
+    mesh = AbstractMesh((4,), ("sp",))
 
     def ring(x):
         def body(c, _):
@@ -286,7 +286,7 @@ def test_comm_bytes_collectives_and_loop_structure():
     def f(x):
         return shard_map(
             ring, mesh=mesh, in_specs=P(), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(x)
 
     x = jnp.zeros((4, 8), jnp.float32)  # 128 bytes
@@ -302,7 +302,7 @@ def test_comm_bytes_collectives_and_loop_structure():
         def branch_a(v):
             return shard_map(
                 gather, mesh=mesh, in_specs=P("sp"), out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )(v)
 
         return lax.cond(pred, branch_a, lambda v: v, x)
@@ -314,7 +314,7 @@ def test_comm_bytes_collectives_and_loop_structure():
 
 
 def test_eqn_comm_bytes_reduce_scatter_and_all_to_all():
-    mesh = AbstractMesh((("tp", 4),))
+    mesh = AbstractMesh((4,), ("tp",))
 
     def f(x):
         return shard_map(

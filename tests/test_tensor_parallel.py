@@ -14,7 +14,7 @@ import pytest
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from torchgpipe_tpu.spmd import shard_map_compat as shard_map
+from torchgpipe_tpu.spmd import _shard_map as shard_map
 from torchgpipe_tpu.models.transformer import (
     TransformerConfig,
     cross_entropy,

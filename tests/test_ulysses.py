@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from torchgpipe_tpu.spmd import shard_map_compat as shard_map
+from torchgpipe_tpu.spmd import _shard_map as shard_map
 from torchgpipe_tpu.models.transformer import (
     TransformerConfig,
     cross_entropy,

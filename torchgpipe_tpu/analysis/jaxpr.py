@@ -68,6 +68,7 @@ REDUCING_COLLECTIVE_PRIMS = tuple(
 # Python host, serializing the device stream.
 HOST_CALLBACK_PRIMS = (
     "debug_callback",
+    "debug_print",  # what jax.debug.print binds in jax 0.9
     "pure_callback",
     "io_callback",
     "host_callback",

@@ -446,13 +446,8 @@ def _check_host_sync(trace: PipelineTrace) -> List[Finding]:
 
 def _dce(closed: Any) -> Optional[Tuple[Any, List[bool]]]:
     """jax's own recursive DCE: (pruned jaxpr, per-invar used mask)."""
-    try:
-        from jax._src.interpreters import partial_eval as pe
-    except Exception:  # pragma: no cover - version fallback
-        try:
-            from jax.interpreters import partial_eval as pe
-        except Exception:
-            return None
+    from jax._src.interpreters import partial_eval as pe
+
     try:
         return pe.dce_jaxpr(
             closed.jaxpr, [True] * len(closed.jaxpr.outvars)

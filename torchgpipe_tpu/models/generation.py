@@ -840,9 +840,12 @@ def _attend_full(
     MXU-friendly pass instead of s cache reads).
 
     ``use_flash=None`` auto-dispatches the Pallas flash kernel on TPU
-    (O(block²) score memory — the long-prompt prefill path) and the
-    dense einsum elsewhere; pass True/False to force (True off-TPU runs
-    the kernel in interpret mode — for tests).  ``seg`` folds the
+    (O(block²) score memory — the long-prompt prefill path) where the
+    shapes meet its tiling (``ops.flash_attention.supports``: the
+    kernel's grid is ``s // block``, so a prompt its blocks do not
+    divide takes the dense einsum, as it does off-TPU); pass True/False
+    to force (True off-TPU runs the kernel in interpret mode — for
+    tests; True at an undivided length raises).  ``seg`` folds the
     sequence-packing block-diagonal term (``seg[i] == seg[j]``) into the
     causal mask — dense path only (the flash kernel has no segment
     hook), mirroring the training path's didactic fallback."""
@@ -856,7 +859,9 @@ def _attend_full(
             )
         use_flash = False
     if use_flash is None:
-        use_flash = on_tpu
+        from torchgpipe_tpu.ops.flash_attention import supports
+
+        use_flash = on_tpu and supports(q.shape, k.shape)
     if use_flash:
         from torchgpipe_tpu.ops.flash_attention import flash_attention
 

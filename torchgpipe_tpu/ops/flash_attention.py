@@ -974,6 +974,14 @@ def flash_attention(
     g = k.shape[2]
     sm_scale = d ** -0.5 if sm_scale is None else sm_scale
     _validate_window(causal, window)
+    if s % block_q or k.shape[1] % block_k:
+        # The grids are ``s // block``: a remainder would leave the tail
+        # rows unwritten (garbage out, no error from the kernel).
+        raise ValueError(
+            f"flash_attention requires sequence lengths divisible by "
+            f"the block sizes (got q {s} / block_q {block_q}, k "
+            f"{k.shape[1]} / block_k {block_k}); see supports()"
+        )
     d_pad = (-d) % 128
     if d_pad:
         if d > 128:
@@ -1004,8 +1012,7 @@ def flash_attention(
     vr = jnp.transpose(v, (0, 2, 1, 3)).reshape(b * g, v.shape[1], d)
     o = _flash(
         qr, kr, vr, h, g, causal, sm_scale,
-        (min(block_q, s), min(block_k, k.shape[1])), interpret, streaming,
-        window,
+        (block_q, block_k), interpret, streaming, window,
     )
     o = jnp.transpose(o.reshape(b, h, s, d), (0, 2, 1, 3))
     return o[..., : d - d_pad] if d_pad else o

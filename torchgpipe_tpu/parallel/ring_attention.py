@@ -22,7 +22,9 @@ backwards around the ring automatically.
 
 from __future__ import annotations
 
-from typing import Any, Optional
+import contextlib
+import os
+from typing import Any, Iterator, Optional
 
 import jax
 import jax.numpy as jnp
@@ -262,6 +264,24 @@ def axis_bound(name: Optional[str]) -> bool:
     return True
 
 
+@contextlib.contextmanager
+def dense_attention_only() -> Iterator[None]:
+    """Pin :func:`attention` to the dense path for programs TRACED inside
+    the context (it reads ``TGPU_DISABLE_FLASH`` at trace time, and a
+    compiled program keeps its branch): reference programs, and lowerings
+    a Pallas kernel cannot serve — a CPU-targeted lowering on a TPU host,
+    an HLO cost analysis (a custom call counts as zero FLOPs)."""
+    before = os.environ.get("TGPU_DISABLE_FLASH")
+    os.environ["TGPU_DISABLE_FLASH"] = "1"
+    try:
+        yield
+    finally:
+        if before is None:
+            del os.environ["TGPU_DISABLE_FLASH"]
+        else:
+            os.environ["TGPU_DISABLE_FLASH"] = before
+
+
 def attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -309,8 +329,6 @@ def attention(
             seg=seg,
         )
     if not axis_bound(axis_name):
-        import os
-
         from torchgpipe_tpu.ops import flash_attention as _fa
 
         dense = lambda q, k, v: full_attention(  # noqa: E731
@@ -329,28 +347,37 @@ def attention(
                 or q.shape[1] >= _fa.PADDED_HEAD_MIN_SEQ
             )
         ):
-            # Resolved at RUN time by platform_index: TPU executes the
-            # kernel branch, everything else the dense branch.  The
-            # kernel is traced with interpret=True on non-TPU hosts —
-            # this jax lowers EVERY platform_dependent branch for the
-            # current platform, and Mosaic has no CPU lowering, so the
-            # compiled-kernel spelling would break CPU lowering outright
-            # (the interpret spelling lowers everywhere and is dead code
-            # at runtime off-TPU).  Net effect: the training jaxpr
-            # carries the real pallas_call on every host — statically
-            # checkable on CPU — while only TPU lowering emits Mosaic.
-            # Known hole (pre-existing on this jax, either spelling): a
+            def flash(q, k, v, interpret):
+                return _fa.flash_attention(
+                    q, k, v, causal=causal, sm_scale=sm_scale,
+                    window=window, interpret=interpret,
+                )
+
+            if jax.default_backend() == "tpu":
+                # On a TPU host the kernel is chosen at TRACE time, not
+                # by platform_dependent: a cond's partial evaluation
+                # makes the vjp carry EVERY branch's residuals
+                # (zero-filled for the branch not taken), and the MPMD
+                # engine's forward and backward are separate programs,
+                # so nothing can prune them — the dense branch's
+                # [b, h, s, s] f32 scores were allocated per block per
+                # stored micro-batch (2 GiB each at 32 heads x 4096,
+                # RESOURCE_EXHAUSTED on the chip).
+                return flash(q, k, v, False)
+            # Off-TPU hosts: resolved at RUN time by platform_index,
+            # which executes the dense branch.  The kernel branch is
+            # traced with interpret=True — this jax lowers EVERY
+            # platform_dependent branch for the current platform, and
+            # Mosaic has no CPU lowering — so the training jaxpr carries
+            # the real pallas_call on every host (statically checkable
+            # on CPU) and is dead code at runtime.  Known hole: a
             # CPU-TARGETED lowering on a TPU-backend host (CPU oracle
-            # under jax.default_device(cpu)) still lowers the Mosaic
-            # branch for CPU and fails — run such oracles under
+            # under jax.default_device(cpu)) lowers the Mosaic kernel
+            # for CPU and fails — run such oracles under
             # TGPU_DISABLE_FLASH=1.
-            interpret = jax.default_backend() != "tpu"
             return lax.platform_dependent(
                 q, k, v,
-                tpu=lambda q, k, v: _fa.flash_attention(
-                    q, k, v, causal=causal, sm_scale=sm_scale, window=window,
-                    interpret=interpret,
-                ),
+                tpu=lambda q, k, v: flash(q, k, v, True),
                 default=dense,
             )
         return dense(q, k, v)

@@ -88,9 +88,7 @@ def classify_error(err: BaseException) -> str:
         return "fatal"
     if isinstance(err, (ConnectionError, TimeoutError)):
         return "transient"
-    if type(err).__name__ == "XlaRuntimeError" or isinstance(
-        err, jax.errors.JaxRuntimeError
-    ):
+    if isinstance(err, jax.errors.JaxRuntimeError):
         msg = str(err)
         if any(code in msg for code in _TRANSIENT_XLA_CODES):
             return "transient"

@@ -353,28 +353,15 @@ def _classify_bwd_recv(
     return tc * S + pi % S, valid
 
 
-def shard_map_compat(
+def _shard_map(
     fn: Callable, mesh: Mesh, in_specs: Any, out_specs: Any
 ) -> Callable:
-    """``jax.shard_map`` across jax versions: the top-level spelling with
-    ``check_vma`` (0.5+), falling back to ``jax.experimental.shard_map``
-    with ``check_rep`` (0.4.x).  Replication checking is disabled either
-    way — the engines' ring programs are intentionally lane-varying."""
-    try:
-        sm = jax.shard_map
-    except AttributeError:  # pre-0.5 jax: experimental spelling only
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    except TypeError:  # older jax spelling
-        return sm(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
-
-
-_shard_map = shard_map_compat
+    """``jax.shard_map`` with replication checking off — the engines'
+    ring programs are intentionally lane-varying."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 @dataclasses.dataclass
@@ -3629,7 +3616,7 @@ class SpmdGPipe:
         param_specs, state_specs, local_init, _ = self._zero_machinery(
             optimizer, params
         )
-        fn = shard_map_compat(
+        fn = _shard_map(
             local_init, self.mesh,
             in_specs=(param_specs,), out_specs=state_specs,
         )
@@ -3806,7 +3793,7 @@ class SpmdGPipe:
             pspecs, sspecs, _, local_update = self._zero_machinery(
                 optimizer, params
             )
-            fn = shard_map_compat(
+            fn = _shard_map(
                 local_update, self.mesh,
                 in_specs=(pspecs, pspecs, sspecs),
                 out_specs=(pspecs, sspecs),

@@ -47,9 +47,9 @@ def host_device() -> Any:
     when unavailable).
 
     Used by the engines' ``init``: initialization is hundreds of tiny ops
-    (one per weight); dispatching each through an accelerator round-trip
-    dominates start-up on remote-attached TPUs, so init on host, then
-    transfer placed pytrees once.
+    (one per weight), each a separate dispatch and most a separate tiny
+    compile on the accelerator, so init on host, then transfer placed
+    pytrees once.
     """
     import contextlib
 
