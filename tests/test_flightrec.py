@@ -154,27 +154,45 @@ def test_mailbox_records_arrivals_with_depth():
 
 
 def test_watchdog_flags_silence_then_clears(tmp_path):
-    rec = FlightRecorder(rank=0, worker="w0",
+    """The alarm's tick, driven with an injected clock: no thread and no
+    sleeping, so a loaded worker cannot miss the clear before the
+    silence returns."""
+    now = [100.0]
+    rec = FlightRecorder(rank=0, worker="w0", clock=lambda: now[0],
                          dump_path=str(tmp_path / "wd.json"))
     rec.record("forward_begin")
     reg = MetricsRegistry()
-    with StallWatchdog(rec, timeout=0.15, poll=0.03, registry=reg) as wd:
-        deadline = time.monotonic() + 5.0
-        while not wd.stalled and time.monotonic() < deadline:
-            time.sleep(0.03)
-        assert wd.stalled
-        assert reg.get("hang_suspected").value(rank="0") == 1.0
-        # The dump fired and carries the watchdog's own evidence (which
-        # must NOT have reset the silence it measured).
-        d = load_dump(str(tmp_path / "wd.json"))
-        assert any(e.kind == "stall_suspected" for e in d.events)
-        # Activity resumes -> the gauge clears.
-        rec.record("fwd", stage=0, mb=0, dur=0.001)
-        deadline = time.monotonic() + 5.0
-        while wd.stalled and time.monotonic() < deadline:
-            time.sleep(0.03)
-        assert not wd.stalled
-        assert reg.get("hang_suspected").value(rank="0") == 0.0
+    stalls = []
+    wd = StallWatchdog(rec, timeout=0.15, poll=0.03, registry=reg,
+                       on_stall=stalls.append)
+    now[0] += 0.1
+    wd._tick()
+    assert not wd.stalled                       # inside the timeout
+    now[0] += 0.1
+    wd._tick()
+    assert wd.stalled and stalls == [pytest.approx(0.2)]
+    assert reg.get("hang_suspected").value(rank="0") == 1.0
+    # The dump fired and carries the watchdog's own evidence (which
+    # must NOT have reset the silence it measured).
+    d = load_dump(str(tmp_path / "wd.json"))
+    assert any(e.kind == "stall_suspected" for e in d.events)
+    now[0] += 1.0
+    wd._tick()
+    assert wd.stalled and len(stalls) == 1      # once per episode
+    # Activity resumes -> the gauge clears.
+    rec.record("fwd", stage=0, mb=0, dur=0.001)
+    wd._tick()
+    assert not wd.stalled
+    assert reg.get("hang_suspected").value(rank="0") == 0.0
+    assert [e.kind for e in rec.events()][-2:] == ["fwd", "stall_cleared"]
+
+
+def test_watchdog_thread_starts_and_stops(tmp_path):
+    rec = FlightRecorder(rank=0, worker="w0")
+    with StallWatchdog(rec, timeout=30.0, poll=0.01) as wd:
+        assert wd._thread is not None and wd._thread.is_alive()
+        thread = wd._thread
+    assert wd._thread is None and not thread.is_alive()
 
 
 def test_preemption_hook_dumps_the_ring(tmp_path):

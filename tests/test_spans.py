@@ -1,0 +1,299 @@
+"""The span spine (``utils.tracing.Timeline.span``) and what records into it:
+the spans inside ``Engine.step``, the ``step`` span of the SPMD train step
+with the schedule it was built with, the named scopes of the compiled step
+and the names of the flash kernels."""
+
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from torchgpipe_tpu.layers import chain, sequential_init
+from torchgpipe_tpu.models.transformer import TransformerConfig, llama
+from torchgpipe_tpu.ops import dense, layer_norm
+from torchgpipe_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_decode_attention,
+)
+from torchgpipe_tpu.parallel import interleaved, zerobubble
+from torchgpipe_tpu.serving import Engine
+from torchgpipe_tpu.spmd import SpmdGPipe, make_mesh, schedule_shape
+from torchgpipe_tpu.utils.tracing import Timeline, default_timeline
+
+CFG = TransformerConfig(vocab=64, dim=32, n_layers=2, n_heads=4, n_kv_heads=2)
+ACTIONS = ("engine.prefill", "engine.decode")
+
+
+def mse(y, t):
+    return jnp.mean((y - t) ** 2)
+
+
+@pytest.fixture(scope="module")
+def flat_params():
+    params, _, _ = sequential_init(
+        llama(CFG), jax.random.PRNGKey(0),
+        jax.ShapeDtypeStruct((2, 8), jnp.int32),
+    )
+    return params
+
+
+def _engine(flat_params):
+    """A toy engine recording into a timeline of its own."""
+    eng = Engine(CFG, flat_params, num_slots=4, max_len=32, prefill_chunk=4)
+    eng.timeline = Timeline()
+    return eng
+
+
+def _serve(eng, n=6, seed=0):
+    rng = np.random.RandomState(seed)
+    for _ in range(n):
+        prompt = rng.randint(0, 64, (int(rng.randint(2, 10)),))
+        eng.submit(prompt.astype(np.int32), int(rng.randint(2, 6)))
+    assert eng.run() == "idle"
+    return list(eng.timeline.events)
+
+
+# --------------------------------------------------------------------- #
+# the spine                                                             #
+# --------------------------------------------------------------------- #
+
+
+def test_span_records_parent_sequence_and_fields():
+    tl = Timeline()
+    with tl.span("outer", stage=2, rows=3) as outer:
+        with tl.span("inner"):
+            tl.annotate(tokens=5)
+        tl.annotate(g=4)
+    inner, got = tl.events            # children close, and land, first
+    assert (got.name, got.stage, got.seq, got.parent) == ("outer", 2, 0, -1)
+    assert got.fields == {"rows": 3, "g": 4} and outer.seq == 0
+    assert (inner.seq, inner.parent, inner.fields) == (1, 0, {"tokens": 5})
+    assert got.t_start <= inner.t_start <= inner.t_end <= got.t_end
+    tl.annotate(lost=1)               # outside any span: a no-op
+    with tl.span("gone") as gone:
+        gone.drop()
+    assert [e.name for e in tl.events] == ["inner", "outer"]
+
+
+def test_bounded_timeline_reports_a_wrap():
+    tl = Timeline(capacity=4)
+    for i in range(3):
+        with tl.span(f"s{i}"):
+            pass
+    assert [e.seq for e in tl.since(0)] == [0, 1, 2]      # not wrapped yet
+    for i in range(3, 7):
+        with tl.span(f"s{i}"):
+            pass
+    assert len(tl.events) == 4 and tl.events[0].seq == 3
+    assert tl.since(2) is None        # seq 2 was pushed out: say so
+    assert [e.seq for e in tl.since(3)] == [3, 4, 5, 6]
+    tl.reset()
+    assert tl.since(0) == []
+
+
+def test_span_lands_in_the_profilers_trace(tmp_path):
+    """Under ``jax.profiler.start_trace`` the span is written into the
+    profile under its own name, on the profiler's clock."""
+    tl = Timeline()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tl.span("spine.probe", rows=3):
+            jnp.ones((8, 8)).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True
+    )
+    data = jax.profiler.ProfileData.from_file(path)
+    found = [
+        e for plane in data.planes for line in plane.lines
+        for e in line.events if e.name == "spine.probe"
+    ]
+    assert len(found) == 1 and found[0].duration_ns > 0
+    assert dict(found[0].stats).get("rows") in (3, "3")
+
+
+def test_default_timeline_is_one_bounded_ring():
+    assert default_timeline() is default_timeline()
+    assert default_timeline().capacity >= 5 * 7 * 1000    # 44 s at 5 x 22 steps/s
+
+
+# --------------------------------------------------------------------- #
+# the serving engine                                                    #
+# --------------------------------------------------------------------- #
+
+
+def test_engine_step_spans_cover_the_step(flat_params):
+    events = _serve(_engine(flat_params))
+    by_seq = {e.seq: e for e in events}
+    children = {}
+    for e in events:
+        children.setdefault(e.parent, []).append(e)
+    steps = [e for e in events if e.name == "engine.step"]
+    assert len(steps) > 6 and all(e.parent == -1 for e in steps)
+    seen = set()
+    for step in steps:
+        admit, action = sorted(children[step.seq], key=lambda e: e.seq)
+        assert admit.name == "engine.admit" and "admitted" in admit.fields
+        assert action.name in ACTIONS and action.fields["rows"] >= 1
+        assert ("g" in action.fields) == (action.name == "engine.prefill")
+        leaves = [e.name for e in sorted(children[action.seq],
+                                         key=lambda e: e.seq)]
+        assert [n for n in leaves if n != "engine.fetch"] == [
+            "engine.build", "engine.dispatch", "engine.emit"]
+        emit = children[action.seq][-1]
+        # A decode step always fetches; a prefill step only where a
+        # prompt completes (and emits its first token).
+        fetched = "engine.fetch" in leaves
+        assert fetched == (action.name == "engine.decode"
+                           or emit.fields["tokens"] > 0)
+        seen.add(action.name)
+    assert seen == set(ACTIONS)
+    for e in events:                  # every child lies inside its parent
+        if e.parent != -1:
+            p = by_seq[e.parent]
+            assert p.t_start <= e.t_start <= e.t_end <= p.t_end
+            assert p.seq < e.seq
+    assert sum(e.fields["admitted"] for e in events
+               if e.name == "engine.admit") == 6
+
+
+@pytest.mark.parametrize("action", ACTIONS)
+def test_action_span_is_open_before_its_program_is_called(flat_params, action):
+    eng = _engine(flat_params)
+    fns = eng._prefill_fns if action == "engine.prefill" else None
+    inner = (next(iter(fns.values())) if fns else eng._decode_fn)
+
+    def probed(*args):
+        with eng.timeline.span("probe"):
+            return inner(*args)
+
+    if fns:
+        fns[next(iter(fns))] = probed
+    else:
+        eng._decode_fn = probed
+    events = _serve(eng, n=2)
+    by_seq = {e.seq: e for e in events}
+    probes = [e for e in events if e.name == "probe"]
+    assert probes
+    for probe in probes:
+        dispatch = by_seq[probe.parent]
+        assert dispatch.name == "engine.dispatch"
+        assert by_seq[dispatch.parent].name == action
+
+
+def test_idle_iteration_records_nothing(flat_params):
+    eng = _engine(flat_params)
+    assert eng.step() is False
+    assert list(eng.timeline.events) == []
+
+
+def test_engine_records_into_the_default_timeline(flat_params):
+    eng = Engine(CFG, flat_params, num_slots=4, max_len=32, prefill_chunk=4)
+    assert eng.timeline is default_timeline()
+
+
+# --------------------------------------------------------------------- #
+# the SPMD train step                                                   #
+# --------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize("schedule,v,ticks,busy", [
+    ("fill_drain", 1, 11, 32),
+    ("1f1b", 1, 22, 64),
+    ("zb", 1, zerobubble.zero_bubble_tables(4, 8).ticks, 3 * 32),
+    ("interleaved", 2, interleaved.interleaved_tables(4, 8, 2).ticks,
+     2 * 2 * 32),
+])
+def test_schedule_shape_counts_4_stages_8_chunks(schedule, v, ticks, busy):
+    """Busy slots are the schedule's cells (forward, backward and zb's W,
+    per virtual stage), the rest of stages x ticks is the bubble."""
+    assert schedule_shape(schedule, 4, 8, v) == {
+        "schedule": schedule, "ticks": ticks, "stage_ticks": 4 * ticks,
+        "busy_stage_ticks": busy,
+    }
+
+
+def test_schedule_shape_agrees_with_the_tables_own_bubble():
+    zb = zerobubble.zero_bubble_tables(4, 8)
+    shape = schedule_shape("zb", 4, 8)
+    assert shape["stage_ticks"] - shape["busy_stage_ticks"] == zb.bubble_ticks
+    il = interleaved.interleaved_tables(4, 8, 2)
+    shape = schedule_shape("interleaved", 4, 8, 2)
+    assert (shape["stage_ticks"] - shape["busy_stage_ticks"]
+            == 4 * il.bubble_ticks)
+
+
+def _pipe(cpu_devices, schedule, n, m, **kw):
+    block = chain([layer_norm(name="ln"), dense(16, name="fc")], name="blk")
+    mesh = make_mesh(n, 1, devices=cpu_devices[:n])
+    return SpmdGPipe(block, n, mesh, chunks=m, loss_fn=mse,
+                     checkpoint="always", schedule=schedule, **kw)
+
+
+@pytest.mark.parametrize("schedule,n,m", [("fill_drain", 4, 8), ("1f1b", 2, 4)])
+def test_step_span_carries_the_schedule_it_was_built_with(
+    cpu_devices, schedule, n, m
+):
+    pipe = _pipe(cpu_devices, schedule, n, m)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2 * m, 16))
+    params = pipe.init(jax.random.PRNGKey(1), x)
+    opt = optax.sgd(1e-2)
+    step = pipe.make_train_step(opt, donate=False)
+    before = len(default_timeline().events)
+    step(params, pipe.place_tree(opt.init(params)), x, x)
+    span = default_timeline().events[-1]      # no tracer: the default ring
+    assert len(default_timeline().events) == before + 1
+    assert span.name == "step" and span.stage == -1 and span.duration > 0
+    assert span.fields == schedule_shape(schedule, n, m)
+    if schedule == "fill_drain":
+        assert (span.fields["stage_ticks"],
+                span.fields["busy_stage_ticks"]) == (44, 32)
+
+
+@pytest.mark.parametrize("schedule", ["fill_drain", "1f1b"])
+def test_compiled_step_names_its_parts(cpu_devices, schedule):
+    pipe = _pipe(cpu_devices, schedule, 2, 2, tracer=Timeline())
+    x = jax.random.normal(jax.random.PRNGKey(0), (4, 16))
+    params = pipe.init(jax.random.PRNGKey(1), x)
+    opt = optax.sgd(1e-2)
+    step = pipe.make_train_step(opt, donate=False)
+    jaxpr = jax.make_jaxpr(step)(params, pipe.place_tree(opt.init(params)), x, x)
+    text = jaxpr.pretty_print(name_stack=True)
+    for scope in ("forward", "backward", "optimizer", "tick"):
+        assert scope in text, scope
+
+
+# --------------------------------------------------------------------- #
+# the kernels                                                           #
+# --------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize("streaming,names", [
+    (False, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
+    (True, ("flash_fwd_stream", "flash_bwd_dq_stream", "flash_bwd_dkv_stream")),
+])
+def test_flash_kernels_carry_their_names(streaming, names):
+    q = jnp.ones((1, 64, 2, 16), jnp.float32)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, interpret=True,
+                                       block_q=32, block_k=32,
+                                       streaming=streaming))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
+    for name in names:
+        assert f"name={name}\n" in text or f"name={name} " in text, name
+
+
+def test_decode_kernel_carries_its_name():
+    q = jnp.ones((1, 1, 2, 128), jnp.float32)
+    cache = jnp.ones((1, 256, 1, 128), jnp.float32)
+    text = str(jax.make_jaxpr(
+        lambda p: flash_decode_attention(q, cache, cache, p, interpret=True)
+    )(jnp.int32(3)))
+    assert "name=flash_decode" in text

@@ -14,10 +14,14 @@ layers:
   :class:`~torchgpipe_tpu.resilience.guard.GuardStats` are re-based on
   it (public APIs unchanged).
 * **Trace spine** — :class:`~torchgpipe_tpu.utils.tracing.Timeline`
-  records per-cell spans in the MPMD engine and scan-granularity
+  records per-cell spans in the MPMD engine, the ``engine.step`` span
+  tree of :class:`~torchgpipe_tpu.serving.Engine` and scan-granularity
   ``step``/``megastep`` spans in :class:`~torchgpipe_tpu.spmd.SpmdGPipe`
   (compiled scan bodies are not host-visible; the honest granularity is
-  the dispatch, with :func:`device_trace` for the XLA interior);
+  the dispatch).  ``Timeline.span`` also opens a profiler annotation, so
+  under :func:`device_trace` the spans sit beside the device's lines,
+  whose operations carry the ``forward``/``backward``/``optimizer``/
+  ``tick`` scopes and the flash kernels' names;
   :func:`overlay_chrome_trace` exports measured-vs-predicted Perfetto
   traces keyed by event-graph node ids ``(stage, micro_batch, phase)``.
 * **Reconciliation** (:func:`reconcile`) — maps measured spans onto

@@ -143,6 +143,7 @@ def _flash_fwd_call(
             block_q=block_q, block_k=block_k, seq_k=k.shape[1],
             window=window,
         ),
+        name="flash_fwd",
         out_shape=(
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
@@ -384,6 +385,7 @@ def _flash_fwd_call_stream(
             _fwd_stream_kernel, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, nk=nk, window=window,
         ),
+        name="flash_fwd_stream",
         out_shape=(
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
@@ -772,6 +774,7 @@ def _flash_bwd_stream(
             block_q=block_q, block_k=block_k, nk=sk // block_k,
             window=window,
         ),
+        name="flash_bwd_dq_stream",
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid=(bh, s // block_q, sk // block_k),
         in_specs=[row3, kv3, kv3, row3, row2, row2],
@@ -800,6 +803,7 @@ def _flash_bwd_stream(
             _dkv_stream_kernel, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, nq=nq_s, window=window,
         ),
+        name="flash_bwd_dkv_stream",
         out_shape=(
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
@@ -854,6 +858,7 @@ def _flash_bwd_resident(
             _dq_kernel, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, seq_k=sk, window=window,
         ),
+        name="flash_bwd_dq",
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid=(bh, s // block_q),
         in_specs=[row_spec3, kv_spec, kv_spec, row_spec3, row_spec2,
@@ -874,6 +879,7 @@ def _flash_bwd_resident(
             _dkv_kernel, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, seq_q=s, window=window,
         ),
+        name="flash_bwd_dkv",
         out_shape=(
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
@@ -1269,6 +1275,7 @@ def flash_decode_attention(
             _decode_kernel, g=g, r=r, hd=hd, sm_scale=hd ** -0.5,
             block_k=block_k, window=window, quant=quant,
         ),
+        name="flash_decode",
         out_shape=jax.ShapeDtypeStruct((b, g, nh * hd), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,

@@ -64,6 +64,7 @@ from torchgpipe_tpu.serving.scheduler import (
     Scheduler,
     normalize_buckets,
 )
+from torchgpipe_tpu.utils.tracing import default_timeline
 
 Pytree = Any
 
@@ -249,6 +250,13 @@ class Engine:
         # appends: trace-inert (never a traced value, never a program-
         # cache token) and zero-cost when None.
         self.recorder = recorder
+        # The span spine (utils.tracing): every iteration that runs a
+        # program records ``engine.step`` with ``engine.admit`` and the
+        # action (``engine.prefill`` / ``engine.decode``) under it, and
+        # ``engine.build`` / ``dispatch`` / ``fetch`` / ``emit`` under
+        # the action — into the process's bounded default timeline,
+        # always on (docs/observability.md, "The trace spine").
+        self.timeline = default_timeline()
         # Per-request coalescing of decode steps: one ``req_decode``
         # flight event per GROUP (flushed at finish/preempt), not one
         # per token — a 4096-event ring must hold whole requests.
@@ -578,26 +586,27 @@ class Engine:
         unless ``donate=True``, in which case retry is impossible and
         transient errors re-raise immediately)."""
         attempt = 0
-        while True:
-            try:
-                # jit dispatch is ASYNC: a device-execution failure
-                # surfaces on materialization, so block here — letting
-                # it escape to the caller's host fetch would skip the
-                # retry AND commit the failed step's arrays to the pool
-                # first.  Free in practice: the engine host-fetches the
-                # step's tokens immediately anyway.
-                return jax.block_until_ready(fn(*args))
-            except Exception as err:  # noqa: BLE001 — classified below
-                if (
-                    self.donate
-                    or classify_error(err) != "transient"
-                    or attempt >= self.guard_policy.max_retries
-                ):
-                    raise
-                delay = self.guard_policy.backoff(attempt)
-                attempt += 1
-                self.metrics.retries += 1
-                self._sleep(delay)
+        with self.timeline.span("engine.dispatch"):
+            while True:
+                try:
+                    # jit dispatch is ASYNC: a device-execution failure
+                    # surfaces on materialization, so block here —
+                    # letting it escape to the caller's host fetch would
+                    # skip the retry AND commit the failed step's arrays
+                    # to the pool first.  Free in practice: the engine
+                    # host-fetches the step's tokens immediately anyway.
+                    return jax.block_until_ready(fn(*args))
+                except Exception as err:  # noqa: BLE001 — classified below
+                    if (
+                        self.donate
+                        or classify_error(err) != "transient"
+                        or attempt >= self.guard_policy.max_retries
+                    ):
+                        raise
+                    delay = self.guard_policy.backoff(attempt)
+                    attempt += 1
+                    self.metrics.retries += 1
+                    self._sleep(delay)
 
     @property
     def compile_stats(self) -> Dict[str, int]:
@@ -774,32 +783,48 @@ class Engine:
     def step(self) -> bool:
         """ONE engine iteration: admit, pick a phase, run its compiled
         program, emit/evict.  Returns False when idle (nothing ran)."""
-        if not self._draining:
-            if self.qos is not None:
-                self._preempt_for_pressure()
-            if (
-                self._prefix_cache is not None
-                and self.scheduler.queue
-                and self.pool.num_free == 0
-            ):
-                # Admission pressure: evict idle prefix entries (their
-                # pins are the only remaining references) so queued
-                # requests beat cached prefixes to slots.
-                self._prefix_cache.reclaim(
-                    self.pool, len(self.scheduler.queue)
-                )
-            for req in self.scheduler.admit():
-                self.metrics.admitted(req.rid)
-                self._on_admit(req)
-        action = self.scheduler.next_action()
-        if action is None:
-            return False
-        if action == "prefill":
-            self._run_prefill()
-        else:
-            self._run_decode()
-        if self.reporter is not None:
-            self.reporter.step()
+        tl = self.timeline
+        with tl.span("engine.step") as step_span:
+            with tl.span("engine.admit") as admit_span:
+                admitted = 0
+                if not self._draining:
+                    if self.qos is not None:
+                        self._preempt_for_pressure()
+                    if (
+                        self._prefix_cache is not None
+                        and self.scheduler.queue
+                        and self.pool.num_free == 0
+                    ):
+                        # Admission pressure: evict idle prefix entries
+                        # (their pins are the only remaining references)
+                        # so queued requests beat cached prefixes to
+                        # slots.
+                        self._prefix_cache.reclaim(
+                            self.pool, len(self.scheduler.queue)
+                        )
+                    for req in self.scheduler.admit():
+                        self.metrics.admitted(req.rid)
+                        self._on_admit(req)
+                        admitted += 1
+                action = self.scheduler.next_action()
+                if action is None:
+                    # Idle: the timeline holds only iterations that ran
+                    # a program.
+                    admit_span.drop()
+                    step_span.drop()
+                    return False
+                tl.annotate(admitted=admitted)
+            # The action's span opens as soon as the action is known,
+            # before its program runs; ``_run_*`` add ``rows`` (and
+            # ``g``) and record build / dispatch / fetch / emit under it.
+            with tl.span("engine." + action):
+                if action == "prefill":
+                    self._run_prefill()
+                else:
+                    self._run_decode()
+                if self.reporter is not None:
+                    with tl.span("engine.emit"):
+                        self.reporter.step()
         return True
 
     def _preempt_for_pressure(self) -> None:
@@ -911,58 +936,74 @@ class Engine:
                   detail=f"reused={m} donor_slot={donor}")
 
     def _run_prefill(self) -> None:
+        tl = self.timeline
         reqs = self.scheduler.prefill_pending()
         # Ladder admission: the smallest bucket covering this step's
         # largest pending chunk — short prompts dispatch a small program
         # instead of paying the max chunk's FLOPs.
         g = self.scheduler.prefill_bucket()
-        name = self._prefill_names[g]
-        tokens = self._token_buffer(name)
-        n_valid = np.zeros((self.pool.num_slots,), np.int32)
-        takes: List[Tuple[Request, int]] = []
-        for r in reqs:
-            take = min(g, r.prompt_len - r.prefilled)
-            tokens[r.slot, :take] = r.prompt[r.prefilled:r.prefilled + take]
-            n_valid[r.slot] = take
-            takes.append((r, take))
+        tl.annotate(rows=len(reqs), g=g)
+        with tl.span("engine.build"):
+            name = self._prefill_names[g]
+            tokens = self._token_buffer(name)
+            n_valid = np.zeros((self.pool.num_slots,), np.int32)
+            takes: List[Tuple[Request, int]] = []
+            finishing = 0
+            for r in reqs:
+                take = min(g, r.prompt_len - r.prefilled)
+                tokens[r.slot, :take] = (
+                    r.prompt[r.prefilled:r.prefilled + take]
+                )
+                n_valid[r.slot] = take
+                takes.append((r, take))
+                finishing += r.prefilled + take >= r.prompt_len
+            lengths_in = self._lengths_for_step()
+            tokens_dev = jnp.asarray(tokens)
+            n_valid_dev = jnp.asarray(n_valid)
         t0 = self._rec_clock()
         tok, _grid, cache, lengths_dev, key = self._dispatch(
             self._prefill_fns[name], self.params, self.pool.cache,
-            self._lengths_for_step(), jnp.asarray(tokens),
-            jnp.asarray(n_valid), self._key,
+            lengths_in, tokens_dev, n_valid_dev, self._key,
         )
         self.pool.cache = cache
         self._key = key
-        if self.recorder is not None:
-            dur = max(self._rec_clock() - t0, 0.0)
-            for r, take in takes:
-                self._rec("req_prefill", r.rid, dur=dur,
-                          detail=f"g={g} take={take}")
-        # Start the device→host token copy NOW; the per-row bookkeeping
-        # below runs while it is in flight (copy_to_host_async is a hint
-        # — np.asarray below is the one materialization point).
-        _start_host_copy(tok)
+        dur = max(self._rec_clock() - t0, 0.0)
+        if finishing:
+            # Start the device→host token copy NOW; the subclass hook
+            # below runs while it is in flight (copy_to_host_async is a
+            # hint — np.asarray below is the one materialization point).
+            _start_host_copy(tok)
         # Subclass hook: speculative decoding mirrors every prefill
         # chunk into its draft model's cache (same bucket, same buffer)
         # so draft and target stay frontier-aligned.
         self._after_prefill_dispatch(g, tokens, n_valid)
-        self._commit_lengths(lengths_dev, n_valid)
-        self.metrics.step("prefill", len(reqs), self.pool.num_slots)
         tok_host: Optional[np.ndarray] = None
-        for r, take in takes:
-            self.pool.lengths[r.slot] += take
-            r.prefilled += take
-            if r.prefill_done:
-                if self._prefix_cache is not None:
-                    # The slot now holds the full prompt's KV: it
-                    # becomes a donor (the insert pins it via the pool
-                    # refcounts, so recycling waits for eviction).
-                    self._prefix_cache.insert(
-                        r.prompt, r.slot, self.pool
-                    )
-                if tok_host is None:
-                    tok_host = np.asarray(tok)  # ONE host fetch per step
-                self._emit(r, int(tok_host[r.slot]))
+        if finishing:
+            # ONE host fetch per step, and only in a step in which a
+            # prompt completes and samples its first token.
+            with tl.span("engine.fetch"):
+                tok_host = np.asarray(tok)
+        with tl.span("engine.emit", tokens=finishing):
+            if self.recorder is not None:
+                for r, take in takes:
+                    self._rec("req_prefill", r.rid, dur=dur,
+                              detail=f"g={g} take={take}")
+            self._commit_lengths(lengths_dev, n_valid)
+            self.metrics.step("prefill", len(reqs), self.pool.num_slots)
+            for r, take in takes:
+                self.pool.lengths[r.slot] += take
+                r.prefilled += take
+                if r.prefill_done:
+                    if self._prefix_cache is not None:
+                        # The slot now holds the full prompt's KV: it
+                        # becomes a donor (the insert pins it via the
+                        # pool refcounts, so recycling waits for
+                        # eviction).
+                        self._prefix_cache.insert(
+                            r.prompt, r.slot, self.pool
+                        )
+                    assert tok_host is not None
+                    self._emit(r, int(tok_host[r.slot]))
 
     def _after_prefill_dispatch(
         self, g: int, tokens: np.ndarray, n_valid: np.ndarray
@@ -971,36 +1012,42 @@ class Engine:
         teacher-force the same prompt chunk into the draft cache."""
 
     def _run_decode(self) -> None:
+        tl = self.timeline
         reqs = self.scheduler.decode_ready()
-        tokens = self._token_buffer("decode")
-        n_valid = np.zeros((self.pool.num_slots,), np.int32)
-        for r in reqs:
-            tokens[r.slot, 0] = self._cur_tok[r.slot]
-            n_valid[r.slot] = 1
+        tl.annotate(rows=len(reqs))
+        with tl.span("engine.build"):
+            tokens = self._token_buffer("decode")
+            n_valid = np.zeros((self.pool.num_slots,), np.int32)
+            for r in reqs:
+                tokens[r.slot, 0] = self._cur_tok[r.slot]
+                n_valid[r.slot] = 1
+            lengths_in = self._lengths_for_step()
+            tokens_dev = jnp.asarray(tokens)
+            n_valid_dev = jnp.asarray(n_valid)
         t0 = self._rec_clock()
         tok, cache, lengths_dev, key = self._dispatch(
             self._decode_fn, self.params, self.pool.cache,
-            self._lengths_for_step(), jnp.asarray(tokens),
-            jnp.asarray(n_valid), self._key,
+            lengths_in, tokens_dev, n_valid_dev, self._key,
         )
         self.pool.cache = cache
         self._key = key
-        _start_host_copy(tok)           # overlap D2H with the bookkeeping
-        self._commit_lengths(lengths_dev, n_valid)
-        self.metrics.step("decode", len(reqs), self.pool.num_slots)
-        if self.recorder is not None:
-            t1 = self._rec_clock()
+        t1 = self._rec_clock()
+        with tl.span("engine.fetch"):
+            tok_host = np.asarray(tok)      # the ONE host fetch per step
+        with tl.span("engine.emit", tokens=len(reqs)):
+            self._commit_lengths(lengths_dev, n_valid)
+            self.metrics.step("decode", len(reqs), self.pool.num_slots)
+            if self.recorder is not None:
+                for r in reqs:
+                    group = self._decode_groups.get(r.rid)
+                    if group is None:
+                        self._decode_groups[r.rid] = [t0, t1, 1.0]
+                    else:
+                        group[1] = t1
+                        group[2] += 1.0
             for r in reqs:
-                group = self._decode_groups.get(r.rid)
-                if group is None:
-                    self._decode_groups[r.rid] = [t0, t1, 1.0]
-                else:
-                    group[1] = t1
-                    group[2] += 1.0
-        tok_host = np.asarray(tok)      # the ONE host fetch per step
-        for r in reqs:
-            self.pool.lengths[r.slot] += 1
-            self._emit(r, int(tok_host[r.slot]))
+                self.pool.lengths[r.slot] += 1
+                self._emit(r, int(tok_host[r.slot]))
 
     def _emit(self, req: Request, token: int) -> None:
         """Stream one token; per-row termination FREES THE SLOT NOW —

@@ -51,6 +51,7 @@ from torchgpipe_tpu.auxgrad import aux_scale
 from torchgpipe_tpu.layers import Layer, Spec
 from torchgpipe_tpu.parallel.tensor import all_gather_value
 from torchgpipe_tpu.resilience import faults as _faults
+from torchgpipe_tpu.utils.tracing import default_timeline
 
 Pytree = Any
 
@@ -186,6 +187,96 @@ def _interleaved_rows(tb: Any) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
 def _sub_key(base: Optional[jax.Array], i: jax.Array) -> Optional[jax.Array]:
     """Per-micro-batch sub-key, or None when running without rng."""
     return None if base is None else jax.random.fold_in(base, i)
+
+
+def _scoped(name: str, fn: Callable) -> Callable:
+    """``fn`` traced under ``jax.named_scope(name)``.  Metadata only: the
+    scope lands in the ``op_name`` of every operation ``fn`` traces (and,
+    wrapped in ``transpose(jvp(...))``, of its autodiff backward), which
+    is what a reader of the device trace can hold on to — ``forward`` /
+    ``backward`` / ``optimizer`` for a step's parts, ``tick`` for a
+    schedule's scan body."""
+
+    def scoped(*args: Any, **kwargs: Any) -> Any:
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+
+    return scoped
+
+
+# The schedules' validity rules.  Written with operators and ``xp.where``
+# alone, so that the SAME function decides a cell inside the traced tick
+# body (``xp=jnp`` on the traced tick and stage) and on the host, over
+# the whole (tick, stage) grid, for :func:`schedule_shape`.
+
+
+def _fill_drain_cell(t: Any, stage: Any, m: int) -> Tuple[Any, Any]:
+    """Fill-drain: at tick ``t`` stage ``stage`` computes micro-batch
+    ``t - stage``; the cell carries a micro-batch (is not fill or drain
+    garbage) where that index is in ``[0, m)``."""
+    mb = t - stage
+    return mb, (mb >= 0) & (mb < m)
+
+
+def _one_f1b_cell(
+    t: Any, stage: Any, n: int, m: int, xp: Any = jnp
+) -> Tuple[Any, Any, Any, Any]:
+    """1F1B closed form (see ``_build_train_step_1f1b``): ``(do_f, i_f,
+    do_b, i_b)`` — whether the stage runs a forward / backward cell at
+    tick ``t`` and on which micro-batch (clipped into range where it
+    runs none)."""
+    tj = t - stage
+    warm = (tj >= 0) & (tj <= n - 1 - stage) & (tj < m)
+    i_s = xp.where(tj >= 0, tj // 2, 0)
+    steady = (
+        (tj >= 0) & (tj % 2 == 0) & (i_s > n - 1 - stage) & (i_s < m)
+    )
+    i_f = xp.clip(xp.where(warm, tj, i_s), 0, m - 1)
+    num = t + stage - (2 * n - 1)
+    do_b = (num >= 0) & (num % 2 == 0) & (num // 2 < m)
+    i_b = xp.clip(xp.where(num >= 0, num // 2, 0), 0, m - 1)
+    return warm | steady, i_f, do_b, i_b
+
+
+def schedule_shape(
+    schedule: str, n_stages: int, chunks: int, virtual_stages: int = 1
+) -> Dict[str, Any]:
+    """What a train step of this schedule is BUILT with: ``ticks`` of its
+    scan, ``stage_ticks`` (stages x ticks) and ``busy_stage_ticks``, those
+    that carry a micro-batch — counted from the rule the tick body itself
+    traces (:func:`_fill_drain_cell`, :func:`_one_f1b_cell`, the ``kind``
+    tables the zb / interleaved scans consume), so a schedule whose masks
+    change changes the count.  ``1 - busy / stage_ticks`` is the bubble as
+    a share of ticks.  Fill-drain counts its forward scan; the backward is
+    that scan's transpose, the same ticks again."""
+    n, m = n_stages, chunks
+    if schedule == "fill_drain":
+        ticks = m + n - 1
+        t, stage = np.arange(ticks)[:, None], np.arange(n)[None, :]
+        busy = _fill_drain_cell(t, stage, m)[1]
+    elif schedule == "1f1b":
+        ticks = 2 * (m + n - 1)
+        t, stage = np.arange(ticks)[:, None], np.arange(n)[None, :]
+        do_f, _, do_b, _ = _one_f1b_cell(t, stage, n, m, xp=np)
+        busy = do_f | do_b
+    elif schedule == "zb":
+        from torchgpipe_tpu.parallel import zerobubble
+
+        tb = zerobubble.zero_bubble_tables(n, m)
+        ticks, busy = tb.ticks, tb.kind != zerobubble.IDLE
+    elif schedule == "interleaved":
+        from torchgpipe_tpu.parallel import interleaved
+
+        tb = interleaved.interleaved_tables(n, m, virtual_stages)
+        ticks, busy = tb.ticks, tb.kind != interleaved.IDLE
+    else:
+        raise ValueError(f"unknown schedule {schedule!r}")
+    return {
+        "schedule": schedule,
+        "ticks": int(ticks),
+        "stage_ticks": int(ticks) * n,
+        "busy_stage_ticks": int(np.count_nonzero(busy)),
+    }
 
 
 def _rule_leaf_specs(spec_tree: Pytree) -> list:
@@ -519,13 +610,17 @@ class SpmdGPipe:
     # plan-drift lint rule compares the running configuration against
     # analysis.planner's certified top plan under it.
     hbm_budget_bytes: Optional[int] = None
-    # Optional runtime timeline (utils.tracing.Timeline — the obs trace
-    # spine).  The compiled scan's cells are not host-visible, so the
-    # HONEST recording granularity is the dispatch: make_train_step's
-    # returned callable records one "step" (K=1) or "megastep" span per
-    # call, at stage -1 (the whole-program row).  With sync=True the
-    # span is true device time (the tracer blocks on the step outputs);
-    # use obs.device_trace for the XLA-level interior of the scan.
+    # Runtime timeline (utils.tracing.Timeline — the obs trace spine);
+    # None records into the process's bounded default timeline.  The
+    # compiled scan's cells are not host-visible, so the HONEST recording
+    # granularity is the dispatch: make_train_step's returned callable
+    # records one "step" (K=1) or "megastep" span per call, at stage -1
+    # (the whole-program row), carrying the schedule it was built with
+    # (:func:`schedule_shape`).  By default the span is what the host
+    # pays to launch the program; with sync=True it is true device time
+    # (the span blocks on the step outputs).  Use obs.device_trace for
+    # the XLA-level interior of the scan: its operations carry the
+    # ``forward`` / ``backward`` / ``optimizer`` / ``tick`` scopes.
     tracer: Any = None
     # Optional user-declared partition-rule table (an ordered
     # analysis.partition_rules.RuleTable or (regex, PartitionSpec)
@@ -1521,8 +1616,8 @@ class SpmdGPipe:
             # gradients (MoE balance) get a runtime scale of 1/m on valid
             # cells and 0 on garbage ones — the scanned schedule then
             # injects exactly mean-over-microbatches like the MPMD engine.
-            mb = t - stage
-            valid_scale = jnp.where((mb >= 0) & (mb < m), 1.0 / m, 0.0)
+            mb, valid = _fill_drain_cell(t, stage, m)
+            valid_scale = jnp.where(valid, 1.0 / m, 0.0)
             plan = _faults.active_plan()
             if plan is not None and plan.nan_at is not None:
                 # Deterministic chaos (resilience.faults): the plan is
@@ -1557,7 +1652,8 @@ class SpmdGPipe:
             # Remat'd prefix: every cell in ticks 0..m-2 is micro-batch
             # < m-1 (or fill garbage).  Zero-length scan (m == 1) is fine.
             act, ys_scan = lax.scan(
-                tick, act0, jnp.arange(m - 1), unroll=self.scan_unroll
+                _scoped("tick", tick), act0, jnp.arange(m - 1),
+                unroll=self.scan_unroll,
             )
 
             # Peeled tail as a SECOND scan (not a Python unroll): the block
@@ -1585,13 +1681,17 @@ class SpmdGPipe:
                 return (ring(y) if send_ahead else y), y
 
             _, ys_tail = lax.scan(
-                tail_tick, act, jnp.arange(m - 1, T), unroll=self.scan_unroll
+                _scoped("tick", tail_tick), act, jnp.arange(m - 1, T),
+                unroll=self.scan_unroll,
             )
             return jax.tree_util.tree_map(
                 lambda a, b: jnp.concatenate([a, b], axis=0), ys_scan, ys_tail
             )
 
-        _, ys = lax.scan(tick, act0, jnp.arange(T), unroll=self.scan_unroll)
+        _, ys = lax.scan(
+            _scoped("tick", tick), act0, jnp.arange(T),
+            unroll=self.scan_unroll,
+        )
         return ys
 
     def _outputs_from_ticks(self, ys: Pytree) -> Pytree:
@@ -1842,20 +1942,7 @@ class SpmdGPipe:
                         lambda a: lax.ppermute(a, self.pp_axis, perm_b),
                         carry["gact"],
                     )
-                tj = t - stage
-                warm = (tj >= 0) & (tj <= n - 1 - stage) & (tj < m)
-                i_s = jnp.where(tj >= 0, tj // 2, 0)
-                steady = (
-                    (tj >= 0)
-                    & (tj % 2 == 0)
-                    & (i_s > n - 1 - stage)
-                    & (i_s < m)
-                )
-                i_f = jnp.clip(jnp.where(warm, tj, i_s), 0, m - 1)
-                do_f = warm | steady
-                num = t + stage - (2 * n - 1)
-                do_b = (num >= 0) & (num % 2 == 0) & (num // 2 < m)
-                i_b = jnp.clip(jnp.where(num >= 0, num // 2, 0), 0, m - 1)
+                do_f, i_f, do_b, i_b = _one_f1b_cell(t, stage, n, m)
 
                 def fwd_store(c):
                     # Stored-vjp forward cell ('never', or 'except_last's
@@ -2019,7 +2106,10 @@ class SpmdGPipe:
 
                 idx = jnp.where(do_f, 0, jnp.where(do_b, 1, 2))
                 carry = lax.switch(
-                    idx, [fwd_branch, bwd_branch, lambda c: c], carry
+                    idx,
+                    [_scoped("forward", fwd_branch),
+                     _scoped("backward", bwd_branch), lambda c: c],
+                    carry,
                 )
                 if send_ahead:
                     # Issue next tick's hand-offs NOW, right after the
@@ -2040,7 +2130,7 @@ class SpmdGPipe:
                 return carry, ()
 
             carry, _ = lax.scan(
-                tick, carry0, jnp.arange(2 * (m + n - 1)),
+                _scoped("tick", tick), carry0, jnp.arange(2 * (m + n - 1)),
                 unroll=self.scan_unroll,
             )
             loss = lax.psum(carry["loss"], self.pp_axis)
@@ -2401,12 +2491,17 @@ class SpmdGPipe:
                     k == ZB_F, 0, jnp.where(k == ZB_B, 1, jnp.where(k == ZB_W, 2, 3))
                 )
                 carry = lax.switch(
-                    sel, [f_branch, b_branch, w_branch, lambda c: c], carry
+                    sel,
+                    [_scoped("forward", f_branch),
+                     _scoped("backward", b_branch),
+                     _scoped("backward_w", w_branch), lambda c: c],
+                    carry,
                 )
                 return carry, ()
 
             carry, _ = lax.scan(
-                tick, carry0, rows_xs, unroll=self.scan_unroll
+                _scoped("tick", tick), carry0, rows_xs,
+                unroll=self.scan_unroll
             )
             loss = lax.psum(carry["loss"], self.pp_axis)
             grads = {"blocks": tmap(lambda g: g[None], carry["gblk"])}
@@ -2855,12 +2950,16 @@ class SpmdGPipe:
 
                 sel = jnp.where(k == FWD, 0, jnp.where(k == BWD, 1, 2))
                 carry = lax.switch(
-                    sel, [fwd_branch, bwd_branch, lambda cr: cr], carry
+                    sel,
+                    [_scoped("forward", fwd_branch),
+                     _scoped("backward", bwd_branch), lambda cr: cr],
+                    carry,
                 )
                 return carry, ()
 
             carry, _ = lax.scan(
-                tick, carry0, rows_xs, unroll=self.scan_unroll
+                _scoped("tick", tick), carry0, rows_xs,
+                unroll=self.scan_unroll
             )
             loss = lax.psum(carry["loss"], self.pp_axis)
             grads = {"blocks": tmap(lambda g: g[None], carry["gblk"])}
@@ -3058,7 +3157,12 @@ class SpmdGPipe:
                 # cross-stage cotangents back along the ring.
                 return jnp.where(stage == n - 1, l, 0.0)
 
-            loss, grads = jax.value_and_grad(loss_of)(params)
+            # value_and_grad, taken apart so that each half has its scope:
+            # the backward's operations read
+            # ``backward/transpose(jvp(forward))/...`` in the trace.
+            loss, vjp_loss = jax.vjp(_scoped("forward", loss_of), params)
+            with jax.named_scope("backward"):
+                (grads,) = vjp_loss(jnp.ones_like(loss))
             loss = lax.psum(loss, self.pp_axis)  # broadcast for reporting
             # pre/post/loss grads land on the consuming stage's lane only;
             # share across pp.  Block grads are per-stage local by
@@ -3622,6 +3726,17 @@ class SpmdGPipe:
         )
         return jax.jit(fn)(params)
 
+    def _timeline(self) -> Any:
+        """Where this pipe's spans go: the ``tracer`` it was given, else
+        the process's default timeline."""
+        return self.tracer if self.tracer is not None else default_timeline()
+
+    def _schedule_shape(self) -> Dict[str, Any]:
+        """The fields of this pipe's ``step`` span."""
+        return schedule_shape(
+            self.schedule, self.n_stages, self.chunks, self.virtual_stages
+        )
+
     def megastep_boundary(self, step: int) -> bool:
         """True when ``step`` completed optimizer steps land on a
         megastep boundary — the cadence checkpoint/preemption hooks run
@@ -3735,7 +3850,10 @@ class SpmdGPipe:
             # plan ends, or vice versa.
             del plan_token
             loss, grads = self.train_step(params, x, target, rng)
-            new_params, new_state = apply_update(params, grads, opt_state)
+            with jax.named_scope("optimizer"):
+                new_params, new_state = apply_update(
+                    params, grads, opt_state
+                )
             return loss, new_params, new_state
 
         compiled = jax.jit(
@@ -3746,6 +3864,7 @@ class SpmdGPipe:
         # The schedule verifier's donation-safety rule reads this to place
         # the donating update event in the step's event graph.
         self._train_step_donate = donate
+        shape = self._schedule_shape()
 
         def step(
             params: Pytree,
@@ -3754,13 +3873,11 @@ class SpmdGPipe:
             target: Pytree,
             rng: Optional[jax.Array] = None,
         ) -> Tuple[jax.Array, Pytree, Pytree]:
-            out = compiled(
-                params, opt_state, x, target, rng, _faults.plan_token()
-            )
-            if self.tracer is not None:
-                # Scan-granularity span (see the ``tracer`` field note).
-                self.tracer.record("step", -1, -1, out)
-            return out
+            # Scan-granularity span (see the ``tracer`` field note).
+            with self._timeline().span("step", **shape) as span:
+                return span.wait(compiled(
+                    params, opt_state, x, target, rng, _faults.plan_token()
+                ))
 
         step.megastep = 1  # type: ignore[attr-defined]
         return step
@@ -3829,7 +3946,8 @@ class SpmdGPipe:
                     jax.random.fold_in(rng, k) if rng is not None else None
                 )
                 loss, grads = self.train_step(p, x_k, tgt_k, key)
-                new_p, new_o = apply_update(p, grads, o)
+                with jax.named_scope("optimizer"):
+                    new_p, new_o = apply_update(p, grads, o)
                 # The in-scan skip-step: cover EXACTLY what StepGuard's
                 # host-side check covers on the K=1 step's output tuple
                 # (loss, new params, new opt state) so megastep(K) is
@@ -3851,6 +3969,7 @@ class SpmdGPipe:
             donate_argnums=(0, 1) if donate else (),
         )
         self._train_step_donate = donate
+        shape = dict(self._schedule_shape(), steps=K)
 
         def step(
             params: Pytree,
@@ -3868,13 +3987,11 @@ class SpmdGPipe:
                         "jnp.stack, or pass megastep=1"
                     )
                 break
-            out = compiled(
-                params, opt_state, x, target, rng, _faults.plan_token()
-            )
-            if self.tracer is not None:
-                # One span per K-step program (scan granularity).
-                self.tracer.record("megastep", -1, -1, out)
-            return out
+            # One span per K-step program (scan granularity).
+            with self._timeline().span("megastep", **shape) as span:
+                return span.wait(compiled(
+                    params, opt_state, x, target, rng, _faults.plan_token()
+                ))
 
         step.megastep = K  # type: ignore[attr-defined]
         return step
@@ -4045,7 +4162,8 @@ class SpmdGPipe:
                 return carry, ()
 
             carry, _ = lax.scan(
-                tick, carry0, rows_xs, unroll=self.scan_unroll
+                _scoped("tick", tick), carry0, rows_xs,
+                unroll=self.scan_unroll
             )
             outs = carry["outs"]
             if with_loss:

@@ -20,33 +20,119 @@ is forced to completion before the next is dispatched, serializing the
 pipeline — measuring how much of the throughput comes from cross-stage
 overlap (the question the reference's unet-timeline experiments answer by
 monkey-patching deps/streams, benchmarks/unet-timeline/main.py:22-75).
+
+The same object is the program's span spine.  ``Timeline.span(name)`` is a
+context manager that opens a ``jax.profiler.TraceAnnotation`` (so the span
+lands in any running profiler session, on the device trace's own clock)
+and on exit appends one event with a sequence number and its parent's.
+``serving.Engine`` and ``SpmdGPipe`` record into :func:`default_timeline`,
+a bounded ring that is always on::
+
+    with tracer.span("engine.step"):
+        with tracer.span("engine.admit"):
+            ...
+            tracer.annotate(admitted=2)
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
+import itertools
+import threading
 import time
-from typing import Any, Callable, Iterator, List, Optional, Tuple
+from typing import (Any, Callable, Deque, Dict, Iterator, List, Optional,
+                    Tuple, Union)
 
 import jax
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(slots=True)
 class TimelineEvent:
-    name: str  # "fwd" | "bwd" | "loss" | ...
+    name: str  # "fwd" | "bwd" | "loss" | "engine.step" | ...
     stage: int
     mbatch: int
     t_start: float
     t_end: float
+    # Spans only (``Timeline.span``); a ``record``-ed cell keeps the
+    # defaults.  ``seq`` counts spans in the order they OPENED, ``parent``
+    # is the ``seq`` of the span open around this one on its thread.
+    seq: int = -1
+    parent: int = -1
+    fields: Optional[Dict[str, Any]] = None
 
     @property
     def duration(self) -> float:
         return self.t_end - self.t_start
 
 
+class _Span:
+    """One open span (see :meth:`Timeline.span`).  A class, not a
+    generator: entering and leaving cost two clock reads, one
+    ``TraceAnnotation`` and one append."""
+
+    __slots__ = ("_tl", "name", "stage", "mbatch", "fields", "seq",
+                 "parent", "_t_start", "_ann", "_late", "_dropped")
+
+    def __init__(self, tl: "Timeline", name: str, stage: int, mbatch: int,
+                 fields: Optional[Dict[str, Any]]) -> None:
+        self._tl = tl
+        self.name = name
+        self.stage = stage
+        self.mbatch = mbatch
+        self.fields = fields
+        self._late: Optional[Dict[str, Any]] = None
+        self._dropped = False
+
+    def __enter__(self) -> "_Span":
+        tl = self._tl
+        stack = tl._stack()
+        self.seq = next(tl._seq)
+        self.parent = stack[-1].seq if stack else -1
+        stack.append(self)
+        # The fields go to the profiler too: TraceMe leaves keyword
+        # arguments unformatted while no session runs (0.2 us for two).
+        self._ann = (jax.profiler.TraceAnnotation(self.name, **self.fields)
+                     if self.fields else
+                     jax.profiler.TraceAnnotation(self.name))
+        self._ann.__enter__()
+        self._t_start = time.perf_counter() - tl._t0
+        return self
+
+    def wait(self, out: Any) -> Any:
+        """Block on ``out`` inside the span where the timeline is
+        ``sync`` (true device time), else return it at once."""
+        if self._tl.sync and out is not None:
+            jax.block_until_ready(out)
+        return out
+
+    def drop(self) -> None:
+        """Leave no event behind (an iteration that ran nothing)."""
+        self._dropped = True
+
+    def __exit__(self, *exc: Any) -> None:
+        tl = self._tl
+        t_end = time.perf_counter() - tl._t0
+        fields = self.fields
+        if self._late:
+            self._ann.set_metadata(**self._late)
+            fields = dict(fields or (), **self._late)
+        self._ann.__exit__(*exc)
+        tl._stack().pop()
+        if not self._dropped:
+            tl._append(TimelineEvent(
+                self.name, self.stage, self.mbatch, self._t_start, t_end,
+                self.seq, self.parent, fields,
+            ))
+
+
 class Timeline:
-    """Per-cell dispatch recorder for the MPMD engine.
+    """The trace spine: per-cell dispatch recorder of the MPMD engine
+    (:meth:`record`) and span recorder of the serving engine and the SPMD
+    train step (:meth:`span`).  One thread records and reads at a time:
+    spans of several threads nest per thread, but reading while another
+    thread appends is not guarded.
 
     With ``sync=False`` (default) the recorded interval is the *dispatch*
     cost (JAX is async; device work overlaps).  With ``sync=True`` each cell
@@ -54,14 +140,66 @@ class Timeline:
     serialized-pipeline ablation baseline.
     """
 
-    def __init__(self, sync: bool = False) -> None:
+    def __init__(self, sync: bool = False,
+                 capacity: Optional[int] = None) -> None:
         self.sync = sync
-        self.events: List[TimelineEvent] = []
-        self._t0 = time.perf_counter()
+        # Unbounded: a list.  ``capacity=N``: a ring of the newest N
+        # events; ``since`` tells a reader whether what it asks for was
+        # pushed out.
+        self.events: Union[List[TimelineEvent], Deque[TimelineEvent]] = (
+            [] if capacity is None else collections.deque(maxlen=capacity)
+        )
+        self.capacity = capacity
+        self._open = threading.local()
+        self.reset()
 
     def reset(self) -> None:
         self.events.clear()
         self._t0 = time.perf_counter()
+        self._seq = itertools.count()
+        self._dropped_seq = -1      # the highest ``seq`` the ring pushed out
+
+    # ------------------------------------------------------------------ #
+    # spans                                                              #
+    # ------------------------------------------------------------------ #
+
+    def span(self, name: str, stage: int = -1, mbatch: int = -1,
+             **fields: Any) -> _Span:
+        """A context manager recording one span: it opens a
+        ``jax.profiler.TraceAnnotation(name, **fields)`` — the span shows
+        in any running profiler session beside the device's lines — and
+        on exit appends one :class:`TimelineEvent` carrying its ``seq``,
+        its ``parent`` (the span open around it on this thread, -1 for
+        none) and ``fields``."""
+        return _Span(self, name, stage, mbatch, fields or None)
+
+    def annotate(self, **fields: Any) -> None:
+        """Add fields to the innermost span open on this thread: what is
+        known only once the work is under way (rows admitted, tokens
+        emitted).  No-op outside a span."""
+        stack = self._stack()
+        if stack:
+            stack[-1]._late = dict(stack[-1]._late or (), **fields)
+
+    def since(self, seq: int) -> Optional[List[TimelineEvent]]:
+        """The spans whose ``seq`` is at least ``seq``, oldest first, or
+        ``None`` where a bounded timeline has pushed one of them out."""
+        if self._dropped_seq >= seq:
+            return None
+        return [e for e in list(self.events) if e.seq >= seq]
+
+    def _stack(self) -> List[_Span]:
+        try:
+            return self._open.stack
+        except AttributeError:
+            self._open.stack = []
+            return self._open.stack
+
+    def _append(self, event: TimelineEvent) -> None:
+        events = self.events
+        if self.capacity is not None and len(events) == self.capacity:
+            self._dropped_seq = max(self._dropped_seq, events[0].seq)
+        events.append(event)
 
     def record(
         self,
@@ -84,7 +222,7 @@ class Timeline:
         if settle > 0.0:
             time.sleep(settle)
         t_end = time.perf_counter() - self._t0
-        self.events.append(TimelineEvent(name, stage, mbatch, t_start, t_end))
+        self._append(TimelineEvent(name, stage, mbatch, t_start, t_end))
         return out
 
     # ------------------------------------------------------------------ #
@@ -159,6 +297,20 @@ class Timeline:
                 f"busy {busy * 1e3:.1f}ms ({100 * busy / total:.0f}%)"
             )
         return "\n".join(lines)
+
+
+# The process-wide default: what ``serving.Engine`` and ``SpmdGPipe`` (with
+# no ``tracer=``) record into.  Always on, so that a step that stalls
+# outside any profiler session still names its phase.  Sized for a 44 s
+# window at five times the measured serving step rate (22 steps/s x 7
+# spans a step, PERF.md section 5): 34k events; about 16 MiB when full.
+DEFAULT_CAPACITY = 65536
+_DEFAULT = Timeline(capacity=DEFAULT_CAPACITY)
+
+
+def default_timeline() -> Timeline:
+    """The one bounded, always-on timeline of this process."""
+    return _DEFAULT
 
 
 @contextlib.contextmanager
