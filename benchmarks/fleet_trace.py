@@ -146,6 +146,8 @@ def _program_cache_sizes(engines: Dict[str, _Engine]) -> Dict[str, int]:
             out[f"{name}/prefix_copy"] = eng._prefix_copy_fn._cache_size()
         for kind, fn in getattr(eng, "_draft_fns", {}).items():
             out[f"{name}/{kind}"] = fn._cache_size()
+        if getattr(eng, "_verify_fn", None) is not None:
+            out[f"{name}/verify"] = eng._verify_fn._cache_size()
     return out
 
 

@@ -77,23 +77,34 @@ def _drive_signatures(
     }
     S = engine.pool.num_slots
 
-    def stub(kind):
-        def fn(params, cache, lengths, tokens, n_valid, key):
-            sigs[kind].add(_signature({
+    def decode_stub(params, cache, lengths, tokens, n_valid, key):
+        sigs["decode"].add(_signature({
+            "cache": cache, "lengths": lengths, "tokens": tokens,
+            "n_valid": n_valid, "key": key,
+        }))
+        # Token 0 for every slot: requests terminate by budget.  Same
+        # output arity as the real body; the engine adopts the advanced
+        # frontiers as its device-resident lengths.
+        return jnp.zeros((S,), jnp.int32), cache, lengths + n_valid, key
+
+    def chunk_stub(kind):
+        # The chunk body's arity (Engine._prefill_body_for): the
+        # compact prefill programs take the rows' ``slots``, the
+        # speculative verify program is called with ``slots=None``.
+        def fn(params, cache, lengths, slots, tokens, n_valid, key):
+            args = {
                 "cache": cache, "lengths": lengths, "tokens": tokens,
                 "n_valid": n_valid, "key": key,
-            }))
-            # Token 0 for every slot: requests terminate by budget.
-            # Same output arity as the real bodies — prefill returns
-            # (tok, per-position grid, cache, advanced lengths, key),
-            # decode (tok, cache, advanced lengths, key); the engine
-            # adopts the advanced frontiers as its device-resident
-            # lengths.
-            tok = jnp.zeros((S,), jnp.int32)
-            if kind == "decode":
-                return tok, cache, lengths + n_valid, key
+            }
+            if slots is not None:
+                args["slots"] = slots
+                lengths = lengths.at[slots].add(n_valid)
+            else:
+                lengths = lengths + n_valid
+            sigs.setdefault(kind, set()).add(_signature(args))
+            tok = jnp.zeros(tokens.shape[:1], jnp.int32)
             grid = jnp.zeros(tokens.shape, jnp.int32)
-            return tok, grid, cache, lengths + n_valid, key
+            return tok, grid, cache, lengths, key
         return fn
 
     def copy_stub(cache, src, dst, n):
@@ -117,14 +128,16 @@ def _drive_signatures(
         dict(engine._prefill_fns), engine._decode_fn,
         engine._prefix_copy_fn,
         dict(draft_fns) if draft_fns is not None else None,
+        getattr(engine, "_verify_fn", None),
     )
-    engine._prefill_fns = {n: stub(n) for n in prefill_names}
+    engine._prefill_fns = {n: chunk_stub(n) for n in prefill_names}
     if engine._decode_fn is not None:
-        engine._decode_fn = stub("decode")
+        engine._decode_fn = decode_stub
     if engine._prefix_copy_fn is not None:
         engine._prefix_copy_fn = copy_stub
     if draft_fns is not None:
         engine._draft_fns = {n: draft_stub(n) for n in draft_fns}
+        engine._verify_fn = chunk_stub("verify")
     try:
         engine.submit(np.zeros((plen,), np.int32), mnew, rid=tag)
         engine.run()
@@ -138,6 +151,7 @@ def _drive_signatures(
         engine._prefix_copy_fn = real[2]
         if real[3] is not None:
             engine._draft_fns = real[3]
+            engine._verify_fn = real[4]
     return sigs
 
 
@@ -156,7 +170,7 @@ def _program_parts(engine: Any) -> str:
     ) + (
         " + prefix_copy" if has_prefix else ""
     ) + (
-        f" + {n_draft} draft" if n_draft else ""
+        f" + verify + {n_draft} draft" if n_draft else ""
     )
 
 
@@ -168,10 +182,12 @@ def certify_ladder(engine: Any) -> List[Finding]:
     chunk ``n`` (``Scheduler.bucket_for``), and ``n`` ranges over
     ``1..max_len`` (admission rejects anything longer), so walking every
     ``n`` exhaustively proves: every reachable dispatch selects a
-    declared bucket, every bucket's token-buffer shape is a declared
-    program signature, and the steady-state program count is exactly
-    ``len(ladder) + 1`` (``Engine.program_count``).  An INFO finding
-    records the certified bound; any violation is an ERROR.
+    declared bucket, every bucket's token-buffer shape — ``(R, g)`` at
+    the engine's ONE compact row count ``prefill_rows``, however many
+    prompts are pending — is a declared program signature, and the
+    steady-state program count is exactly ``len(ladder) + 1``
+    (``Engine.program_count``).  An INFO finding records the certified
+    bound; any violation is an ERROR.
 
     Phase roles shrink the set and the walk follows: a prefill-role
     engine certifies at ``len(ladder)`` (no decode program — streams
@@ -208,7 +224,7 @@ def certify_ladder(engine: Any) -> List[Finding]:
         return findings
     buckets = tuple(getattr(engine, "prefill_buckets",
                             (engine.prefill_chunk,)))
-    S = engine.pool.num_slots
+    R = engine.prefill_rows
     declared = {
         tuple(spec["tokens"].shape)
         for kind, spec in engine.step_input_specs().items()
@@ -217,7 +233,7 @@ def certify_ladder(engine: Any) -> List[Finding]:
     bad: Set[int] = set()
     for n in range(1, engine.pool.max_len + 1):
         g = engine.scheduler.bucket_for(min(n, buckets[-1]))
-        if g not in buckets or (S, g) not in declared:
+        if g not in buckets or (R, g) not in declared:
             bad.add(n)
     if bad:
         findings.append(Finding(
@@ -236,7 +252,8 @@ def certify_ladder(engine: Any) -> List[Finding]:
     has_decode = getattr(engine, "_decode_fn", True) is not None
     expected = (
         len(buckets) + (1 if has_decode else 0)
-        + (1 if has_prefix else 0) + n_draft
+        + (1 if has_prefix else 0)
+        + (n_draft + 1 if n_draft else 0)   # the drafts + verify
     )
     parts = _program_parts(engine)
     if n_programs != expected:
@@ -268,9 +285,11 @@ def certify_speculative(engine: Any) -> List[Finding]:
     steady-state program count (the ``certify_ladder`` exhaustive-walk
     shape, applied to speculation's three dispatch sites):
 
-    1. the VERIFY pass must land in an EXISTING prefill program — the
-       chunk ``gamma + 1`` maps onto a declared ladder bucket, so
-       speculation adds zero target programs;
+    1. the VERIFY chunk ``gamma + 1`` maps onto a declared ladder
+       bucket and the engine declares exactly ONE ``verify`` program
+       there, pool-wide (``[num_slots, bucket]``: a round's rows are
+       every decoding slot), so speculation adds one target program
+       whatever the ladder and the acceptance history;
     2. every reachable draft CATCH-UP lag maps onto a declared draft
        bucket: lags are ``1..gamma + 1`` (bounded by construction — the
        round consumes every accepted token), walked exhaustively;
@@ -296,13 +315,21 @@ def certify_speculative(engine: Any) -> List[Finding]:
         ))
         return findings
     bad: List[str] = []
-    # 1. verify chunk lands in a declared target prefill bucket
+    # 1. verify chunk lands in a declared bucket, where the engine
+    # declares its one pool-wide verify program
     g_v = engine.scheduler.bucket_for(gamma + 1)
     if g_v < gamma + 1 or g_v not in buckets:
         bad.append(
             f"verify chunk gamma+1={gamma + 1} does not fit a declared "
             f"prefill bucket {buckets} — the verify pass would need a "
-            "NEW target program"
+            "program per round size"
+        )
+    verify = engine.step_input_specs().get("verify")
+    want = (engine.pool.num_slots, g_v)
+    if verify is None or tuple(verify["tokens"].shape) != want:
+        bad.append(
+            f"no pool-wide verify program declared at {want} — the "
+            "verify pass would dispatch outside the declared set"
         )
     # 2. exhaustive catch-up lag walk (1..gamma+1)
     for lag in range(1, gamma + 2):
@@ -336,9 +363,8 @@ def certify_speculative(engine: Any) -> List[Finding]:
             message=(
                 f"speculative steady state statically bounded at "
                 f"{total} programs ({len(buckets)} target prefill + "
-                f"decode + {len(draft_buckets)} draft; verify reuses "
-                f"prefill@{g_v}) for every request mix and acceptance "
-                "history"
+                f"decode + verify@{g_v} + {len(draft_buckets)} draft) "
+                "for every request mix and acceptance history"
             ),
         ))
     return findings
@@ -584,7 +610,7 @@ def lint_serving(
                                 f"{_program_parts(engine)}) — every "
                                 "such request compiles a new program; "
                                 "the engine must pad into its fixed "
-                                "(num_slots, bucket) buffers instead"
+                                "(rows, bucket) buffers instead"
                             ),
                         ))
     finally:
@@ -605,6 +631,8 @@ def lint_serving(
     if getattr(engine, "_ingest_fn", None) is not None:
         programs.append(("migrate_ingest", engine._ingest_fn))
     programs.extend(getattr(engine, "_draft_fns", {}).items())
+    if getattr(engine, "_verify_fn", None) is not None:
+        programs.append(("verify", engine._verify_fn))
     for kind, fn in programs:
         spec = base[kind]
         try:
@@ -623,13 +651,22 @@ def lint_serving(
                     )
                 )(spec["cache"], spec["lengths"], spec["tokens"],
                   spec["n_valid"])
-            else:
+            elif kind == "decode":
                 traced = jax.make_jaxpr(
                     lambda c, l, t, n, k, _fn=fn: _fn(
                         engine.params, c, l, t, n, k
                     )
                 )(spec["cache"], spec["lengths"], spec["tokens"],
                   spec["n_valid"], spec["key"])
+            else:
+                # A chunk program: compact prefill (``slots [R]``) or
+                # the pool-wide verify (no ``slots`` in its spec).
+                traced = jax.make_jaxpr(
+                    lambda c, l, s, t, n, k, _fn=fn: _fn(
+                        engine.params, c, l, s, t, n, k
+                    )
+                )(spec["cache"], spec["lengths"], spec.get("slots"),
+                  spec["tokens"], spec["n_valid"], spec["key"])
         except Exception as exc:  # noqa: BLE001 — converted to a finding
             findings.append(Finding(
                 rule="serving-trace",

@@ -17,8 +17,9 @@ the "millions of users" tier (docs/serving.md, fleet section):
   and one fixed-shape copy program; reuse is bitwise vs cold prefill.
 * :mod:`~torchgpipe_tpu.fleet.speculative` — a draft model through the
   same pipelined decode path, target-verified in one chunked
-  ``decode_slots`` step that REUSES the engine's ``g > 1`` prefill
-  program, so the steady-state program count stays fixed
+  ``decode_slots`` step: the engine's ``g > 1`` chunk body jitted once
+  pool-wide (the engine's own prefill programs are compact), so the
+  steady-state program count stays fixed
   (``analysis.serving.certify_speculative``).
 * :mod:`~torchgpipe_tpu.fleet.trace` — a deterministic synthetic
   million-request trace generator (ragged, bursty, shared-prefix

@@ -13,13 +13,17 @@ stream is token-for-token what target-only greedy decode emits
 ``tests/test_speculative.py``; this module is the SERVING instance over
 the slot pool).
 
-The steady-state program-count contract survives untouched, which is
-the whole design:
+The steady-state program count stays fixed, which is the whole design:
 
-* the VERIFY pass reuses the engine's existing ``g > 1`` prefill
-  program — that program already returns the per-position greedy grid
-  (``[S, g]`` argmax), so acceptance is host-side bookkeeping over an
-  output the engine fetches anyway.  ZERO new target programs.
+* the VERIFY pass is the engine's chunk body (``Engine.
+  _prefill_body_for`` — it already returns the per-position greedy
+  grid, ``[rows, g]`` argmax) jitted ONCE in its pool-wide form at the
+  bucket covering ``gamma + 1``: a verify round's rows are every
+  decoding slot, most of the pool, so the pool-wide shape is the right
+  one for it, while the engine's own prefill programs are compact
+  (``R`` rows, the prompts that are prefilling).  ONE target program
+  more than the plain engine, whatever the ladder; acceptance is
+  host-side bookkeeping over the grid.
 * the draft side compiles one chunk program per prefill bucket (prompt
   mirroring AND post-acceptance catch-up share them — the catch-up lag
   is provably ≤ 2 after the first round) plus the ``g = 1`` proposal
@@ -71,7 +75,10 @@ class SpeculativeEngine(Engine):
 
     ``gamma`` proposals per round need a verify chunk of ``gamma + 1``
     tokens, so ``gamma + 1`` must fit the largest prefill bucket (the
-    verify pass reuses that program).  Greedy only: the acceptance rule
+    verify program is the pool-wide form of that bucket's chunk body;
+    the draft programs are pool-wide too, and prompt chunks reach them
+    from the engine's compact prefill step through
+    ``_after_prefill_dispatch``).  Greedy only: the acceptance rule
     is argmax agreement (``temperature > 0`` is refused didactically —
     the distribution-preserving sampled variant lives at the batch
     level in ``models.generation.speculative_generate``).
@@ -123,7 +130,7 @@ class SpeculativeEngine(Engine):
                 f"gamma={self.gamma} needs a verify chunk of "
                 f"{self.gamma + 1} tokens, but the largest prefill "
                 f"bucket is {self.prefill_buckets[-1]} — the verify "
-                "pass reuses the prefill program, so raise "
+                "program is built at a prefill bucket, so raise "
                 "prefill_chunk or lower gamma"
             )
         _check_decodable(draft_cfg, self.pool.max_len)
@@ -143,6 +150,14 @@ class SpeculativeEngine(Engine):
             sorted(set(self.prefill_buckets) | {1})
         )
         self._verify_bucket = self.scheduler.bucket_for(self.gamma + 1)
+        # The verify program: the chunk body at the verify bucket in
+        # its POOL-WIDE form (called with ``slots=None``), one row a
+        # slot.  jit is lazy: it compiles at the first verify round.
+        self.trace_counts["verify"] = 0
+        self._verify_fn = jax.jit(
+            self._prefill_body_for(self._verify_bucket, "verify"),
+            donate_argnums=(1,) if self.donate else (),
+        )
         self._build_draft_programs()
         reg = self.metrics.registry
         self._c_rounds = reg.counter(
@@ -189,10 +204,10 @@ class SpeculativeEngine(Engine):
 
     @property
     def program_count(self) -> int:
-        """Target programs (the base engine's bound, verify included at
-        zero extra) plus the fixed draft set — independent of churn and
-        of acceptance history."""
-        return super().program_count + len(self.draft_buckets)
+        """Target programs (the base engine's bound plus the ONE
+        pool-wide verify program) plus the fixed draft set —
+        independent of churn and of acceptance history."""
+        return super().program_count + 1 + len(self.draft_buckets)
 
     def step_input_specs(self) -> Dict[str, Any]:
         specs = super().step_input_specs()
@@ -208,6 +223,12 @@ class SpeculativeEngine(Engine):
                 "n_valid": sds((S,), np.int32),
                 "tokens": sds(shape, np.int32),
             }
+        # The verify program: the decode program's pool-wide inputs at
+        # the verify bucket (no ``slots``: row i is slot i).
+        specs["verify"] = dict(
+            specs["decode"],
+            tokens=sds((S, self._verify_bucket), np.int32),
+        )
         return specs
 
     @property
@@ -250,12 +271,22 @@ class SpeculativeEngine(Engine):
         self._draft_lengths_dev = None      # host mirror is authoritative
 
     def _after_prefill_dispatch(
-        self, g: int, tokens: np.ndarray, n_valid: np.ndarray
+        self, g: int, slots: np.ndarray, tokens: np.ndarray,
+        n_valid: np.ndarray,
     ) -> None:
-        """Mirror the prompt chunk into the draft cache (same bucket,
-        same token buffer) — draft frontiers track target frontiers
-        through prefill, keeping the steady-state catch-up lag <= 2."""
-        self._dispatch_draft(g, tokens, n_valid)
+        """Mirror the prompt chunks into the draft cache (same bucket,
+        same tokens) — draft frontiers track target frontiers through
+        prefill, keeping the steady-state catch-up lag <= 2.  The draft
+        programs are pool-wide, so the step's compact rows are spread
+        into ``[num_slots, g]`` host buffers here, by slot (padded rows
+        carry nothing and are left out)."""
+        S = self.pool.num_slots
+        live = n_valid > 0
+        d_tokens = np.zeros((S, g), np.int32)
+        d_valid = np.zeros((S,), np.int32)
+        d_tokens[slots[live]] = tokens[live]
+        d_valid[slots[live]] = n_valid[live]
+        self._dispatch_draft(g, d_tokens, d_valid)
 
     # ------------------------------------------------------------------ #
     # the speculative decode round                                       #
@@ -321,11 +352,9 @@ class SpeculativeEngine(Engine):
             )
 
         # Phase B — ONE chunked target step over [cur_tok, proposals]
-        # through the EXISTING prefill program at the covering bucket;
-        # its per-position argmax grid is the acceptance oracle.
-        g_v = self._verify_bucket
-        name = self._prefill_names[g_v]
-        v_tokens = self._token_buffer(name)
+        # through the pool-wide verify program (every decoding slot is
+        # a row); its per-position argmax grid is the acceptance oracle.
+        v_tokens = np.zeros((S, self._verify_bucket), np.int32)
         v_valid = np.zeros((S,), np.int32)
         for r in reqs:
             s = r.slot
@@ -333,8 +362,8 @@ class SpeculativeEngine(Engine):
             v_tokens[s, 1:gamma + 1] = proposals[s]
             v_valid[s] = gamma + 1
         _tok, grid, cache, _lengths_dev, key = self._dispatch(
-            self._prefill_fns[name], self.params, self.pool.cache,
-            self._lengths_for_step(), jnp.asarray(v_tokens),
+            self._verify_fn, self.params, self.pool.cache,
+            self._lengths_for_step(), None, jnp.asarray(v_tokens),
             jnp.asarray(v_valid), self._key,
         )
         self.pool.cache = cache

@@ -66,6 +66,7 @@ teacher-forced test) holds when capacity admits every token
 
 from __future__ import annotations
 
+import functools
 from typing import Any, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -528,6 +529,37 @@ def _decode_chunk(
     return x, KVCache(k=new_k, v=new_v, length=pos0 + g)
 
 
+def _slot_rows(bank: jnp.ndarray, slot: jnp.ndarray) -> jnp.ndarray:
+    """``bank[slot:slot + 1]`` — ONE slot of a cache bank, leading axis
+    kept — as a dynamic slice.  The compact ``decode_slots`` reads each
+    row's slot this way and not as ``bank[slots]``: the TPU compiler
+    serves that gather of whole slots by first copying the ENTIRE bank
+    in ``max_len`` pieces (1.3 ms a bank of a 6 GiB pool: 17 of a
+    compact prefill step's 45 ms), and a copy of the R slots alone
+    (one concatenate of R slices) still costs a quarter of a
+    millisecond a row and layer pair; a slice that feeds the row's own
+    einsums costs nothing beside them (my chip runs, PR 27)."""
+    return lax.dynamic_slice_in_dim(bank, slot, 1, axis=0)
+
+
+@functools.partial(jax.jit, static_argnames=("window",))
+def _attend_row(
+    q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray, pos0: jnp.ndarray,
+    k_scale: Optional[jnp.ndarray], v_scale: Optional[jnp.ndarray],
+    *, window: Optional[int],
+) -> jnp.ndarray:
+    """One row of the compact ``decode_slots``: the dense
+    :func:`_attend_chunk` over one slot's rows.  Jitted so that the
+    ``R`` calls a layer (same shapes, every layer) are traced ONCE and
+    lowered as calls of one function — unrolled bare they tripled the
+    prefill program's trace-and-lower time, which is set-up time at
+    every start of an engine; XLA inlines the calls."""
+    return _attend_chunk(
+        q, ck, cv, pos0, window, use_flash=False,
+        k_scale=k_scale, v_scale=v_scale,
+    )
+
+
 def decode_slots(
     cfg: TransformerConfig,
     params: Pytree,
@@ -536,6 +568,7 @@ def decode_slots(
     lengths: jnp.ndarray,        # [S] int32 — per-slot sequence frontiers
     n_valid: jnp.ndarray,        # [S] int32 — valid tokens this call (0 = no-op row)
     moe: Optional[Any] = None,
+    slots: Optional[jnp.ndarray] = None,  # [R] int32 — row i IS slot slots[i]
 ) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
     """The SLOT-MASKED decode step: ``g`` tokens per slot through all
     blocks, each slot at its OWN position ``lengths[i]``, with row
@@ -549,6 +582,23 @@ def decode_slots(
     IS this step at ``g = 1`` — request churn changes only the VALUES of
     ``tokens``/``lengths``/``n_valid``, never a shape, so arbitrary
     admission/eviction traffic reuses one program per entry point.
+
+    ``slots`` makes the batch COMPACT: ``tokens`` / ``n_valid`` then
+    hold ``R`` rows (any ``R``, not the pool's ``S``) and row ``i`` IS
+    slot ``slots[i]`` of the pool — it reads its frontier
+    ``lengths[slots[i]]``, scatters its K/V rows into the pool at
+    ``(slots[i], frontier + j)`` under the same drop-when-masked rule
+    (every other slot stays bit-untouched, and a donated pool is still
+    updated in place), attends over that slot's ``max_len`` cache rows
+    only (a dynamic slice of the pool a row, never a copy of it) and
+    returns ``logits [R, g, vocab]`` with ``lengths`` advanced at
+    ``slots``.  The work is then ``R x g`` positions whatever the
+    pool's size: the engine's chunked prefill runs this form over the
+    rows that are prefilling.  A padded row is any valid slot index
+    with ``n_valid = 0`` (it writes nothing and advances nothing; a
+    slot may appear again among the padded rows).  Per-row math is that
+    of the pool-wide form (``slots=None``: row ``i`` is slot ``i``),
+    operation for operation.
 
     Mechanics (vs :func:`_decode_chunk`, which this generalizes):
 
@@ -566,16 +616,19 @@ def decode_slots(
     """
     embed_p, block_p, head_p = _split_params(cfg, params)
     mlp_layer = _mlp_layer_for(cfg, moe)
-    S, g = tokens.shape
+    S, g = tokens.shape          # rows of THIS call (R under ``slots``)
     L = cache.k[0].shape[1]
     quant = isinstance(cache, QuantKVCache)
-    x = _embed(cfg, embed_p, tokens, lengths)
+    compact = slots is not None
+    slot_of = slots if compact else jnp.arange(S)       # [S] row -> slot
+    pos0 = lengths[slots] if compact else lengths       # [S] row frontiers
+    x = _embed(cfg, embed_p, tokens, pos0)
     j = jnp.arange(g)[None, :]                          # [1, g]
-    # Write positions: row i token j lands at lengths[i]+j when valid,
+    # Write positions: row i token j lands at pos0[i]+j when valid,
     # at L (out of range -> dropped) when masked.
-    wpos = jnp.where(j < n_valid[:, None], lengths[:, None] + j, L)
-    rows = jnp.arange(S)[:, None]                       # [S, 1]
-    i0 = jnp.arange(S)[:, None, None]                   # [S, 1, 1]
+    wpos = jnp.where(j < n_valid[:, None], pos0[:, None] + j, L)
+    rows = slot_of[:, None]                             # [S, 1]
+    i0 = slot_of[:, None, None]                         # [S, 1, 1]
     new_k, new_v = [], []
     new_ks, new_vs = [], []
     scales = (
@@ -586,7 +639,7 @@ def decode_slots(
     for p, ck, cv, (cks, cvs) in zip(
         block_p, cache.k, cache.v, scales
     ):
-        q, k, v = _block_qkv(cfg, p, x, lengths)
+        q, k, v = _block_qkv(cfg, p, x, pos0)
         if quant:
             kq, ks = _quant_rows(k)
             vq, vs = _quant_rows(v)
@@ -601,18 +654,36 @@ def decode_slots(
         else:
             ck = ck.at[rows, wpos].set(k.astype(ck.dtype), mode="drop")
             cv = cv.at[rows, wpos].set(v.astype(cv.dtype), mode="drop")
-        # Per-row pos0 forces the dense path (the flash decode kernel
-        # takes one scalar pos0), so a slot's read is the same f32
-        # einsum math as the single-request dense path.
-        attn = _attend_chunk(
-            q, ck, cv, lengths, cfg.attn_window, use_flash=False,
-            k_scale=cks if quant else None,
-            v_scale=cvs if quant else None,
-        )
-        x = _block_attn_out(cfg, p, x, attn, mlp_layer)
         new_k.append(ck)
         new_v.append(cv)
-    new_lengths = lengths + n_valid
+        if compact:
+            # One row, one slot: each row attends over ITS slot's
+            # ``max_len`` cache rows (written rows included), read from
+            # the pool by a dynamic slice that feeds the row's own
+            # einsums — no copy of the R slots, let alone of the bank.
+            attn = jnp.concatenate([
+                _attend_row(
+                    q[i:i + 1], _slot_rows(ck, slots[i]),
+                    _slot_rows(cv, slots[i]), pos0[i:i + 1],
+                    _slot_rows(cks, slots[i]) if quant else None,
+                    _slot_rows(cvs, slots[i]) if quant else None,
+                    window=cfg.attn_window,
+                )
+                for i in range(S)
+            ], axis=0)
+        else:
+            # Per-row pos0 forces the dense path (the flash decode
+            # kernel takes one scalar pos0), so a slot's read is the
+            # same f32 einsum math as the single-request dense path.
+            attn = _attend_chunk(
+                q, ck, cv, pos0, cfg.attn_window, use_flash=False,
+                k_scale=cks if quant else None,
+                v_scale=cvs if quant else None,
+            )
+        x = _block_attn_out(cfg, p, x, attn, mlp_layer)
+    new_lengths = (
+        lengths.at[slots].add(n_valid) if compact else lengths + n_valid
+    )
     length = jnp.sum(new_lengths).astype(jnp.int32)  # schema slot only
     if quant:
         out_cache: Any = QuantKVCache(

@@ -7,22 +7,27 @@ with a single ``prefill_chunk``, ``len(ladder) + 1`` with a prefill
 bucket ladder (``prefill_chunk=(1, 2, 4, 8)``; certified by
 ``analysis.serving.certify_ladder``) —
 
-* **prefill** — ``decode_slots`` at ``g = prefill_chunk`` (one program
-  per ladder bucket; each step dispatches the smallest bucket covering
-  its largest pending chunk): every slot's pending prompt chunk
-  teacher-forced at its own frontier, masked rows no-ops; rows
-  finishing their prompt sample their FIRST token from the chunk's
-  last-valid-position logits (so prefill and decode share one sampling
-  site semantics-wise);
-* **decode** — ``decode_slots`` at ``g = 1``: one token per occupied
-  slot, each at its own position.
+* **prefill** — ``decode_slots`` at ``g = prefill_chunk`` over a
+  COMPACT batch of ``R`` rows (one program per ladder bucket, all at
+  the one ``R``; each step dispatches the smallest bucket covering the
+  largest chunk among the rows it takes): the step takes the first
+  ``R`` pending prompts in admission order, hands the program their
+  slot indices, and each row's chunk is teacher-forced at its slot's
+  own frontier — K/V rows scattered into the pool at that slot, the
+  read over that slot's rows only, unused rows masked no-ops.  A step
+  so costs ``R x g`` positions, not ``num_slots x g``; prompts beyond
+  ``R`` wait for the next prefill step.  Rows finishing their prompt
+  sample their FIRST token from the chunk's last-valid-position logits
+  (so prefill and decode share one sampling site semantics-wise);
+* **decode** — ``decode_slots`` at ``g = 1`` over the whole pool: one
+  token per occupied slot, each at its own position.
 
 Request arrival, completion, cancellation, drain — all of it changes
-only the VALUES of ``tokens`` / ``lengths`` / ``n_valid`` / the cache
-arrays, never a shape, so XLA never retraces.  The engine works from
-the SAME trained pipeline params the training engines produce
-(``mpmd_params_for_generation`` / ``spmd_params_for_generation`` — the
-flat per-layer list), with no conversion step.
+only the VALUES of ``slots`` / ``tokens`` / ``lengths`` / ``n_valid`` /
+the cache arrays, never a shape, so XLA never retraces.  The engine
+works from the SAME trained pipeline params the training engines
+produce (``mpmd_params_for_generation`` / ``spmd_params_for_generation``
+— the flat per-layer list), with no conversion step.
 
 Resilience: every compiled-step dispatch retries transient failures
 under :func:`torchgpipe_tpu.resilience.guard.classify_error` (bounded
@@ -82,6 +87,27 @@ def _start_host_copy(arr: Any) -> None:
             pass
 
 
+def prefill_rows_for(num_slots: int) -> int:
+    """``R``, the rows of the compact prefill program, for a pool of
+    ``num_slots``: a fifth of the pool, never under 8 rows (nor over
+    the pool).  A rule and not an ``Engine`` argument, because neither
+    end is a choice a deployment gains from.  A prefill step costs its
+    rows (1.1 ms a row of 32 positions at Mistral-7B widths, whether
+    the row carries a prompt or not), so every row over what the
+    traffic fills is paid for at every step, and a prompt waits as long
+    behind four steps of ``R`` as behind one of ``4 R``.  Under some 8
+    rows a step is paid for by reading the weights, so fewer rows buy
+    nothing.  And the rows must outnumber what the traffic keeps
+    prefilling, or prompts queue for prefill while slots stand empty of
+    decode work: conversation traffic (prompts of 36 chunks, 211 output
+    tokens) keeps 15 % of the pool prefilling, 9.3 of 64 slots.  At 64
+    slots, 8 / 10 / 12 / 16 / 32 rows read 1380 / 1572 / 1530 / 1388 /
+    890 tokens a second where the pool-wide program read 565 (PERF.md
+    section 6, PR 27): the best lie just over the traffic's share, and
+    a fifth is the least that leaves that share a quarter of room."""
+    return min(num_slots, max(8, num_slots // 5))
+
+
 class Engine:
     """Continuous-batching inference engine over a slot-pooled KV cache.
 
@@ -105,6 +131,18 @@ class Engine:
     generate` per-request; sampling takes ``rng`` and applies the same
     temperature/top-k/top-p filter chain ``generate`` uses, batched over
     slots.
+
+    What a step covers.  Decode runs over all ``num_slots`` rows of the
+    pool.  Prefill runs over ``prefill_rows`` rows (``R``): the first
+    ``R`` pending prompts in admission order, each absorbing up to
+    ``prefill_chunk`` tokens, so the step costs what it prefills and
+    time to first token stays first-come first-served; further pending
+    prompts wait one prefill step (counted in
+    ``serving_prefill_deferred_rows``; ``serving_prefill_rows`` over
+    ``serving_prefill_row_capacity`` is the program's fill share).
+    ``R`` comes from ``num_slots`` by :func:`prefill_rows_for` and is
+    not an argument: there is ONE compact program per ladder bucket, so
+    every program exists after one warm-up request.
     """
 
     def __init__(
@@ -316,8 +354,11 @@ class Engine:
         self.trace_counts = {
             name: 0 for name in self._prefill_names.values()
         }
+        # Rows of the compact prefill program (``R``): ONE value for
+        # every ladder bucket, from the pool's size as clamped above.
+        self.prefill_rows = prefill_rows_for(num_slots)
         self._token_shapes = {
-            name: (num_slots, g)
+            name: (self.prefill_rows, g)
             for g, name in self._prefill_names.items()
         }
         if role != "prefill":
@@ -329,58 +370,71 @@ class Engine:
     # compiled programs                                                  #
     # ------------------------------------------------------------------ #
 
-    def _build_programs(self) -> None:
+    def _sample_row(self, logits, key):
+        """[rows, vocab] f32 -> [rows] int32 (traced): ``generate``'s
+        exact filter chain."""
+        if self.temperature == 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), key
+        key, sub = jax.random.split(key)
+        return _sample(
+            logits, sub, self.temperature, self.top_k, self.top_p
+        ), key
+
+    def _prefill_body_for(self, g: int, name: str) -> Callable[..., Tuple]:
+        """The chunk program's python body at bucket ``g``, counted
+        under ``name``.  The bucket is baked into the traced shape
+        (``tokens [rows, g]``); the body is otherwise identical across
+        buckets.  Called with ``slots [R]`` it is the COMPACT prefill
+        program (row ``i`` is slot ``slots[i]``; ``tokens`` /
+        ``n_valid`` / ``tok`` / ``grid`` have ``R`` rows); called with
+        ``slots=None`` it is the pool-wide form, one row a slot — the
+        shape ``fleet.SpeculativeEngine`` jits as its verify program
+        (its rows ARE most of the pool).  The plain engine never calls
+        that form, so never compiles it."""
         cfg, moe = self.cfg, self.moe
-        temperature, top_k, top_p = self.temperature, self.top_k, self.top_p
         counts = self.trace_counts
 
-        def sample_row(logits, key):
-            # [S, vocab] f32 -> [S] int32, generate's exact filter chain.
-            if temperature == 0.0:
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), key
-            key, sub = jax.random.split(key)
-            return _sample(logits, sub, temperature, top_k, top_p), key
+        def prefill_body(params, cache, lengths, slots, tokens, n_valid,
+                         key):
+            counts[name] += 1
+            # ``lengths`` comes back advanced ON DEVICE (at ``slots``,
+            # by the rows each consumed): the next step reuses the
+            # array instead of re-uploading the host mirror.
+            logits, cache, lengths = decode_slots(
+                cfg, params, tokens, cache, lengths, n_valid, moe=moe,
+                slots=slots,
+            )
+            last = jnp.clip(n_valid - 1, 0, g - 1)
+            row_logits = jnp.take_along_axis(
+                logits, last[:, None, None], axis=1
+            )[:, 0]
+            tok, key = self._sample_row(row_logits, key)
+            # Per-POSITION greedy tokens [rows, g]: what the target
+            # model would emit after consuming each input position.
+            # Chunked prefill ignores it (an output nobody fetches);
+            # for speculative decoding's verify pass the grid is the
+            # acceptance oracle (fleet/speculative.py).
+            grid = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return tok, grid, cache, lengths, key
+        return prefill_body
 
-        def prefill_body_for(g, name):
-            # One program per ladder bucket: the bucket size g is baked
-            # into the traced shape (tokens [S, g]); the body is
-            # otherwise identical across buckets.
-            def prefill_body(params, cache, lengths, tokens, n_valid, key):
-                counts[name] += 1
-                logits, cache, _ = decode_slots(
-                    cfg, params, tokens, cache, lengths, n_valid, moe=moe
-                )
-                last = jnp.clip(n_valid - 1, 0, g - 1)
-                row_logits = jnp.take_along_axis(
-                    logits, last[:, None, None], axis=1
-                )[:, 0]
-                tok, key = sample_row(row_logits, key)
-                # Per-POSITION greedy tokens [S, g]: what the target
-                # model would emit after consuming each input position.
-                # Chunked prefill ignores it; speculative decoding's
-                # verify pass IS this program — the grid is the
-                # acceptance oracle, so speculation adds ZERO target
-                # programs (fleet/speculative.py).
-                grid = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                # Advance the frontiers ON DEVICE (lengths += the rows
-                # each slot consumed): the next step reuses this array
-                # instead of re-uploading the host mirror — the per-step
-                # host→device lengths copy disappears from the
-                # steady-state decode path.
-                return tok, grid, cache, lengths + n_valid, key
-            return prefill_body
+    def _build_programs(self) -> None:
+        cfg, moe = self.cfg, self.moe
+        counts = self.trace_counts
 
         def decode_body(params, cache, lengths, tokens, n_valid, key):
             counts["decode"] += 1
-            logits, cache, _ = decode_slots(
+            logits, cache, lengths = decode_slots(
                 cfg, params, tokens, cache, lengths, n_valid, moe=moe
             )
-            tok, key = sample_row(logits[:, 0], key)
-            return tok, cache, lengths + n_valid, key
+            tok, key = self._sample_row(logits[:, 0], key)
+            return tok, cache, lengths, key
 
         donate = (1,) if self.donate else ()
         self._prefill_fns = {
-            name: jax.jit(prefill_body_for(g, name), donate_argnums=donate)
+            name: jax.jit(
+                self._prefill_body_for(g, name), donate_argnums=donate
+            )
             for g, name in self._prefill_names.items()
         }
         self._decode_fn = (
@@ -485,8 +539,9 @@ class Engine:
 
     @property
     def program_count(self) -> int:
-        """The statically bounded compiled-program count: one prefill
-        program per ladder bucket plus the decode program (plus the one
+        """The statically bounded compiled-program count: one compact
+        prefill program per ladder bucket (all at the one row count
+        ``prefill_rows``) plus the decode program (plus the one
         fixed-shape ``prefix_copy`` program when a prefix cache is
         attached) — the figure ``analysis.serving`` certifies and the
         compile-counter test confirms dynamically.  Disaggregation
@@ -514,13 +569,22 @@ class Engine:
         common = {
             "cache": cache_spec,
             "lengths": sds((S,), np.int32),
-            "n_valid": sds((S,), np.int32),
             "key": sds(self._key.shape, self._key.dtype),
         }
+        # ``tokens`` / ``n_valid`` have the program's rows: the pool's
+        # ``num_slots`` for decode, ``R`` for the compact prefill
+        # programs, which also take the rows' slot indices.
         specs = {
-            kind: dict(common, tokens=sds(shape, np.int32))
+            kind: dict(
+                common, tokens=sds(shape, np.int32),
+                n_valid=sds(shape[:1], np.int32),
+            )
             for kind, shape in self._token_shapes.items()
         }
+        for name in self._prefill_names.values():
+            specs[name]["slots"] = sds(
+                self._token_shapes[name][:1], np.int32
+            )
         if self._prefix_copy_fn is not None:
             scalar = sds((), np.int32)
             specs["prefix_copy"] = {
@@ -937,33 +1001,42 @@ class Engine:
 
     def _run_prefill(self) -> None:
         tl = self.timeline
-        reqs = self.scheduler.prefill_pending()
-        # Ladder admission: the smallest bucket covering this step's
-        # largest pending chunk — short prompts dispatch a small program
-        # instead of paying the max chunk's FLOPs.
-        g = self.scheduler.prefill_bucket()
-        tl.annotate(rows=len(reqs), g=g)
+        pending = self.scheduler.prefill_pending()
+        # The compact step: the first R pending prompts in admission
+        # order (oldest first — time to first token stays FIFO); the
+        # rest wait for the next prefill step.
+        cap = self.prefill_rows
+        reqs = pending[:cap]
+        deferred = len(pending) - len(reqs)
+        # Ladder admission: the smallest bucket covering the largest
+        # chunk among the rows this step takes — short prompts dispatch
+        # a small program instead of paying the max chunk's FLOPs.
+        g = self.scheduler.prefill_bucket(reqs)
+        tl.annotate(rows=len(reqs), g=g, cap=cap, deferred=deferred)
         with tl.span("engine.build"):
             name = self._prefill_names[g]
-            tokens = self._token_buffer(name)
-            n_valid = np.zeros((self.pool.num_slots,), np.int32)
-            takes: List[Tuple[Request, int]] = []
+            tokens = self._token_buffer(name)           # [R, g]
+            # Row i is slot slots[i]; rows past len(reqs) are padding
+            # (slot 0, n_valid 0: they write and advance nothing).
+            slots = np.zeros((tokens.shape[0],), np.int32)
+            n_valid = np.zeros((tokens.shape[0],), np.int32)
             finishing = 0
-            for r in reqs:
+            for i, r in enumerate(reqs):
                 take = min(g, r.prompt_len - r.prefilled)
-                tokens[r.slot, :take] = (
+                tokens[i, :take] = (
                     r.prompt[r.prefilled:r.prefilled + take]
                 )
-                n_valid[r.slot] = take
-                takes.append((r, take))
+                slots[i] = r.slot
+                n_valid[i] = take
                 finishing += r.prefilled + take >= r.prompt_len
             lengths_in = self._lengths_for_step()
+            slots_dev = jnp.asarray(slots)
             tokens_dev = jnp.asarray(tokens)
             n_valid_dev = jnp.asarray(n_valid)
         t0 = self._rec_clock()
         tok, _grid, cache, lengths_dev, key = self._dispatch(
             self._prefill_fns[name], self.params, self.pool.cache,
-            lengths_in, tokens_dev, n_valid_dev, self._key,
+            lengths_in, slots_dev, tokens_dev, n_valid_dev, self._key,
         )
         self.pool.cache = cache
         self._key = key
@@ -974,23 +1047,26 @@ class Engine:
             # hint — np.asarray below is the one materialization point).
             _start_host_copy(tok)
         # Subclass hook: speculative decoding mirrors every prefill
-        # chunk into its draft model's cache (same bucket, same buffer)
+        # chunk into its draft model's cache (same bucket, same rows)
         # so draft and target stay frontier-aligned.
-        self._after_prefill_dispatch(g, tokens, n_valid)
+        self._after_prefill_dispatch(g, slots, tokens, n_valid)
         tok_host: Optional[np.ndarray] = None
         if finishing:
             # ONE host fetch per step, and only in a step in which a
             # prompt completes and samples its first token.
             with tl.span("engine.fetch"):
-                tok_host = np.asarray(tok)
+                tok_host = np.asarray(tok)              # [R]: row order
         with tl.span("engine.emit", tokens=finishing):
             if self.recorder is not None:
-                for r, take in takes:
+                for r, take in zip(reqs, n_valid):
                     self._rec("req_prefill", r.rid, dur=dur,
-                              detail=f"g={g} take={take}")
-            self._commit_lengths(lengths_dev, n_valid)
-            self.metrics.step("prefill", len(reqs), self.pool.num_slots)
-            for r, take in takes:
+                              detail=f"g={g} take={int(take)}")
+            advance = np.zeros((self.pool.num_slots,), np.int32)
+            advance[slots[:len(reqs)]] = n_valid[:len(reqs)]
+            self._commit_lengths(lengths_dev, advance)
+            self.metrics.step("prefill", len(reqs), cap, deferred=deferred)
+            for i, r in enumerate(reqs):
+                take = int(n_valid[i])
                 self.pool.lengths[r.slot] += take
                 r.prefilled += take
                 if r.prefill_done:
@@ -1003,13 +1079,16 @@ class Engine:
                             r.prompt, r.slot, self.pool
                         )
                     assert tok_host is not None
-                    self._emit(r, int(tok_host[r.slot]))
+                    self._emit(r, int(tok_host[i]))
 
     def _after_prefill_dispatch(
-        self, g: int, tokens: np.ndarray, n_valid: np.ndarray
+        self, g: int, slots: np.ndarray, tokens: np.ndarray,
+        n_valid: np.ndarray,
     ) -> None:
-        """No-op hook; ``fleet.SpeculativeEngine`` overrides it to
-        teacher-force the same prompt chunk into the draft cache."""
+        """No-op hook, handed the step's COMPACT host buffers (row
+        ``i`` is slot ``slots[i]``; padded rows have ``n_valid`` 0);
+        ``fleet.SpeculativeEngine`` overrides it to teacher-force the
+        same prompt chunks into the draft cache."""
 
     def _run_decode(self) -> None:
         tl = self.timeline
@@ -1393,4 +1472,4 @@ class Engine:
         return out
 
 
-__all__ = ["Engine"]
+__all__ = ["Engine", "prefill_rows_for"]

@@ -97,7 +97,20 @@ class ServingMetrics:
             help="slot-steps doing useful work")
         self._c_total = reg.counter(
             "serving_total_slot_steps",
-            help="slot-steps available (steps * slots)")
+            help="rows the steps' programs had (a decode step: the "
+                 "pool's slots; a prefill step: its row capacity R)")
+        self._c_prefill_rows = reg.counter(
+            "serving_prefill_rows",
+            help="rows of the compact prefill program that carried a "
+                 "prompt chunk (n_valid > 0), summed over prefill steps")
+        self._c_prefill_capacity = reg.counter(
+            "serving_prefill_row_capacity",
+            help="rows the compact prefill program has (R a step); "
+                 "rows over capacity is its fill share")
+        self._c_prefill_deferred = reg.counter(
+            "serving_prefill_deferred_rows",
+            help="pending prompts a prefill step left for the next one "
+                 "(more pending than R), summed over prefill steps")
         self._c_tokens = reg.counter(
             "serving_tokens_out", help="tokens emitted")
         self._c_retries = reg.counter(
@@ -135,6 +148,9 @@ class ServingMetrics:
     decode_steps = _counter_property("_c_decode")
     occupied_slot_steps = _counter_property("_c_occupied")
     total_slot_steps = _counter_property("_c_total")
+    prefill_rows = _counter_property("_c_prefill_rows")
+    prefill_row_capacity = _counter_property("_c_prefill_capacity")
+    prefill_deferred_rows = _counter_property("_c_prefill_deferred")
     tokens_out = _counter_property("_c_tokens")
     retries = _counter_property("_c_retries")
     drains = _counter_property("_c_drains")
@@ -208,9 +224,18 @@ class ServingMetrics:
     # engine iterations                                                  #
     # ------------------------------------------------------------------ #
 
-    def step(self, kind: str, active_slots: int, num_slots: int) -> None:
+    def step(self, kind: str, active_slots: int, num_slots: int,
+             deferred: int = 0) -> None:
+        """One compiled step: ``active_slots`` of the ``num_slots`` rows
+        its program has did useful work.  A prefill step's program is
+        COMPACT (``num_slots`` is its row capacity ``R``, not the
+        pool's size) and may leave ``deferred`` pending prompts to the
+        next prefill step."""
         if kind == "prefill":
             self._c_prefill.inc()
+            self._c_prefill_rows.inc(active_slots)
+            self._c_prefill_capacity.inc(num_slots)
+            self._c_prefill_deferred.inc(deferred)
         else:
             self._c_decode.inc()
         self._c_occupied.inc(active_slots)
@@ -233,6 +258,14 @@ class ServingMetrics:
     @property
     def engine_steps(self) -> int:
         return self.prefill_steps + self.decode_steps
+
+    @property
+    def prefill_fill_share(self) -> float:
+        """Fraction of the compact prefill program's rows that carried
+        a prompt chunk, over all prefill steps."""
+        if self.prefill_row_capacity == 0:
+            return 0.0
+        return self.prefill_rows / self.prefill_row_capacity
 
     @property
     def occupancy(self) -> float:
@@ -268,6 +301,10 @@ class ServingMetrics:
                 if self.engine_steps else 0.0
             ),
             "occupancy": self.occupancy,
+            "prefill_rows": self.prefill_rows,
+            "prefill_row_capacity": self.prefill_row_capacity,
+            "prefill_deferred_rows": self.prefill_deferred_rows,
+            "prefill_fill_share": self.prefill_fill_share,
             "retries": self.retries,
             "drains": self.drains,
             "preempted_requests": self.preempted_requests,

@@ -242,6 +242,10 @@ class Scheduler:
     # ------------------------------------------------------------------ #
 
     def prefill_pending(self) -> List[Request]:
+        """Active requests with prompt left to absorb, in ADMISSION
+        order (``active`` is insertion-ordered): the engine's compact
+        prefill step takes the first ``R`` of them, so the oldest
+        prompt is always served first."""
         return [r for r in self.active.values() if not r.prefill_done]
 
     def bucket_for(self, n: int) -> int:
@@ -253,15 +257,18 @@ class Scheduler:
                 return g
         return self.prefill_buckets[-1]
 
-    def prefill_bucket(self) -> int:
+    def prefill_bucket(
+        self, reqs: Optional[Sequence[Request]] = None
+    ) -> int:
         """The bucket THIS prefill step dispatches: the smallest ladder
-        entry covering every pending request's next chunk (each request's
+        entry covering the next chunk of every request in ``reqs`` (the
+        rows the step takes; default: all pending).  Each request's
         chunk is its remaining prompt capped at the ladder max — one
-        shared ``[slots, g]`` buffer serves all slots, masked rows
-        no-ops, so the step's bucket must cover the largest take)."""
+        shared ``[rows, g]`` buffer serves all rows, so the step's
+        bucket must cover the largest take."""
         need = 0
         cap = self.prefill_buckets[-1]
-        for r in self.prefill_pending():
+        for r in (self.prefill_pending() if reqs is None else reqs):
             need = max(need, min(r.prompt_len - r.prefilled, cap))
         return self.bucket_for(max(need, 1))
 
