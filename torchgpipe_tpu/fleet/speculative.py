@@ -120,6 +120,12 @@ class SpeculativeEngine(Engine):
                 "lag) — compose at the fleet level instead (router over "
                 "a prefix-cached replica and a speculative replica)"
             )
+        if cfg.mla is not None or draft_cfg.mla is not None:
+            raise NotImplementedError(
+                "speculative decoding rolls K and V rows back after a "
+                "rejected draft; a latent-attention model's pool holds "
+                "the KV latent — serve it with the plain Engine"
+            )
         self.gamma = int(gamma)
         self.draft_cfg = draft_cfg
         self.draft_params = list(draft_params)

@@ -73,6 +73,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from torchgpipe_tpu.models import mla
 from torchgpipe_tpu.models.transformer import (
     TransformerConfig,
     _act_fn,
@@ -94,13 +95,35 @@ class KVCache(NamedTuple):
     length: jnp.ndarray   # [] int32 — tokens already cached
 
 
+class LatentCache(NamedTuple):
+    """The cache of a latent-attention model (``cfg.mla``): a layer's
+    row is the normed KV latent and the rotated shared key head, not K
+    and V.  Two banks a layer, because the two are read apart (the
+    latent feeds scores AND output, the key head scores only) and a
+    slice of one wider bank's minor dim would be a copy of the bank."""
+
+    ckv: List[jnp.ndarray]  # each [b, max_len, kv_lora_rank]
+    kpe: List[jnp.ndarray]  # each [b, max_len, qk_rope_head_dim]
+    length: jnp.ndarray     # [] int32 — tokens already cached
+
+
 def init_cache(
     cfg: TransformerConfig, batch: int, max_len: int,
     dtype: Optional[jnp.dtype] = None,
-) -> KVCache:
-    """Zeroed KV cache for ``cfg.n_layers`` blocks."""
-    shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
+) -> Any:
+    """Zeroed cache for ``cfg.n_layers`` blocks, as the attention kind
+    says: :class:`KVCache`, or :class:`LatentCache` under ``cfg.mla``."""
     dt = dtype or cfg.dtype
+    if cfg.mla is not None:
+        m = cfg.mla
+        return LatentCache(
+            ckv=[jnp.zeros((batch, max_len, m.kv_lora_rank), dt)
+                 for _ in range(cfg.n_layers)],
+            kpe=[jnp.zeros((batch, max_len, m.qk_rope_head_dim), dt)
+                 for _ in range(cfg.n_layers)],
+            length=jnp.zeros((), jnp.int32),
+        )
+    shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
     return KVCache(
         k=[jnp.zeros(shape, dt) for _ in range(cfg.n_layers)],
         v=[jnp.zeros(shape, dt) for _ in range(cfg.n_layers)],
@@ -142,6 +165,7 @@ def init_quant_cache(
 ) -> QuantKVCache:
     """Zeroed int8 KV cache for ``cfg.n_layers`` blocks."""
     shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
+    _refuse_mla(cfg, "the int8 QuantKVCache")
     sshape = (batch, cfg.kv_heads, max_len)
     return QuantKVCache(
         k=[jnp.zeros(shape, jnp.int8) for _ in range(cfg.n_layers)],
@@ -150,6 +174,22 @@ def init_quant_cache(
         v_scale=[jnp.zeros(sshape, jnp.float32) for _ in range(cfg.n_layers)],
         length=jnp.zeros((), jnp.int32),
     )
+
+
+def _refuse_mla(cfg: TransformerConfig, what: str) -> None:
+    if cfg.mla is not None:
+        raise NotImplementedError(
+            f"{what} holds K and V rows; a latent-attention model "
+            "(cfg.mla) caches the KV latent instead (LatentCache) and "
+            "is served by prefill / generate / decode_slots and "
+            "serving.Engine's plain pool"
+        )
+
+
+def _cache_rows(cache: Any) -> int:
+    """``max_len`` of a cache of any kind."""
+    bank = cache.ckv if isinstance(cache, LatentCache) else cache.k
+    return bank[0].shape[1]
 
 
 def _embed(cfg: TransformerConfig, embed_p: Pytree,
@@ -269,10 +309,14 @@ def _block_attn_out(
     x: jnp.ndarray,              # [b, g, dim] — block input (residual stream)
     attn: jnp.ndarray,           # [b, g, nh*hd] — attention output
     mlp_layer: Optional[Any],
+    valid: Optional[jnp.ndarray] = None,     # [b, g] bool: real tokens
+    counts_out: Optional[List[jnp.ndarray]] = None,
 ) -> jnp.ndarray:
     """Shared per-block decode epilogue: wo projection (+LoRA, +bias),
     attention residual, ln2 (parallel or sequential residual), MLP
-    residual.  Counterpart of :func:`_block_qkv`."""
+    residual.  Counterpart of :func:`_block_qkv` (and of
+    ``mla.project`` + ``mla.attend``).  ``valid`` / ``counts_out`` as in
+    :func:`_mlp_out`."""
     attn = attn.astype(x.dtype)
     o = attn @ _w(cfg, p, "wo")
     if "lora" in p:
@@ -284,7 +328,7 @@ def _block_attn_out(
     h = _block_norm(
         cfg, p, "ln2", x_in if cfg.parallel_residual else x
     )
-    return x + _mlp_out(cfg, p, h, mlp_layer)
+    return x + _mlp_out(cfg, p, h, mlp_layer, valid, counts_out)
 
 
 def _decode_step(
@@ -314,6 +358,7 @@ def _decode_step(
     specialization lives here."""
     if not ring:
         return _decode_chunk(cfg, block_params, x, cache, mlp_layer)
+    _refuse_mla(cfg, "a ring cache")
     pos = cache.length
     quant = isinstance(cache, QuantKVCache)
     new_k, new_v = [], []
@@ -480,6 +525,20 @@ def _decode_chunk(
     ring's slot reuse cannot undo)."""
     g = x.shape[1]
     pos0 = cache.length
+    if cfg.mla is not None:
+        new_c, new_r = [], []
+        for p, cc, cr in zip(block_params, cache.ckv, cache.kpe):
+            h = _block_norm(cfg, p, "ln1", x)
+            q_nope, q_pe, ckv, kpe = mla.project(cfg, p, h, pos0)
+            cc = lax.dynamic_update_slice_in_dim(
+                cc, ckv.astype(cc.dtype), pos0, 1)
+            cr = lax.dynamic_update_slice_in_dim(
+                cr, kpe.astype(cr.dtype), pos0, 1)
+            attn = mla.attend(cfg, p, q_nope, q_pe, cc, cr, pos0)
+            x = _block_attn_out(cfg, p, x, attn, mlp_layer)
+            new_c.append(cc)
+            new_r.append(cr)
+        return x, LatentCache(ckv=new_c, kpe=new_r, length=pos0 + g)
     quant = isinstance(cache, QuantKVCache)
     new_k, new_v = [], []
     new_ks, new_vs = [], []
@@ -560,6 +619,17 @@ def _attend_row(
     )
 
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _attend_latent_row(
+    cfg: TransformerConfig, wkv_b: jnp.ndarray, q_nope: jnp.ndarray,
+    q_pe: jnp.ndarray, ckv: jnp.ndarray, kpe: jnp.ndarray,
+    pos0: jnp.ndarray,
+) -> jnp.ndarray:
+    """:func:`_attend_row` for a latent cache: ``mla.attend`` over one
+    slot's rows, jitted for the same reason."""
+    return mla.attend(cfg, {"wkv_b": wkv_b}, q_nope, q_pe, ckv, kpe, pos0)
+
+
 def decode_slots(
     cfg: TransformerConfig,
     params: Pytree,
@@ -569,7 +639,8 @@ def decode_slots(
     n_valid: jnp.ndarray,        # [S] int32 — valid tokens this call (0 = no-op row)
     moe: Optional[Any] = None,
     slots: Optional[jnp.ndarray] = None,  # [R] int32 — row i IS slot slots[i]
-) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
+    expert_counts: bool = False,
+) -> Tuple:
     """The SLOT-MASKED decode step: ``g`` tokens per slot through all
     blocks, each slot at its OWN position ``lengths[i]``, with row
     ``i``'s tokens ``j >= n_valid[i]`` masked no-ops (their K/V writes
@@ -611,13 +682,22 @@ def decode_slots(
       ``lengths``); the returned cache carries ``lengths + n_valid``
       summed into its scalar only for schema compatibility.
 
-    Plain and quantized caches; ring caches are not supported (slots
-    recycle by masking, which a ring's position-aliased layout defeats).
+    Plain and quantized caches, and the :class:`LatentCache` of a
+    ``cfg.mla`` model (same write and mask rules on its two banks; the
+    attend is ``mla.attend`` over the slot's latent rows, absorbed at
+    ``g = 1``); ring caches are not supported (slots recycle by
+    masking, which a ring's position-aliased layout defeats).
+
+    ``expert_counts=True`` appends a fourth result: ``int32 [expert
+    layers, held]``, the tokens this call routed to each expert the
+    layer holds (``MoEConfig.held``; masked positions not counted), in
+    block order.
     """
     embed_p, block_p, head_p = _split_params(cfg, params)
     mlp_layer = _mlp_layer_for(cfg, moe)
     S, g = tokens.shape          # rows of THIS call (R under ``slots``)
-    L = cache.k[0].shape[1]
+    L = _cache_rows(cache)
+    counts: Optional[List[jnp.ndarray]] = [] if expert_counts else None
     quant = isinstance(cache, QuantKVCache)
     compact = slots is not None
     slot_of = slots if compact else jnp.arange(S)       # [S] row -> slot
@@ -627,18 +707,44 @@ def decode_slots(
     # Write positions: row i token j lands at pos0[i]+j when valid,
     # at L (out of range -> dropped) when masked.
     wpos = jnp.where(j < n_valid[:, None], pos0[:, None] + j, L)
+    valid = j < n_valid[:, None]                        # [S, g]
     rows = slot_of[:, None]                             # [S, 1]
     i0 = slot_of[:, None, None]                         # [S, 1, 1]
     new_k, new_v = [], []
     new_ks, new_vs = [], []
-    scales = (
-        zip(cache.k_scale, cache.v_scale)
-        if quant
-        else ((None, None) for _ in cache.k)
-    )
-    for p, ck, cv, (cks, cvs) in zip(
-        block_p, cache.k, cache.v, scales
-    ):
+    latent = cfg.mla is not None
+    if latent:
+        scales = ((None, None) for _ in cache.ckv)
+        banks = zip(block_p, cache.ckv, cache.kpe, scales)
+    else:
+        scales = (
+            zip(cache.k_scale, cache.v_scale)
+            if quant
+            else ((None, None) for _ in cache.k)
+        )
+        banks = zip(block_p, cache.k, cache.v, scales)
+    for p, ck, cv, (cks, cvs) in banks:
+        if latent:
+            # ``ck`` / ``cv`` are the latent and the key-head banks.
+            h = _block_norm(cfg, p, "ln1", x)
+            q_nope, q_pe, ckv, kpe = mla.project(cfg, p, h, pos0)
+            ck = ck.at[rows, wpos].set(ckv.astype(ck.dtype), mode="drop")
+            cv = cv.at[rows, wpos].set(kpe.astype(cv.dtype), mode="drop")
+            new_k.append(ck)
+            new_v.append(cv)
+            if compact:
+                attn = jnp.concatenate([
+                    _attend_latent_row(
+                        cfg, p["wkv_b"], q_nope[i:i + 1], q_pe[i:i + 1],
+                        _slot_rows(ck, slots[i]), _slot_rows(cv, slots[i]),
+                        pos0[i:i + 1],
+                    )
+                    for i in range(S)
+                ], axis=0)
+            else:
+                attn = mla.attend(cfg, p, q_nope, q_pe, ck, cv, pos0)
+            x = _block_attn_out(cfg, p, x, attn, mlp_layer, valid, counts)
+            continue
         q, k, v = _block_qkv(cfg, p, x, pos0)
         if quant:
             kq, ks = _quant_rows(k)
@@ -680,18 +786,28 @@ def decode_slots(
                 k_scale=cks if quant else None,
                 v_scale=cvs if quant else None,
             )
-        x = _block_attn_out(cfg, p, x, attn, mlp_layer)
+        x = _block_attn_out(cfg, p, x, attn, mlp_layer, valid, counts)
     new_lengths = (
         lengths.at[slots].add(n_valid) if compact else lengths + n_valid
     )
     length = jnp.sum(new_lengths).astype(jnp.int32)  # schema slot only
-    if quant:
-        out_cache: Any = QuantKVCache(
+    if latent:
+        out_cache: Any = LatentCache(ckv=new_k, kpe=new_v, length=length)
+    elif quant:
+        out_cache = QuantKVCache(
             k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs, length=length
         )
     else:
         out_cache = KVCache(k=new_k, v=new_v, length=length)
-    return _logits(cfg, head_p, x), out_cache, new_lengths
+    out = (_logits(cfg, head_p, x), out_cache, new_lengths)
+    if expert_counts:
+        if not counts:
+            raise ValueError(
+                "expert_counts=True needs expert layers that say what "
+                "they hold (moe=MoEConfig(held=...))"
+            )
+        out += (jnp.stack(counts),)
+    return out
 
 
 def _mask_finished_rows(
@@ -715,6 +831,13 @@ def _mask_finished_rows(
         )
         return lax.dynamic_update_slice_in_dim(n, col, at, axis)
 
+    if isinstance(new, LatentCache):
+        a3 = alive[:, None, None]
+        return LatentCache(
+            ckv=[merge(n, o, a3, 1) for n, o in zip(new.ckv, old.ckv)],
+            kpe=[merge(n, o, a3, 1) for n, o in zip(new.kpe, old.kpe)],
+            length=new.length,
+        )
     a4 = alive[:, None, None, None]
     k = [merge(n, o, a4, 1) for n, o in zip(new.k, old.k)]
     v = [merge(n, o, a4, 1) for n, o in zip(new.v, old.v)]
@@ -822,7 +945,15 @@ def _mlp_layer_for(cfg: TransformerConfig, moe: Optional[Any]) -> Optional[Any]:
 
 
 def _mlp_out(cfg: TransformerConfig, p: Pytree, h: jnp.ndarray,
-             mlp_layer: Optional[Any]) -> jnp.ndarray:
+             mlp_layer: Optional[Any],
+             valid: Optional[jnp.ndarray] = None,
+             counts_out: Optional[List[jnp.ndarray]] = None) -> jnp.ndarray:
+    """The block's feed-forward on normed states ``h [b, g, dim]``, told
+    apart by the block's own keys: an ``"mlp"`` subtree is an expert
+    layer (run by ``mlp_layer``), else the dense forms — so one model
+    may lead with dense blocks and go on with expert blocks.  With
+    ``counts_out`` an expert layer appends its held experts' token
+    counts (positions where ``valid`` is False not counted)."""
     if "mlp" in p:
         if mlp_layer is None:
             raise ValueError(
@@ -830,7 +961,11 @@ def _mlp_out(cfg: TransformerConfig, p: Pytree, h: jnp.ndarray,
                 "family); pass moe=MoEConfig(...) matching the training "
                 "configuration to prefill()/generate()"
             )
-        out, _ = mlp_layer.apply(p["mlp"], (), h, rng=None, train=False)
+        if counts_out is not None:
+            out, held = mlp_layer.meta["forward_counts"](p["mlp"], h, valid)
+            counts_out.append(held)
+        else:
+            out, _ = mlp_layer.apply(p["mlp"], (), h, rng=None, train=False)
         return out.astype(h.dtype)
     if "w_fc" in p:  # classic (GPT-2-style) fc -> act -> proj
         hid = _act_fn(cfg.act)(h @ _w(cfg, p, "w_fc") + p["b_fc"])
@@ -992,11 +1127,30 @@ def prefill(
         )
     W = cfg.attn_window if ring else None
     L = W if ring else max_len
+    mlp_layer = _mlp_layer_for(cfg, moe)
+    if cfg.mla is not None:
+        if ring or kv_quant:
+            _refuse_mla(cfg, "a ring or int8 cache")
+        # The prompt's own rows ARE the cache rows it attends: one
+        # ``mla.attend`` a block over the s new rows, banked after.
+        cache = init_cache(cfg, b, max_len)
+        x = _embed(cfg, embed_p, tokens)
+        new_c, new_r = [], []
+        for p, cc, cr in zip(block_p, cache.ckv, cache.kpe):
+            h = _block_norm(cfg, p, "ln1", x)
+            q_nope, q_pe, ckv, kpe = mla.project(cfg, p, h, 0)
+            attn = mla.attend(cfg, p, q_nope, q_pe, ckv, kpe, 0)
+            x = _block_attn_out(cfg, p, x, attn, mlp_layer)
+            new_c.append(lax.dynamic_update_slice_in_dim(
+                cc, ckv.astype(cc.dtype), 0, 1))
+            new_r.append(lax.dynamic_update_slice_in_dim(
+                cr, kpe.astype(cr.dtype), 0, 1))
+        return _logits(cfg, head_p, x)[:, -1], LatentCache(
+            ckv=new_c, kpe=new_r, length=jnp.asarray(s, jnp.int32))
     cache = (
         init_quant_cache(cfg, b, L) if kv_quant else init_cache(cfg, b, L)
     )
     hd = cfg.head_dim
-    mlp_layer = _mlp_layer_for(cfg, moe)
     x = _embed(cfg, embed_p, tokens)
     new_k, new_v = [], []
     new_ks, new_vs = [], []
@@ -1121,7 +1275,7 @@ def _generate_rows(
             f"row_lengths must hold one frontier per prompt row "
             f"([{b}]), got shape {tuple(rl.shape)}"
         )
-    L = cache.k[0].shape[1]
+    L = _cache_rows(cache)
     _check_decodable(cfg, L)
     if not isinstance(rl, jax.core.Tracer):
         deepest = int(jax.device_get(rl).max())
@@ -1394,6 +1548,7 @@ def beam_search(
         raise ValueError(f"num_beams must be >= 1, got {k}")
     total = _total_len(s, max_new_tokens, max_len)
     _check_decodable(cfg, total)
+    _refuse_mla(cfg, "beam search's reordered cache")
     embed_p, block_p, head_p = _split_params(cfg, params)
     mlp_layer = _mlp_layer_for(cfg, moe)
     logits0, cache = prefill(cfg, params, prompt, total, moe=moe)
@@ -1583,6 +1738,8 @@ def speculative_generate(
         rng = jax.random.PRNGKey(0)  # deterministic path; keys unused
     total = _total_len(s, T, max_len)
     _check_decodable(cfg, total)
+    _refuse_mla(cfg, "speculative decoding's rolled-back cache")
+    _refuse_mla(draft_cfg, "speculative decoding's rolled-back cache")
     # The draft decodes to the same frontier (its table clamps just as
     # silently — garbage proposals would only collapse the acceptance
     # rate, with no error).

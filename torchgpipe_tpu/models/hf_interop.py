@@ -1537,3 +1537,106 @@ def state_dict_to_hf_t5(
 
 
 __all__ += ["state_dict_to_hf_t5"]
+
+
+def config_from_hf_latent_moe(
+    hf_config: Any, held: Any = None
+) -> tuple:
+    """(TransformerConfig, MoEConfig) for the published config of a
+    latent-attention, sigmoid- or softmax-routed expert model (the
+    DeepSeek-V2/V3 key set: ``q_lora_rank``, ``kv_lora_rank``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+    ``rope_scaling`` of type yarn; ``n_routed_experts``,
+    ``num_experts_per_tok``, ``moe_intermediate_size``,
+    ``n_shared_experts``, ``scoring_func``, ``norm_topk_prob``,
+    ``routed_scaling_factor``, ``topk_method``).  ``hf_config`` is any
+    object with those attributes, as they stand in ``config.json``.
+
+    ``held=(first, count)`` makes the expert layers one chip's share
+    (``MoEConfig.held``).  The leading ``first_k_dense_replace`` blocks
+    are dense SwiGLUs of ``intermediate_size`` and are told apart by
+    their params (``generation._mlp_out``), not by a config.  Serving
+    path only: there is no weight importer or training block for this
+    family yet.  An unknown ``topk_method`` or ``scoring_func`` raises
+    instead of falling back to plain softmax top-k."""
+    from torchgpipe_tpu.models.moe import _SELECT, MoEConfig
+    from torchgpipe_tpu.models.transformer import MLAConfig, YarnRope
+
+    hf = hf_config
+    scoring = getattr(hf, "scoring_func", "softmax")
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(
+            f"scoring_func={scoring!r} is not computed here "
+            "('softmax' and 'sigmoid' are)"
+        )
+    select = getattr(hf, "topk_method", "greedy")
+    if select not in _SELECT:
+        raise ValueError(
+            f"topk_method={select!r} is not computed here "
+            f"({sorted(_SELECT)} are); a grouped or bias-corrected "
+            "selection is one more entry of models.moe._SELECT"
+        )
+    if getattr(hf, "attention_bias", False):
+        raise ValueError("attention_bias=True: MLA here is bias-free")
+    if getattr(hf, "moe_layer_freq", 1) != 1:
+        raise ValueError(
+            f"moe_layer_freq={hf.moe_layer_freq}: every layer after the "
+            "leading dense ones is taken to be an expert layer"
+        )
+    yarn = None
+    rs = getattr(hf, "rope_scaling", None)
+    if rs:
+        kind = rs.get("type", rs.get("rope_type"))
+        if kind != "yarn":
+            raise ValueError(
+                f"rope_scaling type {kind!r} is not computed here "
+                "('yarn' is)"
+            )
+        yarn = YarnRope(
+            factor=float(rs["factor"]),
+            original_max_pos=int(rs["original_max_position_embeddings"]),
+            beta_fast=float(rs.get("beta_fast", 32)),
+            beta_slow=float(rs.get("beta_slow", 1)),
+            mscale=float(rs.get("mscale", 1)),
+            mscale_all_dim=float(rs.get("mscale_all_dim", 0)),
+        )
+    dim, inter = hf.hidden_size, hf.intermediate_size
+    cfg = TransformerConfig(
+        vocab=hf.vocab_size,
+        dim=dim,
+        n_layers=hf.num_hidden_layers,
+        n_heads=hf.num_attention_heads,
+        mlp_ratio=3.0 * inter / (2.0 * dim),
+        rope_theta=float(getattr(hf, "rope_theta", 10000.0)),
+        norm_eps=float(hf.rms_norm_eps),
+        tie_embeddings=bool(getattr(hf, "tie_word_embeddings", False)),
+        mla=MLAConfig(
+            q_lora_rank=int(hf.q_lora_rank),
+            kv_lora_rank=int(hf.kv_lora_rank),
+            qk_nope_head_dim=int(hf.qk_nope_head_dim),
+            qk_rope_head_dim=int(hf.qk_rope_head_dim),
+            v_head_dim=int(hf.v_head_dim),
+            rope_scaling=yarn,
+        ),
+    )
+    if cfg.mlp_hidden != inter:
+        raise ValueError(
+            f"intermediate_size={inter} cannot be expressed by this "
+            f"config's 128-aligned SwiGLU formula (got {cfg.mlp_hidden})"
+        )
+    moe = MoEConfig(
+        n_experts=int(hf.n_routed_experts),
+        top_k=int(hf.num_experts_per_tok),
+        dispatch="dropless",
+        scoring=scoring,
+        norm_topk=bool(getattr(hf, "norm_topk_prob", False)),
+        route_scale=float(getattr(hf, "routed_scaling_factor", 1.0)),
+        n_shared=int(getattr(hf, "n_shared_experts", 0) or 0),
+        expert_hidden=int(hf.moe_intermediate_size),
+        held=None if held is None else (int(held[0]), int(held[1])),
+        select=select,
+    )
+    return cfg, moe
+
+
+__all__ += ["config_from_hf_latent_moe"]

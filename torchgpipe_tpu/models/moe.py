@@ -121,6 +121,40 @@ class MoEConfig:
     # ``top_k`` are ignored (the EC gather/scatter is its own path and
     # ``capacity`` plays top_k's role).
     router: str = "topk"
+    # ---- the sigmoid-routed, shared-expert family (DeepSeek-V3 class) -- #
+    # Router score: 'softmax' over the experts (Switch/GShard/Mixtral) or
+    # 'sigmoid' of each logit on its own.
+    scoring: str = "softmax"
+    # Normalise the k selected scores to sum 1.  None keeps the historical
+    # rule (normalise when top_k > 1; a single choice keeps its raw
+    # probability so the router still gets gradient).
+    norm_topk: Optional[bool] = None
+    # Factor on the (normalised) combine weights: routed_scaling_factor.
+    route_scale: float = 1.0
+    # Shared experts: a dense SwiGLU of width n_shared * expert width that
+    # every token takes, added to the routed sum (params under 'shared').
+    n_shared: int = 0
+    # Width of an expert's SwiGLU; None -> cfg.mlp_hidden.
+    expert_hidden: Optional[int] = None
+    # (first, count): this layer HOLDS experts [first, first + count) of
+    # the n_experts the router scores — one chip's share of an expert-
+    # parallel deployment, run without its exchange.  The router keeps all
+    # n_experts outputs and the normalisation runs over all top_k
+    # selected; the routed sum runs over the selected experts that are
+    # held, the shared expert is computed whole, and what absent experts
+    # would add is left out.  Expert params have ``count`` leading rows.
+    # Needs the dropless path ('dropless', or 'auto' which then picks it)
+    # and no ep_axis: no token is dropped whatever the routing.  None
+    # holds every expert.
+    held: Optional[Tuple[int, int]] = None
+    # How the top_k are chosen from the scores (the published
+    # ``topk_method``): 'none' / 'greedy' take the k largest over all
+    # experts.  A grouped rule is one more entry of ``_SELECT``.
+    select: str = "none"
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held if self.held is not None else (0, self.n_experts)
 
 
 @jax.custom_vjp
@@ -228,17 +262,49 @@ def _top_k_select(
     return jnp.stack(idxs), masks, jnp.stack(gates)
 
 
-def _gate_denom(gates: jnp.ndarray, k: int) -> jnp.ndarray:
+def _gate_denom(gates: jnp.ndarray, k: int,
+                norm: Optional[bool] = None) -> jnp.ndarray:
     # k>1: normalize combine weights over the k selections (GShard).  k=1
     # keeps the raw softmax probability as the gate (Switch) — normalizing
     # would pin it to ~1.0 and starve the router of gradient entirely.
-    return jnp.sum(gates, axis=0) + 1e-9 if k > 1 else jnp.ones(())
+    # ``norm`` (MoEConfig.norm_topk) overrides that rule either way.
+    if norm is None:
+        norm = k > 1
+    return jnp.sum(gates, axis=0) + 1e-9 if norm else jnp.ones(())
+
+
+# MoEConfig.select -> selection rule over the scores [t, E]: returns
+# (indices [k, t], one-hot masks, raw gate values [k, t]).
+_SELECT = {"none": _top_k_select, "greedy": _top_k_select}
+
+
+def _scores(moe: MoEConfig, logits: jnp.ndarray) -> jnp.ndarray:
+    if moe.scoring == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def _route(
+    probs: jnp.ndarray, k: int, moe: Optional[MoEConfig] = None,
+) -> Tuple[jnp.ndarray, List[jnp.ndarray], jnp.ndarray]:
+    """Selection and combine weights under ``moe`` (None: the historical
+    plain top-k, normalised when k > 1): per-round expert indices
+    ``[k, t]``, one-hot masks, and the weights ``[k, t]`` — the selected
+    scores, normalised over the k (``norm_topk``) and scaled
+    (``route_scale``)."""
+    select = _SELECT[moe.select if moe is not None else "none"]
+    idxs, masks, raw = select(probs, k)
+    gates = raw / _gate_denom(raw, k, None if moe is None else moe.norm_topk)
+    if moe is not None and moe.route_scale != 1.0:
+        gates = gates * moe.route_scale
+    return idxs, masks, gates
 
 
 def _top_k_dispatch(
     probs: jnp.ndarray,
     k: int,
     capacity: int,
+    moe: Optional[MoEConfig] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dense dispatch/combine tensors from router probabilities.
 
@@ -248,9 +314,8 @@ def _top_k_dispatch(
     order, k-th choices after all (k-1)-th choices (Switch/GShard order).
     """
     t, E = probs.shape
-    _, masks, gates_kt = _top_k_select(probs, k)
+    _, masks, gates_kt = _route(probs, k, moe)
     gates = [gates_kt[kk] for kk in range(k)]
-    denom = _gate_denom(gates_kt, k)
 
     combine = jnp.zeros((t, E, capacity), probs.dtype)
     counts = jnp.zeros((E,), probs.dtype)
@@ -260,7 +325,7 @@ def _top_k_dispatch(
         counts = counts + jnp.sum(mask, axis=0)
         pos = jnp.sum(pos_in_e * mask, axis=-1).astype(jnp.int32)  # [t]
         keep = (pos < capacity) & (jnp.sum(mask, axis=-1) > 0)
-        gate_k = jnp.where(keep, gates[kk] / denom, 0.0)
+        gate_k = jnp.where(keep, gates[kk], 0.0)
         slot = jax.nn.one_hot(pos, capacity, dtype=probs.dtype)  # [t, C]
         combine = combine + (
             mask[:, :, None] * slot[:, None, :] * gate_k[:, None, None]
@@ -272,6 +337,7 @@ def _top_k_dispatch(
 def _flat_assignment(
     probs: jnp.ndarray,
     k: int,
+    moe: Optional[MoEConfig] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Shared routing prologue for the sort-based dispatch paths.
 
@@ -285,10 +351,9 @@ def _flat_assignment(
     ('dropless') paths build on exactly this — their equivalence to the
     dense one-hot path is load-bearing and oracle-tested.
     """
-    idxs, _, gates_kt = _top_k_select(probs, k)
-    denom = _gate_denom(gates_kt, k)
+    idxs, _, gates_kt = _route(probs, k, moe)
     experts = idxs.reshape(-1).astype(jnp.int32)  # [kt], k-major
-    gates = (gates_kt / denom).reshape(-1)
+    gates = gates_kt.reshape(-1)
     order = jnp.argsort(experts, stable=True)
     counts = jnp.bincount(experts, length=probs.shape[1])
     return experts, gates, order, counts
@@ -298,6 +363,7 @@ def _sparse_assignment(
     probs: jnp.ndarray,
     k: int,
     capacity: int,
+    moe: Optional[MoEConfig] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Sort-based slot assignment — identical FCFS semantics to
     :func:`_top_k_dispatch` (token order within a choice round, round kk
@@ -311,7 +377,7 @@ def _sparse_assignment(
     """
     t = probs.shape[0]
     kt = k * t
-    experts, gates, order, counts = _flat_assignment(probs, k)
+    experts, gates, order, counts = _flat_assignment(probs, k, moe)
     sorted_e = experts[order]
     starts = jnp.cumsum(counts) - counts  # segment start per expert
     # Position within the expert group IS the dense path's slot number.
@@ -325,6 +391,7 @@ def _sparse_assignment(
 def _dropless_assignment(
     probs: jnp.ndarray,
     k: int,
+    moe: Optional[MoEConfig] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Expert-sorted token assignment for the dropless path.
 
@@ -333,7 +400,7 @@ def _dropless_assignment(
     ``group_sizes [E]`` are the ragged segment lengths, and ``gates`` are
     the normalized combine weights in *unsorted* k-major order."""
     t = probs.shape[0]
-    _, gates, order, counts = _flat_assignment(probs, k)
+    _, gates, order, counts = _flat_assignment(probs, k, moe)
     tok = jnp.arange(k * t) % t
     return order, tok[order], counts.astype(jnp.int32), gates
 
@@ -357,11 +424,38 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
     weights ``w_gate/w_up [E, dim, hidden]``, ``w_down [E, hidden, dim]``
     (sharded over ``moe.ep_axis`` when set).
     """
-    dim, hidden = cfg.dim, cfg.mlp_hidden
+    dim, hidden = cfg.dim, moe.expert_hidden or cfg.mlp_hidden
     E, K = moe.n_experts, moe.top_k
     dt = cfg.dtype
     if K > E:
         raise ValueError(f"top_k={K} exceeds n_experts={E}")
+    if moe.scoring not in ("softmax", "sigmoid"):
+        raise ValueError(
+            f"MoEConfig.scoring={moe.scoring!r}: expected 'softmax' or "
+            "'sigmoid'"
+        )
+    if moe.select not in _SELECT:
+        raise ValueError(
+            f"MoEConfig.select={moe.select!r}: the selection rules "
+            f"computed here are {sorted(_SELECT)}"
+        )
+    first, n_held = moe.held_range
+    if moe.held is not None:
+        if not (0 <= first and n_held >= 1 and first + n_held <= E):
+            raise ValueError(
+                f"held={moe.held} is not a range [first, first + count) "
+                f"of the {E} experts"
+            )
+        if (moe.dispatch not in ("auto", "dropless")
+                or moe.ep_axis is not None
+                or moe.router != "topk"):
+            raise ValueError(
+                "held=(first, count) computes this chip's experts' part "
+                "through the dropless path, without the exchange: it "
+                "needs dispatch='dropless' (or 'auto'), ep_axis=None and "
+                "router='topk'"
+            )
+    dropless = moe.dispatch == "dropless" or moe.held is not None
     if moe.dispatch not in ("auto", "dense", "sparse", "dropless"):
         raise ValueError(
             "MoEConfig.dispatch must be 'auto'|'dense'|'sparse'|'dropless'"
@@ -402,14 +496,29 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
             # f32 router: routing decisions are argmaxes over near-ties;
             # keeping them out of bf16 avoids batch-dependent flips.
             "router": _normal(ks[0], (dim, E), std, jnp.float32),
-            "w_gate": _normal(ks[1], (E, dim, hidden), std, dt),
-            "w_up": _normal(ks[2], (E, dim, hidden), std, dt),
-            "w_down": _normal(ks[3], (E, hidden, dim), hidden ** -0.5, dt),
+            "w_gate": _normal(ks[1], (n_held, dim, hidden), std, dt),
+            "w_up": _normal(ks[2], (n_held, dim, hidden), std, dt),
+            "w_down": _normal(
+                ks[3], (n_held, hidden, dim), hidden ** -0.5, dt),
         }
+        if moe.n_shared:
+            sh, kss = moe.n_shared * hidden, jax.random.split(ks[0], 4)
+            params["shared"] = {
+                "w_gate": _normal(kss[1], (dim, sh), std, dt),
+                "w_up": _normal(kss[2], (dim, sh), std, dt),
+                "w_down": _normal(kss[3], (sh, dim), sh ** -0.5, dt),
+            }
         return params, ()
 
     def apply(params, state, x, *, rng=None, train=True):
         del rng
+        return forward(params, x, train=train)[0], state
+
+    def forward(params, x, valid=None, *, train=False):
+        """``(y, counts)``: the layer's output and, int32 ``[held]``,
+        the tokens routed to each held expert (positions where ``valid
+        [b, s]`` is False left out of the count; their rows are still
+        computed, as every masked row of a step is)."""
         b, s, d = x.shape
         t = b * s
         xf = x.reshape(t, d)
@@ -423,18 +532,25 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
         else:
             capacity = max(1, math.ceil(moe.capacity_factor * K * t / E))
 
-        logits = xf.astype(jnp.float32) @ params["router"]  # [t, E]
-        probs = jax.nn.softmax(logits, axis=-1)
+        with jax.named_scope("moe.route"):
+            logits = xf.astype(jnp.float32) @ params["router"]  # [t, E]
+            probs = _scores(moe, logits)
 
-        def _finish(y):
-            """Shared epilogue: reshape + optional balance-penalty
-            gradient injection (see add_aux_grad /
+        def _finish(y, counts=None):
+            """Shared epilogue: the shared expert, reshape + optional
+            balance-penalty gradient injection (see add_aux_grad /
             MoEConfig.balance_weight)."""
+            if moe.n_shared:
+                with jax.named_scope("moe.shared"):
+                    sp = params["shared"]
+                    y = y + (
+                        jax.nn.silu(xf @ sp["w_gate"]) * (xf @ sp["w_up"])
+                    ) @ sp["w_down"]
             y = y.reshape(b, s, d).astype(x.dtype)
             if moe.balance_weight > 0.0 and train:
                 _, _, aux = _balance_penalty(probs, E, K)
                 y = add_aux_grad(y, aux, moe.balance_weight)
-            return y, state
+            return y, counts
 
         if moe.router == "expert_choice":
             # Expert-choice routing (Zhou et al. arXiv:2202.09368): each
@@ -453,27 +569,45 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
             )
             return _finish(y)
 
-        if moe.dispatch == "dropless":
+        if dropless:
             # Megablocks-style dropless experts: sort the k*t assignments
             # by expert and run the SwiGLU as grouped matmuls over the
             # ragged segments (lax.ragged_dot → TPU grouped-matmul
             # lowering).  No capacity, no drops, no [E, C, d] buffers —
             # work is exactly k*t rows however unbalanced the router is.
-            order, tok_sorted, group_sizes, gates = _dropless_assignment(
-                probs, K
-            )
-            xs = xf[tok_sorted]  # [kt, d] expert-sorted
-            h = jax.nn.silu(
-                lax.ragged_dot(xs, params["w_gate"], group_sizes)
-            ) * lax.ragged_dot(xs, params["w_up"], group_sizes)
-            ys = lax.ragged_dot(h, params["w_down"], group_sizes)  # [kt, d]
-            gate_sorted = gates[order].astype(ys.dtype)
-            y = (
-                jnp.zeros((t, d), ys.dtype)
-                .at[tok_sorted]
-                .add(ys * gate_sorted[:, None])
-            )
-            return _finish(y)
+            # Under ``held`` the sort key is the LOCAL expert id, with
+            # every assignment to an absent expert (or of a masked
+            # position) keyed past the last held one: the held experts'
+            # segments come first and are the only groups; the rows
+            # behind them belong to no group and their gates are 0.
+            with jax.named_scope("moe.route"):
+                experts, gates, _, _ = _flat_assignment(probs, K, moe)
+                tok = jnp.arange(K * t) % t
+                local = experts - first
+                mine = (local >= 0) & (local < n_held)
+                if valid is not None:
+                    mine = mine & valid.reshape(t)[tok]
+                key = jnp.where(mine, local, n_held)
+                order = jnp.argsort(key, stable=True)
+                group_sizes = jnp.bincount(
+                    key, length=n_held + 1)[:n_held].astype(jnp.int32)
+                tok_sorted = tok[order]
+                gate_sorted = jnp.where(mine, gates, 0.0)[order]
+            with jax.named_scope("moe.experts"):
+                xs = xf[tok_sorted]  # [kt, d] expert-sorted
+                h = jax.nn.silu(
+                    lax.ragged_dot(xs, params["w_gate"], group_sizes)
+                ) * lax.ragged_dot(xs, params["w_up"], group_sizes)
+                ys = lax.ragged_dot(h, params["w_down"], group_sizes)
+                if moe.held is not None or valid is not None:
+                    # Rows of no group hold whatever the lowering left.
+                    ys = jnp.where(gate_sorted[:, None] != 0.0, ys, 0.0)
+                y = (
+                    jnp.zeros((t, d), ys.dtype)
+                    .at[tok_sorted]
+                    .add(ys * gate_sorted.astype(ys.dtype)[:, None])
+                )
+            return _finish(y, group_sizes)
         # Dense one-hot einsum dispatch materializes [t, E, C] tensors; past
         # ~16M elements (64MB f32) the sort-based scatter/gather path wins on
         # memory by orders of magnitude (8k tokens x 64 experts: ~670MB vs
@@ -482,7 +616,8 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
             moe.dispatch == "auto" and t * E * capacity > 1 << 24
         )
         if use_sparse:
-            experts, gates, keep, slot = _sparse_assignment(probs, K, capacity)
+            experts, gates, keep, slot = _sparse_assignment(
+                probs, K, capacity, moe)
             tok = jnp.arange(K * t) % t
             contrib = xf[tok] * keep[:, None].astype(xf.dtype)
             expert_in = (
@@ -490,7 +625,7 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
                 .at[experts, slot].add(contrib)
             )
         else:
-            combine, dispatch = _top_k_dispatch(probs, K, capacity)
+            combine, dispatch = _top_k_dispatch(probs, K, capacity, moe)
             # Dispatch: [t, E, C] one-hot x [t, d] -> expert buffers [E, C, d].
             expert_in = jnp.einsum(
                 "tec,td->ecd", dispatch.astype(xf.dtype), xf
@@ -538,6 +673,9 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
         apply=apply,
         meta={
             "kind": "moe_mlp",
+            # (params, x, valid=None) -> (y, held experts' token counts);
+            # the counts exist on the dropless path (None elsewhere).
+            "forward_counts": forward,
             "balance_weight": moe.balance_weight,
             "ep_axis": ep,
             "validate_mesh": validate_mesh,

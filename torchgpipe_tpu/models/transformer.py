@@ -38,6 +38,46 @@ from torchgpipe_tpu.parallel.tensor import (
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN rotary frequency scaling (Peng et al., arXiv:2309.00071) as
+    the published ``rope_scaling`` record of ``type: yarn`` states it.
+    ``models.mla.yarn_inv_freq`` / ``yarn_mscale`` compute from it."""
+
+    factor: float
+    original_max_pos: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434): the
+    five sizes and the rope-scaling record.  Queries go through a
+    ``q_lora_rank`` bottleneck; keys and values are expanded from ONE
+    ``kv_lora_rank`` latent a token plus one rotary key head of
+    ``qk_rope_head_dim`` shared by all heads — and that pair is what the
+    cache holds (``models.generation.LatentCache``), not K and V."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_scaling: Optional[YarnRope] = None
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_row(self) -> int:
+        """Values cached a token a layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 32000
     dim: int = 512
@@ -148,6 +188,16 @@ class TransformerConfig:
     # + ``SpmdGPipe`` (fill-drain schedule) and by decode; the flat
     # ``llama()`` MPMD list rejects it with a pointer.
     tie_embeddings: bool = False
+    # Attention kind: None is the K/V family above (MHA/GQA); an
+    # :class:`MLAConfig` makes every block latent attention, whose cache
+    # row is the latent and not K and V.  Serving path only
+    # (``models.generation``, ``serving.Engine``): the training block
+    # refuses it.
+    mla: Optional[MLAConfig] = None
+
+    @property
+    def attn_kind(self) -> str:
+        return "gqa" if self.mla is None else "mla"
 
     @property
     def kv_heads(self) -> int:
@@ -308,7 +358,8 @@ def rms_norm(dim: int, *, eps: float = 1e-5, name: str = "rmsnorm") -> Layer:
     )
 
 
-def _rope(x: jnp.ndarray, theta: float, pos_offset: Any = 0) -> jnp.ndarray:
+def _rope(x: jnp.ndarray, theta: float, pos_offset: Any = 0,
+          freqs: Optional[Any] = None, amplitude: float = 1.0) -> jnp.ndarray:
     """Rotary position embedding over the trailing head_dim, positions from
     shape plus ``pos_offset`` (x: [b, s, heads, head_dim]).  A non-zero
     offset gives sequence-parallel shards their *global* token positions;
@@ -316,10 +367,14 @@ def _rope(x: jnp.ndarray, theta: float, pos_offset: Any = 0) -> jnp.ndarray:
     the slot-pooled serving decode, where each slot sits at a different
     sequence frontier.  A ``[b, s]``-shaped offset is taken as ABSOLUTE
     per-token positions (sequence packing: each packed document's
-    positions restart at 0 — ``utils.data.pack_documents``)."""
+    positions restart at 0 — ``utils.data.pack_documents``).  ``freqs``
+    ``[head_dim // 2]`` replaces ``theta``'s inverse frequencies and
+    ``amplitude`` scales cos and sin (YaRN: ``models.mla.rope``)."""
     b, s, h, d = x.shape
     half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if freqs is None:
+        freqs = 1.0 / (
+            theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     # [B', s] positions with B' = b (per-row offset / per-token packed
     # positions) or 1 (shared) — one rotation body either way; the B'=1
     # case broadcasts exactly as the pre-per-row [1, s, 1, half] cos/sin
@@ -332,6 +387,8 @@ def _rope(x: jnp.ndarray, theta: float, pos_offset: Any = 0) -> jnp.ndarray:
     ang = positions[..., None] * freqs  # [B', s, half]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if amplitude != 1.0:
+        cos, sin = amplitude * cos, amplitude * sin
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [
@@ -404,6 +461,13 @@ def transformer_block(
     the block's.
     """
     cfg.validate_arch()
+    if cfg.mla is not None:
+        raise NotImplementedError(
+            "latent attention (cfg.mla) is computed on the serving path "
+            "only (models.generation.prefill / decode_slots, "
+            "serving.Engine; block params from models.mla.init_block); "
+            "the training block has no MLA forward"
+        )
     dim, hd = cfg.dim, cfg.head_dim
     nh, nkv = cfg.n_heads, cfg.kv_heads
     hidden = cfg.mlp_hidden

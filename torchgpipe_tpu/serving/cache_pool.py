@@ -7,7 +7,12 @@ The pool squares that circle the PagedAttention/Orca way, specialised to
 one page per request: a fixed ``[num_slots, max_len, kv_heads, head_dim]``
 K/V bank per layer (a :class:`~torchgpipe_tpu.models.generation.KVCache`
 or int8 :class:`~torchgpipe_tpu.models.generation.QuantKVCache` whose
-batch dim IS the slot dim), a host-side free list handing slots to
+batch dim IS the slot dim) — or, for a latent-attention model
+(``cfg.mla``), what that attention caches: a
+:class:`~torchgpipe_tpu.models.generation.LatentCache` of
+``[num_slots, max_len, kv_lora_rank]`` and ``[num_slots, max_len,
+qk_rope_head_dim]`` a layer; ``init_cache`` decides by the attention
+kind — a host-side free list handing slots to
 requests and taking them back, and a per-slot ``lengths`` vector (host
 mirror, passed into every compiled step) giving each slot its own
 sequence frontier.

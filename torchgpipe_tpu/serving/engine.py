@@ -197,6 +197,33 @@ class Engine:
                 "token-choice routing (router='topk'), which routes "
                 "every token independently of its batch neighbours"
             )
+        # A latent-attention model (``cfg.mla``) is served by the plain
+        # pool and the two step programs; what moves or shares K/V rows
+        # between slots or pools is written for K and V banks.
+        if cfg.mla is not None:
+            for on, what in (
+                (kv_quant, "kv_quant (the int8 QuantKVCache)"),
+                (prefix_cache is not None, "a prefix cache"),
+                (role != "unified", f"role={role!r} (KV-row migration)"),
+            ):
+                if on:
+                    raise NotImplementedError(
+                        f"{what} copies or quantizes K and V rows; a "
+                        "latent-attention model's pool holds the KV "
+                        "latent (generation.LatentCache) — serve it "
+                        "with the plain unified engine"
+                    )
+        # Expert layers that are told what they hold (``MoEConfig.held``)
+        # report the tokens each held expert received: the step programs
+        # then return, in the place of their sampled tokens, the pair
+        # ``(tokens, int32 [expert layers, held])``.  The counts are
+        # read where the tokens are fetched and never on their own
+        # account: a step that fetches nothing leaves its counts here
+        # for the next fetch (``read_expert_counts``).
+        self._expert_counts = (
+            moe is not None and getattr(moe, "held", None) is not None
+        )
+        self._unread_counts: List[Tuple[str, int, Any]] = []
         # ``prefill_chunk`` may be an int (one prefill program — the
         # classic configuration) or a LADDER of chunk sizes (e.g.
         # ``(1, 2, 4, 8)``): one program per bucket, a prefill step
@@ -400,9 +427,9 @@ class Engine:
             # ``lengths`` comes back advanced ON DEVICE (at ``slots``,
             # by the rows each consumed): the next step reuses the
             # array instead of re-uploading the host mirror.
-            logits, cache, lengths = decode_slots(
+            logits, cache, lengths, *held = decode_slots(
                 cfg, params, tokens, cache, lengths, n_valid, moe=moe,
-                slots=slots,
+                slots=slots, expert_counts=self._expert_counts,
             )
             last = jnp.clip(n_valid - 1, 0, g - 1)
             row_logits = jnp.take_along_axis(
@@ -415,7 +442,7 @@ class Engine:
             # for speculative decoding's verify pass the grid is the
             # acceptance oracle (fleet/speculative.py).
             grid = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return tok, grid, cache, lengths, key
+            return (tok, *held) if held else tok, grid, cache, lengths, key
         return prefill_body
 
     def _build_programs(self) -> None:
@@ -424,11 +451,12 @@ class Engine:
 
         def decode_body(params, cache, lengths, tokens, n_valid, key):
             counts["decode"] += 1
-            logits, cache, lengths = decode_slots(
-                cfg, params, tokens, cache, lengths, n_valid, moe=moe
+            logits, cache, lengths, *held = decode_slots(
+                cfg, params, tokens, cache, lengths, n_valid, moe=moe,
+                expert_counts=self._expert_counts,
             )
             tok, key = self._sample_row(logits[:, 0], key)
-            return tok, cache, lengths, key
+            return (tok, *held) if held else tok, cache, lengths, key
 
         donate = (1,) if self.donate else ()
         self._prefill_fns = {
@@ -609,6 +637,11 @@ class Engine:
         (``analysis.serving.certify_disagg``)."""
         sds = jax.ShapeDtypeStruct
         c = self.pool.cache
+        if self.cfg.mla is not None:
+            raise NotImplementedError(
+                "KV-row migration is written for K and V banks; a "
+                "latent-attention pool holds the KV latent"
+            )
         rows: Dict[str, Any] = {
             "k": [sds(b.shape[1:], b.dtype) for b in c.k],
             "v": [sds(b.shape[1:], b.dtype) for b in c.v],
@@ -1041,6 +1074,8 @@ class Engine:
         self.pool.cache = cache
         self._key = key
         dur = max(self._rec_clock() - t0, 0.0)
+        if isinstance(tok, tuple):
+            tok = self._queue_expert_counts("prefill", n_valid.sum(), tok)
         if finishing:
             # Start the device→host token copy NOW; the subclass hook
             # below runs while it is in flight (copy_to_host_async is a
@@ -1056,6 +1091,9 @@ class Engine:
             # prompt completes and samples its first token.
             with tl.span("engine.fetch"):
                 tok_host = np.asarray(tok)              # [R]: row order
+                load = self.read_expert_counts()
+            if load:
+                tl.annotate(**load)
         with tl.span("engine.emit", tokens=finishing):
             if self.recorder is not None:
                 for r, take in zip(reqs, n_valid):
@@ -1080,6 +1118,36 @@ class Engine:
                         )
                     assert tok_host is not None
                     self._emit(r, int(tok_host[i]))
+
+    def _queue_expert_counts(self, kind: str, positions: int,
+                             out: Tuple[Any, Any]) -> Any:
+        """Split a step program's ``(tokens, counts)``: the tokens are
+        returned, the held experts' token counts ``int32 [expert layers,
+        held]`` start their copy to the host and wait for the next
+        fetch of tokens."""
+        tok, counts = out
+        _start_host_copy(counts)
+        self._unread_counts.append((kind, int(positions), counts))
+        return tok
+
+    def read_expert_counts(self) -> Dict[str, int]:
+        """The waiting counts into the counters, oldest first; the
+        newest step's come back as ``held`` / ``max_expert`` (empty
+        where nothing waited).  ``step`` calls it where it has just
+        fetched its tokens — that program is done, and so is every
+        earlier one — and puts the result on its action span; a reader
+        of ``metrics`` calls it after a window that time cut short,
+        and then waits for the last step's program."""
+        load: Dict[str, int] = {}
+        for kind, positions, counts in self._unread_counts:
+            counts = np.asarray(counts)
+            self.metrics.moe_step(
+                kind, positions * self.moe.top_k * counts.shape[0], counts
+            )
+            load = {"held": int(counts.sum()),
+                    "max_expert": int(counts.max())}
+        self._unread_counts.clear()
+        return load
 
     def _after_prefill_dispatch(
         self, g: int, slots: np.ndarray, tokens: np.ndarray,
@@ -1111,8 +1179,13 @@ class Engine:
         self.pool.cache = cache
         self._key = key
         t1 = self._rec_clock()
+        if isinstance(tok, tuple):
+            tok = self._queue_expert_counts("decode", len(reqs), tok)
         with tl.span("engine.fetch"):
             tok_host = np.asarray(tok)      # the ONE host fetch per step
+            load = self.read_expert_counts()
+        if load:
+            tl.annotate(**load)
         with tl.span("engine.emit", tokens=len(reqs)):
             self._commit_lengths(lengths_dev, n_valid)
             self.metrics.step("decode", len(reqs), self.pool.num_slots)

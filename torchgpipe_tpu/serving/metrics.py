@@ -134,6 +134,25 @@ class ServingMetrics:
         self._c_migr_in = reg.counter(
             "serving_migrations_in",
             help="requests ingested mid-stream from a prefill replica")
+        self._c_moe_routed = reg.counter(
+            "serving_moe_routed_assignments",
+            help="(position, expert) assignments the routers made: "
+                 "positions x top_k x expert layers")
+        self._c_moe_held = reg.counter(
+            "serving_moe_held_assignments",
+            help="assignments to experts this engine holds "
+                 "(MoEConfig.held): the routed work done here")
+        # Per step kind: the sums over steps of a step's largest and
+        # mean held-expert token count, and the steps summed.
+        self._moe_load = {
+            kind: [reg.counter(f"serving_moe_{kind}_expert_tokens_{what}",
+                               help=f"sum over {kind} steps of {text}")
+                   for what, text in (
+                       ("max", "the fullest held expert's tokens"),
+                       ("mean", "the mean held expert's tokens"),
+                       ("steps", "1 (steps with expert counts)"))]
+            for kind in ("prefill", "decode")
+        }
         self._h_ttft = reg.histogram(
             "serving_ttft_seconds", help="time to first token (arrival→)")
         self._h_tpot = reg.histogram(
@@ -159,6 +178,8 @@ class ServingMetrics:
     prefix_reused_tokens = _counter_property("_c_prefix_tokens")
     migrations_out = _counter_property("_c_migr_out")
     migrations_in = _counter_property("_c_migr_in")
+    moe_routed_assignments = _counter_property("_c_moe_routed")
+    moe_held_assignments = _counter_property("_c_moe_held")
 
     # ------------------------------------------------------------------ #
     # request lifecycle                                                  #
@@ -241,6 +262,25 @@ class ServingMetrics:
         self._c_occupied.inc(active_slots)
         self._c_total.inc(num_slots)
 
+    def moe_step(self, kind: str, routed: int, counts: Any) -> None:
+        """One step's expert load: ``routed`` assignments made, and
+        ``counts [expert layers, held]`` the tokens each held expert
+        received."""
+        self._c_moe_routed.inc(routed)
+        self._c_moe_held.inc(int(counts.sum()))
+        peak, mean, steps = self._moe_load[kind]
+        peak.inc(float(counts.max()))
+        mean.inc(float(counts.mean()))
+        steps.inc()
+
+    def moe_expert_tokens(self, kind: str) -> Dict[str, float]:
+        """Per ``kind`` step ('prefill' | 'decode'): the fullest and the
+        mean held expert's tokens a step (0 before any such step), and
+        the ``steps`` they are means over."""
+        peak, mean, steps = (c.value() for c in self._moe_load[kind])
+        n = max(steps, 1)
+        return {"max": peak / n, "mean": mean / n, "steps": steps}
+
     def drained(self, unfinished: int) -> None:
         self._c_drains.inc()
         self._c_preempted.inc(unfinished)
@@ -312,6 +352,12 @@ class ServingMetrics:
             "prefix_reused_tokens": self.prefix_reused_tokens,
             "migrations_out": self.migrations_out,
             "migrations_in": self.migrations_in,
+            "moe_routed_assignments": self.moe_routed_assignments,
+            "moe_held_assignments": self.moe_held_assignments,
+            "moe_expert_tokens_max": {
+                k: self.moe_expert_tokens(k)["max"] for k in self._moe_load},
+            "moe_expert_tokens_mean": {
+                k: self.moe_expert_tokens(k)["mean"] for k in self._moe_load},
             "ttft_p50": self._h_ttft.percentile(0.50),
             "ttft_p95": self._h_ttft.percentile(0.95),
             "ttft_p99": self._h_ttft.percentile(0.99),

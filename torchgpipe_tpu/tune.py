@@ -977,7 +977,10 @@ def serving_cache_bytes(
     same ``eval_shape``-only accounting the training-side probes use (no
     allocation, no compile): the pool is laid out by
     ``models.generation.init_cache`` / ``init_quant_cache``, so this IS
-    the HBM the pool will pin, not an estimate."""
+    the HBM the pool will pin, not an estimate.  ``init_cache`` lays a
+    latent-attention model's pool out as its latent rows (``cfg.mla``:
+    ``(kv_lora_rank + qk_rope_head_dim) * itemsize`` bytes a row a
+    layer, not K and V of ``kv_heads * head_dim``)."""
     from torchgpipe_tpu.models.generation import init_cache, init_quant_cache
 
     if kv_quant:
