@@ -84,6 +84,25 @@ def _decode(quant, window):
     return fn, shapes
 
 
+SLOTS, ROWS, CHUNK = 64, 12, 32    # mistral-7b.serve-backlog's pool
+BANK = (SLOTS, MAX_LEN, G, D)
+BANK_BYTES = SLOTS * MAX_LEN * G * D * 2      # one layer's K (or V): 512 MiB
+
+
+def _decode_rows(rows, g, compact):
+    """The per-row kernel over the serving pool's bank: ``rows`` query
+    rows of ``g`` tokens, each at its own frontier (and slot)."""
+    def fn(q, ck, cv, pos0, lengths, slots):
+        return flash_decode_attention(
+            q, ck, cv, pos0, window=WINDOW, lengths=lengths,
+            slots=slots if compact else None,
+        )
+
+    ints = ((rows,), jnp.int32)
+    return fn, [((rows, g, H, D), BF16), (BANK, BF16), (BANK, BF16),
+                ints, ints, ints]
+
+
 def _prefill_attention(s):
     def fn(q, k, v):
         return generation._attend_full(q, k, v, WINDOW)
@@ -105,6 +124,15 @@ CASES = {
     "decode-bf16-window": (*_decode(False, WINDOW), True),
     "decode-int8": (*_decode(True, None), True),
     "decode-int8-window": (*_decode(True, WINDOW), True),
+    # The serving engine's two programs' attention, at the cell's shapes:
+    # the bank is the kernel's operand as it lies, so the executable
+    # holds no temporary of a bank's size (a head-folded view of it was
+    # a relayout copy of 512 MiB a bank: described-chip compile, PR 29).
+    "decode-rows-pool": (*_decode_rows(SLOTS, 1, False), True, BANK_BYTES),
+    "decode-rows-compact": (
+        *_decode_rows(ROWS, CHUNK, True), True, BANK_BYTES),
+    # chip_smoke.py's engine: 8 rows of 128 tokens (a shorter block).
+    "decode-rows-chunk128": (*_decode_rows(8, 128, True), True, BANK_BYTES),
     # prefill()/generate(): a prompt the 128-blocks do not divide must
     # take the dense path (100 was refused by Mosaic, 200 compiled to a
     # short grid that left the tail rows unwritten), an aligned one the
@@ -117,7 +145,7 @@ CASES = {
 
 @pytest.mark.parametrize("name", list(CASES))
 def test_compiles_for_v5e(name, chip, monkeypatch):
-    fn, shapes, wants_kernel = CASES[name]
+    fn, shapes, wants_kernel, *temp_limit = CASES[name]
     # generation.py asks jax.devices() for the platform; a described-chip
     # compile still sees the CPU there, so the test answers for it.
     monkeypatch.setattr(jax, "devices", lambda *a: [chip])
@@ -126,5 +154,60 @@ def test_compiles_for_v5e(name, chip, monkeypatch):
         jax.ShapeDtypeStruct(shape, dtype, sharding=where)
         for shape, dtype in shapes
     ]
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    assert ("tpu_custom_call" in text) == wants_kernel
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == wants_kernel
+    for limit in temp_limit:
+        assert compiled.memory_analysis().temp_size_in_bytes < limit
+
+
+@pytest.mark.parametrize("compact", [False, True], ids=["pool", "compact"])
+def test_decode_slots_compiles_for_v5e(compact, chip, monkeypatch):
+    """``decode_slots`` at depth 1, Mistral-7B widths, over the cell's
+    pool (64 slots x 4096 rows, donated): the decode program (pool-wide,
+    one token a row) and the compact prefill program (12 rows of 32).
+    On a TPU both take the per-row kernel, and neither holds a
+    temporary of a bank's size: the scattered bank goes into the kernel
+    as it lies."""
+    from torchgpipe_tpu.layers import sequential_init
+    from torchgpipe_tpu.models.transformer import TransformerConfig, llama
+
+    cfg = TransformerConfig(
+        vocab=32000, dim=4096, n_layers=1, n_heads=H, n_kv_heads=G,
+        mlp_ratio=14336 / 4096, rope_theta=10000.0, dtype=BF16,
+        attn_window=WINDOW,
+    )
+    monkeypatch.setattr(jax, "devices", lambda *a: [chip])
+    where = SingleDeviceSharding(chip)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where),
+            tree,
+        )
+
+    params = jax.eval_shape(
+        lambda: sequential_init(
+            llama(cfg), jax.random.PRNGKey(0),
+            jax.ShapeDtypeStruct((1, 8), jnp.int32),
+        )[0]
+    )
+    cache = jax.eval_shape(
+        lambda: generation.init_cache(cfg, SLOTS, MAX_LEN)
+    )
+    rows, g = (ROWS, CHUNK) if compact else (SLOTS, 1)
+    ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=where
+    )
+
+    def step(params, cache, lengths, tokens, n_valid, slots):
+        return generation.decode_slots(
+            cfg, params, tokens, cache, lengths, n_valid,
+            slots=slots if compact else None,
+        )
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), ints(SLOTS), ints(rows, g),
+        ints(rows), ints(rows),
+    ).compile()
+    assert "flash_decode" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < BANK_BYTES

@@ -19,7 +19,14 @@ that are prefilling, by slot index), not over the pool's ``num_slots``:
    ``serving_prefill_deferred_rows`` the rows left waiting, on a
    hand-built schedule; the ``engine.prefill`` span carries ``cap`` and
    ``deferred``.
+5. **The per-row decode kernel under ``decode_slots``** (PR 29) —
+   forced in interpret mode it gives the dense path's logits, cache and
+   lengths, pool-wide and compact, with rows that do nothing; off TPU
+   the engine never reaches it; ``serving_attend_rows_read`` over
+   ``serving_attend_rows_capacity`` counts what it fetches.
 """
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -381,3 +388,181 @@ def test_fill_share_and_deferred_rows_on_a_hand_built_schedule(flat_params):
     # Decode steps are pool-wide: occupancy's denominator is the rows
     # each step's program had.
     assert m.total_slot_steps == 32 + SLOTS * m.decode_steps
+
+
+# --------------------------------------------------------------------- #
+# 5. the per-row decode kernel under decode_slots                       #
+# --------------------------------------------------------------------- #
+
+# Heads of 128 (what the kernel tiles) over three 128-blocks of cache.
+KCFG = TransformerConfig(
+    vocab=64, dim=512, n_layers=2, n_heads=4, n_kv_heads=2,
+    dtype=jnp.bfloat16,
+)
+KCFG32 = dataclasses.replace(KCFG, dtype=jnp.float32)
+KSLOTS, KLEN = 6, 384
+
+
+@pytest.fixture(scope="module")
+def kernel_params():
+    return _params(KCFG, 3)
+
+
+@pytest.mark.parametrize("g", [1, 8])
+@pytest.mark.parametrize("compact", [False, True], ids=["pool", "compact"])
+@pytest.mark.parametrize("cfg,tol", [(KCFG, 0.1), (KCFG32, 2e-4)],
+                         ids=["bf16", "f32"])
+def test_decode_slots_through_the_kernel(monkeypatch, cfg, tol, g, compact):
+    """``decode_slots`` with the Pallas kernel forced (interpret mode)
+    against its dense path, on a bf16 pool (to a few of bf16's last
+    bits of the logits: the attention agrees to f32's, and a hidden
+    state then rounds one step apart here and there) and on an f32 pool
+    (tight): the logits of the rows that did something, the cache (a
+    masked row's slot bit for bit) and the lengths, with frontiers on
+    both sides of a block edge, rows with ``n_valid = 0`` and, compact,
+    a padded row that repeats a slot."""
+    from torchgpipe_tpu.models import generation
+
+    KCFG, kernel_params = cfg, _params(cfg, 3)
+    rng = np.random.RandomState(g + 7 * compact)
+    cache = init_cache(KCFG, KSLOTS, KLEN)
+    assert cache.k[0].dtype == cfg.dtype
+    cache = jax.tree_util.tree_map(
+        lambda a: a if a.ndim == 0 else jnp.asarray(
+            rng.standard_normal(a.shape), a.dtype), cache)
+    lengths = np.array([0, 127, 128, 129, KLEN - g, 300], np.int32)
+    if compact:
+        slots = np.array([4, 1, 3, 1], np.int32)    # last row: padding
+        n_valid = np.array([g, 1, g, 0], np.int32)
+    else:
+        slots = None
+        n_valid = np.array([g, 1, 0, g, g, 0], np.int32)
+    tokens = rng.randint(0, 64, (len(n_valid), g)).astype(np.int32)
+
+    def run():
+        return decode_slots(
+            KCFG, kernel_params, jnp.asarray(tokens), cache,
+            jnp.asarray(lengths), jnp.asarray(n_valid),
+            slots=None if slots is None else jnp.asarray(slots),
+        )
+
+    ref_logits, ref_cache, ref_len = run()
+    calls = []
+    orig = generation._attend_chunk
+
+    def forced(*a, **kw):
+        calls.append(kw.get("slots") is not None)
+        return orig(*a, **{**kw, "use_flash": True})
+
+    monkeypatch.setattr(generation, "_attend_chunk", forced)
+    got_logits, got_cache, got_len = run()
+    assert calls == [compact] * KCFG.n_layers
+    assert np.array_equal(np.asarray(got_len), np.asarray(ref_len))
+    for i, n in enumerate(n_valid):
+        np.testing.assert_allclose(
+            np.asarray(got_logits[i, :n]), np.asarray(ref_logits[i, :n]),
+            rtol=tol, atol=tol,
+        )
+    # Layer 0 writes K/V before any attention: bit-equal.  Layer 1's
+    # rows follow layer 0's attention; a slot whose row did nothing
+    # keeps its bytes in both.
+    idle = np.setdiff1d(
+        np.arange(KSLOTS),
+        (np.arange(KSLOTS) if slots is None else slots)[n_valid > 0],
+    )
+    for name in ("k", "v"):
+        got, ref, old = (getattr(c, name) for c in (got_cache, ref_cache,
+                                                    cache))
+        assert np.array_equal(np.asarray(got[0]), np.asarray(ref[0]))
+        np.testing.assert_allclose(
+            np.asarray(got[1], np.float32), np.asarray(ref[1], np.float32),
+            rtol=tol, atol=tol,
+        )
+        for layer in range(KCFG.n_layers):
+            assert np.array_equal(
+                np.asarray(got[layer])[idle], np.asarray(old[layer])[idle]
+            )
+
+
+def test_engine_off_tpu_never_reaches_the_kernel(flat_params, monkeypatch):
+    """Off TPU the dense path serves every step: with the kernel made
+    unreachable the engine still gives ``generate``'s tokens, request
+    for request."""
+    from torchgpipe_tpu.ops import flash_attention
+
+    def unreachable(*a, **kw):
+        raise AssertionError("the decode kernel ran off TPU")
+
+    monkeypatch.setattr(
+        flash_attention, "flash_decode_attention", unreachable
+    )
+    eng = Engine(CFG, flat_params, num_slots=SLOTS, max_len=MAX_LEN,
+                 prefill_chunk=4)
+    prompts = _prompts(seed=5, n=3)
+    rids = [eng.submit(p, 4) for p in prompts]
+    eng.run()
+    for rid, p in zip(rids, prompts):
+        assert np.array_equal(eng.result(rid), _ref(flat_params, p, 4))
+
+
+def test_attend_rows_counters_on_a_three_request_script(
+    kernel_params, monkeypatch
+):
+    """Three prompts of 130, 20 and 260 tokens, chunks of 128, a pool of
+    8 slots x 384 rows.  Off TPU every step reads its capacity: the
+    counters are equal, and each action span carries ``rows_read`` =
+    ``rows_cap``.  Where the platform is a TPU (answered for it here:
+    the counter runs no program) the same frontiers count the
+    128-blocks inside each row's length."""
+    from torchgpipe_tpu.models import generation
+    from torchgpipe_tpu.models.generation import attend_rows_counter
+
+    eng = Engine(KCFG, kernel_params, num_slots=8, max_len=KLEN,
+                 prefill_chunk=128)
+    rng = np.random.RandomState(0)
+    for n in (130, 20, 260):
+        eng.submit(rng.randint(0, 64, (n,)).astype(np.int32), 2)
+    eng.run()
+    m = eng.metrics
+    # prefill: 3 steps of R = 8 rows; decode: pool-wide steps of 8 rows
+    assert m.prefill_steps == 3
+    assert m.attend_rows_capacity == 8 * KLEN * (3 + m.decode_steps)
+    assert m.attend_rows_read == m.attend_rows_capacity
+    reg = m.registry
+    assert reg.counter("serving_attend_rows_read").value() == (
+        m.attend_rows_read)
+    assert reg.counter("serving_attend_rows_capacity").value() == (
+        m.attend_rows_capacity)
+    snap = m.snapshot()
+    assert snap["attend_rows_read"] == snap["attend_rows_capacity"]
+    spans = [e.fields for e in eng.timeline.events
+             if e.name in ("engine.prefill", "engine.decode")]
+    for f in spans[-(3 + m.decode_steps):]:       # the ring is shared
+        assert f["rows_read"] == f["rows_cap"] == 8 * KLEN
+
+    class _Tpu:
+        platform = "tpu"
+
+    monkeypatch.setattr(generation.jax, "devices", lambda *a: [_Tpu()])
+    cache = eng.pool.cache
+    # The first prefill step: rows 0..2 at frontier 0 take 128, 20 and
+    # 128 tokens (one block each: the chunk's last position bounds the
+    # read), five padded rows take nothing.
+    pos0 = np.zeros((8,), np.int32)
+    n_valid = np.array([128, 20, 128, 0, 0, 0, 0, 0], np.int32)
+    assert attend_rows_counter(KCFG, cache, 8, 128)(pos0, n_valid) == (
+        3 * 128, 8 * KLEN)
+    # The second: rows at frontier 128 read two blocks.
+    pos0 = np.array([128, 128, 0, 0, 0, 0, 0, 0], np.int32)
+    n_valid = np.array([2, 128, 0, 0, 0, 0, 0, 0], np.int32)
+    assert attend_rows_counter(KCFG, cache, 8, 128)(pos0, n_valid) == (
+        2 * 256, 8 * KLEN)
+    # A decode step: slots at 131, 21 and 261 tokens, five idle.
+    pos0 = np.array([131, 21, 261, 0, 0, 0, 0, 0], np.int32)
+    n_valid = np.array([1, 1, 1, 0, 0, 0, 0, 0], np.int32)
+    assert attend_rows_counter(KCFG, cache, 8, 1)(pos0, n_valid) == (
+        256 + 128 + 384, 8 * KLEN)
+    # An int8 pool attends dense: read is the capacity.
+    quant = init_quant_cache(KCFG, 8, KLEN)
+    assert attend_rows_counter(KCFG, quant, 8, 1)(pos0, n_valid) == (
+        8 * KLEN, 8 * KLEN)

@@ -535,3 +535,133 @@ def test_decode_flash_quant_wiring_through_generate(monkeypatch):
         cfg, params, tokens, max_new_tokens=6, max_len=256, kv_quant=True
     )
     np.testing.assert_array_equal(np.asarray(flash), np.asarray(dense))
+
+
+# --------------------------------------------------------------------- #
+# decode kernel, a length (and a slot) per row                          #
+# --------------------------------------------------------------------- #
+
+_ROW_L, _ROW_HD, _ROW_NKV, _ROW_R = 1024, 128, 4, 2   # two 512-blocks
+
+
+def _row_frontiers(g):
+    """First-query positions of the rows of one call: a row whose
+    ``pos0 + g`` is 0 (nothing to read: None), g (``pos0`` 0), 511, 512,
+    513, ``max_len - g + 1`` and ``max_len``."""
+    lengths = [0, g, 511, 512, 513, _ROW_L - g + 1, _ROW_L]
+    return [None if n == 0 else max(n - g, 0) for n in lengths]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("slots_kind", ["pool", "perm", "subset", "padded"])
+@pytest.mark.parametrize("window", [None, 300])
+@pytest.mark.parametrize("g", [1, 32])
+def test_decode_kernel_per_row(g, window, slots_kind, dtype):
+    """``flash_decode_attention`` with a ``[b]`` ``pos0`` (and ``slots``)
+    == the dense ``_attend_chunk`` with the same ``[b]`` ``pos0`` over
+    the rows' slots, at frontiers on both sides of a block edge and at
+    the cache's end; a row of length 0 returns zeros, and the other
+    rows come out bit-equal to a call that leaves it out.  ``g`` = 32
+    takes the heads of a 32-bit word a product (two bf16 heads, one f32
+    head), ``g`` = 1 all of them."""
+    from torchgpipe_tpu.models.generation import _attend_chunk
+    from torchgpipe_tpu.ops.flash_attention import (
+        _decode_tiling, flash_decode_attention,
+    )
+
+    nh = _ROW_NKV * _ROW_R
+    per_word = 4 // jnp.dtype(dtype).itemsize
+    assert _decode_tiling(g, nh, _ROW_NKV, 4 // per_word, _ROW_L) == (
+        512, _ROW_NKV if g == 1 else per_word
+    )
+    frontiers = _row_frontiers(g)
+    b = len(frontiers)
+    dead = frontiers.index(None)
+    banks = {"pool": b, "perm": b, "subset": 12, "padded": 12}[slots_kind]
+    rng = np.random.default_rng(g + banks)
+    slots = {
+        "pool": np.arange(b),
+        "perm": rng.permutation(b),
+        "subset": rng.choice(banks, b, replace=False),
+        "padded": rng.choice(banks, b, replace=False),
+    }[slots_kind].astype(np.int32)
+    if slots_kind == "padded":
+        slots[dead] = slots[2]      # the padded row repeats a live slot
+    ks = jax.random.split(jax.random.PRNGKey(g + banks), 3)
+    q = jax.random.normal(ks[0], (b, g, nh, _ROW_HD), jnp.float32)
+    q = q.astype(dtype)
+    shape = (banks, _ROW_L, _ROW_NKV, _ROW_HD)
+    ck = jax.random.normal(ks[1], shape, jnp.float32).astype(dtype)
+    cv = jax.random.normal(ks[2], shape, jnp.float32).astype(dtype)
+    pos0 = np.array([p or 0 for p in frontiers], np.int32)
+    lengths = np.minimum(pos0 + g, _ROW_L).astype(np.int32)
+    lengths[dead] = 0
+
+    def kernel(rows):
+        return np.asarray(flash_decode_attention(
+            q[rows], ck, cv, jnp.asarray(pos0[rows]), window=window,
+            slots=None if slots_kind == "pool" and len(rows) == b
+            else jnp.asarray(slots[rows]),
+            lengths=jnp.asarray(lengths[rows]), interpret=True,
+        ))
+
+    every = np.arange(b)
+    live = every[every != dead]
+    got = kernel(every)
+    ref = np.asarray(_attend_chunk(
+        q, ck[slots], cv[slots], jnp.asarray(pos0), window, use_flash=False
+    ))
+    np.testing.assert_allclose(got[live], ref[live], rtol=2e-5, atol=2e-5)
+    assert not got[dead].any()
+    np.testing.assert_array_equal(kernel(live), got[live])
+
+
+def test_decode_rows_read_counts_the_blocks_of_live_rows():
+    """The host's count of what the kernel fetches: block-rounded rows
+    from the band's first block to the length's last, nothing for a row
+    of length 0."""
+    from torchgpipe_tpu.ops.flash_attention import decode_rows_read
+
+    pos0 = np.array([0, 0, 510, 511, 1023, 700])
+    lengths = np.array([0, 1, 511, 512, 1024, 733])
+    assert decode_rows_read(pos0, lengths, None, 512) == 512 * (
+        0 + 1 + 1 + 1 + 2 + 2
+    )
+    # A window of 100 drops the blocks behind the first query's band.
+    assert decode_rows_read(pos0, lengths, 100, 512) == 512 * (
+        0 + 1 + 1 + 1 + 1 + 1
+    )
+    # The host's count is the kernel's own plan (the grid steps of the
+    # rows that read anything), frontier by frontier.
+    from torchgpipe_tpu.ops.flash_attention import _decode_plan
+
+    rng = np.random.default_rng(0)
+    for window in (None, 1, 100, 700, 4096):
+        pos0 = rng.integers(0, 4096, 64)
+        lengths = np.where(rng.random(64) < 0.2, 0,
+                           np.minimum(pos0 + rng.integers(1, 33), 4096))
+        _, _, count = _decode_plan(
+            jnp.asarray(pos0), jnp.asarray(lengths), window, 512
+        )
+        assert decode_rows_read(pos0, lengths, window, 512) == 512 * int(
+            np.asarray(count)[lengths > 0].sum()
+        )
+
+
+def test_decode_tiling_by_shape():
+    """Block and heads a product, from the shapes alone (Mistral's
+    heads): one token a row takes every head of a 512-block, a chunk
+    of 32 the two bf16 heads of a word, a chunk of 128 a shorter block
+    besides; an f32 cache one head a product; nothing fits 1024."""
+    from torchgpipe_tpu.ops.flash_attention import (
+        _decode_tiling, supports_decode,
+    )
+
+    assert _decode_tiling(1, 32, 8, 2, 4096) == (512, 8)
+    assert _decode_tiling(32, 32, 8, 2, 4096) == (512, 2)
+    assert _decode_tiling(128, 32, 8, 2, 4096) == (256, 2)
+    assert _decode_tiling(32, 32, 8, 4, 4096) == (512, 1)
+    assert _decode_tiling(1024, 32, 8, 2, 4096) is None
+    assert not supports_decode((8, 1024, 32, 128), (8, 4096, 8, 128), None)
+    assert _decode_tiling(1, 32, 8, 2, 384) == (128, 8)

@@ -71,6 +71,7 @@ from typing import Any, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from torchgpipe_tpu.models import mla
@@ -407,6 +408,72 @@ def _decode_step(
     return x, KVCache(k=new_k, v=new_v, length=pos + 1)
 
 
+def _flash_decode_eligible(
+    q_shape: Tuple[int, ...], bank: Any, window: Optional[int], *,
+    quant: bool, per_row: bool,
+) -> bool:
+    """Whether cache attention of these shapes takes the Pallas decode
+    kernel on a TPU (what :func:`_attend_chunk` dispatches by, and what
+    :func:`attend_rows_counter` counts by): shapes the kernel tiles, and not an
+    int8 cache read per row (the int8 kernel takes one scalar ``pos0``).
+    ``bank`` is anything with the cache bank's ``shape`` and ``dtype``."""
+    from torchgpipe_tpu.ops.flash_attention import supports_decode
+
+    return not (quant and per_row) and supports_decode(
+        q_shape, bank.shape, window, jnp.dtype(bank.dtype).itemsize
+    )
+
+
+def attend_rows_counter(
+    cfg: TransformerConfig, cache: Any, rows: int, g: int,
+) -> Any:
+    """A function ``(pos0 [rows], n_valid [rows]) -> (rows read, row
+    capacity)`` for the cache attention of ONE layer of a
+    :func:`decode_slots` call of ``rows`` rows of ``g`` tokens, counted
+    on the HOST (numpy) from the rows' frontiers: capacity is ``rows x
+    max_len``; read is the block-rounded rows the decode kernel fetches
+    (nothing for a row with ``n_valid == 0``) where this platform and
+    these shapes take the kernel, the capacity where the dense path
+    runs (off TPU, a latent or an int8 pool, shapes the kernel does not
+    tile).  What is decided by platform and shape is decided here,
+    once; a call is a few numpy operations over ``rows`` ints.  The
+    serving engine's ``serving_attend_rows_read`` /
+    ``serving_attend_rows_capacity``."""
+    from torchgpipe_tpu.ops.flash_attention import (
+        _decode_tiling, decode_rows_read,
+    )
+
+    max_len = _cache_rows(cache)
+    cap = rows * max_len
+    if (
+        cfg.mla is not None
+        or jax.devices()[0].platform != "tpu"
+        or not _flash_decode_eligible(
+            (rows, g, cfg.n_heads, cfg.head_dim), cache.k[0],
+            cfg.attn_window, quant=isinstance(cache, QuantKVCache),
+            per_row=True,
+        )
+    ):
+        return lambda pos0, n_valid: (cap, cap)
+    bank = cache.k[0]
+    block_k, _ = _decode_tiling(
+        g, cfg.n_heads, bank.shape[2], bank.dtype.itemsize, max_len
+    )
+    # A band as long as the cache drops no block of any frontier.
+    window = cfg.attn_window
+    if window is not None and max_len - window + 1 < block_k:
+        window = None
+
+    def count(pos0: Any, n_valid: Any) -> Tuple[int, int]:
+        pos0 = np.asarray(pos0)
+        live = np.where(
+            np.asarray(n_valid) > 0, np.minimum(pos0 + g, max_len), 0
+        )
+        return decode_rows_read(pos0, live, window, block_k), cap
+
+    return count
+
+
 def _attend_chunk(
     q: jnp.ndarray,          # [b, g, nh, hd] — rope'd queries, positions pos0..pos0+g-1
     ck: jnp.ndarray,         # [b, max_len, nkv, hd]
@@ -418,6 +485,8 @@ def _attend_chunk(
     v_scale: Optional[jnp.ndarray] = None,
     seg_q: Optional[jnp.ndarray] = None,    # [b, g] packed segment ids
     seg_k: Optional[jnp.ndarray] = None,    # [b, max_len] cache segments
+    slots: Optional[jnp.ndarray] = None,    # [b] — row i reads ck[slots[i]]
+    lengths: Optional[jnp.ndarray] = None,  # [b] — cache rows a row needs
 ) -> jnp.ndarray:
     """Causal attention of ``g`` consecutive queries against the cache —
     one MXU-friendly einsum instead of g masked cache reads.  Query i
@@ -425,8 +494,17 @@ def _attend_chunk(
     banded); ``g=1`` is the plain single-token decode read.  A
     ``[b]``-shaped ``pos0`` gives every row its OWN first-query position
     — the serving pool's attention, where each slot sits at its own
-    sequence frontier (dense path only: the flash decode kernel takes
-    one scalar ``pos0``, so auto-dispatch stays dense per-row).
+    sequence frontier.
+
+    ``slots`` makes ``ck`` / ``cv`` (and the scales) a BANK: query row
+    ``i`` attends over bank row ``slots[i]`` (the compact
+    ``decode_slots``).  The kernel reads it through its index map; the
+    dense path takes one row at a time through a dynamic slice
+    (:func:`_attend_row`), never a gather of the bank.  ``lengths``
+    says how many cache rows a row needs (``0``: none — the kernel then
+    fetches nothing for it and returns zeros); the dense path reads
+    every row whatever it says, and a row of length 0 gets garbage that
+    its caller never reads.
 
     ``seg_q``/``seg_k`` fold the sequence-packing mask in: query ``i``
     additionally requires ``seg_q[b, i] == seg_k[b, j]`` (the
@@ -436,18 +514,19 @@ def _attend_chunk(
     segments force the masked einsum (the didactic fallback).
 
     ``use_flash=None`` auto-dispatches the Pallas decode kernel on TPU
-    when the shapes are eligible (``ops.flash_attention.supports_decode``)
-    — its K-block loop is bounded by the RUNTIME length, so per-step cost
-    follows the generated prefix instead of streaming all ``max_len``
-    rows the way this dense einsum does; the dense path masks instead.
-    Pass True/False to force (True off-TPU runs interpret mode — tests).
+    when the shapes are eligible (:func:`_flash_decode_eligible`) — its
+    grid is the blocks inside each row's RUNTIME length, so per-step
+    cost follows the rows that hold a token instead of streaming all
+    ``max_len`` rows the way this dense einsum does; the dense path
+    masks instead.  Pass True/False to force (True off-TPU runs
+    interpret mode — tests).
 
     ``k_scale``/``v_scale``: ``ck``/``cv`` are int8 QuantKVCache buffers
-    with per-(position, head) scales.  The kernel path dequantizes
-    block-wise in VMEM — HBM moves int8 bytes, the actual int8-KV
-    bandwidth win; the dense path dequantizes up front."""
+    with per-(position, head) scales.  The kernel path (one scalar
+    ``pos0``: ``generate``) dequantizes block-wise in VMEM — HBM moves
+    int8 bytes; the dense path dequantizes up front, and is the path
+    of an int8 POOL (per-row ``pos0`` or ``slots``)."""
     on_tpu = jax.devices()[0].platform == "tpu"
-    per_row = jnp.asarray(pos0).ndim == 1
     if seg_q is not None or seg_k is not None:
         if seg_q is None or seg_k is None:
             raise ValueError(
@@ -462,12 +541,9 @@ def _attend_chunk(
             )
         use_flash = False
     if use_flash is None:
-        from torchgpipe_tpu.ops.flash_attention import supports_decode
-
-        use_flash = (
-            not per_row
-            and on_tpu
-            and supports_decode(q.shape, ck.shape, window)
+        use_flash = on_tpu and _flash_decode_eligible(
+            q.shape, ck, window, quant=k_scale is not None,
+            per_row=jnp.ndim(pos0) == 1 or slots is not None,
         )
     if use_flash:
         from torchgpipe_tpu.ops.flash_attention import (
@@ -476,8 +552,25 @@ def _attend_chunk(
 
         return flash_decode_attention(
             q, ck, cv, pos0, window=window, k_scale=k_scale,
-            v_scale=v_scale, interpret=not on_tpu,
+            v_scale=v_scale, slots=slots, lengths=lengths,
+            interpret=not on_tpu,
         )
+    if slots is not None:
+        # One row, one slot: each row attends over ITS slot's
+        # ``max_len`` cache rows, read from the bank by a dynamic slice
+        # that feeds the row's own einsums — no copy of the rows'
+        # slots, let alone of the bank.
+        quant = k_scale is not None
+        return jnp.concatenate([
+            _attend_row(
+                q[i:i + 1], _slot_rows(ck, slots[i]),
+                _slot_rows(cv, slots[i]), pos0[i:i + 1],
+                _slot_rows(k_scale, slots[i]) if quant else None,
+                _slot_rows(v_scale, slots[i]) if quant else None,
+                window=window,
+            )
+            for i in range(q.shape[0])
+        ], axis=0)
     if k_scale is not None:
         ck, cv = _dequant_rows(ck, k_scale), _dequant_rows(cv, v_scale)
     b, g, nh, hd = q.shape
@@ -590,8 +683,10 @@ def _decode_chunk(
 
 def _slot_rows(bank: jnp.ndarray, slot: jnp.ndarray) -> jnp.ndarray:
     """``bank[slot:slot + 1]`` — ONE slot of a cache bank, leading axis
-    kept — as a dynamic slice.  The compact ``decode_slots`` reads each
-    row's slot this way and not as ``bank[slots]``: the TPU compiler
+    kept — as a dynamic slice.  Where the decode kernel does not run
+    (it reads ``bank[slots[i]]`` through its index map) the compact
+    ``decode_slots`` reads each row's slot this way — the latent pool
+    always — and not as ``bank[slots]``: the TPU compiler
     serves that gather of whole slots by first copying the ENTIRE bank
     in ``max_len`` pieces (1.3 ms a bank of a 6 GiB pool: 17 of a
     compact prefill step's 45 ms), and a copy of the R slots alone
@@ -607,7 +702,8 @@ def _attend_row(
     k_scale: Optional[jnp.ndarray], v_scale: Optional[jnp.ndarray],
     *, window: Optional[int],
 ) -> jnp.ndarray:
-    """One row of the compact ``decode_slots``: the dense
+    """One row of the compact ``decode_slots`` where the kernel does
+    not run (off TPU, an int8 pool, shapes it cannot tile): the dense
     :func:`_attend_chunk` over one slot's rows.  Jitted so that the
     ``R`` calls a layer (same shapes, every layer) are traced ONCE and
     lowered as calls of one function — unrolled bare they tripled the
@@ -660,10 +756,10 @@ def decode_slots(
     ``lengths[slots[i]]``, scatters its K/V rows into the pool at
     ``(slots[i], frontier + j)`` under the same drop-when-masked rule
     (every other slot stays bit-untouched, and a donated pool is still
-    updated in place), attends over that slot's ``max_len`` cache rows
-    only (a dynamic slice of the pool a row, never a copy of it) and
-    returns ``logits [R, g, vocab]`` with ``lengths`` advanced at
-    ``slots``.  The work is then ``R x g`` positions whatever the
+    updated in place), attends over that slot's cache rows only (the
+    decode kernel's index map picks the slot, the dense path a dynamic
+    slice of the pool a row: never a copy of it) and returns ``logits
+    [R, g, vocab]`` with ``lengths`` advanced at ``slots``.  The work is then ``R x g`` positions whatever the
     pool's size: the engine's chunked prefill runs this form over the
     rows that are prefilling.  A padded row is any valid slot index
     with ``n_valid = 0`` (it writes nothing and advances nothing; a
@@ -678,6 +774,13 @@ def decode_slots(
     * cache writes are scatters at ``lengths[i] + j`` with out-of-range
       indices for masked tokens (``mode='drop'``): a no-op row's cache
       is bit-untouched, the property the slot-recycling tests pin;
+    * the attend is :func:`_attend_chunk` with the ``[S]`` frontiers:
+      on a TPU, for shapes it tiles, the Pallas decode kernel, which
+      fetches the blocks of each row's slot up to that row's own
+      frontier and nothing for a row with ``n_valid == 0`` (what it
+      reads is the rows that hold a token, block-rounded, not
+      ``S x max_len``); elsewhere, and for an int8 pool, the dense
+      einsum over all ``max_len`` rows of every row's slot;
     * ``cache.length`` is IGNORED (per-slot frontiers live in
       ``lengths``); the returned cache carries ``lengths + n_valid``
       summed into its scalar only for schema compatibility.
@@ -708,6 +811,9 @@ def decode_slots(
     # at L (out of range -> dropped) when masked.
     wpos = jnp.where(j < n_valid[:, None], pos0[:, None] + j, L)
     valid = j < n_valid[:, None]                        # [S, g]
+    # Cache rows a row's attention needs: through its chunk's last
+    # position, none for a row that does nothing in this call.
+    live = jnp.where(n_valid > 0, jnp.minimum(pos0 + g, L), 0)
     rows = slot_of[:, None]                             # [S, 1]
     i0 = slot_of[:, None, None]                         # [S, 1, 1]
     new_k, new_v = [], []
@@ -762,30 +868,18 @@ def decode_slots(
             cv = cv.at[rows, wpos].set(v.astype(cv.dtype), mode="drop")
         new_k.append(ck)
         new_v.append(cv)
-        if compact:
-            # One row, one slot: each row attends over ITS slot's
-            # ``max_len`` cache rows (written rows included), read from
-            # the pool by a dynamic slice that feeds the row's own
-            # einsums — no copy of the R slots, let alone of the bank.
-            attn = jnp.concatenate([
-                _attend_row(
-                    q[i:i + 1], _slot_rows(ck, slots[i]),
-                    _slot_rows(cv, slots[i]), pos0[i:i + 1],
-                    _slot_rows(cks, slots[i]) if quant else None,
-                    _slot_rows(cvs, slots[i]) if quant else None,
-                    window=cfg.attn_window,
-                )
-                for i in range(S)
-            ], axis=0)
-        else:
-            # Per-row pos0 forces the dense path (the flash decode
-            # kernel takes one scalar pos0), so a slot's read is the
-            # same f32 einsum math as the single-request dense path.
-            attn = _attend_chunk(
-                q, ck, cv, pos0, cfg.attn_window, use_flash=False,
-                k_scale=cks if quant else None,
-                v_scale=cvs if quant else None,
-            )
+        # Each row over ITS slot's rows, the written ones included, up
+        # to its own frontier: on a TPU the decode kernel reads the
+        # blocks inside ``live`` through its index maps (the whole bank
+        # is its operand, nothing of it is sliced or copied) and skips
+        # the rows with nothing to do; elsewhere the dense einsum, a
+        # row at a time in the compact form.
+        attn = _attend_chunk(
+            q, ck, cv, pos0, cfg.attn_window,
+            k_scale=cks if quant else None,
+            v_scale=cvs if quant else None,
+            slots=slots, lengths=live,
+        )
         x = _block_attn_out(cfg, p, x, attn, mlp_layer, valid, counts)
     new_lengths = (
         lengths.at[slots].add(n_valid) if compact else lengths + n_valid

@@ -1034,52 +1034,294 @@ def _decode_block_k(s: int) -> Optional[int]:
     return next((c for c in (512, 256, 128) if s % c == 0), None)
 
 
+# The f32 score plane of one product, ``[query rows, block rows x heads]``,
+# up to which a step of the decode kernel takes ALL kv heads of a block
+# through one product (``g = 1``: 32 x 4096 x 4 bytes at Mistral's heads);
+# above it a step takes the heads of one 32-bit word of the tile (two
+# bf16 heads), four such groups a block, and a long chunk a shorter block.
+_DECODE_PLANE_BYTES = 1024 * 1024
+# Scoped VMEM for the decode kernel: two double-buffered tiles of a
+# block's K and V, a chunk's score plane, its probabilities and their
+# three-term bf16 form, queries, output and accumulator (v5e: 128 MiB).
+_DECODE_VMEM_BYTES = 48 * 1024 * 1024
+
+
+def _decode_tiling(
+    g: int, nh: int, nkv: int, itemsize: int, s: int,
+) -> Optional[Tuple[int, int]]:
+    """``(block_k, hw)`` of the decode kernel for ``g`` queries a row,
+    ``nh`` / ``nkv`` heads and a cache of ``s`` rows of ``itemsize``
+    bytes an element: the largest block dividing ``s`` whose score
+    plane fits, with ``hw`` kv heads a product — all ``nkv`` while the
+    plane stays under ``_DECODE_PLANE_BYTES``, else the heads that
+    share a 32-bit sublane word of the cache tile (twice the plane is
+    then allowed: a prompt chunk's).  None: no block fits."""
+    per_word = max(4 // itemsize, 1)
+    for block_k in (512, 256, 128):
+        if s % block_k:
+            continue
+        if g * nh * block_k * nkv * 4 <= _DECODE_PLANE_BYTES:
+            return block_k, nkv
+        if nkv % per_word:
+            continue
+        plane = g * (nh // nkv) * per_word * block_k * per_word * 4
+        if plane <= 2 * _DECODE_PLANE_BYTES:
+            return block_k, per_word
+    return None
+
+
+def _decode_plan(
+    pos0: jnp.ndarray, lengths: jnp.ndarray, window: Optional[int],
+    block_k: int,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``(first, last, count)`` per row: the cache blocks a row's
+    queries read (the band of the FIRST query opens it, the row's
+    length closes it) and how many grid steps the row takes — one at
+    least, in which a row of length 0 writes its zeros."""
+    last = jnp.maximum(lengths - 1, 0) // block_k
+    first = jnp.zeros_like(last)
+    if window is not None:
+        first = jnp.minimum(
+            jnp.maximum(pos0 - window + 1, 0) // block_k, last
+        )
+    return first, last, jnp.where(lengths > 0, last - first + 1, 1)
+
+
+def decode_rows_read(
+    pos0: Any, lengths: Any, window: Optional[int], block_k: int,
+) -> int:
+    """Cache rows, block-rounded, that :func:`flash_decode_attention`
+    fetches for per-row ``pos0`` / ``lengths`` at ``block_k``:
+    :func:`_decode_plan`'s blocks of the rows that read anything, in
+    numpy on the host and in as few operations as it takes (the serving
+    engine counts them before every step:
+    ``serving_attend_rows_read``)."""
+    import numpy as np
+
+    lengths = np.asarray(lengths)
+    blocks = (lengths - 1) // block_k + 1       # 0 for a row of length 0
+    if window is not None:
+        first = np.maximum(np.asarray(pos0) - window + 1, 0) // block_k
+        blocks = np.maximum(blocks - first, lengths > 0)
+    return int(blocks.sum()) * block_k
+
+
+def _decode_steps(
+    pos0: jnp.ndarray, lengths: jnp.ndarray, slots: jnp.ndarray,
+    window: Optional[int], block_k: int, steps: int,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The decode kernel's grid as a flat list: ``(row, slot, block)`` of
+    each grid step, in arrays of ``steps`` entries (the list's capacity:
+    every block of every row), and ``ends [b]``, one past each row's
+    last step (its last entry is the grid's length).
+    Row ``i`` takes the blocks of :func:`_decode_plan` in order; the one
+    step of a row that reads nothing names the tile of the last step
+    that did — resident, so nothing is fetched for it.  Masked sums
+    over a ``[steps, b]`` plane, no gather: the TPU compiler unrolls a
+    gather of scalars into a slice an element."""
+    b = lengths.shape[0]
+    first, last, count = _decode_plan(pos0, lengths, window, block_k)
+    ends = jnp.cumsum(count)
+    starts = ends - count
+    i = jnp.arange(b)
+    live = lengths > 0
+    # The last row at or before each row that reads something (row 0
+    # where none does), and its slot and last block.
+    prev = jnp.max(
+        jnp.where(live[None, :] & (i[None, :] <= i[:, None]), i[None, :], 0),
+        axis=1,
+    )
+    pick = prev[:, None] == i[None, :]
+
+    def of_prev(x: jnp.ndarray) -> jnp.ndarray:
+        return jnp.sum(jnp.where(pick, x[None, :], 0), axis=1)
+
+    resident = of_prev(last)
+    first = jnp.where(live, first, resident)
+    last = jnp.where(live, last, resident)
+    t = jnp.arange(steps)[:, None]
+    hot = (t >= starts[None, :]) & (t < ends[None, :])
+
+    def of_step(x: jnp.ndarray) -> jnp.ndarray:
+        return jnp.sum(jnp.where(hot, x, 0), axis=1).astype(jnp.int32)
+
+    return (
+        of_step(i[None, :]),
+        of_step(of_prev(slots)[None, :]),
+        of_step(jnp.minimum(first[None, :] + t - starts[None, :],
+                            last[None, :])),
+        ends.astype(jnp.int32),
+    )
+
+
+def _dot_rows(x: jnp.ndarray, y: jnp.ndarray, dims: Any) -> jnp.ndarray:
+    """``x . y`` into f32 with no operand rounded.  Against a bf16 ``y``
+    (the cache as stored) an f32 ``x`` goes through the MXU as three
+    bf16 terms whose sum it is, stacked as rows of ONE bf16 product:
+    every partial product is exact and the accumulator is f32, where a
+    product of f32 tiles would make six passes over the block.  A bf16
+    ``x`` is one term; any other ``y`` takes the f32 product."""
+    if y.dtype != jnp.bfloat16:
+        return lax.dot_general(
+            x.astype(jnp.float32), y.astype(jnp.float32), dims,
+            preferred_element_type=jnp.float32,
+        )
+    if x.dtype == jnp.bfloat16:
+        return lax.dot_general(
+            x, y, dims, preferred_element_type=jnp.float32
+        )
+    x = x.astype(jnp.float32)
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    n = x.shape[0]
+    out = lax.dot_general(
+        jnp.concatenate([hi, mid, lo], axis=0), y, dims,
+        preferred_element_type=jnp.float32,
+    )
+    return out[:n] + out[n:2 * n] + out[2 * n:]
+
+
 def _decode_kernel(
+    row_ref: Any,    # [steps] query row of each grid step
+    slot_ref: Any,   # [steps] bank row of its tile (the index maps')
+    blk_ref: Any,    # [steps] cache block of its tile
+    end_ref: Any,    # [b] one past a row's last step (running sum)
+    pos_ref: Any,    # [b] first query's position
+    len_ref: Any,    # [b] cache rows the row reads; 0: none
+    q_ref: Any,      # [1, groups, g*hw*r, hd]
+    k_ref: Any,      # [1, block_k, nkv, hd]
+    v_ref: Any,
+    o_ref: Any,      # [1, groups, g*hw*r, hd] f32
+    m_sc: Any,
+    l_sc: Any,
+    acc_sc: Any,
+    *,
+    r: int,
+    hw: int,
+    block_k: int,
+    window: Optional[int],
+    sm_scale: float,
+) -> None:
+    """One grid step: ALL kv heads of one cache block of one query row,
+    online softmax carried in VMEM scratch across the row's steps.
+
+    The grid is a flat LIST of (row, block) steps made from the runtime
+    lengths (scalar prefetch, visible to the index maps and the kernel):
+    a row takes the blocks from its band's first to its length's last
+    and no other, so bandwidth and compute follow the rows that hold a
+    token, and the next row's first tile is fetched while this row's
+    last is computed.  The grid's length is the list's, a runtime value
+    (a grid sized for a full cache, its tail doing nothing, read 0.54 ms
+    against 0.49 a layer at the serving cell's shapes: chip run, PR 29).
+
+    The tile is the bank's own ``[block_k, nkv, hd]`` (full last dims:
+    no head-folded view, which on the chip is a relayout COPY of the
+    bank).  One product covers ``hw`` heads: the tile collapses to
+    ``[block_k * hw, hd]`` — for all heads a pure view, for the heads
+    of one 32-bit word (two bf16 heads) a sublane-strided load of the
+    tile seen as words — and the columns a query's own head does not
+    own are masked like the rows past its position.  The MXU takes
+    each cache element once either way (it is the stationary operand);
+    only the softmax pays for the masked columns.
+
+    Row ``x`` of a group is query ``x // (hw * r)`` of the chunk, local
+    kv head ``x % (hw * r) // r``; column ``c`` is cache row
+    ``c // hw`` of the block, local head ``c % hw``."""
+    t = pl.program_id(0)
+    i = row_ref[t]
+    jb = blk_ref[t]
+    end = end_ref[i]
+    start = jnp.where(i > 0, end_ref[jnp.maximum(i - 1, 0)], 0)
+    pos0 = pos_ref[i]
+    groups, rows, hd = acc_sc.shape
+    nkv = k_ref.shape[2]
+    cols = block_k * hw
+
+    def tile(ref: Any, grp: int) -> jnp.ndarray:
+        if hw == nkv:
+            return ref[0].reshape(cols, hd)
+        if hw == 1:
+            return ref[0, :, grp, :]
+        words = ref.bitcast(jnp.uint32)[0, :, grp, :]
+        return pltpu.bitcast(words, ref.dtype)
+
+    @pl.when(t == start)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, _NEG)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    @pl.when(len_ref[i] > 0)
+    def _body():
+        x = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        qpos = pos0 + x // (hw * r)
+        c = lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        col = jb * block_k + c // hw
+        valid = (col <= qpos) & (c % hw == x % (hw * r) // r)
+        if window is not None:
+            valid &= col > qpos - window
+        for grp in range(groups):
+            s = _dot_rows(
+                q_ref[0, grp], tile(k_ref, grp), (((1,), (1,)), ((), ()))
+            ) * sm_scale
+            s = jnp.where(valid, s, _NEG)
+            m_prev = m_sc[grp]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_sc[grp] = l_sc[grp] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_sc[grp] = acc_sc[grp] * corr + _dot_rows(
+                p, tile(v_ref, grp), (((1,), (0,)), ((), ()))
+            )
+            m_sc[grp] = m_new
+
+    @pl.when(t == end - 1)
+    def _finish():
+        # A row that read nothing has l == 0: zeros, not 0/0 — its
+        # output still flows through the block's products.
+        l = l_sc[...]
+        o_ref[0] = jnp.where(
+            l > 0, acc_sc[...] / jnp.where(l > 0, l, 1.0), 0.0
+        )
+
+
+def _decode_quant_kernel(
     len_ref: Any,
     q_ref: Any,
     k_ref: Any,
     v_ref: Any,
-    *rest: Any,
+    ks_ref: Any,
+    vs_ref: Any,
+    o_ref: Any,
+    m_sc: Any,
+    l_sc: Any,
+    acc_sc: Any,
+    *,
     g: int,
     r: int,
     hd: int,
     sm_scale: float,
     block_k: int,
     window: Optional[int],
-    quant: bool,
 ) -> None:
-    """One (batch, kv-head, K-block) grid cell: ``g*r`` query rows
-    against one streamed K/V block, online softmax carried in VMEM
-    scratch across the (sequential, innermost) block dimension.
+    """The int8 cache's kernel: one (batch, kv-head, K-block) grid cell,
+    ``g*r`` query rows against one streamed K/V block of ONE head, one
+    scalar length for the batch (``generate`` with ``kv_quant``).
 
-    The live region depends on the RUNTIME cache length (scalar-prefetch
-    ``len_ref``): blocks outside it are skipped — ``pl.when`` elides the
-    compute and the clamped index maps re-request the resident tile so
-    no HBM fetch is issued (the same machinery as the streaming causal
-    kernels).  Per-step cost — bandwidth AND compute — follows the
-    generated prefix, not the cache allocation.  Forward only (decode
-    has no backward).
-
-    Operand layouts are HEAD-FOLDED: Mosaic requires a block's last two
-    dims to be (8k, 128k)-tileable or full axes, so a width-1 block over
-    a ``nkv`` axis cannot lower (caught on real TPU; interpret mode
-    does not enforce tiling).  K/V arrive as ``[1, Bk, hd]`` tiles of a
-    ``[b, s, nkv*hd]`` view — the kv head is picked by the index map as
-    a lane-axis block offset, so the fetch stays one head's tile.
-
-    ``quant=True``: K/V refs are int8 with f32 per-(position, head)
-    scales — dequantized ONE BLOCK AT A TIME in VMEM, so HBM moves half
-    the bytes of a bf16 cache (the actual int8-KV bandwidth win; the
-    dense path dequantizes the whole cache in HBM first and forfeits
-    it).  ``ks_ref``/``vs_ref`` are the head's whole scale row viewed
-    ``[1, 1, nkb, Bk]`` (s floats — fetched once per (batch, head), ~s·4
-    bytes, negligible next to the K tiles); the current block's row is
-    selected with an iota/where reduction because the row index ``jb``
-    is a runtime value and Mosaic has no dynamic sublane indexing."""
-    if quant:
-        ks_ref, vs_ref, o_ref, m_sc, l_sc, acc_sc = rest
-    else:
-        o_ref, m_sc, l_sc, acc_sc = rest
+    Operand layouts are HEAD-FOLDED: K/V arrive as ``[1, Bk, hd]`` tiles
+    of a ``[b, s, nkv*hd]`` view, the kv head picked by the index map as
+    a lane-axis block offset.  (On the chip that view is a relayout of
+    the cache — the bf16 kernel above reads the bank's own layout; an
+    int8 tile packs four heads a word and its scales lie positions-last,
+    so it keeps this form.)  K/V are int8 with f32 per-(position, head)
+    scales, dequantized ONE BLOCK AT A TIME in VMEM.  ``ks_ref`` /
+    ``vs_ref`` are the head's whole scale row viewed ``[1, 1, nkb, Bk]``
+    (s floats, fetched once per (batch, head)); the current block's row
+    is selected with an iota/where reduction because the row index
+    ``jb`` is a runtime value and Mosaic has no dynamic sublane
+    indexing."""
     jb = pl.program_id(2)
     nkb = pl.num_programs(2)
     length = len_ref[0]
@@ -1104,22 +1346,17 @@ def _decode_kernel(
         qb = (
             q_ref[0].reshape(rows, hd).astype(jnp.float32) * sm_scale
         )
-        kb = k_ref[0].astype(jnp.float32)   # [Bk, hd]
-        vb = v_ref[0].astype(jnp.float32)
-        if quant:
-            def row_of(sref):
-                # [nkb, Bk] → row jb (the fetched K tile's block).
-                all_rows = sref[0, 0]
-                sel = (
-                    lax.broadcasted_iota(jnp.int32, all_rows.shape, 0)
-                    == jb
-                )
-                return jnp.sum(
-                    jnp.where(sel, all_rows, 0.0), axis=0
-                )
 
-            kb = kb * row_of(ks_ref).reshape(block_k, 1)
-            vb = vb * row_of(vs_ref).reshape(block_k, 1)
+        def row_of(sref):
+            # [nkb, Bk] → row jb (the fetched K tile's block).
+            all_rows = sref[0, 0]
+            sel = (
+                lax.broadcasted_iota(jnp.int32, all_rows.shape, 0) == jb
+            )
+            return jnp.sum(jnp.where(sel, all_rows, 0.0), axis=0)
+
+        kb = k_ref[0].astype(jnp.float32) * row_of(ks_ref).reshape(block_k, 1)
+        vb = v_ref[0].astype(jnp.float32) * row_of(vs_ref).reshape(block_k, 1)
         s = lax.dot_general(
             qb, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -1152,19 +1389,21 @@ def _decode_kernel(
 
 def supports_decode(
     q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
-    window: Optional[int],
+    window: Optional[int], itemsize: int = 2,
 ) -> bool:
     """Static eligibility for :func:`flash_decode_attention` (the
     auto-dispatch gate in ``models.generation._attend_chunk``): the same
     conditions the kernel entry validates, answered as a bool.  K/V
     stream one block at a time, so there is NO cache-length VMEM cap —
-    only tiling/grouping constraints and a floor under which the dense
-    read is not worth a kernel dispatch."""
+    only tiling/grouping constraints, a floor under which the dense
+    read is not worth a kernel dispatch, and a ceiling on the score
+    plane of one product (``itemsize`` is the cache's: it sets how many
+    heads a product takes once the chunk is long)."""
     b, g, nh, hd = q_shape
     s, nkv = k_shape[1], k_shape[2]
     if hd % 128 != 0 or nkv == 0 or nh % nkv != 0:
         return False
-    if s < 256 or _decode_block_k(s) is None:
+    if s < 256 or _decode_tiling(g, nh, nkv, itemsize, s) is None:
         return False
     return window is None or window >= 1
 
@@ -1172,67 +1411,182 @@ def supports_decode(
 def flash_decode_attention(
     q: jnp.ndarray,              # [b, g, nh, hd] — rope'd queries at
                                  # consecutive positions pos0..pos0+g-1
-    ck: jnp.ndarray,             # [b, max_len, nkv, hd] KV cache
+    ck: jnp.ndarray,             # [slots, max_len, nkv, hd] KV cache
     cv: jnp.ndarray,
-    pos0: jnp.ndarray,           # [] int32 — first query's position
+    pos0: jnp.ndarray,           # [] or [b] int32 — first query's position
     *,
     window: Optional[int] = None,
     block_k: Optional[int] = None,
     k_scale: Optional[jnp.ndarray] = None,  # f32 [b, nkv, max_len]
     v_scale: Optional[jnp.ndarray] = None,
+    slots: Optional[jnp.ndarray] = None,    # [b] int32 — row i reads
+                                            # cache row slots[i]
+    lengths: Optional[jnp.ndarray] = None,  # [b] int32 — cache rows a
+                                            # row reads (0: none)
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Decode-side flash attention: ``g`` consecutive queries against the
     LIVE PREFIX of a KV cache — the Pallas twin of the dense
     ``models.generation._attend_chunk`` (g=1 is the plain per-token
-    decode read; g=γ+1 is speculative verification).
+    decode read; g=γ+1 is speculative verification; g=prefill_chunk a
+    serving engine's prompt chunk).
 
     Unlike the prefill kernels (static causal geometry), the masked
-    region here depends on a RUNTIME scalar: the cache is ``max_len``
-    rows but only ``pos0+g`` are live.  The length rides in as a
-    scalar-prefetch operand, visible to BOTH the block index maps
-    (clamped — tiles outside the live/banded region re-request the
-    resident tile, so no HBM fetch is issued) and the kernel
-    (``pl.when`` skips their compute): per-step bandwidth and FLOPs
-    follow the generated length, not the cache allocation.  K/V stream
-    one ``[block_k, hd]`` tile at a time, so any ``max_len`` tiles the
-    grid can express is supported.  Output is f32 ``[b, g, nh*hd]``,
-    numerically the dense path\'s (same f32 accumulation; oracle-tested
-    in tests/test_flash_attention.py).
+    region here depends on RUNTIME values: the cache is ``max_len`` rows
+    but row ``i`` has only ``pos0[i]+g`` live (``pos0`` a scalar: every
+    row alike).  They ride in as scalar-prefetch operands, visible to
+    BOTH the block index maps and the kernel: the grid is the list of
+    the (row, block) pairs inside each row's live and banded range, so
+    per-step bandwidth and FLOPs follow what the rows hold, not the
+    cache allocation.  K/V stream one ``[block_k, nkv, hd]`` tile of
+    the cache's own layout at a time, so any ``max_len`` tiles the grid
+    can express is supported.  Output is f32 ``[b, g, nh*hd]``,
+    numerically the dense path\'s (f32 scores, statistics and
+    accumulator, no operand rounded; oracle-tested in
+    tests/test_flash_attention.py).
+
+    ``slots`` makes the cache a BANK that the rows index: row ``i``
+    reads ``ck[slots[i]]`` through the index map (no gather, no slice;
+    a slot may repeat).  ``lengths`` says how many cache rows a row
+    reads where that is not ``pos0+g`` (clipped to ``max_len`` either
+    way); a row of length 0 fetches nothing and returns zeros.
 
     ``k_scale``/``v_scale`` (both or neither): the cache is int8 with
     per-(position, head) symmetric scales in the QuantKVCache
-    ``[b, nkv, max_len]`` layout (positions last = the kernel's lane
-    dim, no transpose needed) — dequantized block-wise in VMEM, so the
-    HBM side moves int8 bytes."""
+    ``[b, nkv, max_len]`` layout — dequantized block-wise in VMEM, so
+    the HBM side moves int8 bytes.  One scalar ``pos0``, no ``slots``:
+    the int8 kernel is ``generate``'s, a pool of int8 slots attends
+    dense."""
     b, g, nh, hd = q.shape
     s, nkv = ck.shape[1], ck.shape[2]
     if nh % nkv != 0:
         raise ValueError(f"nh={nh} not divisible by nkv={nkv}")
     r = nh // nkv
-    if block_k is None:
-        block_k = _decode_block_k(s)
-        if block_k is None:
-            raise ValueError(
-                f"cache length {s} has no 128/256/512 block divisor; "
-                "pass block_k or use the dense path"
-            )
-    elif s % block_k != 0:
-        raise ValueError(f"cache length {s} not divisible by {block_k}")
-    if window is not None and window < 1:
-        raise ValueError("window must be >= 1")
     quant = k_scale is not None
     if quant != (v_scale is not None):
         raise ValueError("pass both k_scale and v_scale, or neither")
-    # Head-folded views (pure reshapes — the head axis is contiguous with
-    # hd, so no copy): Mosaic requires a block's last two dims to be
-    # (8k, 128k)-tileable or full axes, which a width-1 nkv-axis block is
-    # not.  The kv head becomes a lane-axis block offset instead.
+    if window is not None and window < 1:
+        raise ValueError("window must be >= 1")
+    if block_k is not None and s % block_k != 0:
+        raise ValueError(f"cache length {s} not divisible by {block_k}")
+    if block_k is not None:
+        # A block of the caller's: every head through one product.
+        tiling = (block_k, nkv)
+    elif quant:
+        one_head = _decode_block_k(s)
+        tiling = None if one_head is None else (one_head, 1)
+    else:
+        tiling = _decode_tiling(g, nh, nkv, ck.dtype.itemsize, s)
+    if not tiling:
+        raise ValueError(
+            f"cache length {s} has no 128/256/512 block divisor that "
+            f"fits {g} queries a row; pass block_k or use the dense path"
+        )
+    block_k, hw = tiling
+    if quant:
+        if slots is not None or lengths is not None or jnp.ndim(pos0):
+            raise ValueError(
+                "the int8 decode kernel takes one scalar pos0 and no "
+                "slots / lengths; use the dense path per row"
+            )
+        return _flash_decode_quant(
+            q, ck, cv, pos0, k_scale, v_scale, window, block_k, interpret
+        )
+    if slots is None:
+        if ck.shape[0] != b:
+            raise ValueError(
+                f"{b} query rows against {ck.shape[0]} cache rows: pass "
+                "slots"
+            )
+        slots = jnp.arange(b)
+    pos0 = jnp.broadcast_to(jnp.asarray(pos0, jnp.int32), (b,))
+    lengths = jnp.clip(
+        pos0 + g if lengths is None else lengths, 0, s
+    ).astype(jnp.int32)
+    return _flash_decode_rows(
+        q, ck, cv, pos0, lengths, slots.astype(jnp.int32), window=window,
+        block_k=block_k, hw=hw, interpret=interpret,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("window", "block_k", "hw", "interpret")
+)
+def _flash_decode_rows(
+    q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray, pos0: jnp.ndarray,
+    lengths: jnp.ndarray, slots: jnp.ndarray, *, window: Optional[int],
+    block_k: int, hw: int, interpret: bool,
+) -> jnp.ndarray:
+    """:func:`flash_decode_attention` over a bf16 / f32 cache, every
+    operand per row.  Jitted so that a model's layers (same shapes,
+    every layer) trace and lower the kernel ONCE and call one function:
+    bare, six layers of the two serving programs added 1.6 s to every
+    start (trace + lower 1.0 -> 2.6 s; described-chip lowering, PR 29)."""
+    b, g, nh, hd = q.shape
+    s, nkv = ck.shape[1], ck.shape[2]
+    r = nh // nkv
+    groups, rows = nkv // hw, g * hw * r
+    # Rows of a group: (query, local head) — a transpose of q's few
+    # rows, nothing of the cache's.
+    qf = q.reshape(b, g, groups, hw * r, hd)
+    qf = jnp.transpose(qf, (0, 2, 1, 3, 4)).reshape(b, groups, rows, hd)
+    row, slot, blk, ends = _decode_steps(
+        pos0, lengths, slots, window, block_k, b * (s // block_k)
+    )
+
+    def kv_im(t: Any, row_ref: Any, slot_ref: Any, blk_ref: Any,
+              *_: Any) -> Tuple:
+        return (slot_ref[t], blk_ref[t], 0, 0)
+
+    def q_im(t: Any, row_ref: Any, *_: Any) -> Tuple:
+        return (row_ref[t], 0, 0, 0)
+
+    out = pl.pallas_call(
+        functools.partial(
+            _decode_kernel, r=r, hw=hw, block_k=block_k, window=window,
+            sm_scale=hd ** -0.5,
+        ),
+        name="flash_decode",
+        out_shape=jax.ShapeDtypeStruct((b, groups, rows, hd), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(ends[-1],),
+            in_specs=[
+                pl.BlockSpec((1, groups, rows, hd), q_im),
+                pl.BlockSpec((1, block_k, nkv, hd), kv_im),
+                pl.BlockSpec((1, block_k, nkv, hd), kv_im),
+            ],
+            out_specs=pl.BlockSpec((1, groups, rows, hd), q_im),
+            scratch_shapes=[
+                pltpu.VMEM((groups, rows, 1), jnp.float32),
+                pltpu.VMEM((groups, rows, 1), jnp.float32),
+                pltpu.VMEM((groups, rows, hd), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_DECODE_VMEM_BYTES,
+        ),
+        interpret=interpret,
+    )(row, slot, blk, ends, pos0, lengths, qf, ck, cv)
+    out = out.reshape(b, groups, g, hw * r, hd)
+    return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(b, g, nh * hd)
+
+
+def _flash_decode_quant(
+    q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray, pos0: jnp.ndarray,
+    k_scale: jnp.ndarray, v_scale: jnp.ndarray, window: Optional[int],
+    block_k: int, interpret: bool,
+) -> jnp.ndarray:
+    """:func:`flash_decode_attention` over an int8 cache."""
+    b, g, nh, hd = q.shape
+    s, nkv = ck.shape[1], ck.shape[2]
+    r = nh // nkv
+    nkb = s // block_k
     qf = q.reshape(b, g, nh * hd)
     ckf = ck.reshape(b, s, nkv * hd)
     cvf = cv.reshape(b, s, nkv * hd)
     length = jnp.reshape(pos0 + g, (1,)).astype(jnp.int32)
-    nkb = s // block_k
 
     def kv_im(i: Any, h: Any, jb: Any, len_ref: Any) -> Tuple:
         # Clamp into the live (and, with a window, banded) block range:
@@ -1249,38 +1603,30 @@ def flash_decode_attention(
         return (i, lax.clamp(first, jb, last), h)
 
     q_im = lambda i, h, jb, len_ref: (i, 0, h)  # noqa: E731
-    in_specs = [
-        pl.BlockSpec((1, g, r * hd), q_im),
-        pl.BlockSpec((1, block_k, hd), kv_im),
-        pl.BlockSpec((1, block_k, hd), kv_im),
-    ]
-    operands = [length, qf, ckf, cvf]
-    if quant:
-        # One head's whole scale row [nkb, Bk] per (batch, head) cell —
-        # s floats, fetched once per (i, h) (the index map is constant
-        # over jb, so Pallas elides per-block refetches); full-axis
-        # last-two dims keep it tileable for any nkb.
-        in_specs += [
-            pl.BlockSpec(
-                (1, 1, nkb, block_k),
-                lambda i, h, jb, len_ref: (i, h, 0, 0),
-            ),
-        ] * 2
-        operands += [
-            k_scale.reshape(b, nkv, nkb, block_k),
-            v_scale.reshape(b, nkv, nkb, block_k),
-        ]
-    out = pl.pallas_call(
+    # One head's whole scale row [nkb, Bk] per (batch, head) cell — s
+    # floats, fetched once per (i, h) (the index map is constant over
+    # jb, so Pallas elides per-block refetches); full-axis last-two
+    # dims keep it tileable for any nkb.
+    scale_spec = pl.BlockSpec(
+        (1, 1, nkb, block_k), lambda i, h, jb, len_ref: (i, h, 0, 0)
+    )
+    return pl.pallas_call(
         functools.partial(
-            _decode_kernel, g=g, r=r, hd=hd, sm_scale=hd ** -0.5,
-            block_k=block_k, window=window, quant=quant,
+            _decode_quant_kernel, g=g, r=r, hd=hd, sm_scale=hd ** -0.5,
+            block_k=block_k, window=window,
         ),
         name="flash_decode",
         out_shape=jax.ShapeDtypeStruct((b, g, nh * hd), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, nkv, nkb),
-            in_specs=in_specs,
+            in_specs=[
+                pl.BlockSpec((1, g, r * hd), q_im),
+                pl.BlockSpec((1, block_k, hd), kv_im),
+                pl.BlockSpec((1, block_k, hd), kv_im),
+                scale_spec,
+                scale_spec,
+            ],
             out_specs=pl.BlockSpec((1, g, r * hd), q_im),
             scratch_shapes=[
                 pltpu.VMEM((g * r, 1), jnp.float32),
@@ -1289,5 +1635,8 @@ def flash_decode_attention(
             ],
         ),
         interpret=interpret,
-    )(*operands)
-    return out
+    )(
+        length, qf, ckf, cvf,
+        k_scale.reshape(b, nkv, nkb, block_k),
+        v_scale.reshape(b, nkv, nkb, block_k),
+    )

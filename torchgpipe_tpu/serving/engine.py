@@ -57,6 +57,7 @@ from torchgpipe_tpu.models.generation import (
     _check_decodable,
     _sample,
     _split_params,
+    attend_rows_counter,
     decode_slots,
 )
 from torchgpipe_tpu.models.transformer import TransformerConfig
@@ -391,6 +392,11 @@ class Engine:
         if role != "prefill":
             self.trace_counts["decode"] = 0
             self._token_shapes["decode"] = (num_slots, 1)
+        # What a step's attention reads, by its program's (rows, g).
+        self._attend_counters = {
+            shape: attend_rows_counter(self.cfg, self.pool.cache, *shape)
+            for shape in set(self._token_shapes.values())
+        }
         self._build_programs()
 
     # ------------------------------------------------------------------ #
@@ -653,6 +659,12 @@ class Engine:
 
     def _token_buffer(self, kind: str) -> np.ndarray:
         return np.zeros(self._token_shapes[kind], np.int32)
+
+    def _attended(self, pos0: np.ndarray, n_valid: np.ndarray,
+                  g: int) -> Tuple[int, int]:
+        """``(rows read, row capacity)`` of a layer's cache attention in
+        the step about to run (``generation.attend_rows_counter``)."""
+        return self._attend_counters[len(n_valid), g](pos0, n_valid)
 
     def _lengths_for_step(self) -> jnp.ndarray:
         """The frontier vector for the next compiled step: the previous
@@ -1066,6 +1078,8 @@ class Engine:
             slots_dev = jnp.asarray(slots)
             tokens_dev = jnp.asarray(tokens)
             n_valid_dev = jnp.asarray(n_valid)
+        attended = self._attended(self.pool.lengths[slots], n_valid, g)
+        tl.annotate(rows_read=attended[0], rows_cap=attended[1])
         t0 = self._rec_clock()
         tok, _grid, cache, lengths_dev, key = self._dispatch(
             self._prefill_fns[name], self.params, self.pool.cache,
@@ -1102,7 +1116,10 @@ class Engine:
             advance = np.zeros((self.pool.num_slots,), np.int32)
             advance[slots[:len(reqs)]] = n_valid[:len(reqs)]
             self._commit_lengths(lengths_dev, advance)
-            self.metrics.step("prefill", len(reqs), cap, deferred=deferred)
+            self.metrics.step(
+                "prefill", len(reqs), cap, deferred=deferred,
+                attended=attended,
+            )
             for i, r in enumerate(reqs):
                 take = int(n_valid[i])
                 self.pool.lengths[r.slot] += take
@@ -1171,6 +1188,8 @@ class Engine:
             lengths_in = self._lengths_for_step()
             tokens_dev = jnp.asarray(tokens)
             n_valid_dev = jnp.asarray(n_valid)
+        attended = self._attended(self.pool.lengths, n_valid, 1)
+        tl.annotate(rows_read=attended[0], rows_cap=attended[1])
         t0 = self._rec_clock()
         tok, cache, lengths_dev, key = self._dispatch(
             self._decode_fn, self.params, self.pool.cache,
@@ -1188,7 +1207,10 @@ class Engine:
             tl.annotate(**load)
         with tl.span("engine.emit", tokens=len(reqs)):
             self._commit_lengths(lengths_dev, n_valid)
-            self.metrics.step("decode", len(reqs), self.pool.num_slots)
+            self.metrics.step(
+                "decode", len(reqs), self.pool.num_slots,
+                attended=attended,
+            )
             if self.recorder is not None:
                 for r in reqs:
                     group = self._decode_groups.get(r.rid)

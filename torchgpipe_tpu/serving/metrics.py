@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from torchgpipe_tpu.obs.registry import (
     MetricsRegistry,
@@ -111,6 +111,16 @@ class ServingMetrics:
             "serving_prefill_deferred_rows",
             help="pending prompts a prefill step left for the next one "
                  "(more pending than R), summed over prefill steps")
+        self._c_attend_read = reg.counter(
+            "serving_attend_rows_read",
+            help="cache rows (block-rounded) a layer's attention reads, "
+                 "summed over the rows of every step: what the decode "
+                 "kernel fetches on a TPU, the capacity where the dense "
+                 "path runs")
+        self._c_attend_capacity = reg.counter(
+            "serving_attend_rows_capacity",
+            help="rows of every step x max_len; read over capacity is "
+                 "the share of the pool a step's attention reads")
         self._c_tokens = reg.counter(
             "serving_tokens_out", help="tokens emitted")
         self._c_retries = reg.counter(
@@ -170,6 +180,8 @@ class ServingMetrics:
     prefill_rows = _counter_property("_c_prefill_rows")
     prefill_row_capacity = _counter_property("_c_prefill_capacity")
     prefill_deferred_rows = _counter_property("_c_prefill_deferred")
+    attend_rows_read = _counter_property("_c_attend_read")
+    attend_rows_capacity = _counter_property("_c_attend_capacity")
     tokens_out = _counter_property("_c_tokens")
     retries = _counter_property("_c_retries")
     drains = _counter_property("_c_drains")
@@ -246,12 +258,16 @@ class ServingMetrics:
     # ------------------------------------------------------------------ #
 
     def step(self, kind: str, active_slots: int, num_slots: int,
-             deferred: int = 0) -> None:
+             deferred: int = 0, attended: Tuple[int, int] = (0, 0)) -> None:
         """One compiled step: ``active_slots`` of the ``num_slots`` rows
         its program has did useful work.  A prefill step's program is
         COMPACT (``num_slots`` is its row capacity ``R``, not the
         pool's size) and may leave ``deferred`` pending prompts to the
-        next prefill step."""
+        next prefill step.  ``attended`` is ``(rows read, row
+        capacity)`` of a layer's cache attention in this step
+        (``models.generation.attend_rows_counter``)."""
+        self._c_attend_read.inc(attended[0])
+        self._c_attend_capacity.inc(attended[1])
         if kind == "prefill":
             self._c_prefill.inc()
             self._c_prefill_rows.inc(active_slots)
@@ -345,6 +361,8 @@ class ServingMetrics:
             "prefill_row_capacity": self.prefill_row_capacity,
             "prefill_deferred_rows": self.prefill_deferred_rows,
             "prefill_fill_share": self.prefill_fill_share,
+            "attend_rows_read": self.attend_rows_read,
+            "attend_rows_capacity": self.attend_rows_capacity,
             "retries": self.retries,
             "drains": self.drains,
             "preempted_requests": self.preempted_requests,
