@@ -1,18 +1,11 @@
-"""Multi-process distributed pipeline training driver.
+"""Rank entry point for the real-process TcpTransport tests
+(``test_real_processes.py``): one OS process per rank joined over
+:class:`~torchgpipe_tpu.distributed.TcpTransport` (host-staged sockets,
+like the reference's RPC transport: benchmarks/distributed/accuracy/
+main.py:106-204, 347-368), training a small MLP split across the ranks
+with per-epoch checkpoints and bounded receives.
 
-Reference: benchmarks/distributed/accuracy/main.py:106-204, 347-368 — one OS
-process per rank joined over RPC (``--rank/--world/--master``), training a
-sequential model split across ranks.  Here ranks join over
-:class:`~torchgpipe_tpu.distributed.TcpTransport` (host-staged sockets, like
-the reference's RPC transport); for single-host multi-device runs prefer the
-in-process engine, and for pod-scale runs the SPMD engine (SURVEY.md §2.3).
-
-Example (two shells)::
-
-    python -m benchmarks.distributed_accuracy --rank 0 --world 2 \
-        --master 127.0.0.1 --port-base 29500
-    python -m benchmarks.distributed_accuracy --rank 1 --world 2 \
-        --master 127.0.0.1 --port-base 29500
+Usage: ``python tcp_rank.py --rank R --world W --port-base P --balance a,b,c``
 """
 
 from __future__ import annotations
@@ -24,39 +17,18 @@ import click
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import hr_time, softmax_xent
-from torchgpipe_tpu.balance import balance_by_time
 from torchgpipe_tpu.distributed import (
     DistributedGPipe,
     DistributedGPipeDataLoader,
     TcpTransport,
 )
-from torchgpipe_tpu.layers import sequential_init
-from torchgpipe_tpu.models import resnet50, vgg16
-from torchgpipe_tpu.models.transformer import TransformerConfig, llama
-
-def _mlp(classes):
-    from torchgpipe_tpu.ops import dense, flatten, relu
-
-    return [
-        flatten(), dense(64, name="fc1"), relu("r1"),
-        dense(64, name="fc2"), relu("r2"), dense(classes, name="fc3"),
-    ]
+from torchgpipe_tpu.ops import dense, flatten, relu
 
 
-MODELS = {
-    # The reference's distributed accuracy bench trains sequential
-    # resnet101/vgg16 over RPC ranks (benchmarks/distributed/accuracy/
-    # {resnet,vgg}); scaled-width counterparts of both are here.
-    "resnet50": lambda classes: resnet50(num_classes=classes, base_width=16),
-    "vgg16": lambda classes: vgg16(
-        num_classes=classes, base_width=16, head_width=256
-    ),
-    "llama-small": lambda classes: llama(
-        TransformerConfig(vocab=classes, dim=128, n_layers=4, n_heads=4)
-    ),
-    "mlp": _mlp,  # tiny smoke-test model
-}
+def softmax_xent(out, tgt):
+    logits = out.astype(jnp.float32)
+    logp = jax.nn.log_softmax(logits.reshape(-1, logits.shape[-1]))
+    return -jnp.mean(logp[jnp.arange(logp.shape[0]), tgt.reshape(-1)])
 
 
 @click.command()
@@ -64,19 +36,13 @@ MODELS = {
 @click.option("--world", required=True, type=int)
 @click.option("--master", default="127.0.0.1")
 @click.option("--port-base", default=29500)
-@click.option("--model", "model_name", default="resnet50",
-              type=click.Choice(sorted(MODELS)))
-@click.option("--balance", default=None, type=str,
-              help="comma-separated per-rank layer counts; default: profiled "
-                   "balance_by_time on rank 0's layer costs (reference: "
-                   "benchmarks/distributed/accuracy/main.py balance_by_time "
-                   "fallback)")
+@click.option("--balance", required=True, type=str,
+              help="comma-separated per-rank layer counts")
 @click.option("--chunks", default=4)
 @click.option("--batch-size", default=32)
 @click.option("--epochs", default=2)
 @click.option("--steps", default=8)
 @click.option("--classes", default=10)
-@click.option("--image", default=32)
 @click.option("--recv-timeout", default=None, type=float,
               help="bound every cross-rank receive; a dead peer surfaces as "
                    "a TimeoutError naming the missing channel instead of a "
@@ -88,10 +54,12 @@ MODELS = {
                    "state here after every epoch and resumes from the last "
                    "completed epoch on restart (the reference's RPC mode "
                    "has neither failure detection nor recovery)")
-def main(rank, world, master, port_base, model_name, balance, chunks,
-         batch_size, epochs, steps, classes, image, recv_timeout,
-         connect_timeout, checkpoint_dir):
-    layers = MODELS[model_name](classes)
+def main(rank, world, master, port_base, balance, chunks, batch_size, epochs,
+         steps, classes, recv_timeout, connect_timeout, checkpoint_dir):
+    layers = [
+        flatten(), dense(64, name="fc1"), relu("r1"),
+        dense(64, name="fc2"), relu("r2"), dense(classes, name="fc3"),
+    ]
     workers = [f"rank{r}" for r in range(world)]
     # Each rank listens on port_base + rank; peers dial the master host.
     addresses = {f"rank{r}": (master, port_base + r) for r in range(world)}
@@ -99,48 +67,16 @@ def main(rank, world, master, port_base, model_name, balance, chunks,
     transport = TcpTransport(
         f"rank{rank}", addresses, connect_timeout=connect_timeout
     )
+    in_spec = jax.ShapeDtypeStruct((batch_size, 16), jnp.float32)
 
-    if model_name == "llama-small":
-        x0 = jnp.zeros((batch_size, 64), jnp.int32)
-
-        def make_batch(key):
-            # Next-token LM objective: labels are the inputs shifted by one.
-            tokens = jax.random.randint(key, x0.shape, 0, classes)
-            return tokens, jnp.roll(tokens, -1, axis=1)
-    else:
-        shape = (
-            (batch_size, image, image, 3)
-            if model_name in ("resnet50", "vgg16")
-            else (batch_size, 16)
+    def make_batch(key):
+        kx, ky = jax.random.split(key)
+        return (
+            jax.random.normal(kx, in_spec.shape),
+            jax.random.randint(ky, (batch_size,), 0, classes),
         )
-        x0 = jnp.zeros(shape, jnp.float32)
 
-        def make_batch(key):
-            kx, ky = jax.random.split(key)
-            return (
-                jax.random.normal(kx, x0.shape),
-                jax.random.randint(ky, (batch_size,), 0, classes),
-            )
-    in_spec = jax.ShapeDtypeStruct(x0.shape, x0.dtype)
-
-    if balance:
-        balance = [int(v) for v in balance.split(",")]
-    elif rank == 0:
-        # Profile on rank 0 only and broadcast: wall-clock profiling on every
-        # rank independently could disagree on the balance and deadlock the
-        # pipe with mismatched stage ownership.
-        params0, states0, _ = sequential_init(
-            layers, jax.random.PRNGKey(0), in_spec
-        )
-        balance = balance_by_time(
-            world, layers, params0, states0, x0, timeout=0.5
-        )
-        print(f"[rank 0] profiled balance: {balance}", flush=True)
-        for r in range(1, world):
-            transport.send(f"rank{r}", "balance", 0, balance)
-    else:
-        balance = list(transport.mailbox.get("balance", 0, timeout=600))
-
+    balance = [int(v) for v in balance.split(",")]
     pipe = DistributedGPipe(
         layers, rank, workers, balance, chunks=chunks,
         transport=transport, mailbox=transport.mailbox,
@@ -159,8 +95,8 @@ def main(rank, world, master, port_base, model_name, balance, chunks,
         else None
     )
     ckpt_meta = (
-        f"{model_name}|world={world}|rank={rank}|balance={balance}|"
-        f"classes={classes}|image={image}|chunks={chunks}"
+        f"mlp|world={world}|rank={rank}|balance={balance}|"
+        f"classes={classes}|chunks={chunks}"
     )
     start_epoch = 0
     if ckpt_path and os.path.exists(ckpt_path):
@@ -222,7 +158,7 @@ def main(rank, world, master, port_base, model_name, balance, chunks,
                 loss, gys, _ = pipe.loss_grads(outs, yb, softmax_xent)
                 grads, state = pipe.backward(gys)
                 print(
-                    f"{hr_time(time.time() - t0)} | epoch {epoch + 1} "
+                    f"{time.time() - t0:7.1f}s | epoch {epoch + 1} "
                     f"step {step + 1}: loss {float(loss):.4f}",
                     flush=True,
                 )
@@ -298,7 +234,4 @@ def _load_rank_checkpoint(path, params, state, meta: str, ckpt_dir: str):
 
 
 if __name__ == "__main__":
-    from torchgpipe_tpu.utils.compile_cache import enable_compile_cache
-
-    enable_compile_cache()
     main()

@@ -51,7 +51,7 @@ def _free_port_base(world: int, tries: int = 40) -> int:
 
 
 def _spawn(rank: int, world: int, port_base: int, logdir: str, extra):
-    """Launch one rank of benchmarks.distributed_accuracy on CPU.
+    """Launch one rank (``tcp_rank.py``, beside this file) on CPU.
 
     Every rank is pinned to the CPU backend, with the repo root on
     PYTHONPATH (tests/subproc_env.py): ranks are separate processes, and
@@ -61,10 +61,11 @@ def _spawn(rank: int, world: int, port_base: int, logdir: str, extra):
     log = open(os.path.join(logdir, f"rank{rank}.log"), "wb")
     proc = subprocess.Popen(
         [
-            sys.executable, "-m", "benchmarks.distributed_accuracy",
+            sys.executable,
+            os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "tcp_rank.py"),
             "--rank", str(rank), "--world", str(world),
-            "--port-base", str(port_base),
-            "--model", "mlp", "--balance", "2,2,2",
+            "--port-base", str(port_base), "--balance", "2,2,2",
             "--chunks", "2", "--batch-size", "8", "--classes", "4",
             *extra,
         ],

@@ -475,9 +475,8 @@ def test_supports_decode_gate():
 def test_decode_kernel_quant_matches_dense_dequant(g, pos0, window):
     """int8 cache + scales through the kernel (block-wise VMEM dequant)
     == dequantize-then-dense — the QuantKVCache attend contract."""
-    from torchgpipe_tpu.models.generation import (
-        _attend_chunk, _quant_rows,
-    )
+    from torchgpipe_tpu.models.generation import _attend_chunk
+    from torchgpipe_tpu.models.kv_cache import _quant_rows
     from torchgpipe_tpu.ops.flash_attention import flash_decode_attention
 
     b, S, nkv, r, hd = 2, 512, 2, 2, 128

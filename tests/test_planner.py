@@ -9,9 +9,7 @@ ordering rules AND the memory certification, whose numbers must match
 ``tune.mpmd_stage_memory_profile`` exactly), the one-call
 ``apply_plan`` handoff, and the CLI exit codes of
 ``tools/plan_report.py`` / the ``plan-verify`` step in
-``tools/ci_lint.py``.  The predicted-vs-measured rank-order rung
-(``bench.py --plan-validate``) runs slow-marked via
-``benchmarks.plan_validate.run``.
+``tools/ci_lint.py``.
 """
 
 import jax
@@ -478,6 +476,24 @@ def test_plan_report_cli_rejects_unknown_preset(capsys):
 
 
 @pytest.mark.slow  # full tiny-llama searches (traced jaxprs, no device)
+def test_report_presets_reproduce_published_mlp_hidden():
+    """The llama3-8b / 1b presets must reproduce the published MLP hidden
+    sizes through TransformerConfig's SwiGLU 2/3 scaling."""
+    import jax.numpy as jnp
+
+    from tools.presets import PRESETS
+    from torchgpipe_tpu.models.transformer import TransformerConfig
+
+    want = {"llama3-8b": 14336, "1b": 8192}
+    for name, hidden in want.items():
+        dim, n_layers, n_heads, n_kv, vocab, ratio = PRESETS[name]
+        cfg = TransformerConfig(
+            vocab=vocab, dim=dim, n_layers=n_layers, n_heads=n_heads,
+            n_kv_heads=n_kv, mlp_ratio=ratio, dtype=jnp.bfloat16,
+        )
+        assert cfg.mlp_hidden == hidden, (name, cfg.mlp_hidden, hidden)
+
+
 def test_plan_report_cli_exit_codes(capsys):
     from tools.plan_report import main
 
@@ -506,46 +522,6 @@ def test_ci_lint_plan_verify_gate_passes():
 
     assert main(["--skip-typegate", "--skip-schedule", "--skip-pipeline",
                  "--skip-serving"]) == 0
-
-
-# --------------------------------------------------------------------- #
-# predicted-vs-measured rank order (the bench.py --plan-validate rung)  #
-# --------------------------------------------------------------------- #
-
-
-@pytest.mark.slow  # compiles + times 3 tiny-llama training variants
-def test_predicted_rank_order_matches_measured():
-    """The acceptance rung, run exactly as the bench contract ships it:
-    a clean single-device subprocess.  (In-process under the test
-    harness the 8-virtual-device CPU split overlaps the per-cell MPMD
-    dispatch and compresses the recompute gaps below timing noise —
-    the rung's contract is the one-device serialized measurement, where
-    the never : except_last : always work ratios 1 : 7/6 : 4/3 dominate
-    the clock.)"""
-    import json
-    import pathlib
-    import subprocess
-    import sys
-
-    from benchmarks.plan_validate import MODES
-
-    from tests.subproc_env import REPO, cpu_subproc_env
-
-    assert len(MODES) >= 3  # the >=3-candidate contract
-    proc = subprocess.run(
-        [sys.executable, str(pathlib.Path(REPO) / "bench.py"),
-         "--plan-validate"],
-        env=cpu_subproc_env(), capture_output=True, text=True,
-        timeout=540, cwd=REPO,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    result = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert result["match"], (
-        f"planner predicted {result['predicted_order']} but measured "
-        f"{result['measured_order']} ({result['measured_step_s']})"
-    )
-    assert result["predicted_order"] == result["measured_order"]
-    assert result["predicted_order"] == list(MODES)  # never wins on work
 
 
 # --------------------------------------------------------------------- #

@@ -766,7 +766,7 @@ def test_simulate_pipeline_survives_train_trace_with_barrier_spans():
     """The engine's gathered-loss barrier records at mb -1 (and SPMD
     step spans at stage -1); simulate_pipeline must project the CELLS
     and ignore aggregate spans — a traced training run is the function's
-    documented input (benchmarks/unet_timeline.py feeds one directly)."""
+    documented input."""
     tracer = Timeline(sync=True)
     model = GPipe(_layers(), balance=[2, 2], chunks=4, tracer=tracer)
     in_spec = jax.ShapeDtypeStruct((8, 8), jnp.float32)

@@ -58,7 +58,7 @@ def _plan_one(
     import jax
     import jax.numpy as jnp
 
-    from benchmarks.llama_speed import PRESETS
+    from tools.presets import PRESETS
     from torchgpipe_tpu.analysis import planner
     from torchgpipe_tpu.models.transformer import (
         TransformerConfig,

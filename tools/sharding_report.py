@@ -50,7 +50,7 @@ def _report_one(
     import jax
     import jax.numpy as jnp
 
-    from benchmarks.llama_speed import PRESETS
+    from tools.presets import PRESETS
     from torchgpipe_tpu.analysis import planner, sharding
     from torchgpipe_tpu.analysis.diagnostics import Severity, format_findings
     from torchgpipe_tpu.models.transformer import (

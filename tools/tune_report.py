@@ -9,7 +9,7 @@ CPU mesh), so the table is printable on any machine::
     python tools/tune_report.py --preset 1b --seq 4096 --stages 4 \
         --batch 8 --budget-gib 15.75
 
-Preset names come from ``benchmarks/llama_speed.py``; ``--fused-ce``
+Preset names come from ``tools/presets.py``; ``--fused-ce``
 swaps the lm head for the chunked-vocab CE loss layer so the CE chunk
 axis of the sweep activates.  See docs/tuning.md.
 """
@@ -55,7 +55,7 @@ def main(argv: Sequence[str] | None = None) -> int:
     import jax.numpy as jnp
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    from benchmarks.llama_speed import PRESETS
+    from tools.presets import PRESETS
     from torchgpipe_tpu import tune
     from torchgpipe_tpu.models.transformer import (
         TransformerConfig,

@@ -152,8 +152,7 @@ def mpmd_chunk_options(
 
 
 # Megastep candidates: K optimizer steps per compiled program
-# (make_train_step(megastep=K)).  The canonical rungs bench.py's
-# --megastep ladder times.
+# (make_train_step(megastep=K)).
 MEGASTEP_SPACE: Tuple[int, ...] = (1, 4, 16)
 
 

@@ -288,7 +288,7 @@ class TcpTransport:
         )
         host, port = self.addresses[dst]
         # Rendezvous tolerance: ranks are launched by hand in separate
-        # shells (see benchmarks.distributed_accuracy), so the peer's
+        # shells (see tests/distributed/tcp_rank.py), so the peer's
         # listener may not be up yet — retry refused connections until
         # connect_timeout instead of crashing the first sender.
         deadline = time.monotonic() + self.connect_timeout

@@ -23,8 +23,7 @@ the "millions of users" tier (docs/serving.md, fleet section):
   (``analysis.serving.certify_speculative``).
 * :mod:`~torchgpipe_tpu.fleet.trace` — a deterministic synthetic
   million-request trace generator (ragged, bursty, shared-prefix
-  tenants) driving ``bench.py --fleet``, so fleet claims are measured,
-  not asserted.
+  tenants) that the fleet tests and ``tools/*_verify.py`` replay.
 * :mod:`~torchgpipe_tpu.fleet.autoscaler` — :class:`Autoscaler`:
   replica count as a control loop — Little's-law pricing off the
   measured ``CostModel`` + MMPP arrival rates, SLO-burn override,

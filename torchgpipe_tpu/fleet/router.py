@@ -621,8 +621,8 @@ class Router:
 
     def reset_replica_steps(self) -> None:
         """Re-zero the per-replica step clocks ``die_at_step`` keys on
-        — e.g. between an untimed warmup pass and a timed fault region
-        (``benchmarks/fleet_trace.py``), so a death step means "step
+        — e.g. between an untimed warmup pass and a timed fault
+        region, so a death step means "step
         within THIS region" rather than "since router construction"."""
         for name in self._replica_steps:
             self._replica_steps[name] = 0

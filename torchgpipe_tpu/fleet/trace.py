@@ -27,8 +27,7 @@ Honesty contract (the "no silent caps" acceptance rule): a request
 whose prompt + budget cannot fit ``max_len`` is never silently
 resized — :func:`synthetic_trace` SKIPS it and counts it in
 ``TraceStats.skipped_too_long``, and every consumer is expected to
-surface that count (``bench.py --fleet`` refuses to publish a run
-whose stats it didn't log).
+surface that count.
 """
 
 from __future__ import annotations
@@ -219,8 +218,8 @@ def prefill_heavy_config(
     base load punctuated by bursts of LONG prompts with small budgets —
     prefill storms.  On a unified fleet every storm steals decode
     iterations from in-flight streams (TPOT spikes); a phase-split
-    fleet absorbs it in the prefill pool (``bench.py --disagg``
-    measures exactly this).  Deterministic per (n_requests, seed,
+    fleet absorbs it in the prefill pool.  Deterministic per
+    (n_requests, seed,
     max_len); keyword overrides replace any field."""
     burst_lo = max_len // 2
     cfg = dict(

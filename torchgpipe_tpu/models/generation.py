@@ -16,9 +16,10 @@ Design, TPU-first:
   paths cannot diverge in schema.  Numerical agreement IS tested
   (``tests/test_generation.py`` teacher-forces decode against the full
   forward).
-* **Static shapes everywhere.**  The KV cache is a fixed
-  ``[b, max_len, kv_heads, head_dim]`` buffer written with
-  ``lax.dynamic_update_slice_in_dim`` at a traced position; the decode
+* **Static shapes everywhere.**  The cache is a set of fixed buffers
+  written at a traced position (what a cache row is — the kinds, their
+  banks and layout, the writes — lives in :mod:`.kv_cache`; this module
+  chooses the attention that reads them); the decode
   loop is ONE ``lax.scan`` over ``max_new_tokens`` ticks compiled once
   — no per-token retracing, no data-dependent shapes (XLA requirement).
   Finished rows (EOS seen) keep scanning but freeze their output — the
@@ -74,7 +75,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from torchgpipe_tpu.models import mla
+from torchgpipe_tpu.models import kv_cache, mla
+from torchgpipe_tpu.models.kv_cache import (  # noqa: F401 (re-exported)
+    KVCache,
+    LatentCache,
+    QuantKVCache,
+    _cache_rows,
+    _dequant_rows,
+    _refuse_mla,
+    init_cache,
+    init_quant_cache,
+)
 from torchgpipe_tpu.models.transformer import (
     TransformerConfig,
     _act_fn,
@@ -86,111 +97,6 @@ from torchgpipe_tpu.models.transformer import (
 )
 
 Pytree = Any
-
-
-class KVCache(NamedTuple):
-    """Per-layer K/V buffers plus the current fill length."""
-
-    k: List[jnp.ndarray]  # each [b, max_len, n_kv, hd]
-    v: List[jnp.ndarray]
-    length: jnp.ndarray   # [] int32 — tokens already cached
-
-
-class LatentCache(NamedTuple):
-    """The cache of a latent-attention model (``cfg.mla``): a layer's
-    row is the normed KV latent and the rotated shared key head, not K
-    and V.  Two banks a layer, because the two are read apart (the
-    latent feeds scores AND output, the key head scores only) and a
-    slice of one wider bank's minor dim would be a copy of the bank."""
-
-    ckv: List[jnp.ndarray]  # each [b, max_len, kv_lora_rank]
-    kpe: List[jnp.ndarray]  # each [b, max_len, qk_rope_head_dim]
-    length: jnp.ndarray     # [] int32 — tokens already cached
-
-
-def init_cache(
-    cfg: TransformerConfig, batch: int, max_len: int,
-    dtype: Optional[jnp.dtype] = None,
-) -> Any:
-    """Zeroed cache for ``cfg.n_layers`` blocks, as the attention kind
-    says: :class:`KVCache`, or :class:`LatentCache` under ``cfg.mla``."""
-    dt = dtype or cfg.dtype
-    if cfg.mla is not None:
-        m = cfg.mla
-        return LatentCache(
-            ckv=[jnp.zeros((batch, max_len, m.kv_lora_rank), dt)
-                 for _ in range(cfg.n_layers)],
-            kpe=[jnp.zeros((batch, max_len, m.qk_rope_head_dim), dt)
-                 for _ in range(cfg.n_layers)],
-            length=jnp.zeros((), jnp.int32),
-        )
-    shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
-    return KVCache(
-        k=[jnp.zeros(shape, dt) for _ in range(cfg.n_layers)],
-        v=[jnp.zeros(shape, dt) for _ in range(cfg.n_layers)],
-        length=jnp.zeros((), jnp.int32),
-    )
-
-
-class QuantKVCache(NamedTuple):
-    """int8 K/V buffers with per-(position, kv-head) scales — half the
-    cache HBM footprint/traffic of bf16 and a quarter of f32; see
-    ``generate(kv_quant=True)``."""
-
-    k: List[jnp.ndarray]        # int8 [b, L, n_kv, hd]
-    v: List[jnp.ndarray]
-    k_scale: List[jnp.ndarray]  # f32 [b, n_kv, L] (kernel lane layout:
-    # the flash decode kernel tiles scales along L, so storing L last
-    # avoids a per-step transpose of the whole buffer)
-    v_scale: List[jnp.ndarray]
-    length: jnp.ndarray
-
-
-def _quant_rows(rows: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Symmetric per-(position, head) int8 quantization over head_dim."""
-    amax = jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=-1)
-    scale = jnp.maximum(amax, 1e-8) / 127.0
-    q = jnp.clip(
-        jnp.round(rows.astype(jnp.float32) / scale[..., None]), -127, 127
-    ).astype(jnp.int8)
-    return q, scale
-
-
-def _dequant_rows(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
-    # scale is [b, n_kv, L] (see QuantKVCache); rows are [b, L, n_kv, hd].
-    return q.astype(jnp.float32) * jnp.transpose(scale, (0, 2, 1))[..., None]
-
-
-def init_quant_cache(
-    cfg: TransformerConfig, batch: int, max_len: int
-) -> QuantKVCache:
-    """Zeroed int8 KV cache for ``cfg.n_layers`` blocks."""
-    shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
-    _refuse_mla(cfg, "the int8 QuantKVCache")
-    sshape = (batch, cfg.kv_heads, max_len)
-    return QuantKVCache(
-        k=[jnp.zeros(shape, jnp.int8) for _ in range(cfg.n_layers)],
-        v=[jnp.zeros(shape, jnp.int8) for _ in range(cfg.n_layers)],
-        k_scale=[jnp.zeros(sshape, jnp.float32) for _ in range(cfg.n_layers)],
-        v_scale=[jnp.zeros(sshape, jnp.float32) for _ in range(cfg.n_layers)],
-        length=jnp.zeros((), jnp.int32),
-    )
-
-
-def _refuse_mla(cfg: TransformerConfig, what: str) -> None:
-    if cfg.mla is not None:
-        raise NotImplementedError(
-            f"{what} holds K and V rows; a latent-attention model "
-            "(cfg.mla) caches the KV latent instead (LatentCache) and "
-            "is served by prefill / generate / decode_slots and "
-            "serving.Engine's plain pool"
-        )
-
-
-def _cache_rows(cache: Any) -> int:
-    """``max_len`` of a cache of any kind."""
-    bank = cache.ckv if isinstance(cache, LatentCache) else cache.k
-    return bank[0].shape[1]
 
 
 def _embed(cfg: TransformerConfig, embed_p: Pytree,
@@ -275,10 +181,10 @@ def _block_qkv(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Shared per-block decode prologue: ln1, q/k/v projections (+LoRA
     deltas, +Qwen2 biases), head reshape, Qwen3 per-head q/k RMSNorm,
-    rope at ``pos``.  ONE body for the single-token, chunked, and
-    slot-masked decode paths — a model-family quirk added here reaches
-    all three at once; only cache-write indexing and the attend stay
-    with each caller."""
+    rope at ``pos``.  ONE body for prefill and the single-token,
+    chunked, and slot-masked decode paths — a model-family quirk added
+    here reaches all four at once; only where the rows are written
+    (``kv_cache``'s two writes) and the attend stay with each caller."""
     b, g, _ = x.shape
     hd = cfg.head_dim
     wq, wk, wv = _w(cfg, p, "wq"), _w(cfg, p, "wk"), _w(cfg, p, "wv")
@@ -361,51 +267,19 @@ def _decode_step(
         return _decode_chunk(cfg, block_params, x, cache, mlp_layer)
     _refuse_mla(cfg, "a ring cache")
     pos = cache.length
-    quant = isinstance(cache, QuantKVCache)
-    new_k, new_v = [], []
-    new_ks, new_vs = [], []
-    scales = (
-        zip(cache.k_scale, cache.v_scale)
-        if quant
-        else ((None, None) for _ in cache.k)
-    )
-    for p, ck, cv, (cks, cvs) in zip(
-        block_params, cache.k, cache.v, scales
-    ):
-        q, k, v = _block_qkv(cfg, p, x, pos)
-        slot = jnp.mod(pos, ck.shape[1])
-        if quant:
-            kq, ks = _quant_rows(k)
-            vq, vs = _quant_rows(v)
-            ck = lax.dynamic_update_slice_in_dim(ck, kq, slot, 1)
-            cv = lax.dynamic_update_slice_in_dim(cv, vq, slot, 1)
-            cks = lax.dynamic_update_slice_in_dim(
-                cks, jnp.transpose(ks, (0, 2, 1)), slot, 2
-            )
-            cvs = lax.dynamic_update_slice_in_dim(
-                cvs, jnp.transpose(vs, (0, 2, 1)), slot, 2
-            )
-            rk, rv = _dequant_rows(ck, cks), _dequant_rows(cv, cvs)
-            new_ks.append(cks)
-            new_vs.append(cvs)
-        else:
-            ck = lax.dynamic_update_slice_in_dim(
-                ck, k.astype(ck.dtype), slot, 1
-            )
-            cv = lax.dynamic_update_slice_in_dim(
-                cv, v.astype(cv.dtype), slot, 1
-            )
-            rk, rv = ck, cv
+    W = _cache_rows(cache)
+    new = []
+    for p, layer in zip(block_params, kv_cache.layers(cache)):
+        q, *rows = _block_qkv(cfg, p, x, pos)
+        slot = jnp.mod(pos, W)
+        layer = kv_cache.write_columns(layer, rows, slot)
+        rk, rv, cks, cvs = layer
+        if cks is not None:
+            rk, rv = _dequant_rows(rk, cks), _dequant_rows(rv, cvs)
         attn = _attend_ring(q, rk, rv, pos)
         x = _block_attn_out(cfg, p, x, attn, mlp_layer)
-        new_k.append(ck)
-        new_v.append(cv)
-    if quant:
-        return x, QuantKVCache(
-            k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs,
-            length=pos + 1,
-        )
-    return x, KVCache(k=new_k, v=new_v, length=pos + 1)
+        new.append(layer)
+    return x, kv_cache.rebuild(cache, new, pos + 1)
 
 
 def _flash_decode_eligible(
@@ -618,67 +492,27 @@ def _decode_chunk(
     ring's slot reuse cannot undo)."""
     g = x.shape[1]
     pos0 = cache.length
-    if cfg.mla is not None:
-        new_c, new_r = [], []
-        for p, cc, cr in zip(block_params, cache.ckv, cache.kpe):
+    new = []
+    for p, layer in zip(block_params, kv_cache.layers(cache)):
+        if cfg.mla is not None:
             h = _block_norm(cfg, p, "ln1", x)
-            q_nope, q_pe, ckv, kpe = mla.project(cfg, p, h, pos0)
-            cc = lax.dynamic_update_slice_in_dim(
-                cc, ckv.astype(cc.dtype), pos0, 1)
-            cr = lax.dynamic_update_slice_in_dim(
-                cr, kpe.astype(cr.dtype), pos0, 1)
-            attn = mla.attend(cfg, p, q_nope, q_pe, cc, cr, pos0)
-            x = _block_attn_out(cfg, p, x, attn, mlp_layer)
-            new_c.append(cc)
-            new_r.append(cr)
-        return x, LatentCache(ckv=new_c, kpe=new_r, length=pos0 + g)
-    quant = isinstance(cache, QuantKVCache)
-    new_k, new_v = [], []
-    new_ks, new_vs = [], []
-    scales = (
-        zip(cache.k_scale, cache.v_scale)
-        if quant
-        else ((None, None) for _ in cache.k)
-    )
-    for p, ck, cv, (cks, cvs) in zip(
-        block_params, cache.k, cache.v, scales
-    ):
-        q, k, v = _block_qkv(cfg, p, x, pos0)
-        if quant:
-            kq, ks = _quant_rows(k)
-            vq, vs = _quant_rows(v)
-            ck = lax.dynamic_update_slice_in_dim(ck, kq, pos0, 1)
-            cv = lax.dynamic_update_slice_in_dim(cv, vq, pos0, 1)
-            cks = lax.dynamic_update_slice_in_dim(
-                cks, jnp.transpose(ks, (0, 2, 1)), pos0, 2
-            )
-            cvs = lax.dynamic_update_slice_in_dim(
-                cvs, jnp.transpose(vs, (0, 2, 1)), pos0, 2
-            )
-            new_ks.append(cks)
-            new_vs.append(cvs)
+            q_nope, q_pe, *rows = mla.project(cfg, p, h, pos0)
+            layer = kv_cache.write_columns(layer, rows, pos0)
+            attn = mla.attend(cfg, p, q_nope, q_pe, *layer[:2], pos0)
         else:
-            ck = lax.dynamic_update_slice_in_dim(
-                ck, k.astype(ck.dtype), pos0, 1
+            q, *rows = _block_qkv(cfg, p, x, pos0)
+            layer = kv_cache.write_columns(layer, rows, pos0)
+            ck, cv, cks, cvs = layer
+            # An int8 layer's banks (cks/cvs non-None) go to the attend
+            # AS-IS: the flash decode kernel dequantizes block-wise in
+            # VMEM (int8 HBM traffic); the dense path dequantizes at the
+            # attend instead.
+            attn = _attend_chunk(
+                q, ck, cv, pos0, cfg.attn_window, k_scale=cks, v_scale=cvs
             )
-            cv = lax.dynamic_update_slice_in_dim(
-                cv, v.astype(cv.dtype), pos0, 1
-            )
-        # Quant caches (cks/cvs non-None) go to the attend AS-IS: the
-        # flash decode kernel dequantizes block-wise in VMEM (int8 HBM
-        # traffic); the dense path dequantizes at the attend instead.
-        attn = _attend_chunk(
-            q, ck, cv, pos0, cfg.attn_window, k_scale=cks, v_scale=cvs
-        )
         x = _block_attn_out(cfg, p, x, attn, mlp_layer)
-        new_k.append(ck)
-        new_v.append(cv)
-    if quant:
-        return x, QuantKVCache(
-            k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs,
-            length=pos0 + g,
-        )
-    return x, KVCache(k=new_k, v=new_v, length=pos0 + g)
+        new.append(layer)
+    return x, kv_cache.rebuild(cache, new, pos0 + g)
 
 
 def _slot_rows(bank: jnp.ndarray, slot: jnp.ndarray) -> jnp.ndarray:
@@ -801,7 +635,6 @@ def decode_slots(
     S, g = tokens.shape          # rows of THIS call (R under ``slots``)
     L = _cache_rows(cache)
     counts: Optional[List[jnp.ndarray]] = [] if expert_counts else None
-    quant = isinstance(cache, QuantKVCache)
     compact = slots is not None
     slot_of = slots if compact else jnp.arange(S)       # [S] row -> slot
     pos0 = lengths[slots] if compact else lengths       # [S] row frontiers
@@ -814,85 +647,46 @@ def decode_slots(
     # Cache rows a row's attention needs: through its chunk's last
     # position, none for a row that does nothing in this call.
     live = jnp.where(n_valid > 0, jnp.minimum(pos0 + g, L), 0)
-    rows = slot_of[:, None]                             # [S, 1]
-    i0 = slot_of[:, None, None]                         # [S, 1, 1]
-    new_k, new_v = [], []
-    new_ks, new_vs = [], []
-    latent = cfg.mla is not None
-    if latent:
-        scales = ((None, None) for _ in cache.ckv)
-        banks = zip(block_p, cache.ckv, cache.kpe, scales)
-    else:
-        scales = (
-            zip(cache.k_scale, cache.v_scale)
-            if quant
-            else ((None, None) for _ in cache.k)
-        )
-        banks = zip(block_p, cache.k, cache.v, scales)
-    for p, ck, cv, (cks, cvs) in banks:
-        if latent:
-            # ``ck`` / ``cv`` are the latent and the key-head banks.
+    at = kv_cache.scatter_index(slot_of, wpos)
+    new = []
+    for p, layer in zip(block_p, kv_cache.layers(cache)):
+        if cfg.mla is not None:
             h = _block_norm(cfg, p, "ln1", x)
-            q_nope, q_pe, ckv, kpe = mla.project(cfg, p, h, pos0)
-            ck = ck.at[rows, wpos].set(ckv.astype(ck.dtype), mode="drop")
-            cv = cv.at[rows, wpos].set(kpe.astype(cv.dtype), mode="drop")
-            new_k.append(ck)
-            new_v.append(cv)
+            q_nope, q_pe, *rows = mla.project(cfg, p, h, pos0)
+            layer = kv_cache.write_scattered(layer, rows, at)
+            cc, cr = layer[:2]
             if compact:
                 attn = jnp.concatenate([
                     _attend_latent_row(
                         cfg, p["wkv_b"], q_nope[i:i + 1], q_pe[i:i + 1],
-                        _slot_rows(ck, slots[i]), _slot_rows(cv, slots[i]),
+                        _slot_rows(cc, slots[i]), _slot_rows(cr, slots[i]),
                         pos0[i:i + 1],
                     )
                     for i in range(S)
                 ], axis=0)
             else:
-                attn = mla.attend(cfg, p, q_nope, q_pe, ck, cv, pos0)
-            x = _block_attn_out(cfg, p, x, attn, mlp_layer, valid, counts)
-            continue
-        q, k, v = _block_qkv(cfg, p, x, pos0)
-        if quant:
-            kq, ks = _quant_rows(k)
-            vq, vs = _quant_rows(v)
-            ck = ck.at[rows, wpos].set(kq, mode="drop")
-            cv = cv.at[rows, wpos].set(vq, mode="drop")
-            i1 = jnp.arange(ck.shape[2])[None, None, :]
-            i2 = wpos[:, :, None]
-            cks = cks.at[i0, i1, i2].set(ks, mode="drop")
-            cvs = cvs.at[i0, i1, i2].set(vs, mode="drop")
-            new_ks.append(cks)
-            new_vs.append(cvs)
+                attn = mla.attend(cfg, p, q_nope, q_pe, cc, cr, pos0)
         else:
-            ck = ck.at[rows, wpos].set(k.astype(ck.dtype), mode="drop")
-            cv = cv.at[rows, wpos].set(v.astype(cv.dtype), mode="drop")
-        new_k.append(ck)
-        new_v.append(cv)
-        # Each row over ITS slot's rows, the written ones included, up
-        # to its own frontier: on a TPU the decode kernel reads the
-        # blocks inside ``live`` through its index maps (the whole bank
-        # is its operand, nothing of it is sliced or copied) and skips
-        # the rows with nothing to do; elsewhere the dense einsum, a
-        # row at a time in the compact form.
-        attn = _attend_chunk(
-            q, ck, cv, pos0, cfg.attn_window,
-            k_scale=cks if quant else None,
-            v_scale=cvs if quant else None,
-            slots=slots, lengths=live,
-        )
+            q, *rows = _block_qkv(cfg, p, x, pos0)
+            layer = kv_cache.write_scattered(layer, rows, at)
+            ck, cv, cks, cvs = layer
+            # Each row over ITS slot's rows, the written ones included,
+            # up to its own frontier: on a TPU the decode kernel reads
+            # the blocks inside ``live`` through its index maps (the
+            # whole bank is its operand, nothing of it is sliced or
+            # copied) and skips the rows with nothing to do; elsewhere
+            # the dense einsum, a row at a time in the compact form.
+            attn = _attend_chunk(
+                q, ck, cv, pos0, cfg.attn_window, k_scale=cks, v_scale=cvs,
+                slots=slots, lengths=live,
+            )
         x = _block_attn_out(cfg, p, x, attn, mlp_layer, valid, counts)
+        new.append(layer)
     new_lengths = (
         lengths.at[slots].add(n_valid) if compact else lengths + n_valid
     )
     length = jnp.sum(new_lengths).astype(jnp.int32)  # schema slot only
-    if latent:
-        out_cache: Any = LatentCache(ckv=new_k, kpe=new_v, length=length)
-    elif quant:
-        out_cache = QuantKVCache(
-            k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs, length=length
-        )
-    else:
-        out_cache = KVCache(k=new_k, v=new_v, length=length)
+    out_cache = kv_cache.rebuild(cache, new, length)
     out = (_logits(cfg, head_p, x), out_cache, new_lengths)
     if expert_counts:
         if not counts:
@@ -902,54 +696,6 @@ def decode_slots(
             )
         out += (jnp.stack(counts),)
     return out
-
-
-def _mask_finished_rows(
-    new: Any, old: Any, alive: jnp.ndarray, pos: jnp.ndarray
-) -> Any:
-    """Per-row masked no-op: rows finished (``alive[i]=False``) keep their
-    OLD cache content — eos padding never enters a finished row's K/V, so
-    its cache stays bit-exact at the row's true frontier (the property
-    batched serving and multi-turn continuation rely on).  The decode
-    step wrote exactly ONE position (``pos``; ring buffers wrap it to
-    their window), so only that column is merged back — O(b·heads·dim)
-    per layer, not a full-cache copy.  The shared scalar ``length`` still
-    advances (static shapes)."""
-
-    def merge(n: jnp.ndarray, o: jnp.ndarray, a: jnp.ndarray, axis: int):
-        at = jnp.mod(pos, n.shape[axis])
-        col = jnp.where(
-            a,
-            lax.dynamic_slice_in_dim(n, at, 1, axis),
-            lax.dynamic_slice_in_dim(o, at, 1, axis),
-        )
-        return lax.dynamic_update_slice_in_dim(n, col, at, axis)
-
-    if isinstance(new, LatentCache):
-        a3 = alive[:, None, None]
-        return LatentCache(
-            ckv=[merge(n, o, a3, 1) for n, o in zip(new.ckv, old.ckv)],
-            kpe=[merge(n, o, a3, 1) for n, o in zip(new.kpe, old.kpe)],
-            length=new.length,
-        )
-    a4 = alive[:, None, None, None]
-    k = [merge(n, o, a4, 1) for n, o in zip(new.k, old.k)]
-    v = [merge(n, o, a4, 1) for n, o in zip(new.v, old.v)]
-    if isinstance(new, QuantKVCache):
-        a3 = alive[:, None, None]
-        return QuantKVCache(
-            k=k, v=v,
-            k_scale=[
-                merge(n, o, a3, 2)
-                for n, o in zip(new.k_scale, old.k_scale)
-            ],
-            v_scale=[
-                merge(n, o, a3, 2)
-                for n, o in zip(new.v_scale, old.v_scale)
-            ],
-            length=new.length,
-        )
-    return KVCache(k=k, v=v, length=new.length)
 
 
 def row_frontiers(
@@ -1222,92 +968,24 @@ def prefill(
     W = cfg.attn_window if ring else None
     L = W if ring else max_len
     mlp_layer = _mlp_layer_for(cfg, moe)
-    if cfg.mla is not None:
-        if ring or kv_quant:
-            _refuse_mla(cfg, "a ring or int8 cache")
-        # The prompt's own rows ARE the cache rows it attends: one
-        # ``mla.attend`` a block over the s new rows, banked after.
-        cache = init_cache(cfg, b, max_len)
-        x = _embed(cfg, embed_p, tokens)
-        new_c, new_r = [], []
-        for p, cc, cr in zip(block_p, cache.ckv, cache.kpe):
-            h = _block_norm(cfg, p, "ln1", x)
-            q_nope, q_pe, ckv, kpe = mla.project(cfg, p, h, 0)
-            attn = mla.attend(cfg, p, q_nope, q_pe, ckv, kpe, 0)
-            x = _block_attn_out(cfg, p, x, attn, mlp_layer)
-            new_c.append(lax.dynamic_update_slice_in_dim(
-                cc, ckv.astype(cc.dtype), 0, 1))
-            new_r.append(lax.dynamic_update_slice_in_dim(
-                cr, kpe.astype(cr.dtype), 0, 1))
-        return _logits(cfg, head_p, x)[:, -1], LatentCache(
-            ckv=new_c, kpe=new_r, length=jnp.asarray(s, jnp.int32))
+    if ring or kv_quant:
+        _refuse_mla(cfg, "a ring or int8 cache")
     cache = (
         init_quant_cache(cfg, b, L) if kv_quant else init_cache(cfg, b, L)
     )
-    hd = cfg.head_dim
     x = _embed(cfg, embed_p, tokens)
-    new_k, new_v = [], []
-    new_ks, new_vs = [], []
-
-    def bank(rows, buf, sbuf):
-        """Write [b, n, ...] rows at columns 0..n-1 of ``buf`` (and the
-        scale buffer when quantized); ``rows`` may be a gather for ring
-        banking."""
-        if kv_quant:
-            q, sc = _quant_rows(rows)
-            return (
-                lax.dynamic_update_slice_in_dim(buf, q, 0, 1),
-                lax.dynamic_update_slice_in_dim(
-                    sbuf, jnp.transpose(sc, (0, 2, 1)), 0, 2
-                ),
-            )
-        return (
-            lax.dynamic_update_slice_in_dim(
-                buf, rows.astype(buf.dtype), 0, 1
-            ),
-            None,
-        )
-    scale_bufs = (
-        zip(cache.k_scale, cache.v_scale)
-        if kv_quant
-        else ((None, None) for _ in cache.k)
-    )
-    for p, ck, cv, (sk, sv) in zip(
-        block_p, cache.k, cache.v, scale_bufs
-    ):
-        wq, wk, wv = _w(cfg, p, "wq"), _w(cfg, p, "wk"), _w(cfg, p, "wv")
-        nh_loc = wq.shape[1] // hd
-        nkv_loc = wk.shape[1] // hd
-        h = _block_norm(cfg, p, "ln1", x)
-        q, k, v = h @ wq, h @ wk, h @ wv
-        if "lora" in p:
-            lo = p["lora"]
-            q = q + _lora_delta(cfg, lo, h, "qa", "qb")
-            k = k + _lora_delta(cfg, lo, h, "ka", "kb")
-            v = v + _lora_delta(cfg, lo, h, "va", "vb")
-        if "bq" in p:  # Qwen2-style projection biases
-            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-        q = q.reshape(b, s, nh_loc, hd)
-        k = k.reshape(b, s, nkv_loc, hd)
-        v = v.reshape(b, s, nkv_loc, hd)
-        if "qn" in p:  # Qwen3-style per-head q/k RMSNorm, pre-rope
-            q = _rms(q, p["qn"], cfg.norm_eps)
-            k = _rms(k, p["kn"], cfg.norm_eps)
-        q = _maybe_rope(cfg, q, 0)
-        k = _maybe_rope(cfg, k, 0)
-        attn = _attend_full(q, k, v, cfg.attn_window, use_flash)
-        attn = attn.astype(x.dtype)
-        o = attn @ _w(cfg, p, "wo")
-        if "lora" in p:
-            o = o + _lora_delta(cfg, p["lora"], attn, "oa", "ob")
-        if "bo" in p:
-            o = o + p["bo"]
-        x_in = x
-        x = x + o
-        h = _block_norm(
-            cfg, p, "ln2", x_in if cfg.parallel_residual else x
-        )
-        x = x + _mlp_out(cfg, p, h, mlp_layer)
+    new = []
+    for p, layer in zip(block_p, kv_cache.layers(cache)):
+        # The prompt's own rows ARE the rows it attends: one full-
+        # sequence attention a block over the s new rows, banked after.
+        if cfg.mla is not None:
+            h = _block_norm(cfg, p, "ln1", x)
+            q_nope, q_pe, *rows = mla.project(cfg, p, h, 0)
+            attn = mla.attend(cfg, p, q_nope, q_pe, *rows, 0)
+        else:
+            q, *rows = _block_qkv(cfg, p, x, 0)
+            attn = _attend_full(q, *rows, cfg.attn_window, use_flash)
+        x = _block_attn_out(cfg, p, x, attn, mlp_layer)
         if ring:
             # Slot j gets the newest prompt position congruent to j
             # (mod W); never-written slots (s < W) gather garbage that
@@ -1315,24 +993,10 @@ def prefill(
             jslots = jnp.arange(W)
             p_j = (s - 1) - jnp.mod((s - 1) - jslots, W)
             idx = jnp.clip(p_j, 0, s - 1)
-            k_rows, v_rows = jnp.take(k, idx, axis=1), jnp.take(v, idx, axis=1)
-        else:
-            k_rows, v_rows = k, v
-        bk, bks = bank(k_rows, ck, sk)
-        bv, bvs = bank(v_rows, cv, sv)
-        new_k.append(bk)
-        new_v.append(bv)
-        if kv_quant:
-            new_ks.append(bks)
-            new_vs.append(bvs)
-    length = jnp.asarray(s, jnp.int32)
-    cache = (
-        QuantKVCache(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs,
-                     length=length)
-        if kv_quant
-        else KVCache(k=new_k, v=new_v, length=length)
-    )
-    return _logits(cfg, head_p, x)[:, -1], cache
+            rows = [jnp.take(r, idx, axis=1) for r in rows]
+        new.append(kv_cache.write_columns(layer, rows, 0))
+    return _logits(cfg, head_p, x)[:, -1], kv_cache.rebuild(
+        cache, new, jnp.asarray(s, jnp.int32))
 
 
 def _generate_rows(
@@ -1574,7 +1238,7 @@ def generate(
         if eos_id is not None:
             # Rows already finished BEFORE this step are masked no-ops:
             # their eos feed's K/V write is dropped.
-            new_cache = _mask_finished_rows(
+            new_cache = kv_cache.keep_finished_rows(
                 new_cache, cache, was_alive, cache.length
             )
         return (new_cache, _logits(cfg, head_p, x)[:, 0], key, alive), tok

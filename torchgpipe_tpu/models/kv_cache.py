@@ -1,0 +1,317 @@
+"""What a cache row is: the kinds of cache, their banks and layout, and
+every way a row is written, merged, copied or exported.
+
+A cache holds, for each block, *banks*: fixed ``[b, ...]`` buffers whose
+leading axis is the batch (a serving pool's SLOT axis) and which have
+one *length* axis, the position in the sequence.  Three kinds:
+
+* :class:`KVCache` — ``k`` and ``v``, each ``[b, L, n_kv, hd]``;
+* :class:`QuantKVCache` — the same two banks in int8, plus ``k_scale``
+  and ``v_scale``, f32 ``[b, n_kv, L]``: per-(position, kv-head)
+  symmetric scales with the length LAST (the flash decode kernel tiles
+  scales along ``L``, so storing ``L`` last avoids a per-step transpose
+  of the whole buffer);
+* :class:`LatentCache` — what latent attention (``cfg.mla``) caches:
+  ``ckv [b, L, kv_lora_rank]`` and ``kpe [b, L, qk_rope_head_dim]``.
+
+Every kind has two CONTENT banks a layer (K and V, or the latent and
+the rotated key head) and, where its rows are quantised, one scale bank
+beside each.  :func:`layers` hands a layer's banks out as ``(a, b,
+a_scale, b_scale)`` with ``None`` for the scales a kind does not have;
+the writes take and return that tuple and :func:`rebuild` makes a cache
+of the same kind from a list of them.  Which attention reads the banks
+is the caller's decision (``generation._attend_chunk``, ``mla.attend``);
+an int8 layer is quantised HERE at the write and dequantised by the
+attention at the read.
+
+The callers — ``models.generation``, ``serving.engine``,
+``serving.cache_pool`` and ``tune`` — know none of the above: a new kind
+of row is a new class here, its entry in ``_LENGTH_AXIS`` and, in
+``generation``, the lines that choose its attention.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from torchgpipe_tpu.models.transformer import TransformerConfig
+
+# One layer's banks: the two content banks, then their scale banks
+# (``None`` for a kind that has none).
+Layer = Tuple[jnp.ndarray, jnp.ndarray,
+              Optional[jnp.ndarray], Optional[jnp.ndarray]]
+
+
+class KVCache(NamedTuple):
+    """Per-layer K/V buffers plus the current fill length."""
+
+    k: List[jnp.ndarray]  # each [b, max_len, n_kv, hd]
+    v: List[jnp.ndarray]
+    length: jnp.ndarray   # [] int32 — tokens already cached
+
+
+class LatentCache(NamedTuple):
+    """The cache of a latent-attention model (``cfg.mla``): a layer's
+    row is the normed KV latent and the rotated shared key head, not K
+    and V.  Two banks a layer, because the two are read apart (the
+    latent feeds scores AND output, the key head scores only) and a
+    slice of one wider bank's minor dim would be a copy of the bank."""
+
+    ckv: List[jnp.ndarray]  # each [b, max_len, kv_lora_rank]
+    kpe: List[jnp.ndarray]  # each [b, max_len, qk_rope_head_dim]
+    length: jnp.ndarray     # [] int32 — tokens already cached
+
+
+class QuantKVCache(NamedTuple):
+    """int8 K/V buffers with per-(position, kv-head) scales — half the
+    cache HBM footprint/traffic of bf16 and a quarter of f32; see
+    ``generate(kv_quant=True)``."""
+
+    k: List[jnp.ndarray]        # int8 [b, L, n_kv, hd]
+    v: List[jnp.ndarray]
+    k_scale: List[jnp.ndarray]  # f32 [b, n_kv, L]
+    v_scale: List[jnp.ndarray]
+    length: jnp.ndarray
+
+
+# The axis of each bank on which its length lies (axis 0 is the batch /
+# slot axis of every bank).  THE statement of the layout: the writes,
+# the merge and the copy below index by it and nothing else does.
+_LENGTH_AXIS = {"k": 1, "v": 1, "ckv": 1, "kpe": 1, "k_scale": 2, "v_scale": 2}
+
+
+def _bank_fields(cache: Any) -> Tuple[str, ...]:
+    """The bank fields of ``cache``'s kind: content banks, then scales."""
+    return tuple(f for f in cache._fields if f != "length")
+
+
+def _refuse_mla(cfg: TransformerConfig, what: str) -> None:
+    if cfg.mla is not None:
+        raise NotImplementedError(
+            f"{what} holds K and V rows; a latent-attention model "
+            "(cfg.mla) caches the KV latent instead (LatentCache) and "
+            "is served by prefill / generate / decode_slots and "
+            "serving.Engine's plain pool"
+        )
+
+
+def init_cache(
+    cfg: TransformerConfig, batch: int, max_len: int,
+    dtype: Optional[jnp.dtype] = None,
+) -> Any:
+    """Zeroed cache for ``cfg.n_layers`` blocks, as the attention kind
+    says: :class:`KVCache`, or :class:`LatentCache` under ``cfg.mla``."""
+    dt = dtype or cfg.dtype
+    if cfg.mla is not None:
+        m = cfg.mla
+        return LatentCache(
+            ckv=[jnp.zeros((batch, max_len, m.kv_lora_rank), dt)
+                 for _ in range(cfg.n_layers)],
+            kpe=[jnp.zeros((batch, max_len, m.qk_rope_head_dim), dt)
+                 for _ in range(cfg.n_layers)],
+            length=jnp.zeros((), jnp.int32),
+        )
+    shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
+    return KVCache(
+        k=[jnp.zeros(shape, dt) for _ in range(cfg.n_layers)],
+        v=[jnp.zeros(shape, dt) for _ in range(cfg.n_layers)],
+        length=jnp.zeros((), jnp.int32),
+    )
+
+
+def init_quant_cache(
+    cfg: TransformerConfig, batch: int, max_len: int
+) -> QuantKVCache:
+    """Zeroed int8 KV cache for ``cfg.n_layers`` blocks."""
+    shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
+    _refuse_mla(cfg, "the int8 QuantKVCache")
+    sshape = (batch, cfg.kv_heads, max_len)
+    return QuantKVCache(
+        k=[jnp.zeros(shape, jnp.int8) for _ in range(cfg.n_layers)],
+        v=[jnp.zeros(shape, jnp.int8) for _ in range(cfg.n_layers)],
+        k_scale=[jnp.zeros(sshape, jnp.float32) for _ in range(cfg.n_layers)],
+        v_scale=[jnp.zeros(sshape, jnp.float32) for _ in range(cfg.n_layers)],
+        length=jnp.zeros((), jnp.int32),
+    )
+
+
+def _quant_rows(rows: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Symmetric per-(position, head) int8 quantization over head_dim."""
+    amax = jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=-1)
+    scale = jnp.maximum(amax, 1e-8) / 127.0
+    q = jnp.clip(
+        jnp.round(rows.astype(jnp.float32) / scale[..., None]), -127, 127
+    ).astype(jnp.int8)
+    return q, scale
+
+
+def _dequant_rows(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
+    # scale is [b, n_kv, L] (see QuantKVCache); rows are [b, L, n_kv, hd].
+    return q.astype(jnp.float32) * jnp.transpose(scale, (0, 2, 1))[..., None]
+
+
+def _cache_rows(cache: Any) -> int:
+    """``max_len`` of a cache of any kind."""
+    field = _bank_fields(cache)[0]
+    return getattr(cache, field)[0].shape[_LENGTH_AXIS[field]]
+
+
+def layers(cache: Any) -> Iterator[Layer]:
+    """Each layer's banks in block order, as ``(a, b, a_scale,
+    b_scale)``: the two content banks and their scale banks, ``None``
+    where the kind has none."""
+    for banks in zip(*(getattr(cache, f) for f in _bank_fields(cache))):
+        yield banks + (None,) * (4 - len(banks))
+
+
+def rebuild(cache: Any, new_layers: List[Layer], length: jnp.ndarray) -> Any:
+    """A cache of ``cache``'s kind from per-layer banks (as the writes
+    return them) and a new ``length``."""
+    fields = _bank_fields(cache)
+    return type(cache)(
+        *(list(bank) for bank in list(zip(*new_layers))[:len(fields)]),
+        length=length,
+    )
+
+
+def _write(layer: Layer, rows: Tuple, put: Any, put_scales: Any) -> Layer:
+    """``layer`` with the content rows ``rows = (a rows, b rows)`` put
+    into its banks: cast to the banks' dtype, or — a layer with scale
+    banks — quantised to int8 (:func:`_quant_rows`) with the ``[b, g,
+    n_kv]`` scales put into the scale banks."""
+    a, b, a_scale, b_scale = layer
+    if a_scale is None:
+        return (put(a, rows[0].astype(a.dtype)),
+                put(b, rows[1].astype(b.dtype)), None, None)
+    (qa, sa), (qb, sb) = _quant_rows(rows[0]), _quant_rows(rows[1])
+    return (put(a, qa), put(b, qb)) + put_scales(a_scale, sa, b_scale, sb)
+
+
+def write_columns(layer: Layer, rows: Tuple, at: Any) -> Layer:
+    """``layer`` with the new content rows ``rows = (a [b, g, ...],
+    b [b, g, ...])`` written at columns ``at .. at + g - 1`` of every
+    row (one offset for the whole batch: the chunk at ``pos0``, the
+    ring at ``pos % W``, a prompt at 0)."""
+
+    def put(bank, new, axis=1):
+        return lax.dynamic_update_slice_in_dim(bank, new, at, axis)
+
+    def put_scales(a_scale, sa, b_scale, sb):
+        return (put(a_scale, jnp.transpose(sa, (0, 2, 1)), 2),
+                put(b_scale, jnp.transpose(sb, (0, 2, 1)), 2))
+
+    return _write(layer, rows, put, put_scales)
+
+
+class ScatterIndex(NamedTuple):
+    """Where :func:`write_scattered` puts a call's rows: batch row ``i``
+    is slot ``slots[i]`` and its token ``j`` lands at column
+    ``wpos[i, j]`` — ``max_len`` (out of range) drops it."""
+
+    rows: jnp.ndarray   # [S, 1] — slot of each row, against wpos
+    rows3: jnp.ndarray  # [S, 1, 1] — the same, against a scale bank
+    wpos: jnp.ndarray   # [S, g]
+
+
+def scatter_index(slots: jnp.ndarray, wpos: jnp.ndarray) -> ScatterIndex:
+    """The index of one call's writes, built once for all its layers."""
+    return ScatterIndex(slots[:, None], slots[:, None, None], wpos)
+
+
+def write_scattered(layer: Layer, rows: Tuple, at: ScatterIndex) -> Layer:
+    """``layer`` with the new content rows ``rows = (a [S, g, ...],
+    b [S, g, ...])`` scattered to ``(slot, column)`` per token, masked
+    tokens dropped: every other slot, and every column not named, stays
+    bit-untouched (and a donated bank is updated in place)."""
+
+    def put(bank, new):
+        return bank.at[at.rows, at.wpos].set(new, mode="drop")
+
+    def put_scales(a_scale, sa, b_scale, sb):
+        heads = jnp.arange(a_scale.shape[1])[None, None, :]
+        cols = at.wpos[:, :, None]
+        return (a_scale.at[at.rows3, heads, cols].set(sa, mode="drop"),
+                b_scale.at[at.rows3, heads, cols].set(sb, mode="drop"))
+
+    return _write(layer, rows, put, put_scales)
+
+
+def _map_banks(cache: Any, fn: Any) -> Any:
+    """A cache of ``cache``'s kind and length whose every bank is
+    ``fn(field, layer, bank, the bank's length axis)``, field by field
+    in layout order."""
+    return type(cache)(
+        *(
+            [fn(f, i, bank, _LENGTH_AXIS[f])
+             for i, bank in enumerate(getattr(cache, f))]
+            for f in _bank_fields(cache)
+        ),
+        length=cache.length,
+    )
+
+
+def keep_finished_rows(
+    new: Any, old: Any, alive: jnp.ndarray, pos: jnp.ndarray
+) -> Any:
+    """Per-row masked no-op: rows finished (``alive[i]=False``) keep their
+    OLD cache content — eos padding never enters a finished row's banks,
+    so its cache stays bit-exact at the row's true frontier (the property
+    batched serving and multi-turn continuation rely on).  The decode
+    step wrote exactly ONE position (``pos``; ring buffers wrap it to
+    their window), so only that column is merged back — O(b·heads·dim)
+    per layer, not a full-cache copy.  ``new``'s ``length`` is kept: the
+    shared scalar still advances (static shapes)."""
+    masks: Dict[int, jnp.ndarray] = {}  # ``alive`` against a bank, by rank
+
+    def merge(f: str, i: int, n: jnp.ndarray, axis: int) -> jnp.ndarray:
+        if n.ndim not in masks:
+            masks[n.ndim] = alive[(slice(None),) + (None,) * (n.ndim - 1)]
+        at = jnp.mod(pos, n.shape[axis])
+        col = jnp.where(
+            masks[n.ndim],
+            lax.dynamic_slice_in_dim(n, at, 1, axis),
+            lax.dynamic_slice_in_dim(getattr(old, f)[i], at, 1, axis),
+        )
+        return lax.dynamic_update_slice_in_dim(n, col, at, axis)
+
+    return _map_banks(new, merge)
+
+
+def copy_rows(cache: Any, src: Any, dst: jnp.ndarray, n: jnp.ndarray) -> Any:
+    """``cache`` with rows ``[0, n)`` of slot ``dst`` of every bank
+    taken from ``src``: a slot index of the same cache, or one slot's
+    shipped rows (:func:`slot_rows`, of this or of another pool with the
+    same :func:`slot_row_specs`).  Rows ``>= n`` of ``dst`` and every
+    other slot are untouched.  ``src`` (an index), ``dst`` and ``n`` may
+    be traced values: one fixed-shape program serves every copy."""
+    L = _cache_rows(cache)
+    row_mask = jnp.arange(L) < n          # [L]
+    shipped = isinstance(src, dict)
+
+    def put(f: str, i: int, bank: jnp.ndarray, axis: int) -> jnp.ndarray:
+        # A slot's rows have lost the slot axis, so their length axis
+        # (and the mask's) sits at ``axis - 1``.
+        shape = [1] * (bank.ndim - 1)
+        shape[axis - 1] = L
+        m = row_mask.reshape(shape)
+        row = src[f][i] if shipped else bank[src]
+        return bank.at[dst].set(jnp.where(m, row, bank[dst]))
+
+    return _map_banks(cache, put)
+
+
+def slot_rows(cache: Any, slot: Any) -> Dict[str, List[jnp.ndarray]]:
+    """One slot's rows of every bank, slot axis sliced away, by field."""
+    return {
+        f: [bank[slot] for bank in getattr(cache, f)]
+        for f in _bank_fields(cache)
+    }
+
+
+def slot_row_specs(cache: Any) -> Dict[str, List[jax.ShapeDtypeStruct]]:
+    """The shapes and dtypes of :func:`slot_rows`."""
+    return jax.eval_shape(lambda c: slot_rows(c, 0), cache)

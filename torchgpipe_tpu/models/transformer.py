@@ -58,7 +58,7 @@ class MLAConfig:
     ``q_lora_rank`` bottleneck; keys and values are expanded from ONE
     ``kv_lora_rank`` latent a token plus one rotary key head of
     ``qk_rope_head_dim`` shared by all heads — and that pair is what the
-    cache holds (``models.generation.LatentCache``), not K and V."""
+    cache holds (``models.kv_cache.LatentCache``), not K and V."""
 
     q_lora_rank: int
     kv_lora_rank: int

@@ -14,8 +14,7 @@ edge.  This module is the black box every rank carries:
   receive wait-start / match (with mailbox depth), per-cell compute
   completions, forward/backward loop boundaries, transport connect
   retries and timeouts, guard decisions.  Recording is one clock read
-  and one deque append — bounded memory, bounded cost (the
-  ``bench.py --flightrec-overhead`` rung gates it at <2% of a step).
+  and one deque append — bounded memory, bounded cost.
 * **Dump-on-demand** — :meth:`FlightRecorder.dump` writes the ring as
   JSON; the distributed engine dumps automatically on a receive
   timeout / ``PeerDiedError`` (:meth:`crash_dump`), and

@@ -10,9 +10,10 @@ they export next to the serving and guard metrics:
 * **measured MFU** — ``flops_per_step * real_token_fraction /
   (dt * peak)`` when both the analytic step FLOPs
   (:func:`measured_step_flops`, the ``analysis.jaxpr.flops_estimate``
-  walker — the same numerator the planner predicts with) and a
-  published chip peak (``utils.hw.chip_peak_bf16_flops``) are known;
-  omitted on host CPU.  ``real_token_fraction``
+  walker — the same numerator the planner predicts with) and the
+  chip's published peak (``peak_flops=``, the caller's to give: the
+  package keeps no peak table) are known; omitted otherwise.
+  ``real_token_fraction``
   (``utils.data.real_token_fraction``) keeps ragged-data MFU honest:
   the traced FLOPs price padded shapes, so pad arithmetic is scaled
   OUT of the numerator — a padded run reports lower MFU than a packed
@@ -22,8 +23,7 @@ they export next to the serving and guard metrics:
   shows up in the same log line as the step-time spike it caused.
 
 ``step()`` is host-side bookkeeping only (two clock reads, a histogram
-observe) — the ``--obs-overhead`` bench rung gates it at <2% of a tiny
-CPU step.  Every ``log_every`` steps one structured (JSON) line goes to
+observe).  Every ``log_every`` steps one structured (JSON) line goes to
 ``emit`` — parseable, greppable, and stable across PRs.
 """
 
@@ -125,9 +125,7 @@ class StepReporter:
                 f"{real_token_fraction}"
             )
         self.real_token_fraction = float(real_token_fraction)
-        self.peak_flops = (
-            peak_flops if peak_flops is not None else _default_peak()
-        )
+        self.peak_flops = peak_flops
         self.guard = guard
         # Optional obs.replan.ReplanOnDrift hook: its applied-replan
         # count mirrors into the same log line as the step-time shift
@@ -294,19 +292,6 @@ class StepReporter:
             if v is not None
         }
         return f"OBS | {json.dumps(payload)}"
-
-
-def _default_peak() -> Optional[float]:
-    """The default MFU denominator: the default device's published bf16
-    peak, None on host CPU (MFU is then omitted, never faked)."""
-    try:
-        import jax
-
-        from torchgpipe_tpu.utils.hw import chip_peak_bf16_flops
-
-        return chip_peak_bf16_flops(jax.devices()[0])
-    except Exception:  # noqa: BLE001 — no backend is a valid state
-        return None
 
 
 __all__ = ["StepReporter", "measured_step_flops"]

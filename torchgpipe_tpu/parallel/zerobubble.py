@@ -116,8 +116,8 @@ def fused_1f1b_weighted_makespan(
     The comparator for :meth:`ZeroBubbleTables.weighted_makespan`: the
     >=1.2x zb win band (tests/test_zerobubble.py) is this figure over the
     zb makespan at uniform split costs ``(t_f, t_bw/2, t_bw/2)``; the
-    ratio is cost-profile-dependent, so benchmark drivers should evaluate
-    it at their CALIBRATED costs (benchmarks/zb_timing.py)."""
+    ratio is cost-profile-dependent, so a measurement should evaluate
+    it at its CALIBRATED costs."""
     total = 0.0
     for t in range(2 * (m + n - 1)):
         c = 0.0

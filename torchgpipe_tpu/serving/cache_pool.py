@@ -4,15 +4,12 @@ The TPU serving problem in one sentence: request churn must never change
 an array shape (XLA recompiles per shape — the ``recompilation-hazard``
 lint rule), yet requests arrive, finish and cancel at arbitrary times.
 The pool squares that circle the PagedAttention/Orca way, specialised to
-one page per request: a fixed ``[num_slots, max_len, kv_heads, head_dim]``
-K/V bank per layer (a :class:`~torchgpipe_tpu.models.generation.KVCache`
-or int8 :class:`~torchgpipe_tpu.models.generation.QuantKVCache` whose
-batch dim IS the slot dim) — or, for a latent-attention model
-(``cfg.mla``), what that attention caches: a
-:class:`~torchgpipe_tpu.models.generation.LatentCache` of
-``[num_slots, max_len, kv_lora_rank]`` and ``[num_slots, max_len,
-qk_rope_head_dim]`` a layer; ``init_cache`` decides by the attention
-kind — a host-side free list handing slots to
+one page per request: fixed banks of ``num_slots x max_len`` rows per
+layer — a cache of :mod:`torchgpipe_tpu.models.kv_cache` (``KVCache``,
+int8 ``QuantKVCache`` or, for a latent-attention model, ``LatentCache``:
+``init_cache`` decides by the attention kind) whose batch dim IS the
+slot dim; what a row holds and how its banks are laid out is that
+module's business, not the pool's — a host-side free list handing slots to
 requests and taking them back, and a per-slot ``lengths`` vector (host
 mirror, passed into every compiled step) giving each slot its own
 sequence frontier.
@@ -37,7 +34,7 @@ from typing import Any, Dict, List, Optional
 import jax.numpy as jnp
 import numpy as np
 
-from torchgpipe_tpu.models.generation import init_cache, init_quant_cache
+from torchgpipe_tpu.models.kv_cache import init_cache, init_quant_cache
 from torchgpipe_tpu.models.transformer import TransformerConfig
 
 

@@ -51,9 +51,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from torchgpipe_tpu.models import kv_cache
 from torchgpipe_tpu.models.generation import (
-    KVCache,
-    QuantKVCache,
     _check_decodable,
     _sample,
     _split_params,
@@ -476,99 +475,41 @@ class Engine:
             else jax.jit(decode_body, donate_argnums=donate)
         )
 
+        # Two fixed-shape programs around ONE masked row copy
+        # (``kv_cache.copy_rows``: rows [0, n) of a source into slot
+        # ``dst`` of every bank): src/dst/n are traced VALUES, so one
+        # program serves every reuse and every migration and the static
+        # program count holds.  Bitwise either way: the source's rows
+        # are exactly what this pool's own cold prefill of the same
+        # tokens at the same positions writes (prefill is replica-
+        # independent), so a reused or migrated request's cache equals
+        # the cold one bit-for-bit and its greedy stream is unchanged
+        # (the fleet-verify and disagg-verify gates).
+        copy_donate = (0,) if self.donate else ()
+
         self._ingest_fn = None
         if self.role == "decode":
             counts["migrate_ingest"] = 0
-            L = self.pool.max_len
 
             def ingest_body(cache, rows, dst, n):
-                # The cross-pool twin of ``prefix_copy_body``: write a
-                # migrated request's shipped KV rows (one slot's worth,
-                # slot axis sliced away — see ``export_kv_rows``) into
-                # rows [0, n) of slot ``dst``, every layer, K, V and
-                # int8 scales.  dst/n are traced VALUES — ONE
-                # fixed-shape program serves every migration, keeping
-                # the decode pool's program count at exactly two.
-                # Bitwise: the donor rows are what this pool's own
-                # prefill of the same tokens at the same positions
-                # would have written (prefill is replica-independent —
-                # the disagg-verify gate), so decode resumes the greedy
-                # stream unchanged.
+                # The source is a migrated request's shipped rows (one
+                # slot's worth — see ``export_kv_rows``).
                 counts["migrate_ingest"] += 1
-                row_mask = jnp.arange(L) < n          # [L]
+                return kv_cache.copy_rows(cache, rows, dst, n)
 
-                def put_len_axis(bank, row, axis):
-                    # ``axis`` is the BANK's length axis; the shipped
-                    # row lost the slot axis, so its length axis (and
-                    # the mask's) sits at ``axis - 1``.
-                    shape = [1] * (bank.ndim - 1)
-                    shape[axis - 1] = L
-                    m = row_mask.reshape(shape)
-                    merged = jnp.where(m, row, bank[dst])
-                    return bank.at[dst].set(merged)
-
-                k = [put_len_axis(b, r, 1)
-                     for b, r in zip(cache.k, rows["k"])]
-                v = [put_len_axis(b, r, 1)
-                     for b, r in zip(cache.v, rows["v"])]
-                if isinstance(cache, QuantKVCache):
-                    return QuantKVCache(
-                        k=k, v=v,
-                        k_scale=[put_len_axis(b, r, 2)
-                                 for b, r in zip(cache.k_scale,
-                                                 rows["k_scale"])],
-                        v_scale=[put_len_axis(b, r, 2)
-                                 for b, r in zip(cache.v_scale,
-                                                 rows["v_scale"])],
-                        length=cache.length,
-                    )
-                return KVCache(k=k, v=v, length=cache.length)
-
-            self._ingest_fn = jax.jit(
-                ingest_body, donate_argnums=(0,) if self.donate else ()
-            )
+            self._ingest_fn = jax.jit(ingest_body, donate_argnums=copy_donate)
 
         self._prefix_copy_fn = None
         if self._prefix_cache is not None:
             counts["prefix_copy"] = 0
-            L = self.pool.max_len
 
             def prefix_copy_body(cache, src, dst, n):
-                # Copy rows [0, n) of slot ``src`` into slot ``dst``
-                # for every layer (K, V, and int8 scales).  src/dst/n
-                # are traced VALUES — one fixed-shape program serves
-                # every reuse, preserving the static program count.
-                # Bitwise: the donor's rows are exactly what a cold
-                # prefill of the same tokens at the same positions
-                # writes, so a reused request's cache equals the cold
-                # one bit-for-bit (the fleet-verify gate).
+                # The source is the donor slot ``src`` of this pool.
                 counts["prefix_copy"] += 1
-                row_mask = jnp.arange(L) < n          # [L]
-
-                def copy_len_axis(bank, axis):
-                    # mask shaped to broadcast along the length axis
-                    shape = [1] * (bank.ndim - 1)
-                    shape[axis - 1] = L
-                    m = row_mask.reshape(shape)
-                    merged = jnp.where(m, bank[src], bank[dst])
-                    return bank.at[dst].set(merged)
-
-                k = [copy_len_axis(b, 1) for b in cache.k]
-                v = [copy_len_axis(b, 1) for b in cache.v]
-                if isinstance(cache, QuantKVCache):
-                    return QuantKVCache(
-                        k=k, v=v,
-                        k_scale=[copy_len_axis(b, 2)
-                                 for b in cache.k_scale],
-                        v_scale=[copy_len_axis(b, 2)
-                                 for b in cache.v_scale],
-                        length=cache.length,
-                    )
-                return KVCache(k=k, v=v, length=cache.length)
+                return kv_cache.copy_rows(cache, src, dst, n)
 
             self._prefix_copy_fn = jax.jit(
-                prefix_copy_body,
-                donate_argnums=(0,) if self.donate else (),
+                prefix_copy_body, donate_argnums=copy_donate
             )
 
     @property
@@ -641,21 +582,12 @@ class Engine:
         in a disaggregated fleet is certified by comparing these specs
         between the prefill and decode engines
         (``analysis.serving.certify_disagg``)."""
-        sds = jax.ShapeDtypeStruct
-        c = self.pool.cache
         if self.cfg.mla is not None:
             raise NotImplementedError(
                 "KV-row migration is written for K and V banks; a "
                 "latent-attention pool holds the KV latent"
             )
-        rows: Dict[str, Any] = {
-            "k": [sds(b.shape[1:], b.dtype) for b in c.k],
-            "v": [sds(b.shape[1:], b.dtype) for b in c.v],
-        }
-        if isinstance(c, QuantKVCache):
-            rows["k_scale"] = [sds(b.shape[1:], b.dtype) for b in c.k_scale]
-            rows["v_scale"] = [sds(b.shape[1:], b.dtype) for b in c.v_scale]
-        return rows
+        return kv_cache.slot_row_specs(self.pool.cache)
 
     def _token_buffer(self, kind: str) -> np.ndarray:
         return np.zeros(self._token_shapes[kind], np.int32)
@@ -1292,16 +1224,7 @@ class Engine:
             raise ValueError(
                 f"request {req.rid!r} holds no slot — nothing to export"
             )
-        slot = req.slot
-        c = self.pool.cache
-        rows: Dict[str, Any] = {
-            "k": [b[slot] for b in c.k],
-            "v": [b[slot] for b in c.v],
-        }
-        if isinstance(c, QuantKVCache):
-            rows["k_scale"] = [b[slot] for b in c.k_scale]
-            rows["v_scale"] = [b[slot] for b in c.v_scale]
-        return rows
+        return kv_cache.slot_rows(self.pool.cache, req.slot)
 
     def complete_migration(self, req: Request) -> None:
         """Donor-side epilogue: the decode replica has ingested the KV

@@ -22,7 +22,7 @@ and assign as plain numbers, ``snapshot()`` keeps every legacy key.
 
 Everything is host-side bookkeeping around the engine loop — no device
 work, no effect on the two compiled programs.  ``snapshot()`` returns a
-plain-dict view the tests and ``bench.py --decode-serving`` read; the
+plain-dict view the tests read; the
 ``clock`` is injectable so tests can drive deterministic time.
 """
 

@@ -6,8 +6,8 @@ discrete configs whose cost would otherwise be paid in full-size TPU
 compiles (minutes per infeasible config).  Everything that search needs
 is *statically knowable* on any host:
 
-* **FLOPs** from XLA's HLO cost analysis (``lower()`` only traces; the
-  same MFU math as ``benchmarks/common.analytic_flops``) — including the
+* **FLOPs** from XLA's HLO cost analysis (``lower()`` only traces) —
+  including the
   per-policy RECOMPUTE cost, because the lowered per-cell vjp contains
   the remat region's replay;
 * **residual/peak bytes** from ``jax.eval_shape`` over the cell's vjp
@@ -47,8 +47,8 @@ Pytree = Any
 GiB = 2 ** 30
 
 # HBM headroom a config needs beyond its modeled residents: program temp,
-# reserved, transient transfers (bench.py's measured ~2.4 GiB at the
-# amoebanet headline rung).
+# reserved, transient transfers (~2.4 GiB measured on an AmoebaNet-D
+# training step before PR 21; not re-measured).
 DEFAULT_OVERHEAD_BYTES = int(2.4 * GiB)
 
 # Params + gradients + two Adam moments, all at the param dtype — the
@@ -63,8 +63,7 @@ DEFAULT_PARAM_SCALE = 4.0
 # (ROADMAP A6).  The megastep
 # axis amortizes it as ``DISPATCH_OVERHEAD_FLOPS / K`` per optimizer
 # step; like OFFLOAD_RANK_TAX this is a documented RANKING device, not
-# a wall-clock promise — bench.py's --megastep rung validates the
-# direction on real hardware.
+# a wall-clock promise.
 DISPATCH_OVERHEAD_FLOPS = 2.0e11
 
 # Lane-time discount the slot-buffer schedules (1f1b/zb/interleaved)
@@ -98,8 +97,7 @@ def tree_bytes(tree: Pytree) -> int:
 
 def hlo_flops(fn: Callable, *args: Pytree) -> Optional[float]:
     """HLO-cost-analysis FLOPs of ``fn(*args)`` — abstract lowering only,
-    no compile, no execution (``benchmarks/common.analytic_flops``
-    convention, host-CPU client fallback included)."""
+    no compile, no execution (host-CPU client fallback included)."""
     specs = _avalify(args)
     for kwargs in ({}, {"backend": "cpu"}):
         try:
@@ -138,7 +136,7 @@ def xla_memory_analysis(fn: Callable, *args: Pytree) -> Optional[Any]:
 
 
 # --------------------------------------------------------------------- #
-# MPMD (GPipe) per-stage residual probes — bench.py's rung predictor     #
+# MPMD (GPipe) per-stage residual probes                                #
 # --------------------------------------------------------------------- #
 
 
@@ -153,8 +151,8 @@ def mpmd_stage_memory_profile(
     schedules); ``input_bytes[j]`` is its input activation (what a
     CHECKPOINTED cell saves for recompute-ahead).  The schedule verifier's
     memory certification weights the event graph's live intervals with
-    these numbers; :func:`mpmd_stage_residual_bytes` is the max-residual
-    reduction ``bench.py``'s rung predictor uses."""
+    these numbers; :func:`mpmd_stage_residual_bytes` is their
+    max-residual reduction."""
     try:
         from torchgpipe_tpu.layers import sequential_init
 
@@ -332,9 +330,8 @@ def _spmd_plain_step(pipe: Any, x_spec: Pytree, tgt_spec: Pytree) -> Tuple[
     """The un-pipelined fwd+loss+bwd with the block loop UNROLLED (one
     block apply per stage, no scan) — the MFU numerator, costable by
     XLA's HLO cost analysis, whose while-loop handling would otherwise
-    count a scanned body once (same convention as
-    benchmarks/common.analytic_flops: recompute counts against
-    utilization, never inflates it)."""
+    count a scanned body once (recompute counts against utilization,
+    never inflates it)."""
     try:
         params_spec = jax.eval_shape(
             lambda r: pipe._init_host(r, x_spec), jax.random.PRNGKey(0)
@@ -502,8 +499,7 @@ def megastep_options(
 ) -> List[int]:
     """Megastep K candidates — delegates to the planner's canonical
     space (:func:`torchgpipe_tpu.analysis.planner.megastep_options`),
-    so the sweep, the lint rules and ``bench.py --megastep``'s ladder
-    all share ONE definition."""
+    so the sweep and the lint rules share ONE definition."""
     from torchgpipe_tpu.analysis.planner import megastep_options as opts
 
     return opts(requested, steps)
@@ -792,7 +788,7 @@ def _tune_spmd(
 
 
 # --------------------------------------------------------------------- #
-# MPMD scoring (bench.py's hardware-rung picker)                         #
+# MPMD scoring                                                          #
 # --------------------------------------------------------------------- #
 
 _MODE_RECOMPUTE = {
@@ -925,7 +921,7 @@ def resolve_policy(label: Optional[str]) -> Any:
 def apply_candidate(pipe: Any, cand: Candidate) -> Any:
     """Rebuild an :class:`~torchgpipe_tpu.spmd.SpmdGPipe` with a swept
     candidate's (checkpoint, policy, chunks, CE chunk) applied — what
-    ``benchmarks/llama_speed.py --autotune`` runs after the sweep."""
+    a caller runs after the sweep."""
     loss_fn = pipe.loss_fn
     meta = getattr(loss_fn, "meta", None)
     if (
@@ -976,12 +972,12 @@ def serving_cache_bytes(
     """Bytes of a ``(num_slots, max_len)`` serving KV-cache pool — the
     same ``eval_shape``-only accounting the training-side probes use (no
     allocation, no compile): the pool is laid out by
-    ``models.generation.init_cache`` / ``init_quant_cache``, so this IS
+    ``models.kv_cache.init_cache`` / ``init_quant_cache``, so this IS
     the HBM the pool will pin, not an estimate.  ``init_cache`` lays a
     latent-attention model's pool out as its latent rows (``cfg.mla``:
     ``(kv_lora_rank + qk_rope_head_dim) * itemsize`` bytes a row a
     layer, not K and V of ``kv_heads * head_dim``)."""
-    from torchgpipe_tpu.models.generation import init_cache, init_quant_cache
+    from torchgpipe_tpu.models.kv_cache import init_cache, init_quant_cache
 
     if kv_quant:
         spec = jax.eval_shape(

@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-One rule for every entry point (``chip_smoke.py``, ``bench.py``, the
-``benchmarks/`` drivers, the test suite): where the caller exported
+One rule for every entry point (``chip_smoke.py``, ``chipbench/run.py``,
+the test suite): where the caller exported
 ``JAX_COMPILATION_CACHE_DIR``, jax reads the variable itself and nothing
 is set in code; otherwise the cache is a FIXED directory inside the
 checkout.  The path is part of the cache key, so a temporary, pid- or

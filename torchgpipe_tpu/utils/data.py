@@ -310,8 +310,7 @@ def padded_batches(
 ) -> Iterator[Tuple[Pytree, Pytree]]:
     """The PADDED baseline over the same documents: one document per
     ``[block_len]`` row, tail padded — the layout whose pad FLOPs
-    :func:`pack_documents` exists to reclaim (the ``bench.py --packing``
-    rung runs both over one corpus).  ``x`` is a plain ``[B, S]`` token
+    :func:`pack_documents` exists to reclaim.  ``x`` is a plain ``[B, S]`` token
     array (no segment ids — the un-packed contract); ``y`` carries the
     same labels/weights schema, so ONE loss function serves both paths.
     """
