@@ -211,3 +211,44 @@ def test_decode_slots_compiles_for_v5e(compact, chip, monkeypatch):
     ).compile()
     assert "flash_decode" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < BANK_BYTES
+
+
+def test_mpmd_stored_backward_compiles_for_v5e(chip, monkeypatch):
+    """The MPMD engine's backward over STORED residuals (``except_last``
+    keeps the last micro-batch's vjp; chip_smoke.py phase b, stage 1:
+    one Mistral-7B block and the head at 4096): its dK/dV kernel in
+    512-blocks missed the compiler's default 16 MiB of scoped VMEM by
+    0.26 MiB in this program alone (chip run, PR 31), which is why the
+    training kernels state their own limit."""
+    import chip_smoke
+    from torchgpipe_tpu import GPipe
+    from torchgpipe_tpu.layers import sequential_init
+    from torchgpipe_tpu.models.transformer import llama
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [chip])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    where = SingleDeviceSharding(chip)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where),
+            tree,
+        )
+
+    layers = llama(chip_smoke.mistral_config(2))
+    params, state, _ = jax.eval_shape(
+        lambda key: sequential_init(
+            layers, key, jax.ShapeDtypeStruct((1, SEQ), jnp.int32)
+        ),
+        jax.random.PRNGKey(0),
+    )
+    model = GPipe(layers, [2, 2], devices=[chip], chunks=4,
+                  checkpoint="except_last")
+    stage = model._pipeline.stages[1]
+    x = jax.ShapeDtypeStruct((1, SEQ, H * D), BF16)
+    y, ext, _, pull = jax.eval_shape(
+        lambda p, s, x: stage.fwd_vjp(p, s, x, {}, None, 1.0),
+        list(params[2:]), list(state[2:]), x,
+    )
+    compiled = stage.bwd.lower(on_chip(pull), (on_chip(y), on_chip(ext)))
+    assert "flash_bwd_dkv" in compiled.compile().as_text()

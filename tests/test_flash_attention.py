@@ -127,6 +127,38 @@ def test_auto_picker_padded_head_seq_gate():
     assert has_pallas(128, 256)       # exact tile: any supported length
 
 
+@pytest.mark.parametrize("s,block", [(256, 256), (384, 128), (512, 512)])
+def test_default_blocks_follow_the_length(s, block):
+    """``block_q`` / ``block_k`` left out are the largest of 512 / 256 /
+    128 dividing the sequence (a grid step's fixed cost is what the
+    kernels pay most for: PERF.md, PR 31); the result is the dense
+    oracle's whatever the block."""
+    from torchgpipe_tpu.ops.flash_attention import _largest_block
+
+    assert _largest_block(s) == block
+    assert _largest_block(100) is None
+    b, h, g, d = 1, 2, 1, 8
+    ks = jax.random.split(jax.random.PRNGKey(29), 3)
+    q = _rand(ks[0], (b, s, h, d))
+    k = _rand(ks[1], (b, s, g, d))
+    v = _rand(ks[2], (b, s, g, d))
+
+    def fn(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, window=200, interpret=True
+        )
+
+    np.testing.assert_allclose(
+        np.asarray(fn(q, k, v)),
+        np.asarray(full_attention(q, k, v, causal=True, window=200)),
+        rtol=2e-5, atol=2e-5,
+    )
+    # The forward's grid: (batch * heads, sequence / block).
+    assert f"grid=({b * h}, {s // block})" in str(
+        jax.make_jaxpr(fn)(q, k, v)
+    )
+
+
 def test_bf16_inputs():
     b, s, h, d = 1, 32, 2, 8
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
@@ -358,6 +390,127 @@ def test_sliding_window_validation():
     from torchgpipe_tpu.parallel.ring_attention import attention
     with pytest.raises(ValueError, match="requires causal"):
         attention(q, k, v, causal=False, window=8)
+
+
+# --------------------------------------------------------------------- #
+# bf16 tiles go into the MXU as stored                                  #
+# --------------------------------------------------------------------- #
+
+# Unit roundoff of bfloat16 (8 significant bits).  On bf16 inputs the
+# kernels and the dense path do the same arithmetic in another order:
+# bf16 x bf16 products summed in f32 (exact up to f32 rounding), an f32
+# softmax, the probabilities (in the backward: dS) rounded to bf16 ONCE
+# before their product, the result rounded to bf16 ONCE.  Four roundings
+# of at most ``u`` between the two paths: elementwise they differ by at
+# most 4u of the array's largest element, and by half of that in norm
+# (independent roundings add in quadrature; measured 1.9u and 0.9u).
+_BF16_U = 2.0 ** -8
+
+
+def _close_in_bf16(got, ref):
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    assert np.abs(got - ref).max() <= 4 * _BF16_U * np.abs(ref).max()
+    assert np.linalg.norm(got - ref) <= 2 * _BF16_U * np.linalg.norm(ref)
+
+
+@pytest.mark.parametrize("blocks", [(16, 32), (32, 16)],
+                         ids=["bq16-bk32", "bq32-bk16"])
+@pytest.mark.parametrize("g", [4, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("window", [None, 24], ids=["causal", "window"])
+@pytest.mark.parametrize("streaming", [False, True],
+                         ids=["resident", "streaming"])
+def test_bf16_matches_dense_on_the_same_inputs(streaming, window, g, blocks):
+    """bf16 q / k / v / cotangent through both kernel families (tiles
+    into the MXU as stored, p and dS rounded to the tile's type):
+    forward and all three gradients against ``full_attention`` on the
+    SAME bf16 arrays, at a tolerance counted in bf16 roundings."""
+    b, s, h, d = 1, 64, 4, 16
+    ks = jax.random.split(jax.random.PRNGKey(23), 4)
+    q, k, v, cot = (
+        _rand(key, (b, s, heads, d)).astype(jnp.bfloat16)
+        for key, heads in zip(ks, (h, g, g, h))
+    )
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, window=window, block_q=blocks[0],
+            block_k=blocks[1], interpret=True, streaming=streaming,
+        )
+
+    def dense(q, k, v):
+        return full_attention(q, k, v, causal=True, window=window)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v).astype(jnp.float32) * cot.astype(jnp.float32)
+        )
+
+    out = flash(q, k, v)
+    assert out.dtype == jnp.bfloat16
+    _close_in_bf16(out, dense(q, k, v))
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(gf, gr):
+        assert a.dtype == jnp.bfloat16
+        _close_in_bf16(a, b_)
+
+
+def _kernel_dots(jaxpr):
+    """``(lhs dtype, rhs dtype, result dtype)`` of every ``dot_general``
+    inside the ``pallas_call`` kernels of a jaxpr, by kernel name."""
+    from torchgpipe_tpu.analysis.jaxpr import subjaxprs
+
+    found = {}
+
+    def walk(jp, kernel):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "dot_general" and kernel is not None:
+                found.setdefault(kernel, []).append((
+                    *(x.aval.dtype for x in eqn.invars),
+                    eqn.outvars[0].aval.dtype,
+                ))
+            inner = kernel
+            if eqn.primitive.name == "pallas_call":
+                inner = eqn.params["name"]
+            for sub in subjaxprs(eqn):
+                walk(sub, inner)
+
+    walk(jaxpr.jaxpr, None)
+    return found
+
+
+@pytest.mark.parametrize("streaming", [False, True],
+                         ids=["resident", "streaming"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_kernel_products_take_the_tiles_as_stored(dtype, streaming):
+    """The cast must not come back unnoticed: every ``dot_general`` of
+    the three kernels of a bf16 call has bf16 operands and an f32
+    result (one pass of the MXU; a product of f32 tiles makes six), and
+    a float32 call still multiplies float32 tiles."""
+    b, s, h, g, d = 1, 64, 4, 2, 16
+    q = jnp.zeros((b, s, h, d), dtype)
+    k = jnp.zeros((b, s, g, d), dtype)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, window=24, block_q=16, block_k=32,
+            interpret=True, streaming=streaming,
+        ).astype(jnp.float32))
+
+    dots = _kernel_dots(
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, k)
+    )
+    tail = "_stream" if streaming else ""
+    assert {name: len(v) for name, v in dots.items()} == {
+        "flash_fwd" + tail: 2,       # Q K^T, P V
+        "flash_bwd_dq" + tail: 3,    # Q K^T, dO V^T, dS K
+        "flash_bwd_dkv" + tail: 4,   # Q K^T, P^T dO, dO V^T, dS^T Q
+    }
+    want = (jnp.dtype(dtype), jnp.dtype(dtype), jnp.dtype(jnp.float32))
+    for name, products in dots.items():
+        assert all(p == want for p in products), (name, products)
 
 
 # --------------------------------------------------------------------- #

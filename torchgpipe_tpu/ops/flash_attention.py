@@ -3,6 +3,13 @@
 The framework's hot op: fused online-softmax attention that never
 materializes the ``[s, s]`` score matrix in HBM — scores live in VMEM one
 ``[block_q, block_k]`` tile at a time, with f32 accumulation on the MXU.
+The MXU is fed the tiles of q, k, v and dO as they are stored: a bf16
+tile goes into its product as bf16 (one pass), scores, softmax
+statistics, ``delta`` and every accumulator stay f32, and the f32
+intermediates ``p`` and ``dS`` are rounded to the tile's type before
+their product, as the dense path rounds ``p.astype(v.dtype)``; float32
+inputs keep products of float32 tiles (:func:`_dot_tile`, the one place
+that decides).
 Backward follows the standard flash decomposition (Dao, FlashAttention-2;
 public algorithm, implemented here from the math against
 /opt/skills/guides/pallas_guide.md):
@@ -42,6 +49,13 @@ _NEG = -1e30
 # only one [block, d] tile of K/V in VMEM at a time.
 _STREAM_BYTES = 4 * 1024 * 1024
 
+# Scoped VMEM for the six training kernels (v5e has 128 MiB).  The
+# compiler's default of 16 MiB holds 512-blocks at 4096 in the SPMD step
+# and misses them by 0.26 MiB in the MPMD engine's stored-residual
+# backward program (dK/dV: whole q / dO rows, the lane-padded lse / delta
+# columns and five f32 score planes; described-chip compile, PR 31).
+_TRAIN_VMEM = pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024)
+
 
 def _validate_window(causal: bool, window: Optional[int]) -> None:
     """Shared entry-point validation for sliding-window attention."""
@@ -55,10 +69,84 @@ def _validate_window(causal: bool, window: Optional[int]) -> None:
         raise ValueError("window must be >= 1")
 
 
+def _largest_block(s: int) -> Optional[int]:
+    """Largest standard block size dividing sequence length ``s``."""
+    return next((c for c in (512, 256, 128) if s % c == 0), None)
+
+
 def _kv_index(i: jax.Array, h: int, g: int) -> jax.Array:
     """Row in the [b*g, s, d] K/V array for query row ``i`` of [b*h, s, d]."""
     r = h // g
     return (i // h) * g + (i % h) // r
+
+
+# --------------------------------------------------------------------- #
+# products: what a grid step hands the MXU                              #
+# --------------------------------------------------------------------- #
+
+# dot_general dimension numbers of the kernels' 2-D products.
+_NT = (((1,), (1,)), ((), ()))  # x . y^T   (Q K^T, dO V^T)
+_NN = (((1,), (0,)), ((), ()))  # x . y     (P V, dS K)
+_TN = (((0,), (0,)), ((), ()))  # x^T . y   (P^T dO, dS^T Q)
+
+
+def _dot_rows(x: jnp.ndarray, y: jnp.ndarray, dims: Any) -> jnp.ndarray:
+    """``x . y`` into f32 with no operand rounded.  Against a bf16 ``y``
+    (the cache as stored) an f32 ``x`` goes through the MXU as three
+    bf16 terms whose sum it is, stacked as rows of ONE bf16 product:
+    every partial product is exact and the accumulator is f32.  (The
+    other exact form, a product of f32 tiles at ``Precision.HIGHEST``,
+    makes six passes over the block; at the default precision Mosaic
+    rounds f32 operands to bf16 and makes one: chip runs, PR 31.)  A
+    bf16 ``x`` is one term; any other ``y`` takes the f32 product."""
+    if y.dtype != jnp.bfloat16:
+        return lax.dot_general(
+            x.astype(jnp.float32), y.astype(jnp.float32), dims,
+            preferred_element_type=jnp.float32,
+        )
+    if x.dtype == jnp.bfloat16:
+        return lax.dot_general(
+            x, y, dims, preferred_element_type=jnp.float32
+        )
+    x = x.astype(jnp.float32)
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    n = x.shape[0]
+    out = lax.dot_general(
+        jnp.concatenate([hi, mid, lo], axis=0), y, dims,
+        preferred_element_type=jnp.float32,
+    )
+    return out[:n] + out[n:2 * n] + out[2 * n:]
+
+
+def _dot_tile(x: jnp.ndarray, y: jnp.ndarray, dims: Any) -> jnp.ndarray:
+    """``x . y`` into f32 as the six training kernels take it.  ``y`` is
+    a tile of q, k, v or dO AS STORED; ``x`` is another such tile or an
+    f32 intermediate (``p``, ``dS``).  Against a bf16 ``y`` the product
+    is one bf16 pass of the MXU with an f32 accumulator: a stored bf16
+    ``x`` goes in as it is (bf16 x bf16 is exact in f32), an f32 ``x``
+    is rounded to bf16 first — the model's own arithmetic,
+    ``p.astype(v.dtype)`` in the dense path
+    (``ring_attention.full_attention``) and in the published
+    implementations — and one that contracts its rows (``P^T dO``,
+    ``dS^T Q``) is transposed while it is still f32: a bf16 transposed
+    operand read 5 % slower in the dK/dV kernel (chip run, PR 31).  Any
+    other ``y`` (a float32 caller) keeps the product of f32 tiles it
+    had.  The operand's dtype decides, nothing else.
+
+    A product of f32 tiles at the default precision is ALSO one bf16
+    pass, the operands rounded on the way in (against an f32
+    ``highest`` reference it reads this form's error, 2.5e-3 of a
+    gradient's norm; ``Precision.HIGHEST`` costs four times the
+    kernel): stating the type keeps the arithmetic under any default,
+    it does not buy MXU time (chip runs, PR 31)."""
+    if y.dtype == jnp.bfloat16:
+        if dims == _TN:
+            x, dims = x.T, _NN
+        x = x.astype(jnp.bfloat16)
+    return _dot_rows(x, y, dims)
 
 
 # --------------------------------------------------------------------- #
@@ -81,7 +169,7 @@ def _fwd_kernel(
     window: Optional[int],
 ) -> None:
     j = pl.program_id(1)
-    qb = q_ref[0].astype(jnp.float32) * sm_scale  # [Bq, d]
+    qb = q_ref[0]  # [Bq, d], as stored
     nk = seq_k // block_k
     jk0 = 0
     if causal:
@@ -93,22 +181,17 @@ def _fwd_kernel(
 
     def body(jb, carry):
         m, l, acc = carry
-        kb = k_ref[0, pl.ds(jb * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(jb * block_k, block_k), :].astype(jnp.float32)
-        s = lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [Bq, Bk]
+        kb = k_ref[0, pl.ds(jb * block_k, block_k), :]
+        vb = v_ref[0, pl.ds(jb * block_k, block_k), :]
+        # The scale goes on the f32 scores: on a bf16 q it would round.
+        s = _dot_tile(qb, kb, _NT) * sm_scale  # [Bq, Bk]
         if causal:
             s = _mask_causal(s, j, jb, block_q, block_k, window)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_new = acc * corr + lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_new = acc * corr + _dot_tile(p, vb, _NN)
         return m_new, l_new, acc_new
 
     m0 = jnp.full((block_q, 1), _NEG, jnp.float32)
@@ -158,6 +241,7 @@ def _flash_fwd_call(
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
         ),
+        compiler_params=_TRAIN_VMEM,
         interpret=interpret,
     )(q, k, v)
     return o, lse
@@ -239,8 +323,7 @@ def _last_valid_q(
 # VMEM — Pallas's pipelining skips the HBM copy when the block index is
 # unchanged between iterations, and ``pl.when`` skips the compute.  Net:
 # masked cells cost one grid bump, no bandwidth, no FLOPs (the reason
-# streaming used to lose to dense at moderate causal lengths —
-# BENCH_NOTES round-2 table, 87.1 vs 64.8 ms @4k).
+# streaming used to lose to dense at moderate causal lengths).
 
 
 def _clamped_kv_block(
@@ -332,13 +415,7 @@ def _fwd_stream_kernel(
 
     @pl.when(run)
     def _body():
-        qb = q_ref[0].astype(jnp.float32) * sm_scale
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        s = _dot_tile(q_ref[0], k_ref[0], _NT) * sm_scale
         if causal:
             s = _mask_causal(s, j, jk, block_q, block_k, window)
         m_prev = m_sc[...]
@@ -346,10 +423,7 @@ def _fwd_stream_kernel(
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
         l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_sc[...] = acc_sc[...] * corr + lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_sc[...] = acc_sc[...] * corr + _dot_tile(p, v_ref[0], _NN)
         m_sc[...] = m_new
 
     @pl.when(jk == nk - 1)
@@ -405,6 +479,7 @@ def _flash_fwd_call_stream(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        compiler_params=_TRAIN_VMEM,
         interpret=interpret,
     )(q, k, v)
     return o, lse
@@ -441,26 +516,14 @@ def _dq_stream_kernel(
 
     @pl.when(run)
     def _body():
-        qb = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        dob = do_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
+        kb = k_ref[0]
+        s = _dot_tile(q_ref[0], kb, _NT) * sm_scale
         if causal:
             s = _mask_causal(s, j, jk, block_q, block_k, window)
         p = jnp.exp(s - lse_ref[0])
-        dp = lax.dot_general(
-            dob, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dp = _dot_tile(do_ref[0], v_ref[0], _NT)
         ds = p * (dp - delta_ref[0])
-        dq_sc[...] = dq_sc[...] + lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dq_sc[...] = dq_sc[...] + _dot_tile(ds, kb, _NN)
 
     @pl.when(jk == nk - 1)
     def _finish():
@@ -501,30 +564,16 @@ def _dkv_stream_kernel(
 
     @pl.when(run)
     def _body():
-        qb = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        dob = do_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
+        qb = q_ref[0]
+        dob = do_ref[0]
+        s = _dot_tile(qb, k_ref[0], _NT) * sm_scale
         if causal:
             s = _mask_causal(s, jq, jk, block_q, block_k, window)
         p = jnp.exp(s - lse_ref[0])
-        dv_sc[...] = dv_sc[...] + lax.dot_general(
-            p, dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = lax.dot_general(
-            dob, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dv_sc[...] = dv_sc[...] + _dot_tile(p, dob, _TN)
+        dp = _dot_tile(dob, v_ref[0], _NT)
         ds = p * (dp - delta_ref[0])
-        dk_sc[...] = dk_sc[...] + lax.dot_general(
-            ds, qb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dk_sc[...] = dk_sc[...] + _dot_tile(ds, qb, _TN)
 
     @pl.when(jq == nq - 1)
     def _finish():
@@ -554,8 +603,8 @@ def _dq_kernel(
     window: Optional[int],
 ) -> None:
     j = pl.program_id(1)
-    qb = q_ref[0].astype(jnp.float32)
-    dob = do_ref[0].astype(jnp.float32)
+    qb = q_ref[0]
+    dob = do_ref[0]
     lse_b = lse_ref[0]      # [Bq, 1]
     delta_b = delta_ref[0]  # [Bq, 1]
     nk = seq_k // block_k
@@ -566,24 +615,15 @@ def _dq_kernel(
             jk0 = _first_valid_kv(j, block_q, block_k, window)
 
     def body(jb, dq):
-        kb = k_ref[0, pl.ds(jb * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(jb * block_k, block_k), :].astype(jnp.float32)
-        s = lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
+        kb = k_ref[0, pl.ds(jb * block_k, block_k), :]
+        vb = v_ref[0, pl.ds(jb * block_k, block_k), :]
+        s = _dot_tile(qb, kb, _NT) * sm_scale
         if causal:
             s = _mask_causal(s, j, jb, block_q, block_k, window)
         p = jnp.exp(s - lse_b)  # [Bq, Bk]
-        dp = lax.dot_general(
-            dob, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dp = _dot_tile(dob, vb, _NT)
         ds = p * (dp - delta_b)
-        return dq + lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        return dq + _dot_tile(ds, kb, _NN)
 
     dq = lax.fori_loop(
         jk0, nk, body, jnp.zeros((block_q, q_ref.shape[-1]), jnp.float32)
@@ -609,8 +649,8 @@ def _dkv_kernel(
     window: Optional[int],
 ) -> None:
     jk = pl.program_id(1)
-    kb = k_ref[0].astype(jnp.float32)  # [Bk, d]
-    vb = v_ref[0].astype(jnp.float32)
+    kb = k_ref[0]  # [Bk, d]
+    vb = v_ref[0]
     nq = seq_q // block_q
     jq0 = lax.div(jk * block_k, block_q) if causal else 0
     jq_hi = (
@@ -620,30 +660,18 @@ def _dkv_kernel(
 
     def body(jq, carry):
         dk, dv = carry
-        qb = q_ref[0, pl.ds(jq * block_q, block_q), :].astype(jnp.float32)
-        dob = do_ref[0, pl.ds(jq * block_q, block_q), :].astype(jnp.float32)
+        qb = q_ref[0, pl.ds(jq * block_q, block_q), :]
+        dob = do_ref[0, pl.ds(jq * block_q, block_q), :]
         lse_b = lse_ref[0, pl.ds(jq * block_q, block_q), :]      # [Bq, 1]
         delta_b = delta_ref[0, pl.ds(jq * block_q, block_q), :]  # [Bq, 1]
-        s = lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
+        s = _dot_tile(qb, kb, _NT) * sm_scale
         if causal:
             s = _mask_causal(s, jq, jk, block_q, block_k, window)
         p = jnp.exp(s - lse_b)  # [Bq, Bk]
-        dv_new = dv + lax.dot_general(
-            p, dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = lax.dot_general(
-            dob, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dv_new = dv + _dot_tile(p, dob, _TN)
+        dp = _dot_tile(dob, vb, _NT)
         ds = p * (dp - delta_b)
-        dk_new = dk + lax.dot_general(
-            ds, qb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dk_new = dk + _dot_tile(ds, qb, _TN)
         return dk_new, dv_new
 
     d = k_ref.shape[-1]
@@ -780,6 +808,7 @@ def _flash_bwd_stream(
         in_specs=[row3, kv3, kv3, row3, row2, row2],
         out_specs=row3,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_TRAIN_VMEM,
         interpret=interpret,
     )(*kernel_args)
 
@@ -815,6 +844,7 @@ def _flash_bwd_stream(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=_TRAIN_VMEM,
         interpret=interpret,
     )(*kernel_args)
 
@@ -864,6 +894,7 @@ def _flash_bwd_resident(
         in_specs=[row_spec3, kv_spec, kv_spec, row_spec3, row_spec2,
                   row_spec2],
         out_specs=row_spec3,
+        compiler_params=_TRAIN_VMEM,
         interpret=interpret,
     )(*kernel_args)
 
@@ -888,6 +919,7 @@ def _flash_bwd_resident(
         in_specs=[full_row3, kvb_spec, kvb_spec, full_row3, full_row2,
                   full_row2],
         out_specs=(out_kvb, out_kvb),
+        compiler_params=_TRAIN_VMEM,
         interpret=interpret,
     )(*kernel_args)
 
@@ -911,8 +943,9 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # by the auto-picker: the MXU pads the lane dim to 128 either way, but the
 # kernel's fixed overheads only amortize at the lengths where flash was
 # measured faster (resident kernels: 14.5 vs 18.9 ms at seq 2048, 43.8 vs
-# 64.7 ms at 4096 fwd+bwd on v5e — BENCH_NOTES.md flash table).  Exact
-# 128-multiple heads keep using the kernel at any supported length.
+# 64.7 ms at 4096 fwd+bwd on v5e, while the kernels still multiplied f32
+# tiles; not measured since: ROADMAP A4).  Exact 128-multiple heads keep
+# using the kernel at any supported length.
 PADDED_HEAD_MIN_SEQ = 2048
 
 
@@ -943,8 +976,8 @@ def flash_attention(
     *,
     causal: bool = True,
     sm_scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: bool = False,
     streaming: Optional[bool] = None,
     window: Optional[int] = None,
@@ -955,6 +988,13 @@ def flash_attention(
     ``d < 128`` (the head dim is zero-padded to one 128-lane tile — exact,
     see :func:`supports`) and sequence lengths divisible by the block
     sizes; ``interpret=True`` runs the kernels on any backend for testing.
+
+    ``block_q`` / ``block_k`` left out are the largest of 512 / 256 / 128
+    that divides the sequence: a grid step costs about 0.3 us before it
+    touches an element (one dependent chain of product, row maximum,
+    exp, product), so at 4096 a forward call takes 6.4 ms in 128-blocks,
+    2.4 in 256 and 1.4 in 512, whatever the MXU is fed; 1024 does not
+    fit VMEM (chip runs and described-chip compiles, PERF.md, PR 31).
 
     ``window`` (requires ``causal``) is Mistral-style sliding-window
     attention: attend iff ``0 <= qpos - kpos < window``.  Every kernel
@@ -980,6 +1020,11 @@ def flash_attention(
     g = k.shape[2]
     sm_scale = d ** -0.5 if sm_scale is None else sm_scale
     _validate_window(causal, window)
+    # A length no block divides keeps 128 and meets the check below.
+    if block_q is None:
+        block_q = _largest_block(s) or 128
+    if block_k is None:
+        block_k = _largest_block(k.shape[1]) or 128
     if s % block_q or k.shape[1] % block_k:
         # The grids are ``s // block``: a remainder would leave the tail
         # rows unwritten (garbage out, no error from the kernel).
@@ -1009,7 +1054,7 @@ def flash_attention(
         d = d + d_pad
     if streaming is None:
         # K+V rows of one head resident in the non-streaming kernels, in
-        # the input dtype (the per-block f32 cast is transient).
+        # the input dtype (the tiles go into the MXU as they are stored).
         streaming = (
             2 * k.shape[1] * d * jnp.dtype(k.dtype).itemsize > _STREAM_BYTES
         )
@@ -1027,11 +1072,6 @@ def flash_attention(
 # --------------------------------------------------------------------- #
 # decode: few-query attention against a KV cache                        #
 # --------------------------------------------------------------------- #
-
-
-def _decode_block_k(s: int) -> Optional[int]:
-    """Largest standard block size dividing cache length ``s``."""
-    return next((c for c in (512, 256, 128) if s % c == 0), None)
 
 
 # The f32 score plane of one product, ``[query rows, block rows x heads]``,
@@ -1152,35 +1192,6 @@ def _decode_steps(
                             last[None, :])),
         ends.astype(jnp.int32),
     )
-
-
-def _dot_rows(x: jnp.ndarray, y: jnp.ndarray, dims: Any) -> jnp.ndarray:
-    """``x . y`` into f32 with no operand rounded.  Against a bf16 ``y``
-    (the cache as stored) an f32 ``x`` goes through the MXU as three
-    bf16 terms whose sum it is, stacked as rows of ONE bf16 product:
-    every partial product is exact and the accumulator is f32, where a
-    product of f32 tiles would make six passes over the block.  A bf16
-    ``x`` is one term; any other ``y`` takes the f32 product."""
-    if y.dtype != jnp.bfloat16:
-        return lax.dot_general(
-            x.astype(jnp.float32), y.astype(jnp.float32), dims,
-            preferred_element_type=jnp.float32,
-        )
-    if x.dtype == jnp.bfloat16:
-        return lax.dot_general(
-            x, y, dims, preferred_element_type=jnp.float32
-        )
-    x = x.astype(jnp.float32)
-    hi = x.astype(jnp.bfloat16)
-    rest = x - hi.astype(jnp.float32)
-    mid = rest.astype(jnp.bfloat16)
-    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-    n = x.shape[0]
-    out = lax.dot_general(
-        jnp.concatenate([hi, mid, lo], axis=0), y, dims,
-        preferred_element_type=jnp.float32,
-    )
-    return out[:n] + out[n:2 * n] + out[2 * n:]
 
 
 def _decode_kernel(
@@ -1473,7 +1484,7 @@ def flash_decode_attention(
         # A block of the caller's: every head through one product.
         tiling = (block_k, nkv)
     elif quant:
-        one_head = _decode_block_k(s)
+        one_head = _largest_block(s)
         tiling = None if one_head is None else (one_head, 1)
     else:
         tiling = _decode_tiling(g, nh, nkv, ck.dtype.itemsize, s)
