@@ -252,3 +252,50 @@ def test_mpmd_stored_backward_compiles_for_v5e(chip, monkeypatch):
     )
     compiled = stage.bwd.lower(on_chip(pull), (on_chip(y), on_chip(ext)))
     assert "flash_bwd_dkv" in compiled.compile().as_text()
+
+
+def test_mixed_attention_expert_train_step_compiles_for_v5e(chip, monkeypatch):
+    """The ``mellum2.train-4x8192`` cell's whole train step (``llama_moe_spmd``
+    through ``SpmdGPipe.make_train_step``, AdamW, 4 micro-batches of one
+    8,192-token row, published widths, depth 8, 16 of 64 experts held):
+    window and full flash calls side by side, the grouped expert products
+    and their transposes, under the chip's 15.75 GiB.  Without the expert
+    sum's own recomputation (``moe.py``) this program needs 19.4 GiB."""
+    import optax
+
+    from chipbench import weights_mellum2
+    from chipbench.builders import spmd_train_moe
+    from chipbench.common import HERE, load_json
+    from torchgpipe_tpu.models.moe import llama_moe_spmd
+    from torchgpipe_tpu.models.transformer import cross_entropy
+    from torchgpipe_tpu.spmd import SpmdGPipe, make_mesh
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [chip])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    where = SingleDeviceSharding(chip)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where),
+            tree,
+        )
+
+    m = load_json(HERE / "configs" / "mellum2.json")
+    tr = m["train"]
+    cfg, moe = spmd_train_moe.program_config(m)
+    block, pre, post = llama_moe_spmd(cfg, moe, 1)
+    pipe = SpmdGPipe(block, 1, make_mesh(1, devices=[chip]), chunks=tr["chunks"],
+                     loss_fn=cross_entropy, pre=pre, post=post)
+    params = jax.eval_shape(
+        lambda: weights_mellum2.stack_for_stages(weights_mellum2.make_flat(m, 0), 1)
+    )
+    opt = optax.adamw(**tr["optimizer"])
+    step = pipe.make_train_step(opt)
+    tokens = jax.ShapeDtypeStruct((tr["batch"], tr["seq"]), jnp.int32, sharding=where)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(jax.eval_shape(opt.init, params)), tokens, tokens,
+    ).compile()
+    text = compiled.as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "ragged-dot"):
+        assert kernel in text
+    assert compiled.memory_analysis().peak_memory_in_bytes < 15.75 * 2 ** 30

@@ -483,11 +483,13 @@ def test_qwen2_trains_through_pipeline(cpu_devices):
     assert np.abs(np.asarray(grads["blocks"][0]["bq"])).sum() > 0
 
 
-def test_bias_mismatch_and_mixed_window_rejected():
+def test_bias_mismatch_rejected_and_mixed_window_imported():
     """A biased checkpoint through the plain Llama importer raises with a
     pointer at from_hf_qwen2; a Qwen2 config mixing windowed and full
-    layers is rejected rather than silently diverging."""
+    layers imports into the per-layer attention description, and the
+    logits match HF at a sequence longer than the window."""
     from torchgpipe_tpu.models.hf_interop import from_hf_qwen2, params_from_hf
+    from torchgpipe_tpu.models.transformer import AttnLayer
 
     cfg_hf = transformers.Qwen2Config(
         vocab_size=64, hidden_size=32, intermediate_size=128,
@@ -502,17 +504,31 @@ def test_bias_mismatch_and_mixed_window_rejected():
         vocab_size=64, hidden_size=32, intermediate_size=128,
         num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
         use_sliding_window=True, sliding_window=3, max_window_layers=2,
+        attn_implementation="eager",
     )
     torch.manual_seed(0)
     m2 = transformers.Qwen2ForCausalLM(mixed).eval()
+    cfg, params = from_hf_qwen2(m2)
     types = list(getattr(mixed, "layer_types", []))
     if "sliding_attention" in types and "full_attention" in types:
-        with pytest.raises(ValueError, match="model-global"):
-            from_hf_qwen2(m2)
-    else:
-        # transformers version without mixed layer_types: import works
-        # and maps (or ignores) the window uniformly.
-        from_hf_qwen2(m2)
+        theta = cfg.rope_theta
+        assert cfg.attn_window is None and cfg.attn_layers == tuple(
+            AttnLayer(3 if t == "sliding_attention" else None, theta)
+            for t in types
+        )
+        b, s = 2, 9  # s > window
+        tokens = np.arange(b * s).reshape(b, s) % cfg.vocab
+        with torch.no_grad():
+            ref = m2(torch.tensor(tokens)).logits.numpy()
+        out, _ = sequential_apply(
+            llama(cfg), params, [() for _ in range(cfg.n_layers + 2)],
+            jnp.asarray(tokens, jnp.int32), rng=None, train=False,
+        )
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), ref, rtol=2e-4, atol=2e-4
+        )
+    # else: a transformers version without mixed layer_types maps (or
+    # ignores) the window uniformly, and the import above has worked.
 
 
 def test_mistral_sliding_window_imported():

@@ -123,6 +123,12 @@ def _embed(cfg: TransformerConfig, embed_p: Pytree,
     return x
 
 
+def _window(cfg: TransformerConfig) -> Optional[int]:
+    """The window every layer attends in (``_check_decodable`` admits a
+    one-entry attention period only)."""
+    return cfg.attn_layer(0).window
+
+
 def _w(cfg: TransformerConfig, p: Pytree, key: str) -> jnp.ndarray:
     """Weight read-site accessor: plain arrays pass through; weight-only
     int8 leaves (``models.quant``) dequantize here, so every decode path
@@ -324,7 +330,7 @@ def attend_rows_counter(
         or jax.devices()[0].platform != "tpu"
         or not _flash_decode_eligible(
             (rows, g, cfg.n_heads, cfg.head_dim), cache.k[0],
-            cfg.attn_window, quant=isinstance(cache, QuantKVCache),
+            _window(cfg), quant=isinstance(cache, QuantKVCache),
             per_row=True,
         )
     ):
@@ -334,7 +340,7 @@ def attend_rows_counter(
         g, cfg.n_heads, bank.shape[2], bank.dtype.itemsize, max_len
     )
     # A band as long as the cache drops no block of any frontier.
-    window = cfg.attn_window
+    window = _window(cfg)
     if window is not None and max_len - window + 1 < block_k:
         window = None
 
@@ -508,7 +514,7 @@ def _decode_chunk(
             # VMEM (int8 HBM traffic); the dense path dequantizes at the
             # attend instead.
             attn = _attend_chunk(
-                q, ck, cv, pos0, cfg.attn_window, k_scale=cks, v_scale=cvs
+                q, ck, cv, pos0, _window(cfg), k_scale=cks, v_scale=cvs
             )
         x = _block_attn_out(cfg, p, x, attn, mlp_layer)
         new.append(layer)
@@ -677,7 +683,7 @@ def decode_slots(
             # copied) and skips the rows with nothing to do; elsewhere
             # the dense einsum, a row at a time in the compact form.
             attn = _attend_chunk(
-                q, ck, cv, pos0, cfg.attn_window, k_scale=cks, v_scale=cvs,
+                q, ck, cv, pos0, _window(cfg), k_scale=cks, v_scale=cvs,
                 slots=slots, lengths=live,
             )
         x = _block_attn_out(cfg, p, x, attn, mlp_layer, valid, counts)
@@ -747,6 +753,15 @@ def _check_decodable(cfg: TransformerConfig, positions: int) -> None:
             "the decode paths compute pre-norm blocks; "
             f"norm_position={cfg.norm_position!r} (BERT-class post-norm) "
             "models are encoders — use the training/apply path"
+        )
+    if len(cfg.attn_period) > 1:
+        raise NotImplementedError(
+            "cfg.attn_layers mixes attention layer types by layer "
+            f"({len(cfg.attn_period)} entries): the caches here are one "
+            "kind for every layer, and window layers would need a ring "
+            "beside full layers' rows; such a model trains through "
+            "transformer_block / llama_spmd / llama_moe_spmd and is not "
+            "generated from or served yet"
         )
     _check_max_pos(cfg, positions)
 
@@ -960,12 +975,12 @@ def prefill(
     if s > max_len:
         raise ValueError(f"prompt length {s} exceeds max_len {max_len}")
     _check_decodable(cfg, s)
-    if ring and cfg.attn_window is None:
+    if ring and _window(cfg) is None:
         raise ValueError(
             "ring caches hold exactly the attention window: set "
             "cfg.attn_window to use ring=True"
         )
-    W = cfg.attn_window if ring else None
+    W = _window(cfg) if ring else None
     L = W if ring else max_len
     mlp_layer = _mlp_layer_for(cfg, moe)
     if ring or kv_quant:
@@ -984,7 +999,7 @@ def prefill(
             attn = mla.attend(cfg, p, q_nope, q_pe, *rows, 0)
         else:
             q, *rows = _block_qkv(cfg, p, x, 0)
-            attn = _attend_full(q, *rows, cfg.attn_window, use_flash)
+            attn = _attend_full(q, *rows, _window(cfg), use_flash)
         x = _block_attn_out(cfg, p, x, attn, mlp_layer)
         if ring:
             # Slot j gets the newest prompt position congruent to j
@@ -1156,7 +1171,7 @@ def generate(
             f"cache_mode must be 'full' or 'ring', got {cache_mode!r}"
         )
     ring = cache_mode == "ring"
-    if ring and cfg.attn_window is None:
+    if ring and _window(cfg) is None:
         raise ValueError(
             "cache_mode='ring' holds exactly the attention window: set "
             "cfg.attn_window"

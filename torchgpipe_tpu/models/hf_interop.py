@@ -41,6 +41,7 @@ layouts); decoder families also EXPORT back via their
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, List, Tuple
 
 import jax.numpy as jnp
@@ -343,11 +344,11 @@ def from_hf_qwen2(model: Any, *, untie: bool = False) -> tuple:
     ``attn_bias`` / ``attn_window``.  Everything else (RMSNorm, SwiGLU,
     rotary, GQA, tying) flows through the Llama importer unchanged.
 
-    Window caveat: HF Qwen2 windows only the layers past
-    ``max_window_layers`` (``config.layer_types``); this framework's
-    ``attn_window`` is model-global, so the mapping is applied only when
-    EVERY layer is windowed and a mixed layout is rejected rather than
-    silently diverging at long sequences."""
+    HF Qwen2 windows only the layers past ``max_window_layers``
+    (``config.layer_types``): a layout in which every layer is windowed
+    maps to the model-global ``attn_window``, a mixed one to the per-layer
+    description ``attn_layers`` (training path; generation and serving
+    refuse a mixed period)."""
     import dataclasses
 
     hfc = model.config
@@ -361,14 +362,33 @@ def from_hf_qwen2(model: Any, *, untie: bool = False) -> tuple:
     return cfg, params_from_hf(sd, cfg)
 
 
+def _attn_period(types: List[str], entries: Dict[str, Any]) -> tuple:
+    """The shortest repeating period of ``layer_types`` as
+    ``TransformerConfig.attn_layers``: layer ``i`` is of type
+    ``types[i % len(period)]``, each type's entry from ``entries``."""
+    unknown = sorted(set(types) - set(entries))
+    if unknown:
+        raise ValueError(
+            f"layer_types {unknown} are not computed here "
+            f"({sorted(entries)} are)"
+        )
+    period = next(
+        p for p in range(1, len(types) + 1)
+        if all(t == types[i % p] for i, t in enumerate(types))
+    )
+    return tuple(entries[t] for t in types[:period])
+
+
 def _apply_qwen_window(
     cfg: TransformerConfig, hfc: Any
 ) -> TransformerConfig:
-    """Qwen-family sliding windows: map to the model-global
-    ``attn_window`` only when EVERY layer is windowed; reject mixed
-    ``max_window_layers`` layouts rather than silently diverging at
-    sequences past the window."""
+    """Qwen-family sliding windows (``layer_types``: the layers past
+    ``max_window_layers`` are windowed): the model-global ``attn_window``
+    when EVERY layer is windowed, else the per-layer description
+    ``attn_layers`` with the published layout as its period."""
     import dataclasses
+
+    from torchgpipe_tpu.models.transformer import AttnLayer
 
     if not (
         getattr(hfc, "use_sliding_window", False)
@@ -379,18 +399,15 @@ def _apply_qwen_window(
         getattr(hfc, "layer_types", None)
         or ["sliding_attention"] * cfg.n_layers
     )
+    window = int(hfc.sliding_window)
     if all(t == "sliding_attention" for t in types):
-        return dataclasses.replace(cfg, attn_window=int(hfc.sliding_window))
-    if any(t == "sliding_attention" for t in types):
-        raise ValueError(
-            "this checkpoint mixes full-attention and sliding-window "
-            f"layers (max_window_layers="
-            f"{getattr(hfc, 'max_window_layers', '?')}); attn_window is "
-            "model-global here, so importing it would silently diverge "
-            "from HF at sequences past the window — per-layer windows "
-            "are not supported"
-        )
-    return cfg  # every layer full attention — nothing to map
+        return dataclasses.replace(cfg, attn_window=window)
+    if not any(t == "sliding_attention" for t in types):
+        return cfg  # every layer full attention — nothing to map
+    return dataclasses.replace(cfg, attn_layers=_attn_period(types, {
+        "sliding_attention": AttnLayer(window, cfg.rope_theta),
+        "full_attention": AttnLayer(None, cfg.rope_theta),
+    }))
 
 
 def from_hf_qwen3(model: Any, *, untie: bool = False) -> tuple:
@@ -1640,3 +1657,103 @@ def config_from_hf_latent_moe(
 
 
 __all__ += ["config_from_hf_latent_moe"]
+
+
+def _rope_entry(record: Any, window: Any) -> Any:
+    """One layer type's :class:`AttnLayer` from its ``rope_parameters``
+    record (``rope_type`` 'default' or 'yarn')."""
+    from torchgpipe_tpu.models.mla import yarn_amplitude
+    from torchgpipe_tpu.models.transformer import AttnLayer, YarnRope
+
+    kind = record.get("rope_type", record.get("type", "default"))
+    theta = float(record["rope_theta"])
+    if kind == "default":
+        return AttnLayer(window, theta)
+    if kind != "yarn":
+        raise ValueError(
+            f"rope_type {kind!r} is not computed here ('default' and "
+            "'yarn' are)"
+        )
+    yarn = YarnRope(
+        factor=float(record["factor"]),
+        original_max_pos=int(record["original_max_position_embeddings"]),
+        beta_fast=float(record.get("beta_fast", 32)),
+        beta_slow=float(record.get("beta_slow", 1)),
+        mscale=float(record.get("mscale", 1)),
+        mscale_all_dim=float(record.get("mscale_all_dim", 0)),
+    )
+    stated = record.get("attention_factor")
+    if stated is not None and not math.isclose(
+            float(stated), yarn_amplitude(yarn), rel_tol=1e-6):
+        raise ValueError(
+            f"attention_factor {stated} is not what the record's factor "
+            f"and mscales give ({yarn_amplitude(yarn)}); a free factor on "
+            "cos and sin is not computed here"
+        )
+    return AttnLayer(window, theta, yarn)
+
+
+def config_from_hf_mixed_moe(hf_config: Any, held: Any = None) -> tuple:
+    """(TransformerConfig, MoEConfig) for the published config of a GQA
+    model whose layers mix window and full attention and whose
+    feed-forwards are softmax-routed experts (the key set
+    ``layer_types``, ``sliding_window``, ``rope_parameters`` by layer
+    type, ``mlp_layer_types``, ``num_experts``, ``num_experts_per_tok``,
+    ``moe_intermediate_size``, ``norm_topk_prob``).  ``hf_config`` is any
+    object with those attributes, as they stand in ``config.json``.
+
+    ``layer_types`` becomes the repeating period ``cfg.attn_layers``
+    (window and rope record a type; a YaRN record's
+    ``attention_factor``, where stated, has to be what its factor and
+    mscales give).  The lineage's attention norms q and k per
+    head without a key of its own (``qk_norm``).  ``held=(first,
+    count)`` makes the expert layers one chip's share
+    (``MoEConfig.held``); no expert is shared and none is dropped
+    (``dispatch='dropless'``).  Every ``mlp_layer_types`` entry has to be
+    ``'sparse'``: a dense layer among them would take
+    ``intermediate_size``, which is recorded and otherwise unused."""
+    from torchgpipe_tpu.models.moe import MoEConfig
+
+    hf = hf_config
+    if getattr(hf, "attention_bias", False):
+        raise ValueError("attention_bias=True is not read by this importer")
+    dense = sorted(set(hf.mlp_layer_types) - {"sparse"})
+    if dense:
+        raise ValueError(
+            f"mlp_layer_types {dense}: every layer is taken to be an "
+            "expert layer ('sparse')"
+        )
+    dim, inter = hf.hidden_size, hf.intermediate_size
+    window = int(hf.sliding_window)
+    cfg = TransformerConfig(
+        vocab=hf.vocab_size,
+        dim=dim,
+        n_layers=hf.num_hidden_layers,
+        n_heads=hf.num_attention_heads,
+        n_kv_heads=hf.num_key_value_heads,
+        n_head_dim=int(hf.head_dim),
+        mlp_ratio=3.0 * inter / (2.0 * dim),
+        norm_eps=float(hf.rms_norm_eps),
+        tie_embeddings=bool(getattr(hf, "tie_word_embeddings", False)),
+        qk_norm=True,
+        act=hf.hidden_act,
+        attn_layers=_attn_period(list(hf.layer_types), {
+            "sliding_attention": _rope_entry(
+                hf.rope_parameters["sliding_attention"], window),
+            "full_attention": _rope_entry(
+                hf.rope_parameters["full_attention"], None),
+        }),
+    )
+    moe = MoEConfig(
+        n_experts=int(hf.num_experts),
+        top_k=int(hf.num_experts_per_tok),
+        dispatch="dropless",
+        scoring="softmax",
+        norm_topk=bool(hf.norm_topk_prob),
+        expert_hidden=int(hf.moe_intermediate_size),
+        held=None if held is None else (int(held[0]), int(held[1])),
+    )
+    return cfg, moe
+
+
+__all__ += ["config_from_hf_mixed_moe"]

@@ -100,13 +100,18 @@ def score_scale(m: MLAConfig) -> float:
     return scale
 
 
-def rope_amplitude(m: MLAConfig) -> float:
-    """The factor YaRN puts on cos and sin."""
-    y = m.rope_scaling
+def yarn_amplitude(y: Optional[YarnRope]) -> float:
+    """The factor YaRN puts on cos and sin: the ratio of its two mscale
+    terms (``0.1 ln(factor) + 1`` with the defaults)."""
     if y is None:
         return 1.0
     return yarn_mscale(y.factor, y.mscale) / yarn_mscale(
         y.factor, y.mscale_all_dim)
+
+
+def rope_amplitude(m: MLAConfig) -> float:
+    """The factor YaRN puts on cos and sin."""
+    return yarn_amplitude(m.rope_scaling)
 
 
 def rope(cfg: TransformerConfig, x: jnp.ndarray, pos: Any) -> jnp.ndarray:
@@ -250,5 +255,6 @@ def attend(
 
 __all__ = [
     "absorbs", "attend", "attn_shapes", "init_block", "project", "rope",
-    "rope_amplitude", "score_scale", "yarn_inv_freq", "yarn_mscale",
+    "rope_amplitude", "score_scale", "yarn_amplitude", "yarn_inv_freq",
+    "yarn_mscale",
 ]

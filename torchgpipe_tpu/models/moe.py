@@ -59,6 +59,7 @@ from torchgpipe_tpu.layers import Layer, chain
 from torchgpipe_tpu.models.transformer import (
     TransformerConfig,
     _normal,
+    layers_per_stage,
     lm_head,
     token_embedding,
     transformer_block,
@@ -103,10 +104,11 @@ class MoEConfig:
     # sorted by expert and the expert MLP runs as grouped matmuls over the
     # ragged expert segments (``lax.ragged_dot``) — NO token is ever
     # dropped, and per-step work is exactly ``k*t`` rows regardless of
-    # router balance.  Requires local experts (``ep_axis=None``); with an
-    # ep axis the all_to_all needs the static per-lane buffers only the
-    # capacity paths provide.  'auto' picks dense or sparse by the dense
-    # tensor's size.
+    # router balance.  Requires ``ep_axis=None``: every expert local, or
+    # ``held`` (below) naming this chip's share of them, run without the
+    # exchange; with an ep axis the all_to_all needs the static per-lane
+    # buffers only the capacity paths provide.  'auto' picks dense or
+    # sparse by the dense tensor's size (dropless under ``held``).
     dispatch: str = "auto"
     # Routing direction: 'topk' (default — each token picks its top-k
     # experts; Switch/GShard) or 'expert_choice' (each EXPERT picks its
@@ -408,12 +410,85 @@ def _dropless_assignment(
 def _expert_ffn(expert_in: jnp.ndarray, params: Pytree) -> jnp.ndarray:
     """Batched per-expert SwiGLU on ``[E, C, d]`` buffers (MXU einsums) —
     the one expert-compute block shared by every dispatch path that uses
-    rectangular expert buffers (the dropless path's ragged twin lives
-    inline with its ``ragged_dot`` calls)."""
+    rectangular expert buffers (the dropless path's ragged twin is
+    :func:`_expert_sum`)."""
     h = jax.nn.silu(
         jnp.einsum("ecd,edh->ech", expert_in, params["w_gate"])
     ) * jnp.einsum("ecd,edh->ech", expert_in, params["w_up"])
     return jnp.einsum("ech,ehd->ecd", h, params["w_down"])
+
+
+def _expert_sum(
+    xf: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
+    w_down: jnp.ndarray, gate_sorted: jnp.ndarray, tok_sorted: jnp.ndarray,
+    group_sizes: jnp.ndarray, zero: Optional[str] = None,
+) -> jnp.ndarray:
+    """The dropless path's expert sum ``[t, d]``: the expert-sorted rows
+    ``xf[tok_sorted]`` through the SwiGLU as three grouped products over
+    ``group_sizes`` and, times their gates, added back to their tokens.
+
+    Rows behind the last group (``held``: an absent expert's; a masked
+    position's) belong to no group and have gate 0.  The TPU's grouped
+    product leaves such rows of its RESULT as they were in memory, in the
+    forward and in its transposes alike (the CPU lowering writes zeros):
+    ``zero='result'`` zeroes them in the last product's result, which is
+    all a forward needs; ``zero='all'`` on both sides of every product,
+    which the transposes need (a NaN there reaches every token's
+    gradient through the transposed gather: chip run, PR 32)."""
+    in_group = (
+        jnp.arange(tok_sorted.shape[0]) < jnp.sum(group_sizes))[:, None]
+
+    def grouped(x, w):
+        if zero != "all":
+            return lax.ragged_dot(x, w, group_sizes)
+        x = jnp.where(in_group, x, 0.0)
+        return jnp.where(in_group, lax.ragged_dot(x, w, group_sizes), 0.0)
+
+    xs = xf[tok_sorted]  # [kt, d] expert-sorted
+    h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+    ys = grouped(h, w_down)
+    if zero == "result":
+        ys = jnp.where(gate_sorted[:, None] != 0.0, ys, 0.0)
+    return (
+        jnp.zeros(xf.shape, ys.dtype)
+        .at[tok_sorted]
+        .add(ys * gate_sorted.astype(ys.dtype)[:, None])
+    )
+
+
+@jax.custom_vjp
+def _held_expert_sum(xf, w_gate, w_up, w_down, gate_sorted, tok_sorted,
+                     group_sizes):
+    """:func:`_expert_sum` where rows lie in no group, with its own
+    backward: the forward zeroes what it must and keeps nothing but its
+    arguments; the backward recomputes the sum with every product zeroed
+    on both sides and transposes that.  Recomputed because under the
+    pipeline's stage-wide recomputation every layer's gathered rows
+    (``[k*t, d]``, three quarters of them in no group at a quarter of the
+    experts held, and their products) would otherwise be alive at once:
+    19.4 of 15.75 GiB at 8 layers x 65,536 rows (described-chip compile,
+    PR 32)."""
+    return _expert_sum(xf, w_gate, w_up, w_down, gate_sorted, tok_sorted,
+                       group_sizes, zero="result")
+
+
+def _held_expert_sum_fwd(*args):
+    return _held_expert_sum(*args), args
+
+
+def _held_expert_sum_bwd(args, g):
+    # The barrier ties the recomputation to the cotangent's arrival, as
+    # jax.checkpoint's does: without it the compiler merges it with the
+    # forward's own products and keeps every layer's rows alive after all.
+    args, g = lax.optimization_barrier((args, g))
+    *diff, tok_sorted, group_sizes = args
+    _, vjp = jax.vjp(
+        lambda *d: _expert_sum(*d, tok_sorted, group_sizes, zero="all"),
+        *diff)
+    return (*vjp(g), None, None)
+
+
+_held_expert_sum.defvjp(_held_expert_sum_fwd, _held_expert_sum_bwd)
 
 
 def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Layer:
@@ -593,20 +668,11 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
                     key, length=n_held + 1)[:n_held].astype(jnp.int32)
                 tok_sorted = tok[order]
                 gate_sorted = jnp.where(mine, gates, 0.0)[order]
+            ragged = moe.held is not None or valid is not None
             with jax.named_scope("moe.experts"):
-                xs = xf[tok_sorted]  # [kt, d] expert-sorted
-                h = jax.nn.silu(
-                    lax.ragged_dot(xs, params["w_gate"], group_sizes)
-                ) * lax.ragged_dot(xs, params["w_up"], group_sizes)
-                ys = lax.ragged_dot(h, params["w_down"], group_sizes)
-                if moe.held is not None or valid is not None:
-                    # Rows of no group hold whatever the lowering left.
-                    ys = jnp.where(gate_sorted[:, None] != 0.0, ys, 0.0)
-                y = (
-                    jnp.zeros((t, d), ys.dtype)
-                    .at[tok_sorted]
-                    .add(ys * gate_sorted.astype(ys.dtype)[:, None])
-                )
+                y = (_held_expert_sum if ragged else _expert_sum)(
+                    xf, params["w_gate"], params["w_up"], params["w_down"],
+                    gate_sorted, tok_sorted, group_sizes)
             return _finish(y, group_sizes)
         # Dense one-hot einsum dispatch materializes [t, E, C] tensors; past
         # ~16M elements (64MB f32) the sort-based scatter/gather path wins on
@@ -674,8 +740,10 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
         meta={
             "kind": "moe_mlp",
             # (params, x, valid=None) -> (y, held experts' token counts);
-            # the counts exist on the dropless path (None elsewhere).
+            # the counts exist on the dropless path (None elsewhere),
+            # ``counts_held`` of them.
             "forward_counts": forward,
+            "counts_held": n_held if dropless else None,
             "balance_weight": moe.balance_weight,
             "ep_axis": ep,
             "validate_mesh": validate_mesh,
@@ -754,11 +822,24 @@ def find_routers(params: Pytree) -> List[jnp.ndarray]:
 
 
 def moe_transformer_block(
-    cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe_block"
+    cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe_block",
+    layer: int = 0,
 ) -> Layer:
     """Pre-norm block with routed-expert feed-forward (attention from
-    :func:`transformer_block`, MoE in the MLP slot)."""
-    return transformer_block(cfg, name=name, mlp=moe_mlp(cfg, moe))
+    :func:`transformer_block`, of layer ``layer``'s type; MoE in the MLP
+    slot)."""
+    return transformer_block(
+        cfg, name=name, mlp=moe_mlp(cfg, moe), layer=layer)
+
+
+def _moe_blocks(
+    cfg: TransformerConfig, moe: MoEConfig, count: int, prefix: str
+) -> List[Layer]:
+    """Blocks ``0 .. count-1``, each of its own layer's attention type."""
+    return [
+        moe_transformer_block(cfg, moe, name=f"{prefix}{i}", layer=i)
+        for i in range(count)
+    ]
 
 
 def llama_moe(cfg: TransformerConfig, moe: MoEConfig) -> List[Layer]:
@@ -772,11 +853,33 @@ def llama_moe(cfg: TransformerConfig, moe: MoEConfig) -> List[Layer]:
             "llama_moe_spmd(cfg, moe, n) + SpmdGPipe, or set "
             "tie_embeddings=False"
         )
-    layers: List[Layer] = [token_embedding(cfg)]
-    for i in range(cfg.n_layers):
-        layers.append(moe_transformer_block(cfg, moe, name=f"moe_block{i}"))
-    layers.append(lm_head(cfg))
-    return layers
+    return [
+        token_embedding(cfg),
+        *_moe_blocks(cfg, moe, cfg.n_layers, "moe_block"),
+        lm_head(cfg),
+    ]
+
+
+def _counted_stage(blocks: List[Layer]) -> Layer:
+    """One SPMD stage of expert blocks.  Where every block counts its
+    held experts' tokens (``meta['apply_counts']``), so does the stage:
+    ``apply_counts(params, x, rng=, train=) -> (y, int32 [layers, held])``
+    — what ``SpmdGPipe``'s train step hands out beside the loss."""
+    stage = chain(blocks, name="stage")
+    applies = [b.meta.get("apply_counts") for b in blocks]
+    if not all(applies):
+        return stage
+
+    def apply_counts(params, x, *, rng=None, train=True):
+        counts = []
+        for i, (fn, p) in enumerate(zip(applies, params)):
+            key = None if rng is None else jax.random.fold_in(rng, i)
+            x, c = fn(p, x, rng=key, train=train)
+            counts.append(c)
+        return x, jnp.stack(counts)
+
+    return dataclasses.replace(
+        stage, meta=dict(stage.meta, apply_counts=apply_counts))
 
 
 def llama_moe_spmd(
@@ -784,23 +887,17 @@ def llama_moe_spmd(
     *, gather_logits: bool = True
 ) -> Tuple[Layer, Layer, Layer]:
     """(block, pre, post) for the SPMD engine: each stage runs
-    ``n_layers // n_stages`` MoE blocks.
+    ``n_layers // n_stages`` MoE blocks, block ``i`` of a stage of layer
+    ``i``'s attention type (a stage holds whole periods of
+    ``cfg.attn_period``: :func:`~torchgpipe_tpu.models.transformer.layers_per_stage`).
 
     ``gather_logits`` as in :func:`~torchgpipe_tpu.models.transformer.llama_spmd`:
     pass ``False`` under ``cfg.tp_axis`` (with
     ``loss_fn=vocab_parallel_cross_entropy(cfg.tp_axis)``) for 1/tp logits
     memory."""
-    if cfg.n_layers % n_stages != 0:
-        raise ValueError(
-            f"n_layers={cfg.n_layers} must divide evenly into {n_stages} stages"
-        )
-    per = cfg.n_layers // n_stages
-    block = chain(
-        [moe_transformer_block(cfg, moe, name=f"b{i}") for i in range(per)],
-        name="stage",
-    )
+    per = layers_per_stage(cfg, n_stages)
     return (
-        block,
+        _counted_stage(_moe_blocks(cfg, moe, per, "b")),
         token_embedding(cfg),
         lm_head(cfg, gather_logits=gather_logits),
     )

@@ -52,6 +52,18 @@ class YarnRope:
 
 
 @dataclasses.dataclass(frozen=True)
+class AttnLayer:
+    """The attention of one layer TYPE of a K/V-family model: its window
+    (attend iff ``0 <= qpos - kpos < window``; None is full causal
+    attention) and its rope record.  ``TransformerConfig.attn_layers``
+    holds one entry a type, in the order the types repeat."""
+
+    window: Optional[int]
+    rope_theta: float
+    yarn: Optional[YarnRope] = None
+
+
+@dataclasses.dataclass(frozen=True)
 class MLAConfig:
     """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434): the
     five sizes and the rope-scaling record.  Queries go through a
@@ -103,6 +115,16 @@ class TransformerConfig:
     # Composes with sp_impl='ulysses' (full-seq local compute windows
     # exactly) but not the ring path.
     attn_window: Optional[int] = None
+    # Attention described per layer: a repeating PERIOD of
+    # :class:`AttnLayer` entries, layer ``i`` taking entry ``i % len``
+    # (window and full layers mixed, each kind with its own rope record).
+    # None is the one-entry period ``(AttnLayer(attn_window,
+    # rope_theta),)``: every reader goes through :meth:`attn_layer`, so a
+    # model-global window is the same description, not a second path.
+    # Training path (``transformer_block``); ``models.generation`` and
+    # ``serving.Engine`` refuse a period of more than one entry (window
+    # layers would need a ring beside full layers' rows).
+    attn_layers: Optional[Tuple[AttnLayer, ...]] = None
     # Tensor parallelism: name of the mesh axis attention heads and MLP
     # hidden units are sharded over (Megatron-style; see
     # torchgpipe_tpu.parallel.tensor).  None = no weight sharding.  The tp
@@ -200,6 +222,18 @@ class TransformerConfig:
         return "gqa" if self.mla is None else "mla"
 
     @property
+    def attn_period(self) -> Tuple[AttnLayer, ...]:
+        """The repeating per-layer attention description."""
+        if self.attn_layers is not None:
+            return self.attn_layers
+        return (AttnLayer(self.attn_window, self.rope_theta),)
+
+    def attn_layer(self, layer: int) -> AttnLayer:
+        """The entry that layer ``layer`` (0-based, model-global) takes."""
+        period = self.attn_period
+        return period[layer % len(period)]
+
+    @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
@@ -250,6 +284,13 @@ class TransformerConfig:
                 "norm_position='post' and parallel_residual do not "
                 "compose (no published family; the parallel form is "
                 "defined on pre-norm branches)"
+            )
+        if self.attn_layers is not None and (
+            not self.attn_layers or self.attn_window is not None
+        ):
+            raise ValueError(
+                "attn_layers is a non-empty period of AttnLayer entries "
+                "and carries the windows itself: leave attn_window None"
             )
         if not 0.0 < self.rope_pct <= 1.0:
             raise ValueError(f"rope_pct={self.rope_pct} must be in (0, 1]")
@@ -401,23 +442,35 @@ def _rope(x: jnp.ndarray, theta: float, pos_offset: Any = 0,
 
 
 def _maybe_rope(
-    cfg: TransformerConfig, x: jnp.ndarray, pos_offset: Any
+    cfg: TransformerConfig, x: jnp.ndarray, pos_offset: Any, layer: int = 0
 ) -> jnp.ndarray:
     """The config's position treatment for a ``[b, s, heads, head_dim]``
-    projection: full rotary, PARTIAL rotary (``rope_pct < 1`` — GPT-NeoX
-    rotates only the leading ``int(head_dim * rope_pct)`` dims), or
-    nothing (``pos_emb='learned'`` models position at the embedding).
-    ONE definition shared by the training block and every generation
-    path."""
+    projection of layer ``layer``: full rotary, PARTIAL rotary
+    (``rope_pct < 1`` — GPT-NeoX rotates only the leading
+    ``int(head_dim * rope_pct)`` dims), or nothing (``pos_emb='learned'``
+    models position at the embedding); theta, and YaRN's frequencies and
+    amplitude where the layer's rope record has them, from
+    ``cfg.attn_layer(layer)``.  ONE definition shared by the training
+    block and every generation path."""
     if cfg.pos_emb != "rope":
         return x
+    entry = cfg.attn_layer(layer)
+    rot = x.shape[-1] if cfg.rope_pct >= 1.0 else int(
+        x.shape[-1] * cfg.rope_pct)
+    freqs, amplitude = None, 1.0
+    if entry.yarn is not None:
+        from torchgpipe_tpu.models.mla import yarn_amplitude, yarn_inv_freq
+
+        freqs = jnp.asarray(yarn_inv_freq(rot, entry.rope_theta, entry.yarn))
+        amplitude = yarn_amplitude(entry.yarn)
+
+    def rotate(part):
+        return _rope(part, entry.rope_theta, pos_offset, freqs=freqs,
+                     amplitude=amplitude)
+
     if cfg.rope_pct >= 1.0:
-        return _rope(x, cfg.rope_theta, pos_offset)
-    rot = int(x.shape[-1] * cfg.rope_pct)
-    return jnp.concatenate(
-        [_rope(x[..., :rot], cfg.rope_theta, pos_offset), x[..., rot:]],
-        axis=-1,
-    )
+        return rotate(x)
+    return jnp.concatenate([rotate(x[..., :rot]), x[..., rot:]], axis=-1)
 
 
 # --------------------------------------------------------------------- #
@@ -447,12 +500,17 @@ def _is_packed_act(x: Any) -> bool:
 
 
 def transformer_block(
-    cfg: TransformerConfig, *, name: str = "block", mlp: Optional[Layer] = None
+    cfg: TransformerConfig, *, name: str = "block",
+    mlp: Optional[Layer] = None, layer: int = 0,
 ) -> Layer:
     """One pre-norm block: x + attn(norm(x)); x + mlp(norm(x)).
 
     Residuals are internal to the layer, so a pipeline can split the model at
     any block boundary without skip routing.
+
+    ``layer`` is the block's 0-based position in the model: its window and
+    rope record are ``cfg.attn_layer(layer)``'s (one entry for a model-
+    global window, a repeating period where window and full layers mix).
 
     ``mlp`` swaps the dense SwiGLU feed-forward for a custom layer on the
     normalized hidden states (e.g. :func:`torchgpipe_tpu.models.moe.moe_mlp`
@@ -472,6 +530,13 @@ def transformer_block(
     nh, nkv = cfg.n_heads, cfg.kv_heads
     hidden = cfg.mlp_hidden
     dt = cfg.dtype
+    window = cfg.attn_layer(layer).window
+    # The operation table tells the two kinds of layer apart by this scope.
+    attn_scope = "attn.window" if window is not None else "attn.full"
+    mlp_meta = mlp.meta if (mlp is not None and isinstance(mlp.meta, dict)) else {}
+    # An expert layer that counts its held experts' tokens (the dropless
+    # path): the block can hand the counts out beside its output.
+    counted = mlp_meta.get("counts_held") is not None
 
     def init(rng, in_spec):
         ks = jax.random.split(rng, 9)
@@ -535,6 +600,15 @@ def transformer_block(
         return params, ()
 
     def apply(params, state, x, *, rng=None, train=True):
+        return forward(params, x, rng, train)[0], state
+
+    def apply_counts(params, x, *, rng=None, train=True):
+        """``(y, counts)``: the block's output and the expert layer's
+        held experts' token counts (``moe_mlp``'s ``forward_counts``)."""
+        return forward(params, x, rng, train, counts=True)
+
+    def forward(params, x, rng, train, counts=False):
+        held = None
         # Sequence packing: a packed activation tuple carries the block-
         # diagonal mask term (segment_ids) and per-token positions through
         # the residual stream; both ride out unchanged.
@@ -588,17 +662,18 @@ def transformer_block(
         if "qn" in params:  # Qwen3-style per-head q/k RMSNorm, pre-rope
             q = _rms(q, params["qn"], cfg.norm_eps)
             k = _rms(k, params["kn"], cfg.norm_eps)
-        q = _maybe_rope(cfg, q, pos_offset)
-        k = _maybe_rope(cfg, k, pos_offset)
+        q = _maybe_rope(cfg, q, pos_offset, layer)
+        k = _maybe_rope(cfg, k, pos_offset, layer)
         # GQA: K/V stay at n_kv heads — the attention kernel groups queries
         # at the compute site, so the sp ring only moves n_kv-head blocks.
         # Under tp, lanes hold contiguous head ranges, so the local q→kv
         # pairing (h // r with r = nh_loc/nkv_loc = nh/nkv) matches global.
-        attn = attention(
-            q, k, v, axis_name=cfg.sp_axis if sp_active else None,
-            causal=cfg.causal, impl=cfg.sp_impl, window=cfg.attn_window,
-            seg=seg,
-        )
+        with jax.named_scope(attn_scope):
+            attn = attention(
+                q, k, v, axis_name=cfg.sp_axis if sp_active else None,
+                causal=cfg.causal, impl=cfg.sp_impl, window=window,
+                seg=seg,
+            )
         attn_flat = attn.reshape(b, s, nh_loc * hd)
         attn_out = attn_flat @ params["wo"]
         if "lora" in params:
@@ -627,7 +702,10 @@ def transformer_block(
             h = _block_norm(
                 cfg, params, "ln2", x_in if cfg.parallel_residual else x
             )
-        if mlp is not None:
+        if counts:
+            mlp_out, held = mlp_meta["forward_counts"](
+                params["mlp"], h, train=train)
+        elif mlp is not None:
             mlp_out, _ = mlp.apply(params["mlp"], (), h, rng=rng, train=train)
         elif "w_fc" in params:
             # Classic (GPT-2-style) feed-forward: fc -> act -> proj.
@@ -655,11 +733,10 @@ def transformer_block(
         else:
             x = x + mlp_out
         if packed:
-            return (x, seg, pk_pos), state
-        return x, state
+            return (x, seg, pk_pos), held
+        return x, held
 
     tp = cfg.tp_axis
-    mlp_meta = mlp.meta if (mlp is not None and isinstance(mlp.meta, dict)) else {}
 
     def validate_mesh(mesh):
         if tp is not None and tp in mesh.axis_names:
@@ -675,7 +752,7 @@ def transformer_block(
                         "heads / hidden units across lanes"
                     )
         if (
-            cfg.attn_window is not None
+            window is not None
             and cfg.sp_impl == "ring"
             and cfg.sp_axis is not None
             and cfg.sp_axis in mesh.axis_names
@@ -788,6 +865,8 @@ def transformer_block(
         # Surfaced so the engine's ragged-batch warning can see a MoE
         # balance penalty through the block wrapper (spmd._row_coupled).
         meta["balance_weight"] = mlp_meta["balance_weight"]
+    if counted:
+        meta["apply_counts"] = apply_counts
     return Layer(name=name, init=init, apply=apply, meta=meta)
 
 
@@ -1167,10 +1246,33 @@ def llama(cfg: TransformerConfig, *, head: bool = True) -> List[Layer]:
         )
     layers: List[Layer] = [token_embedding(cfg)]
     for i in range(cfg.n_layers):
-        layers.append(transformer_block(cfg, name=f"block{i}"))
+        layers.append(transformer_block(cfg, name=f"block{i}", layer=i))
     if head:
         layers.append(lm_head(cfg))
     return layers
+
+
+def layers_per_stage(cfg: TransformerConfig, n_stages: int) -> int:
+    """Blocks one stage of the SPMD engine runs.  Stages are STACKED (one
+    program, the stage a leading axis of every leaf), so every stage runs
+    the same layer types in the same order: a stage holds whole periods
+    of ``cfg.attn_period``, and block ``i`` of any stage is a layer of
+    type ``i % len(period)``."""
+    if cfg.n_layers % n_stages != 0:
+        raise ValueError(
+            f"n_layers={cfg.n_layers} must divide evenly into {n_stages} stages"
+        )
+    per = cfg.n_layers // n_stages
+    period = len(cfg.attn_period)
+    if per % period != 0:
+        raise ValueError(
+            f"{per} layers a stage do not hold whole periods of the "
+            f"{period} attention layer types (cfg.attn_layers): the SPMD "
+            "engine's stages are stacked, so each must run the same types "
+            "in the same order — use a stage count that leaves a multiple "
+            f"of {period} layers a stage"
+        )
+    return per
 
 
 def llama_spmd(
@@ -1183,13 +1285,10 @@ def llama_spmd(
     ``gather_logits=False`` (with
     ``loss_fn=vocab_parallel_cross_entropy(cfg.tp_axis)``) to keep logits
     vocab-sharded through the loss — 1/tp of the logits memory."""
-    if cfg.n_layers % n_stages != 0:
-        raise ValueError(
-            f"n_layers={cfg.n_layers} must divide evenly into {n_stages} stages"
-        )
-    per = cfg.n_layers // n_stages
+    per = layers_per_stage(cfg, n_stages)
     block = chain(
-        [transformer_block(cfg, name=f"b{i}") for i in range(per)], name="stage"
+        [transformer_block(cfg, name=f"b{i}", layer=i) for i in range(per)],
+        name="stage",
     )
     return (
         block,

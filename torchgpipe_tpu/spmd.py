@@ -934,6 +934,30 @@ class SpmdGPipe:
                 "'except_last' or 'offload'"
             )
         self._block_fn = block_fn
+        # A block that counts something as it runs declares
+        # ``meta['apply_counts']``: ``(params, x, rng=, train=) -> (y,
+        # counts)``, an int array that adds up over cells.  The fill-drain
+        # train step runs its cells through that, under the same
+        # recomputation, and hands the sum out beside the loss.
+        meta = self.block.meta if isinstance(self.block.meta, dict) else {}
+        counts_apply = (
+            meta.get("apply_counts") if self.schedule == "fill_drain"
+            else None
+        )
+        self._counted = counts_apply is not None
+        if self._counted:
+
+            def block_fn_counts(params, x, rng, aux_s, train):
+                with aux_scale(aux_s):
+                    return counts_apply(params, x, rng=rng, train=train)
+
+            self._cell_fns_counts = (
+                block_fn_counts if self.checkpoint == "never"
+                else jax.checkpoint(
+                    block_fn_counts, static_argnums=(4,),
+                    policy=self.remat_policy),
+                block_fn_counts,
+            )
         # Spec prefix for the stacked block params: stage dim over pp, plus
         # any per-leaf sharding the layers declare (tensor/expert-parallel
         # weights) — see layer_param_specs.
@@ -1561,11 +1585,13 @@ class SpmdGPipe:
 
     def _local_pipeline(
         self, blocks_local: Pytree, x_mb: Pytree, rng: Optional[jax.Array],
-        train: bool,
+        train: bool, counted: bool = False,
     ) -> Pytree:
         """Run the fill-drain schedule locally; returns stacked per-tick
         outputs ``[T, b, ...]`` (garbage except where tick >= n-1 on the last
-        stage).
+        stage).  ``counted`` (a block that declares ``apply_counts``):
+        ``(outputs, counts)``, the block's counts summed over this lane's
+        live cells.
 
         ``checkpoint='except_last'`` (reference gpipe.py:360-367) peels the
         schedule: ticks ``0..m-2`` — whose cells all belong to micro-batches
@@ -1641,12 +1667,28 @@ class SpmdGPipe:
         #   gather) instead of gating it.  Initial carry: zeros either
         #   way (``ppermute`` of zeros is zeros — same values).
         send_ahead = self.send_ahead
+        fn, fn_plain = (
+            self._cell_fns_counts if counted
+            else (self._block_fn, self._block_fn_plain)
+        )
+
+        def emit(out, valid_scale):
+            """What a tick stacks: the cell's output, and under
+            ``counted`` its counts, zeroed on a fill or drain tick."""
+            if not counted:
+                return out, out
+            y, c = out
+            return y, (y, jnp.where(valid_scale > 0, c, jnp.zeros_like(c)))
+
+        def finish(ys):
+            return (ys[0], jnp.sum(ys[1], axis=0)) if counted else ys
 
         def tick(carry, t):
             recv = carry if send_ahead else ring(carry)
             x_in, key, valid_scale = splice(recv, t)
-            y = self._block_fn(params_local, x_in, key, valid_scale, train)
-            return (ring(y) if send_ahead else y), y
+            y, out = emit(
+                fn(params_local, x_in, key, valid_scale, train), valid_scale)
+            return (ring(y) if send_ahead else y), out
 
         if self.checkpoint == "except_last" and train:
             # Remat'd prefix: every cell in ticks 0..m-2 is micro-batch
@@ -1668,31 +1710,33 @@ class SpmdGPipe:
                 own = t - (m - 1)  # the stage whose cell is micro-batch m-1
 
                 def plain_cell(x):
-                    return self._block_fn_plain(
+                    return fn_plain(
                         params_local, x, key, valid_scale, train
                     )
 
                 def remat_cell(x):
-                    return self._block_fn(
+                    return fn(
                         params_local, x, key, valid_scale, train
                     )
 
-                y = lax.cond(stage == own, plain_cell, remat_cell, x_in)
-                return (ring(y) if send_ahead else y), y
+                y, out = emit(
+                    lax.cond(stage == own, plain_cell, remat_cell, x_in),
+                    valid_scale)
+                return (ring(y) if send_ahead else y), out
 
             _, ys_tail = lax.scan(
                 _scoped("tick", tail_tick), act, jnp.arange(m - 1, T),
                 unroll=self.scan_unroll,
             )
-            return jax.tree_util.tree_map(
+            return finish(jax.tree_util.tree_map(
                 lambda a, b: jnp.concatenate([a, b], axis=0), ys_scan, ys_tail
-            )
+            ))
 
         _, ys = lax.scan(
             _scoped("tick", tick), act0, jnp.arange(T),
             unroll=self.scan_unroll,
         )
-        return ys
+        return finish(ys)
 
     def _outputs_from_ticks(self, ys: Pytree) -> Pytree:
         """Slice micro-batch outputs [m, b, ...] from the tick stack."""
@@ -3013,6 +3057,7 @@ class SpmdGPipe:
             return self._build_train_step_zb(use_rng, masked)
         n = self.n_stages
         data_spec = self._data_specs()
+        counted = self._counted
 
         def local(params, x_mb, tgt_mb, *rest):
             rest = list(rest)
@@ -3044,7 +3089,8 @@ class SpmdGPipe:
                     if self.fsdp
                     else params["blocks"]
                 )
-                ys = self._local_pipeline(blocks_in, x_in, rng, True)
+                ys = self._local_pipeline(blocks_in, x_in, rng, True, counted)
+                ys, counts = ys if counted else (ys, None)
                 outs = self._outputs_from_ticks(ys)
                 gathered = microbatch.gather_stacked(outs)
                 tgt = microbatch.gather_stacked(tgt_mb)
@@ -3119,13 +3165,13 @@ class SpmdGPipe:
                         )
                         if self.loss_reduction == "mean":
                             l = l * mean_scale
-                        return l
+                        return l, counts
                     l = self._loss_call(p_loss_t, my, tgt_my)
                     if self.loss_reduction == "mean":
                         l = l / n
                     # LOCAL per-slice loss; the psum after value_and_grad
                     # reassembles the global loss for reporting.
-                    return l
+                    return l, counts
                 if self.post is not None:
                     # post runs on every pp lane but only the last stage's
                     # activations are real (and its grads are psum'd over
@@ -3155,12 +3201,16 @@ class SpmdGPipe:
                 # seed one cotangent per device and over-count gradients by
                 # the pp size — the transposed ppermutes already carry the
                 # cross-stage cotangents back along the ring.
-                return jnp.where(stage == n - 1, l, 0.0)
+                return jnp.where(stage == n - 1, l, 0.0), counts
 
             # value_and_grad, taken apart so that each half has its scope:
             # the backward's operations read
             # ``backward/transpose(jvp(forward))/...`` in the trace.
-            loss, vjp_loss = jax.vjp(_scoped("forward", loss_of), params)
+            # loss_of returns (loss, counts); counts is None, and dropped,
+            # for a block that declares none.
+            forward = loss_of if counted else (lambda p: loss_of(p)[0])
+            loss, vjp_loss, *aux = jax.vjp(
+                _scoped("forward", forward), params, has_aux=counted)
             with jax.named_scope("backward"):
                 (grads,) = vjp_loss(jnp.ones_like(loss))
             loss = lax.psum(loss, self.pp_axis)  # broadcast for reporting
@@ -3182,6 +3232,13 @@ class SpmdGPipe:
                 red = lax.pmean if self.loss_reduction == "mean" else lax.psum
                 loss = red(loss, self.sp_axis)
                 grads = red(grads, self.sp_axis)
+            if counted:
+                # This stage's counts, summed over the lanes that each saw
+                # a share of the tokens; stages stack on the way out.
+                token_axes = tuple(a for a in (
+                    self.dp_axis, self.ep_axis, self.sp_axis) if a)
+                counts = lax.psum(aux[0], token_axes) if token_axes else aux[0]
+                return loss, grads, counts[None]
             return loss, grads
 
         param_specs = {
@@ -3203,7 +3260,8 @@ class SpmdGPipe:
             local,
             self.mesh,
             in_specs=in_specs,
-            out_specs=(P(), param_specs),
+            out_specs=(P(), param_specs) + (
+                (P(self.pp_axis),) if counted else ()),
         )
         return jax.jit(mapped)
 
@@ -3439,6 +3497,15 @@ class SpmdGPipe:
         randomness (dropout raises loudly without it, matching the MPMD
         engine); omit it for deterministic models.
         """
+        return self._train_step_out(params, x, target, rng)[:2]
+
+    def _train_step_out(
+        self, params: Pytree, x: Pytree, target: Pytree,
+        rng: Optional[jax.Array] = None,
+    ) -> Tuple:
+        """:meth:`train_step`'s ``(loss, grads)``, and behind them the
+        block's counts ``[stages, ...]`` where it declares
+        ``apply_counts`` (fill-drain schedule)."""
         self._check_params(params)
         token = self._fault_token_checked(for_train=True)
         pad = self._check_batch(
@@ -3758,7 +3825,11 @@ class SpmdGPipe:
         state, ``update(grads, state, params) -> (updates, state)``).
         Returns ``step(params, opt_state, x, target, rng=None) ->
         (loss, new_params, new_opt_state)``; initialize ``opt_state``
-        with ``place_tree(optimizer.init(params))``.
+        with ``place_tree(optimizer.init(params))``.  A block that
+        declares ``meta['apply_counts']`` (an expert stage's held
+        experts' token counts) gets its counts, summed over the step's
+        micro-batches and stacked over the stages, as a FOURTH result
+        (fill-drain schedule), to be fetched with the loss.
 
         Two wins over calling :meth:`train_step` and applying the
         optimizer in a second jitted program (the reference's shape:
@@ -3849,12 +3920,13 @@ class SpmdGPipe:
             # (baked into the traced train_step) is never reused after the
             # plan ends, or vice versa.
             del plan_token
-            loss, grads = self.train_step(params, x, target, rng)
+            loss, grads, *counts = self._train_step_out(
+                params, x, target, rng)
             with jax.named_scope("optimizer"):
                 new_params, new_state = apply_update(
                     params, grads, opt_state
                 )
-            return loss, new_params, new_state
+            return (loss, new_params, new_state, *counts)
 
         compiled = jax.jit(
             whole,
