@@ -312,8 +312,10 @@ def test_specs_name_the_compact_inputs_and_lint_clean(
 ):
     """``step_input_specs`` are true statements about the programs: the
     prefill programs take ``slots [R]``, ``tokens [R, g]``, ``n_valid
-    [R]`` beside the pool-wide ``lengths``; decode stays pool-wide; the
-    speculative verify program is pool-wide at its bucket.  The lint's
+    [R]``, ``finish [R]`` beside the pool-wide ``lengths`` and device
+    token vector ``cur_tok``; decode stays pool-wide and its ``tokens``
+    IS that vector; the speculative verify program is pool-wide at its
+    bucket and takes neither.  The lint's
     churn grid and ladder walk hold at ``R < num_slots``."""
     from torchgpipe_tpu.analysis import (
         Severity, certify_speculative, lint_serving,
@@ -327,7 +329,9 @@ def test_specs_name_the_compact_inputs_and_lint_clean(
         assert spec["tokens"].shape == (8, g)
         assert spec["n_valid"].shape == (8,)
         assert spec["lengths"].shape == (SLOTS,)
-    assert specs["decode"]["tokens"].shape == (SLOTS, 1)
+        assert spec["finish"].shape == (8,)
+        assert spec["cur_tok"].shape == (SLOTS,)
+    assert specs["decode"]["tokens"].shape == (SLOTS,)
     assert specs["decode"]["n_valid"].shape == (SLOTS,)
     assert "slots" not in specs["decode"]
     assert eng.program_count == 5 == len(specs)
@@ -339,7 +343,7 @@ def test_specs_name_the_compact_inputs_and_lint_clean(
     se = _engine("speculative", flat_params, draft_params)
     specs = se.step_input_specs()
     assert specs["verify"]["tokens"].shape == (SLOTS, 8)
-    assert "slots" not in specs["verify"]
+    assert not {"slots", "cur_tok", "finish"} & set(specs["verify"])
     assert specs["prefill"]["tokens"].shape == (8, 8)
     # prefill + decode + verify + the draft set (1 and 8)
     assert se.program_count == 5 == len(specs)

@@ -372,6 +372,9 @@ class _FakeEngine:
     def take_migration_ready(self):
         return []
 
+    def unfinished(self):
+        return []
+
     def drain(self):
         self.admitting = False
         return {"tree": {}, "requests": {}}

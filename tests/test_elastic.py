@@ -461,6 +461,9 @@ class _FakeEngine:
         self.scheduler = _FakeScheduler()
         self.admitting = True
 
+    def unfinished(self):
+        return []
+
     def drain(self):
         self.admitting = False
         return {"tree": {}, "requests": {}}

@@ -200,11 +200,12 @@ def test_bf16_weights_fail_the_same_tolerance(model):
 def test_engine_serves_the_references_tokens_with_slots_recycled(model, chunk):
     """Seven requests through a three-slot engine: each served token is the
     reference's best at its position (within ``TOL``), the expert counters
-    add up, and a step that fetched its tokens carries the held experts'
-    load on its span (a prefill step that finishes no prompt fetches
-    nothing: its counts are read at the next fetch, into the counters)."""
+    add up, and every step's held-expert load is read where the host
+    waits for that step (one step later, under the next step's action),
+    onto that ``engine.fetch`` span and into the counters."""
     cfg, moe, flat = model
-    eng = Engine(cfg, flat, moe=moe, num_slots=3, max_len=48, prefill_chunk=chunk)
+    eng = Engine(cfg, flat, moe=moe, num_slots=3, max_len=48, prefill_chunk=chunk,
+                 donate=True)      # as the cell runs it: one step in flight
     rng = np.random.default_rng(chunk)
     reqs = {f"r{i}": (tokens_of(20 + i, int(rng.integers(3, 22))), int(rng.integers(2, 9)))
             for i in range(7)}
@@ -228,13 +229,13 @@ def test_engine_serves_the_references_tokens_with_slots_recycled(model, chunk):
     assert eng.compile_stats == {"prefill": 1, "decode": 1}
     actions = [e for e in list(default_timeline().events)[mark:]
                if e.name in ("engine.prefill", "engine.decode")]
-    fetched = {e.parent for e in list(default_timeline().events)[mark:] if e.name == "engine.fetch"}
-    assert actions and all(("held" in e.fields) == (e.seq in fetched) for e in actions)
-    assert any(e.seq not in fetched for e in actions)
-    assert all(e.fields["held"] >= e.fields["max_expert"] >= 0 for e in actions if e.seq in fetched)
+    fetches = [e for e in list(default_timeline().events)[mark:] if e.name == "engine.fetch"]
+    # One wait a launched program: the first step waits for none, the last for two.
+    assert len(fetches) == len(actions) and {e.parent for e in fetches} < {e.seq for e in actions}
+    assert all(e.fields["held"] >= e.fields["max_expert"] >= 0 for e in fetches)
     kinds = {k: sum(e.name == "engine." + k for e in actions) for k in ("prefill", "decode")}
     assert {k: eng.metrics.moe_expert_tokens(k)["steps"] for k in kinds} == kinds
-    assert sum(e.fields["held"] for e in actions if e.seq in fetched) < snap["moe_held_assignments"]
+    assert sum(e.fields["held"] for e in fetches) == snap["moe_held_assignments"]
 
 
 # --------------------------------------------------------------------- #

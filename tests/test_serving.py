@@ -551,6 +551,404 @@ def test_lint_serving_clean_with_ladder(flat_params):
 
 
 # --------------------------------------------------------------------- #
+# one step in flight                                                    #
+# --------------------------------------------------------------------- #
+
+
+def _latent_moe_toy():
+    """The latent-attention, routed-expert toy of ``tests/test_mla_moe.py``,
+    told what it holds: its step programs return ``(tokens, counts)``."""
+    import test_mla_moe as toy
+    from chipbench import weights_axk1
+
+    cfg, moe = toy.configs()
+    return cfg, moe, weights_axk1.make_flat(toy.TOY, 11), toy.TOY["vocab_size"]
+
+
+def _stream(eng, reqs, serial, **submit_kw):
+    """Every request's tokens as ``on_token`` hands them over.  Half of
+    ``reqs`` is queued at once, the rest arrives one every third
+    iteration, so slots are freed and taken mid-run.  ``serial``: the
+    reference loop, which settles after every step (nothing is ever in
+    flight when a step is built; an engine that does not donate its
+    cache is that loop already)."""
+    got = {}
+
+    def on_token(rid, tok):
+        got.setdefault(rid, []).append(int(tok))
+
+    pending = [(f"q{i}", p, n) for i, (p, n) in enumerate(reqs)]
+    for rid, p, n in pending[:len(pending) // 2]:
+        eng.submit(p, n, rid=rid, on_token=on_token, **submit_kw)
+    late = pending[len(pending) // 2:]
+    it = 0
+    while late or not eng.scheduler.idle:
+        if late and it % 3 == 0:
+            rid, p, n = late.pop(0)
+            eng.submit(p, n, rid=rid, on_token=on_token, **submit_kw)
+        eng.step()
+        if serial:
+            eng._settle()
+        it += 1
+    assert eng._inflight is None        # idle implies settled
+    return got
+
+
+@pytest.mark.parametrize("model", ["gqa", "latent_moe"])
+@pytest.mark.parametrize("sampling", ["greedy", "seeded"])
+def test_overlapped_streams_equal_the_serial_loops(flat_params, model,
+                                                   sampling):
+    """Step k+1 is launched before step k's tokens are fetched; the
+    streams are token for token those of a loop that settles after
+    every step — greedy and seeded sampling (the key travels on the
+    device), mixed prompt lengths, admissions and evictions mid-run, on
+    the GQA toy and on the latent / expert toy (``(tok, counts)``)."""
+    if model == "gqa":
+        cfg, params, vocab, kw = CFG, flat_params, 64, {}
+    else:
+        cfg, moe, params, vocab = _latent_moe_toy()
+        kw = {"moe": moe}
+    if sampling == "seeded":
+        kw.update(temperature=0.8, top_k=12, rng=jax.random.PRNGKey(5))
+    reqs = _workload(seed=21, n=9, vocab=vocab, plen_hi=14, new_hi=9)
+
+    def engine(donate):
+        return Engine(cfg, params, num_slots=3, max_len=32,
+                      prefill_chunk=4, donate=donate, **kw)
+
+    fast, slow = engine(True), engine(False)
+    got = _stream(fast, reqs, serial=False)
+    want = _stream(slow, reqs, serial=True)
+    assert got == want
+    assert sorted(got) == sorted(f"q{i}" for i in range(len(reqs)))
+    assert all(len(got[f"q{i}"]) == n for i, (_, n) in enumerate(reqs))
+    a, b = fast.metrics.snapshot(), slow.metrics.snapshot()
+    for key in ("prefill_steps", "decode_steps", "tokens_out",
+                "occupancy", "moe_held_assignments"):
+        assert a[key] == b[key], key
+    assert b["steps_launched_ahead"] == 0
+    # Every step but the first of a busy stretch is launched ahead.
+    assert a["steps_launched_ahead"] >= a["engine_steps"] - 3
+    assert fast.compile_stats == slow.compile_stats == {
+        "prefill": 1, "decode": 1}
+    if model == "gqa" and sampling == "greedy":
+        for i, (p, n) in enumerate(reqs):
+            assert got[f"q{i}"] == _ref(flat_params, p, n).tolist()
+
+
+def test_eos_row_launched_behind_is_discarded(flat_params):
+    """A request that ends by EOS at step k has a row in step k+1, which
+    was launched before k's tokens were fetched: that row emits nothing,
+    no token is counted for it, and the slot's next tenant matches its
+    cold run bit for bit."""
+    from torchgpipe_tpu.utils.tracing import Timeline
+
+    (p1, _), (p2, n2) = _workload(seed=4, n=2, new_hi=9)
+    full = _ref(flat_params, p1, 12).tolist()
+    cut = next(j for j in range(2, 12) if full[j] not in full[:j])
+    eos = full[cut]
+
+    def serve(serial):
+        eng = Engine(CFG, flat_params, num_slots=1, max_len=32,
+                     prefill_chunk=4, donate=not serial)
+        eng.timeline = Timeline()
+        seen = []
+        eng.submit(p1, 12, rid="a", eos_id=eos,
+                   on_token=lambda rid, t: seen.append(int(t)))
+        eng.submit(p2, n2, rid="b")
+        while not eng.scheduler.idle:
+            eng.step()
+            if serial:
+                eng._settle()
+        return eng, seen
+
+    fast, seen = serve(serial=False)
+    slow, _ = serve(serial=True)
+    assert seen == full[:cut + 1] == fast.result("a").tolist()
+    assert fast.status("a") == "finished"
+    assert fast.metrics.requests["a"].tokens == cut + 1
+    assert fast.metrics.tokens_out == slow.metrics.tokens_out == cut + 1 + n2
+    assert sum(e.fields["tokens"] for e in fast.timeline.events
+               if e.name == "engine.emit") == cut + 1 + n2
+    # The one wasted row is a decode step the serial loop never runs.
+    assert fast.metrics.decode_steps == slow.metrics.decode_steps + 1
+    # The next tenant of the ONE slot, whose frontier was reset on
+    # release, never attends the row written past the end of "a".
+    assert fast.result("b").tolist() == _ref(flat_params, p2, n2).tolist()
+    assert fast.result("b").tolist() == slow.result("b").tolist()
+    fast.pool.check_refcounts()
+    assert fast.pool.num_free == 1
+
+
+@pytest.mark.parametrize("kind", ["fatal", "exhausted"])
+def test_a_failed_step_that_cannot_be_retried_leaves_the_pre_step_state(
+        flat_params, kind):
+    """``donate=False``: the engine waits for a step where it launches
+    it, under the retry, and commits only what is known good.  A failure
+    that cannot be retried (not transient, or the retries used up)
+    re-raises with the POOL HOLDING THE PRE-STEP ARRAYS and the host's
+    books where they were — no step is ever in flight behind it — so
+    the same engine goes on, exactly, once the fault is gone."""
+    sleeps = []
+    eng = Engine(CFG, flat_params, num_slots=2, max_len=32,
+                 prefill_chunk=4, sleep=sleeps.append)
+    reqs = [(p, max(n, 6)) for p, n in _workload(seed=9, n=3, new_hi=9)]
+    rids = [eng.submit(p, n) for p, n in reqs]
+    while eng.metrics.decode_steps < 3:
+        assert eng.step() and eng._inflight is None
+    real = eng._decode_fn
+    error = {"fatal": ValueError("a wrong program"),
+             "exhausted": ConnectionError("the link stays down")}[kind]
+
+    def broken(*args):
+        raise error
+
+    eng._decode_fn = broken
+    while eng.scheduler.next_action() != "decode":
+        assert eng.step()
+    cache, key = eng.pool.cache, eng._key
+    books = (eng.pool.lengths.copy(), eng._lengths_shadow.copy(),
+             eng._cur_tok.copy(), eng.metrics.snapshot()["engine_steps"],
+             {r: (q.prefilled, list(q.generated), q.in_flight)
+              for r, q in eng._requests.items()})
+    with pytest.raises(type(error)):
+        eng.step()
+    retries = 0 if kind == "fatal" else eng.guard_policy.max_retries
+    assert len(sleeps) == eng.metrics.snapshot()["retries"] == retries
+    assert eng.pool.cache is cache and eng._key is key
+    assert eng._inflight is None
+    now = (eng.pool.lengths, eng._lengths_shadow, eng._cur_tok,
+           eng.metrics.snapshot()["engine_steps"],
+           {r: (q.prefilled, list(q.generated), q.in_flight)
+            for r, q in eng._requests.items()})
+    for was, got in zip(books[:3], now[:3]):
+        assert was.tolist() == got.tolist()
+    assert books[3:] == now[3:]
+    eng._decode_fn = real
+    assert eng.run() == "idle"
+    for rid, (p, n) in zip(rids, reqs):
+        assert eng.result(rid).tolist() == _ref(flat_params, p, n).tolist()
+
+
+@pytest.mark.parametrize("via", ["step", "unfinished"])
+def test_a_failure_met_at_the_wait_abandons_the_steps_in_flight(
+        flat_params, monkeypatch, via):
+    """``donate=True``: a step's inputs are consumed by its launch, so
+    its failure — met where the host waits for it, one step later, with
+    another step launched behind it — cannot be retried: it re-raises,
+    nothing stays in flight, and ``drain()`` (the router's failover)
+    snapshots every unfinished request with the tokens it DID deliver,
+    those that had ended by length in the lost steps included.  The
+    router's listing (``unfinished``) meets the failure once and is
+    whole on the next call."""
+    from torchgpipe_tpu.serving import engine as engine_mod
+
+    eng = Engine(CFG, flat_params, num_slots=2, max_len=32,
+                 prefill_chunk=4, donate=True)
+    reqs = [(p, 6) for p, _ in _workload(seed=9, n=3)]
+    rids = [eng.submit(p, n) for p, n in reqs]
+    real_wait = jax.block_until_ready
+    state = {"armed": False, "raised": 0}
+
+    def wait(x):
+        if state["armed"]:
+            state["raised"] += 1
+            raise ConnectionError("transient blip on the device")
+        return real_wait(x)
+
+    monkeypatch.setattr(engine_mod.jax, "block_until_ready", wait)
+    # Run until a step that ends a request BY LENGTH is in flight.
+    while not (eng._inflight is not None and eng._inflight.released):
+        assert eng.step()
+    lost = [r.rid for r in eng._inflight.released]
+    delivered = {r: list(eng._requests[r].generated) for r in rids}
+    state["armed"] = True
+    with pytest.raises(ConnectionError):
+        eng.step() if via == "step" else eng.unfinished()
+    state["armed"] = False
+    assert state["raised"] == 1 and eng.metrics.snapshot()["retries"] == 0
+    assert set(lost) <= set(eng.unfinished())
+    assert eng._inflight is None
+    assert all(q.in_flight == 0 for q in eng._requests.values())
+    snap = eng.drain()
+    unfinished = snap["requests"]
+    assert unfinished and set(lost) <= set(unfinished)
+    for rid in unfinished:
+        assert snap["tree"][rid]["generated"].tolist() == delivered[rid]
+    # The snapshot resumes exactly on a fresh engine.
+    fresh = Engine(CFG, flat_params, num_slots=2, max_len=32,
+                   prefill_chunk=4, donate=True)
+    for kw in Engine.restore_requests(snap):
+        kw = dict(kw)
+        fresh.submit(kw.pop("prompt"), kw.pop("max_new_tokens"), **kw)
+    assert fresh.run() == "idle"
+    for rid, (p, n) in zip(rids, reqs):
+        got = (fresh if rid in unfinished else eng).result(rid).tolist()
+        assert got == _ref(flat_params, p, n).tolist()
+
+
+def test_router_failover_records_a_lost_step_and_resumes_exactly(
+        flat_params, monkeypatch):
+    """A failover asked for while the replica's step in flight is
+    doomed: the router lists the unfinished requests through the
+    engine's public ``unfinished()``, RECORDS the lost step in a
+    ``failover`` event, and every stream finishes on the survivor
+    token for token."""
+    from torchgpipe_tpu import fleet
+    from torchgpipe_tpu.serving import engine as engine_mod
+
+    class Recorder:
+        events = []
+
+        def record(self, kind, detail="", rid=None):
+            self.events.append((kind, detail))
+
+    router = fleet.Router(
+        {n: Engine(CFG, flat_params, num_slots=2, max_len=32,
+                   prefill_chunk=4, donate=True) for n in ("r0", "r1")},
+        recorder=Recorder(), seed=0,
+    )
+    reqs = [(p, 6) for p, _ in _workload(seed=9, n=3)]
+    rids = [router.submit(p, n, session="s") for p, n in reqs]
+    dying = router._records[rids[0]].replica
+    eng = router.replicas[dying].engine
+    while not (eng._inflight is not None and eng._inflight.released):
+        assert router.step()
+    doomed = eng._inflight.tok
+    real_wait = jax.block_until_ready
+
+    def wait(x):
+        if x is doomed:
+            raise RuntimeError("the chip is gone")
+        return real_wait(x)
+
+    monkeypatch.setattr(engine_mod.jax, "block_until_ready", wait)
+    moved = router.failover(dying)
+    assert moved and not router.replicas[dying].alive
+    lost = [d for k, d in Recorder.events
+            if k == "failover" and "step in flight was lost" in d]
+    assert len(lost) == 1 and "the chip is gone" in lost[0]
+    assert router.run() == "idle"
+    for rid, (p, n) in zip(rids, reqs):
+        assert router.result(rid).tolist() == _ref(flat_params, p, n).tolist()
+
+
+def _stepped(flat_params, serial, steps=9, **kw):
+    """An engine ``steps`` iterations into a workload: the overlapped
+    one with a step in flight, the serial reference with none."""
+    eng = Engine(CFG, flat_params, num_slots=2, max_len=48,
+                 prefill_chunk=4, donate=not serial, **kw)
+    reqs = _workload(seed=31, n=4, new_hi=9)
+    rids = [eng.submit(p, n + 4) for p, n in reqs]
+    for _ in range(steps):
+        eng.step()
+        if serial:
+            eng._settle()
+    assert (eng._inflight is None) == serial
+    return eng, rids, [(p, n + 4) for p, n in reqs]
+
+
+@pytest.mark.parametrize("what", ["cancel", "preempt", "drain", "swap",
+                                  "status"])
+def test_callers_see_the_serial_state_with_a_step_in_flight(flat_params,
+                                                            what):
+    """``cancel`` / ``preempt_request`` / ``drain`` + ``restore_requests``
+    / ``swap_params`` / ``status`` + ``result`` first settle the step in
+    flight: what they observe and return is what the serial loop
+    shows after the same steps."""
+    fast, rids, reqs = _stepped(flat_params, serial=False)
+    slow, _, _ = _stepped(flat_params, serial=True)
+    active = list(slow.scheduler.active)
+    assert active
+    rid = active[0]
+
+    def finish(eng, kwargs=()):
+        for kw in kwargs:
+            kw = dict(kw)
+            eng.submit(kw.pop("prompt"), kw.pop("max_new_tokens"), **kw)
+        eng.run()
+        return {r: eng.result(r).tolist() for r in rids
+                if r in eng._requests}
+
+    if what == "cancel":
+        assert fast.cancel(rid) and slow.cancel(rid)
+        assert fast._inflight is None
+        assert fast.result(rid).tolist() == slow.result(rid).tolist()
+        assert finish(fast) == finish(slow)
+    elif what == "preempt":
+        a, b = fast.preempt_request(rid), slow.preempt_request(rid)
+        assert a["prompt"].tolist() == b["prompt"].tolist()
+        assert a["emitted_prefix"] == b["emitted_prefix"]
+        assert a["max_new_tokens"] == b["max_new_tokens"]
+        got = finish(fast, [a])
+        assert got == finish(slow, [b])
+        want = dict(zip(rids, reqs))[rid]
+        assert got[rid] == _ref(flat_params, *want, max_len=48).tolist()
+    elif what == "drain":
+        a, b = fast.drain(), slow.drain()
+        assert a["requests"] == b["requests"]
+        assert {r: {k: v.tolist() for k, v in t.items()}
+                for r, t in a["tree"].items()} == {
+                    r: {k: v.tolist() for k, v in t.items()}
+                    for r, t in b["tree"].items()}
+        fresh = Engine(CFG, flat_params, num_slots=2, max_len=48,
+                       prefill_chunk=4)
+        done = finish(fresh, Engine.restore_requests(a))
+        for r, (p, n) in zip(rids, reqs):
+            got = done[r] if r in done else fast.result(r).tolist()
+            assert got == _ref(flat_params, p, n, max_len=48).tolist()
+    elif what == "swap":
+        fast.swap_params(flat_params, 1)
+        slow.swap_params(flat_params, 1)
+        assert fast._inflight is None and fast.version == 1
+        assert {r: fast.result(r).tolist() for r in rids} == {
+            r: slow.result(r).tolist() for r in rids}
+        assert finish(fast) == finish(slow)
+        assert fast.compile_stats == {"prefill": 1, "decode": 1}
+    else:
+        assert [fast.status(r) for r in rids] == [
+            slow.status(r) for r in rids]
+        assert fast._inflight is None
+        assert {r: fast.result(r).tolist() for r in rids} == {
+            r: slow.result(r).tolist() for r in rids}
+        assert fast.metrics.tokens_out == slow.metrics.tokens_out
+
+
+@pytest.mark.parametrize("donate", [True, False])
+def test_steps_launched_ahead_and_the_spans_that_say_so(flat_params, donate):
+    """On an uninterrupted run of an engine that donates its cache every
+    step but the first is launched while the one before it is in flight:
+    the counter reads steps - 1 and each ``engine.step`` span carries
+    ``ahead``.  An engine that can retry a failed step (``donate=False``)
+    waits for every step where it launches it: nothing is ever launched
+    ahead, and the pool is held twice, not three times.  The program set
+    is what it was either way."""
+    from torchgpipe_tpu.utils.tracing import Timeline
+
+    eng = Engine(CFG, flat_params, num_slots=3, max_len=32,
+                 prefill_chunk=4, donate=donate)
+    eng.timeline = Timeline()
+    for p, n in _workload(seed=2, n=5):
+        eng.submit(p, n)
+    assert eng.run() == "idle"
+    snap = eng.metrics.snapshot()
+    steps = [e for e in eng.timeline.events if e.name == "engine.step"]
+    assert len(steps) == snap["engine_steps"] > 8
+    ahead = snap["engine_steps"] - 1 if donate else 0
+    assert snap["steps_launched_ahead"] == ahead
+    assert [e.fields["ahead"] for e in steps] == (
+        [0] + [1] * ahead if donate else [0] * len(steps))
+    assert eng.compile_stats == {"prefill": 1, "decode": 1}
+    assert eng.program_count == 2
+    # A call that finds no action and nothing in flight is idle and
+    # leaves no span; the engine never reports idle with a step in
+    # flight.
+    before = len(eng.timeline.events)
+    assert eng.step() is False and eng._inflight is None
+    assert len(eng.timeline.events) == before
+
+
+# --------------------------------------------------------------------- #
 # soak (slow tier)                                                      #
 # --------------------------------------------------------------------- #
 

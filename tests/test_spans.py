@@ -41,9 +41,12 @@ def flat_params():
     return params
 
 
-def _engine(flat_params):
-    """A toy engine recording into a timeline of its own."""
-    eng = Engine(CFG, flat_params, num_slots=4, max_len=32, prefill_chunk=4)
+def _engine(flat_params, donate=True):
+    """A toy engine recording into a timeline of its own; it donates
+    its cache, as the benchmark's engines do, so it keeps a step in
+    flight."""
+    eng = Engine(CFG, flat_params, num_slots=4, max_len=32, prefill_chunk=4,
+                 donate=donate)
     eng.timeline = Timeline()
     return eng
 
@@ -127,8 +130,11 @@ def test_default_timeline_is_one_bounded_ring():
 # --------------------------------------------------------------------- #
 
 
-def test_engine_step_spans_cover_the_step(flat_params):
-    events = _serve(_engine(flat_params))
+@pytest.mark.parametrize("donate", [True, False])
+def test_engine_step_spans_cover_the_step(flat_params, donate):
+    eng = _engine(flat_params, donate)
+    events = _serve(eng)
+    eng_tokens = eng.metrics.tokens_out
     by_seq = {e.seq: e for e in events}
     children = {}
     for e in events:
@@ -143,16 +149,28 @@ def test_engine_step_spans_cover_the_step(flat_params):
         assert ("g" in action.fields) == (action.name == "engine.prefill")
         leaves = [e.name for e in sorted(children[action.seq],
                                          key=lambda e: e.seq)]
-        assert [n for n in leaves if n != "engine.fetch"] == [
-            "engine.build", "engine.dispatch", "engine.emit"]
-        emit = children[action.seq][-1]
-        # A decode step always fetches; a prefill step only where a
-        # prompt completes (and emits its first token).
-        fetched = "engine.fetch" in leaves
-        assert fetched == (action.name == "engine.decode"
-                           or emit.fields["tokens"] > 0)
+        # Build and launch this step's program; then, where a step was
+        # in flight (``ahead``), wait for THAT step and deliver its
+        # tokens under the one ``engine.emit`` that also advances the
+        # books by this step's counts.
+        ahead = step.fields["ahead"]
+        assert ahead == (donate and step is not steps[0])
+        want = ["engine.build", "engine.dispatch"]
+        want += ["engine.fetch"] if ahead else []
+        want += ["engine.emit"]
+        # The run's last step leaves nothing to launch behind it, so it
+        # is waited for and delivered in its own iteration; so is every
+        # step of an engine that can retry (``donate=False``), which has
+        # waited for it inside ``engine.dispatch`` already.
+        own = step is steps[-1] or not donate
+        want += ["engine.fetch", "engine.emit"] if own else []
+        assert leaves == want
         seen.add(action.name)
     assert seen == set(ACTIONS)
+    # Every token was delivered under some ``engine.emit``.
+    assert sum(e.fields["tokens"] for e in events
+               if e.name == "engine.emit") == eng_tokens
+    assert not any(e.name == "engine.settle" for e in events)
     for e in events:                  # every child lies inside its parent
         if e.parent != -1:
             p = by_seq[e.parent]

@@ -83,28 +83,31 @@ def _drive_signatures(
             "n_valid": n_valid, "key": key,
         }))
         # Token 0 for every slot: requests terminate by budget.  Same
-        # output arity as the real body; the engine adopts the advanced
-        # frontiers as its device-resident lengths.
+        # output arity as the real body (``tokens`` is the device token
+        # vector ``[num_slots]``, returned with the samples written in);
+        # the engine adopts the advanced frontiers as its
+        # device-resident lengths.
         return jnp.zeros((S,), jnp.int32), cache, lengths + n_valid, key
 
     def chunk_stub(kind):
         # The chunk body's arity (Engine._prefill_body_for): the
         # compact prefill programs take the rows' ``slots``, the
         # speculative verify program is called with ``slots=None``.
-        def fn(params, cache, lengths, slots, tokens, n_valid, key):
+        def fn(params, cache, lengths, cur_tok, slots, tokens, n_valid,
+               finish, key):
             args = {
                 "cache": cache, "lengths": lengths, "tokens": tokens,
                 "n_valid": n_valid, "key": key,
             }
             if slots is not None:
-                args["slots"] = slots
+                args.update(slots=slots, cur_tok=cur_tok, finish=finish)
                 lengths = lengths.at[slots].add(n_valid)
             else:
                 lengths = lengths + n_valid
             sigs.setdefault(kind, set()).add(_signature(args))
             tok = jnp.zeros(tokens.shape[:1], jnp.int32)
             grid = jnp.zeros(tokens.shape, jnp.int32)
-            return tok, grid, cache, lengths, key
+            return tok, grid, cache, lengths, cur_tok, key
         return fn
 
     def copy_stub(cache, src, dst, n):
@@ -521,11 +524,7 @@ def lint_serving(
     base_sig = {kind: _signature(spec) for kind, spec in base.items()}
     buckets = tuple(getattr(engine, "prefill_buckets",
                             (engine.prefill_chunk,)))
-    if (
-        len(buckets) == 1
-        and "decode" in base_sig
-        and base_sig.get("prefill") == base_sig["decode"]
-    ):
+    if buckets == (1,) and "decode" in base_sig:
         findings.append(Finding(
             rule="serving-program-split",
             severity=Severity.WARNING,
@@ -659,14 +658,17 @@ def lint_serving(
                 )(spec["cache"], spec["lengths"], spec["tokens"],
                   spec["n_valid"], spec["key"])
             else:
-                # A chunk program: compact prefill (``slots [R]``) or
-                # the pool-wide verify (no ``slots`` in its spec).
+                # A chunk program: compact prefill (``slots [R]``, the
+                # device token vector and the rows that complete a
+                # prompt) or the pool-wide verify (none of the three in
+                # its spec).
                 traced = jax.make_jaxpr(
-                    lambda c, l, s, t, n, k, _fn=fn: _fn(
-                        engine.params, c, l, s, t, n, k
+                    lambda c, l, ct, s, t, n, f, k, _fn=fn: _fn(
+                        engine.params, c, l, ct, s, t, n, f, k
                     )
-                )(spec["cache"], spec["lengths"], spec.get("slots"),
-                  spec["tokens"], spec["n_valid"], spec["key"])
+                )(spec["cache"], spec["lengths"], spec.get("cur_tok"),
+                  spec.get("slots"), spec["tokens"], spec["n_valid"],
+                  spec.get("finish"), spec["key"])
         except Exception as exc:  # noqa: BLE001 — converted to a finding
             findings.append(Finding(
                 rule="serving-trace",

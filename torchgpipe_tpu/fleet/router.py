@@ -703,14 +703,18 @@ class Router:
 
     def _unfinished_on(self, name: str) -> List[str]:
         eng = self.replicas[name].engine
-        return [
-            r.rid
-            for r in (*eng.scheduler.queue,
-                      *eng.scheduler.active.values(),
-                      # migration-parked work (prefill role; absent on
-                      # policy-test engine facades)
-                      *getattr(eng, "_migration_ready", ()))
-        ]
+        try:
+            return eng.unfinished()
+        except Exception as err:  # noqa: BLE001 — recorded, failover goes on
+            # The replica's step in flight died with it.  The engine has
+            # put that step's requests back among its active ones, so
+            # the second listing is whole.
+            self._record_event(
+                "failover",
+                detail=f"{name}: the step in flight was lost "
+                       f"({type(err).__name__}: {err})",
+            )
+            return eng.unfinished()
 
     def _resubmit(self, kwargs: List[Dict[str, Any]]) -> None:
         for kw in kwargs:

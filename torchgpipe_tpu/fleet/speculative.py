@@ -144,7 +144,7 @@ class SpeculativeEngine(Engine):
             draft_cfg, self.pool.num_slots, self.pool.max_len
         )
         # Device-resident draft frontier, the draft twin of the base
-        # engine's _lengths_for_step/_commit_lengths: consecutive draft
+        # engine's _lengths_for_step / _adopt: consecutive draft
         # dispatches re-feed the compiled step's own advanced lengths
         # array instead of re-uploading the host mirror; only the
         # per-round rollback (and slot recycling) invalidates it.
@@ -317,7 +317,13 @@ class SpeculativeEngine(Engine):
             ))
         return parts[0] if len(parts) == 1 else np.concatenate(parts)
 
-    def _run_decode(self) -> None:
+    def _run_decode(self) -> bool:
+        # The accepted length — a comparison of token VALUES — sets
+        # every row's frontier and the next draft's inputs, so a round
+        # is never launched ahead: it settles the step in flight (a
+        # prefill step, launched ahead by the base engine) before it
+        # builds, and delivers its own tokens itself, at depth 0.
+        self._settle()
         reqs = self.scheduler.decode_ready()
         S = self.pool.num_slots
         gamma = self.gamma
@@ -367,10 +373,10 @@ class SpeculativeEngine(Engine):
             v_tokens[s, 0] = self._cur_tok[s]
             v_tokens[s, 1:gamma + 1] = proposals[s]
             v_valid[s] = gamma + 1
-        _tok, grid, cache, _lengths_dev, key = self._dispatch(
+        _tok, grid, cache, _lengths_dev, _cur, key = self._dispatch(
             self._verify_fn, self.params, self.pool.cache,
-            self._lengths_for_step(), None, jnp.asarray(v_tokens),
-            jnp.asarray(v_valid), self._key,
+            self._lengths_for_step(), None, None, jnp.asarray(v_tokens),
+            jnp.asarray(v_valid), None, self._key,
         )
         self.pool.cache = cache
         self._key = key
@@ -417,6 +423,7 @@ class SpeculativeEngine(Engine):
                 if r.status != "active":
                     break       # budget/eos hit mid-round: drop the rest
                 self._emit(r, tok)
+        return False
 
 
 __all__ = ["SpeculativeEngine"]

@@ -20,7 +20,37 @@ bucket ladder (``prefill_chunk=(1, 2, 4, 8)``; certified by
   sample their FIRST token from the chunk's last-valid-position logits
   (so prefill and decode share one sampling site semantics-wise);
 * **decode** — ``decode_slots`` at ``g = 1`` over the whole pool: one
-  token per occupied slot, each at its own position.
+  token per occupied slot, each at its own position.  The tokens it
+  consumes are the previous programs' sampled tokens, and they never
+  leave the device for it: the engine holds a device vector of every
+  slot's current token, the decode program returns it with its samples
+  written at the rows that ran, and the prefill program writes a first
+  token into it where a prompt completes.
+
+One step in flight.  Nothing the host does between two steps needs the
+VALUE of a token but handing it to ``on_token`` and comparing it with
+``eos_id``: which rows run, ``n_valid``, how far ``pool.lengths`` /
+``prefilled`` advance, who ends by length, which slot frees and who is
+admitted into it are functions of counts known at launch.  So a step's
+bookkeeping is split in two — the ADVANCE, from counts, when the step is
+launched; the DELIVERY, when its tokens are on the host — and
+:meth:`Engine.step` launches step ``k+1`` before it waits for step
+``k``'s tokens: the device runs ``k+1`` while the host delivers ``k``
+and builds ``k+2``.  Whatever observes or changes a request from outside
+(``result``, ``status``, ``cancel``, ``drain``, ...) first settles the
+step in flight, so a caller sees the state a strictly serial loop shows.
+A request that ends by EOS at step ``k`` has a row in step ``k+1``
+already: that row's token is discarded at delivery — it is not a token
+(never appended, streamed or counted).  There is no switch: a step is
+launched ahead exactly when its inputs are known without the tokens and
+nothing has to be known of the step before it.  Who needs the tokens
+first (speculative decoding's acceptance, a prefill-role engine's
+hand-off, QoS preemption under pressure) settles first and so runs the
+same code at depth 0; so does an engine that promises to RETRY a failed
+step (``donate=False``): the retry needs the step's inputs alive until
+the step is known good, a step launched behind it would hold a third
+KV pool beside them, so such an engine waits for every step where it
+launches it, as it always did, and keeps two.
 
 Request arrival, completion, cancellation, drain — all of it changes
 only the VALUES of ``slots`` / ``tokens`` / ``lengths`` / ``n_valid`` /
@@ -44,6 +74,7 @@ tested).
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -74,11 +105,29 @@ from torchgpipe_tpu.utils.tracing import default_timeline
 Pytree = Any
 
 
+@dataclasses.dataclass
+class _Launched:
+    """One compiled step between its launch and its delivery."""
+
+    kind: str                       # "prefill" | "decode"
+    tok: Any                        # device vector holding the samples
+    counts: Any                     # held experts' token counts, or None
+    positions: int                  # positions the step computed
+    # (request, row of ``tok``) of every row that sampled a token.
+    rows: List[Tuple[Request, int]]
+    t0: float                       # recorder clock at launch
+    chunks: List[Tuple[str, int, int]] = dataclasses.field(
+        default_factory=list)       # prefill: (rid, g, take) for the recorder
+    # Requests that ended BY LENGTH when this step was launched: out of
+    # the scheduler, their last token still on the device.
+    released: List[Request] = dataclasses.field(default_factory=list)
+
+
 def _start_host_copy(arr: Any) -> None:
     """Begin an ASYNC device→host copy of ``arr`` (best-effort: not
     every backend/array exposes it).  The engine calls this right after
-    a step so the sampled-token transfer rides under the host-side
-    bookkeeping between dispatch and the blocking ``np.asarray``."""
+    a launch so the sampled-token transfer starts the moment the program
+    ends, not when the host comes to wait for it."""
     start = getattr(arr, "copy_to_host_async", None)
     if start is not None:
         try:
@@ -216,14 +265,12 @@ class Engine:
         # Expert layers that are told what they hold (``MoEConfig.held``)
         # report the tokens each held expert received: the step programs
         # then return, in the place of their sampled tokens, the pair
-        # ``(tokens, int32 [expert layers, held])``.  The counts are
-        # read where the tokens are fetched and never on their own
-        # account: a step that fetches nothing leaves its counts here
-        # for the next fetch (``read_expert_counts``).
+        # ``(tokens, int32 [expert layers, held])``.  The counts ride on
+        # the step's record and are read where the host waits for that
+        # step, never on their own account (``read_expert_counts``).
         self._expert_counts = (
             moe is not None and getattr(moe, "held", None) is not None
         )
-        self._unread_counts: List[Tuple[str, int, Any]] = []
         # ``prefill_chunk`` may be an int (one prefill program — the
         # classic configuration) or a LADDER of chunk sizes (e.g.
         # ``(1, 2, 4, 8)``): one program per bucket, a prefill step
@@ -352,7 +399,15 @@ class Engine:
         # step touches them) but still holding their slot — the KV rows
         # ARE the migration payload, freed by :meth:`complete_migration`.
         self._migration_ready: List[Request] = []
+        # Every slot's current token.  The DEVICE vector is what the
+        # step programs read and write (``None``: upload the mirror);
+        # the host mirror is filled at delivery and is what ``drain`` /
+        # ``preempt_request`` / speculative decoding read.
         self._cur_tok = np.zeros((num_slots,), np.int32)
+        self._tok_dev: Optional[jnp.ndarray] = None
+        # The step in flight: launched, advanced, not yet delivered.
+        self._inflight: Optional[_Launched] = None
+        self._delivering = False
         # Device-resident slot frontiers: the compiled steps RETURN the
         # advanced lengths vector, so steady-state decode re-feeds the
         # previous step's output instead of uploading the host mirror
@@ -421,13 +476,14 @@ class Engine:
         ``n_valid`` / ``tok`` / ``grid`` have ``R`` rows); called with
         ``slots=None`` it is the pool-wide form, one row a slot — the
         shape ``fleet.SpeculativeEngine`` jits as its verify program
-        (its rows ARE most of the pool).  The plain engine never calls
-        that form, so never compiles it."""
+        (its rows ARE most of the pool; it passes ``cur_tok`` and
+        ``finish`` as ``None``: its tokens are the host's to accept).
+        The plain engine never calls that form, so never compiles it."""
         cfg, moe = self.cfg, self.moe
         counts = self.trace_counts
 
-        def prefill_body(params, cache, lengths, slots, tokens, n_valid,
-                         key):
+        def prefill_body(params, cache, lengths, cur_tok, slots, tokens,
+                         n_valid, finish, key):
             counts[name] += 1
             # ``lengths`` comes back advanced ON DEVICE (at ``slots``,
             # by the rows each consumed): the next step reuses the
@@ -447,20 +503,36 @@ class Engine:
             # for speculative decoding's verify pass the grid is the
             # acceptance oracle (fleet/speculative.py).
             grid = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (tok, *held) if held else tok, grid, cache, lengths, key
+            if cur_tok is not None:
+                # A row whose prompt completes here (``finish``: the
+                # host knows it from counts) hands its first token to
+                # its slot's entry of the device token vector, where
+                # the next decode step reads it; every other row's
+                # index is out of range and dropped.
+                dst = jnp.where(finish, slots, cur_tok.shape[0])
+                cur_tok = cur_tok.at[dst].set(tok, mode="drop")
+            return ((tok, *held) if held else tok, grid, cache, lengths,
+                    cur_tok, key)
         return prefill_body
 
     def _build_programs(self) -> None:
         cfg, moe = self.cfg, self.moe
         counts = self.trace_counts
 
-        def decode_body(params, cache, lengths, tokens, n_valid, key):
+        def decode_body(params, cache, lengths, cur_tok, n_valid, key):
             counts["decode"] += 1
+            # ``cur_tok [num_slots]`` is the device token vector: the
+            # rows that run consume their entry (the others a 0, as a
+            # host-built buffer would hold) and get their sample
+            # written back; the vector goes on to the next step.
+            live = n_valid > 0
+            tokens = jnp.where(live, cur_tok, 0)[:, None]
             logits, cache, lengths, *held = decode_slots(
                 cfg, params, tokens, cache, lengths, n_valid, moe=moe,
                 expert_counts=self._expert_counts,
             )
             tok, key = self._sample_row(logits[:, 0], key)
+            tok = jnp.where(live, tok, cur_tok)
             return (tok, *held) if held else tok, cache, lengths, key
 
         donate = (1,) if self.donate else ()
@@ -547,8 +619,11 @@ class Engine:
             "key": sds(self._key.shape, self._key.dtype),
         }
         # ``tokens`` / ``n_valid`` have the program's rows: the pool's
-        # ``num_slots`` for decode, ``R`` for the compact prefill
-        # programs, which also take the rows' slot indices.
+        # ``num_slots`` for decode, whose ``tokens`` IS the device
+        # token vector; ``R`` for the compact prefill programs, which
+        # also take the rows' slot indices, the token vector
+        # (``cur_tok``) and the rows that complete a prompt
+        # (``finish``).
         specs = {
             kind: dict(
                 common, tokens=sds(shape, np.int32),
@@ -556,9 +631,13 @@ class Engine:
             )
             for kind, shape in self._token_shapes.items()
         }
+        if "decode" in specs:
+            specs["decode"]["tokens"] = sds((S,), np.int32)
         for name in self._prefill_names.values():
-            specs[name]["slots"] = sds(
-                self._token_shapes[name][:1], np.int32
+            rows = self._token_shapes[name][:1]
+            specs[name].update(
+                slots=sds(rows, np.int32), finish=sds(rows, np.bool_),
+                cur_tok=sds((S,), np.int32),
             )
         if self._prefix_copy_fn is not None:
             scalar = sds((), np.int32)
@@ -611,32 +690,44 @@ class Engine:
             self._lengths_shadow = np.array(self.pool.lengths, copy=True)
         return self._lengths_dev
 
-    def _commit_lengths(self, lengths_dev: jnp.ndarray,
-                        n_valid: np.ndarray) -> None:
-        """Adopt the step's advanced device frontiers and mirror the
-        advance into the shadow (the engine's own per-row host
-        bookkeeping applies the same ``+= n_valid`` to
-        ``pool.lengths``, so the compare in :meth:`_lengths_for_step`
-        keeps matching until something OTHER than a step mutates it)."""
-        self._lengths_dev = lengths_dev
-        self._lengths_shadow = self._lengths_shadow + n_valid
+    def _tokens_for_step(self) -> jnp.ndarray:
+        """The device token vector for the next compiled step: the last
+        step's output, or one upload of the host mirror where something
+        other than a step wrote a slot's token (``ingest_migration``,
+        which settles first, so the mirror is whole)."""
+        if self._tok_dev is None:
+            self._tok_dev = jnp.asarray(self._cur_tok.copy())
+        return self._tok_dev
 
-    def _dispatch(self, fn: Callable[..., Tuple], *args: Any) -> Tuple:
-        """Run a compiled step under the transient-retry policy (the
+    def _dispatch(self, fn: Callable[..., Tuple], *args: Any,
+                  wait: bool = True) -> Tuple:
+        """Call a compiled program under the transient-retry policy (the
         serving twin of StepGuard's retry half; inputs are not donated
         unless ``donate=True``, in which case retry is impossible and
-        transient errors re-raise immediately)."""
+        transient errors re-raise immediately).
+
+        jit dispatch is ASYNC: a device-execution failure surfaces on
+        materialization.  With ``wait`` the result is materialized HERE,
+        under the retry, and the caller commits it only after this
+        returns: A FAILED STEP'S ARRAYS ARE NEVER LEFT IN THE POOL.
+        Every program of an engine that can retry (``donate=False``) is
+        called so, the two step programs included — the pool then holds
+        the pre-step arrays whenever this raises, and the host's books
+        have not moved.  ``wait=False`` is for the step programs of a
+        DONATING engine alone (:meth:`_launch`): their inputs are
+        consumed by the call, so there is no pre-step state to keep, a
+        retry is impossible wherever the failure is met, and it is met
+        where the host waits for the step (:meth:`_wait`), one step
+        later — the step in flight is dropped (:meth:`_abandon`), the
+        error re-raises, and the engine is good for ``drain()`` (a
+        host-side snapshot, the router's failover) and nothing else,
+        as it was when a donated step failed under this call."""
         attempt = 0
         with self.timeline.span("engine.dispatch"):
             while True:
                 try:
-                    # jit dispatch is ASYNC: a device-execution failure
-                    # surfaces on materialization, so block here —
-                    # letting it escape to the caller's host fetch would
-                    # skip the retry AND commit the failed step's arrays
-                    # to the pool first.  Free in practice: the engine
-                    # host-fetches the step's tokens immediately anyway.
-                    return jax.block_until_ready(fn(*args))
+                    out = fn(*args)
+                    return jax.block_until_ready(out) if wait else out
                 except Exception as err:  # noqa: BLE001 — classified below
                     if (
                         self.donate
@@ -677,6 +768,7 @@ class Engine:
         are bitwise what a fresh engine cold-started on ``params``
         produces — the ``rollout-verify`` gate.
         """
+        self._settle()
         new = list(params)
         _split_params(self.cfg, new)    # validates the per-layer list
 
@@ -802,6 +894,7 @@ class Engine:
             raise ValueError(f"duplicate request id {rid!r}")
 
     def cancel(self, rid: str) -> bool:
+        self._settle()
         ok = self.scheduler.cancel(rid)
         if ok:
             self.metrics.finished(rid, status="cancelled")
@@ -812,9 +905,11 @@ class Engine:
     def result(self, rid: str) -> np.ndarray:
         """All tokens request ``rid`` has produced so far (across a
         drain/resume), as ``np.int32 [n]``."""
+        self._settle()
         return np.asarray(self._requests[rid].tokens(), np.int32)
 
     def status(self, rid: str) -> str:
+        self._settle()
         return self._requests[rid].status
 
     # ------------------------------------------------------------------ #
@@ -822,9 +917,17 @@ class Engine:
     # ------------------------------------------------------------------ #
 
     def step(self) -> bool:
-        """ONE engine iteration: admit, pick a phase, run its compiled
-        program, emit/evict.  Returns False when idle (nothing ran)."""
+        """ONE engine iteration: admit, pick a phase, build its inputs
+        from the ADVANCED state, launch its compiled program — and only
+        then wait for the PREVIOUS step's tokens and deliver them, so
+        the device runs this step while the host hands out the last
+        one's tokens and builds the next (the module docstring, "One
+        step in flight").  A call that finds no action but a step in
+        flight settles it (under an ``engine.settle`` span: an
+        ``engine.step`` span is one launched program) and returns True;
+        False means idle, and then nothing is in flight."""
         tl = self.timeline
+        action = None
         with tl.span("engine.step") as step_span:
             with tl.span("engine.admit") as admit_span:
                 admitted = 0
@@ -849,23 +952,34 @@ class Engine:
                         admitted += 1
                 action = self.scheduler.next_action()
                 if action is None:
-                    # Idle: the timeline holds only iterations that ran
-                    # a program.
+                    # The timeline holds ``engine.step`` only for
+                    # iterations that launched a program.
                     admit_span.drop()
                     step_span.drop()
-                    return False
-                tl.annotate(admitted=admitted)
-            # The action's span opens as soon as the action is known,
-            # before its program runs; ``_run_*`` add ``rows`` (and
-            # ``g``) and record build / dispatch / fetch / emit under it.
-            with tl.span("engine." + action):
-                if action == "prefill":
-                    self._run_prefill()
                 else:
-                    self._run_decode()
-                if self.reporter is not None:
-                    with tl.span("engine.emit"):
-                        self.reporter.step()
+                    tl.annotate(admitted=admitted)
+            if action is not None:
+                # The action's span opens as soon as the action is
+                # known, before its program runs; ``_run_*`` add ``rows``
+                # (and ``g``) and record build / dispatch / fetch / emit
+                # under it.
+                with tl.span("engine." + action):
+                    if action == "prefill":
+                        ahead = self._run_prefill()
+                    else:
+                        ahead = self._run_decode()
+                    if self.reporter is not None:
+                        with tl.span("engine.emit"):
+                            self.reporter.step()
+                # ``ahead``: the program was launched while the step
+                # before it was still in flight.
+                tl.annotate(ahead=int(ahead))
+        if action is not None:
+            return True
+        if self._inflight is None:
+            return False
+        with tl.span("engine.settle"):
+            self._settle()
         return True
 
     def _preempt_for_pressure(self) -> None:
@@ -882,6 +996,12 @@ class Engine:
             return
         if sched.pool.num_free > 0 and len(sched.active) < sched.max_active:
             return      # admission can proceed — nothing to yield
+        # Whether admission is blocked, and by whom, depends on who has
+        # just ended by EOS and on what the tenants have spent: token
+        # values.  Under pressure the step in flight is settled first.
+        self._settle()
+        if sched.pool.num_free > 0 and len(sched.active) < sched.max_active:
+            return
         from torchgpipe_tpu.serving.qos import TIER_PRIORITY
 
         want = min(
@@ -910,6 +1030,7 @@ class Engine:
         ``emitted_prefix`` extended — exactly the drain/restore schema,
         per-request.  Greedy decode is prefix-deterministic, so the
         resumed stream is bitwise the unpreempted one."""
+        self._settle()
         req = self.scheduler.active.get(rid)
         if req is None:
             raise ValueError(
@@ -963,6 +1084,12 @@ class Engine:
         if m <= 0 or donor is None:
             return
         assert req.slot is not None
+        # The copy is ordered on the device behind the step in flight,
+        # and the donor's books were advanced when that step was
+        # launched; it is waited for HERE, under its own retry, so the
+        # step in flight is settled first (a failure of that step is
+        # met at its own wait, not inside the copy's).
+        self._settle()
         t0 = self._rec_clock()
         new_cache = self._dispatch(
             self._prefix_copy_fn, self.pool.cache,
@@ -976,7 +1103,7 @@ class Engine:
                   dur=max(self._rec_clock() - t0, 0.0),
                   detail=f"reused={m} donor_slot={donor}")
 
-    def _run_prefill(self) -> None:
+    def _run_prefill(self) -> bool:
         tl = self.timeline
         pending = self.scheduler.prefill_pending()
         # The compact step: the first R pending prompts in admission
@@ -997,7 +1124,9 @@ class Engine:
             # (slot 0, n_valid 0: they write and advance nothing).
             slots = np.zeros((tokens.shape[0],), np.int32)
             n_valid = np.zeros((tokens.shape[0],), np.int32)
-            finishing = 0
+            # Rows whose prompt completes in this step: they sample
+            # their first token (known from counts, not from a token).
+            finish = np.zeros((tokens.shape[0],), np.bool_)
             for i, r in enumerate(reqs):
                 take = min(g, r.prompt_len - r.prefilled)
                 tokens[i, :take] = (
@@ -1005,52 +1134,37 @@ class Engine:
                 )
                 slots[i] = r.slot
                 n_valid[i] = take
-                finishing += r.prefilled + take >= r.prompt_len
+                finish[i] = r.prefilled + take >= r.prompt_len
             lengths_in = self._lengths_for_step()
-            slots_dev = jnp.asarray(slots)
-            tokens_dev = jnp.asarray(tokens)
-            n_valid_dev = jnp.asarray(n_valid)
+            args = [
+                self.params, self.pool.cache, lengths_in,
+                self._tokens_for_step(), jnp.asarray(slots),
+                jnp.asarray(tokens), jnp.asarray(n_valid),
+                jnp.asarray(finish), self._key,
+            ]
         attended = self._attended(self.pool.lengths[slots], n_valid, g)
         tl.annotate(rows_read=attended[0], rows_cap=attended[1])
-        t0 = self._rec_clock()
-        tok, _grid, cache, lengths_dev, key = self._dispatch(
-            self._prefill_fns[name], self.params, self.pool.cache,
-            lengths_in, slots_dev, tokens_dev, n_valid_dev, self._key,
+        step = self._launch(
+            "prefill", self._prefill_fns[name], args,
+            rows=[(r, i) for i, r in enumerate(reqs) if finish[i]],
+            positions=int(n_valid.sum()),
         )
-        self.pool.cache = cache
-        self._key = key
-        dur = max(self._rec_clock() - t0, 0.0)
-        if isinstance(tok, tuple):
-            tok = self._queue_expert_counts("prefill", n_valid.sum(), tok)
-        if finishing:
-            # Start the device→host token copy NOW; the subclass hook
-            # below runs while it is in flight (copy_to_host_async is a
-            # hint — np.asarray below is the one materialization point).
-            _start_host_copy(tok)
+        if self.recorder is not None:
+            step.chunks = [
+                (r.rid, g, int(take)) for r, take in zip(reqs, n_valid)
+            ]
         # Subclass hook: speculative decoding mirrors every prefill
         # chunk into its draft model's cache (same bucket, same rows)
         # so draft and target stay frontier-aligned.
         self._after_prefill_dispatch(g, slots, tokens, n_valid)
-        tok_host: Optional[np.ndarray] = None
-        if finishing:
-            # ONE host fetch per step, and only in a step in which a
-            # prompt completes and samples its first token.
-            with tl.span("engine.fetch"):
-                tok_host = np.asarray(tok)              # [R]: row order
-                load = self.read_expert_counts()
-            if load:
-                tl.annotate(**load)
-        with tl.span("engine.emit", tokens=finishing):
-            if self.recorder is not None:
-                for r, take in zip(reqs, n_valid):
-                    self._rec("req_prefill", r.rid, dur=dur,
-                              detail=f"g={g} take={int(take)}")
-            advance = np.zeros((self.pool.num_slots,), np.int32)
-            advance[slots[:len(reqs)]] = n_valid[:len(reqs)]
-            self._commit_lengths(lengths_dev, advance)
+
+        def advance(ahead: bool) -> None:
+            shadow = np.zeros((self.pool.num_slots,), np.int32)
+            shadow[slots[:len(reqs)]] = n_valid[:len(reqs)]
+            self._lengths_shadow = self._lengths_shadow + shadow
             self.metrics.step(
                 "prefill", len(reqs), cap, deferred=deferred,
-                attended=attended,
+                attended=attended, ahead=ahead,
             )
             for i, r in enumerate(reqs):
                 take = int(n_valid[i])
@@ -1058,45 +1172,249 @@ class Engine:
                 r.prefilled += take
                 if r.prefill_done:
                     if self._prefix_cache is not None:
-                        # The slot now holds the full prompt's KV: it
-                        # becomes a donor (the insert pins it via the
-                        # pool refcounts, so recycling waits for
-                        # eviction).
+                        # The slot holds the full prompt's KV once this
+                        # step has run, and whatever copies from it runs
+                        # behind this step on the device: it becomes a
+                        # donor (the insert pins it via the pool
+                        # refcounts, so recycling waits for eviction).
                         self._prefix_cache.insert(
                             r.prompt, r.slot, self.pool
                         )
-                    assert tok_host is not None
-                    self._emit(r, int(tok_host[i]))
+                    self._advance_token(step, r)
 
-    def _queue_expert_counts(self, kind: str, positions: int,
-                             out: Tuple[Any, Any]) -> Any:
-        """Split a step program's ``(tokens, counts)``: the tokens are
-        returned, the held experts' token counts ``int32 [expert layers,
-        held]`` start their copy to the host and wait for the next
-        fetch of tokens."""
-        tok, counts = out
-        _start_host_copy(counts)
-        self._unread_counts.append((kind, int(positions), counts))
-        return tok
+        return self._after_launch(step, advance)
+
+    def _run_decode(self) -> bool:
+        tl = self.timeline
+        reqs = self.scheduler.decode_ready()
+        tl.annotate(rows=len(reqs))
+        with tl.span("engine.build"):
+            n_valid = np.zeros((self.pool.num_slots,), np.int32)
+            for r in reqs:
+                n_valid[r.slot] = 1
+            lengths_in = self._lengths_for_step()
+            # The rows' input tokens are the device token vector's
+            # entries: the last programs' samples, never fetched for
+            # this.
+            args = [
+                self.params, self.pool.cache, lengths_in,
+                self._tokens_for_step(), jnp.asarray(n_valid), self._key,
+            ]
+        attended = self._attended(self.pool.lengths, n_valid, 1)
+        tl.annotate(rows_read=attended[0], rows_cap=attended[1])
+        step = self._launch(
+            "decode", self._decode_fn, args,
+            rows=[(r, r.slot) for r in reqs], positions=len(reqs),
+        )
+
+        def advance(ahead: bool) -> None:
+            self._lengths_shadow = self._lengths_shadow + n_valid
+            self.metrics.step(
+                "decode", len(reqs), self.pool.num_slots,
+                attended=attended, ahead=ahead,
+            )
+            for r in reqs:
+                self.pool.lengths[r.slot] += 1
+                self._advance_token(step, r)
+
+        return self._after_launch(step, advance)
+
+    # ------------------------------------------------------------------ #
+    # launch / advance / wait / deliver                                  #
+    # ------------------------------------------------------------------ #
+
+    def _launch(self, kind: str, fn: Callable[..., Tuple], args: List[Any],
+                *, rows: List[Tuple[Request, int]],
+                positions: int) -> _Launched:
+        """Launch a step program and adopt its outputs.  A donating
+        engine does not wait (``jit`` returns at once): the next step is
+        built on the outputs, on the device, whether or not this one
+        has run yet.  An engine that can retry waits HERE, under the
+        retry (:meth:`_dispatch`), and adopts what is known good."""
+        step = _Launched(
+            kind=kind, tok=None, counts=None, positions=positions,
+            rows=rows, t0=self._rec_clock(),
+        )
+        self._adopt(step, self._dispatch(fn, *args, wait=not self.donate))
+        return step
+
+    def _adopt(self, step: _Launched, out: Tuple) -> None:
+        """Take a launched step's outputs as the engine's device state
+        and the step's token vector (and expert counts) as what its
+        delivery reads."""
+        if step.kind == "prefill":
+            tok, _grid, cache, lengths, cur_tok, key = out
+        else:
+            tok, cache, lengths, key = out
+        if isinstance(tok, tuple):
+            tok, step.counts = tok
+            _start_host_copy(step.counts)
+        if step.kind == "decode":
+            cur_tok = tok       # the vector itself, samples written in
+        step.tok = tok
+        if step.rows:
+            # Start the device→host token copy NOW: it runs the moment
+            # the program ends (a hint; ``_wait`` materializes).
+            _start_host_copy(tok)
+        self.pool.cache = cache
+        self._lengths_dev = lengths
+        self._tok_dev = cur_tok
+        self._key = key
+
+    def _advance_token(self, step: _Launched, req: Request) -> None:
+        """``req`` samples a token in ``step``: count it, and end the
+        request BY LENGTH here, at launch — its slot frees NOW, the
+        iteration-level eviction continuous batching is made of — where
+        that token is its last.  The request stays ``active`` until the
+        token is delivered."""
+        req.in_flight += 1
+        if req.remaining_launches <= 0:
+            step.released.append(req)
+            self.scheduler.release(req)
+
+    def _after_launch(self, step: _Launched,
+                      advance: Callable[[bool], None]) -> bool:
+        """The second half of an iteration, behind the launch of
+        ``step``: wait for the step launched BEFORE it and fetch its
+        tokens (``engine.fetch``: the one place the host waits on the
+        device), then, under ``engine.emit``, advance the engine's books
+        by ``step``'s counts and deliver the earlier step's tokens.
+        Returns whether ``step`` was launched ahead (another step was
+        in flight)."""
+        prev, self._inflight = self._inflight, None
+        tok_host = self._wait(prev, step) if prev is not None else None
+        self._inflight = step
+        with self.timeline.span("engine.emit"):
+            advance(prev is not None)
+            delivered = (
+                self._deliver(prev, tok_host) if prev is not None else 0
+            )
+            self.timeline.annotate(tokens=delivered)
+        if self._settles_now(step):
+            self._settle()
+        return prev is not None
+
+    def _settles_now(self, step: _Launched) -> bool:
+        """Whether ``step`` is delivered in its own iteration: the
+        engine has waited for it already (``donate=False``: the retry,
+        :meth:`_launch`), nothing is left to launch behind it (so
+        ``scheduler.idle`` implies that nothing is in flight), or a
+        prefill-role engine completed a prompt in it — parking a request
+        for the decode pool is a delivery-time act (the hand-off carries
+        the first token), and ``take_migration_ready`` must see it
+        after this iteration."""
+        return not self.donate or self.scheduler.idle or (
+            self.role == "prefill" and bool(step.rows)
+        )
+
+    def _wait(self, step: _Launched,
+              behind: Optional[_Launched] = None) -> Optional[np.ndarray]:
+        """Block until ``step``'s program is done and return its token
+        vector on the host (``None`` where no row sampled).  A failure
+        of a step launched without a wait (a donating engine's) surfaces
+        here and cannot be retried; ``behind`` is the step already
+        launched on ``step``'s outputs, if any, and goes with it."""
+        with self.timeline.span("engine.fetch"):
+            try:
+                jax.block_until_ready(step.tok)
+            except Exception:
+                self._abandon(step, behind)
+                raise
+            tok_host = np.asarray(step.tok) if step.rows else None
+            # The span of the wait carries the load of the step waited
+            # for (``held`` / ``max_expert``).
+            self.timeline.annotate(**self._count_experts(step))
+        return tok_host
+
+    def _abandon(self, *steps: Optional[_Launched]) -> None:
+        """A step failed for good: forget what is in flight, and put the
+        requests that had ended by length in it, their last token never
+        delivered, back among the active ones (slotless), so that
+        ``drain`` — the router's failover — snapshots them with the
+        tokens they did deliver."""
+        self._inflight = None
+        self._tok_dev = None
+        for step in steps:
+            for req, _row in (step.rows if step is not None else ()):
+                req.in_flight = 0
+            for req in (step.released if step is not None else ()):
+                if req.status == "active":
+                    self.scheduler.active[req.rid] = req
+
+    def _deliver(self, step: _Launched,
+                 tok_host: Optional[np.ndarray]) -> int:
+        """Hand out ``step``'s tokens; returns how many.  A row whose
+        request is no longer active — it ended by EOS, or was cancelled,
+        in the step this one was launched behind — is DISCARDED: not
+        appended, not streamed, not counted.  The cache row it wrote
+        lies past its request's end, in a slot whose frontier was reset
+        on release."""
+        t1 = self._rec_clock()
+        if self.recorder is not None:
+            dur = max(t1 - step.t0, 0.0)
+            for rid, g, take in step.chunks:
+                self._rec("req_prefill", rid, dur=dur,
+                          detail=f"g={g} take={take}")
+        self._delivering = True
+        delivered = 0
+        try:
+            for req, row in step.rows:
+                req.in_flight -= 1
+                if req.status != "active":
+                    continue
+                if step.kind == "decode" and self.recorder is not None:
+                    group = self._decode_groups.get(req.rid)
+                    if group is None:
+                        self._decode_groups[req.rid] = [step.t0, t1, 1.0]
+                    else:
+                        group[1] = t1
+                        group[2] += 1.0
+                assert tok_host is not None
+                self._emit(req, int(tok_host[row]))
+                delivered += 1
+        finally:
+            self._delivering = False
+        return delivered
+
+    def _settle(self) -> None:
+        """Wait for the step in flight, if any, and deliver its tokens:
+        afterwards the engine's state is what a serial loop shows after
+        the same steps.  Everything that observes or changes a request
+        from outside ``step`` calls this first.  A no-op while a
+        delivery is under way (an ``on_token`` callback that calls back
+        into the engine sees the delivery it is part of)."""
+        step = self._inflight
+        if step is None or self._delivering:
+            return
+        self._inflight = None
+        tok_host = self._wait(step)
+        with self.timeline.span("engine.emit"):
+            self.timeline.annotate(tokens=self._deliver(step, tok_host))
+
+    def _count_experts(self, step: _Launched) -> Dict[str, int]:
+        """``step``'s held-expert token counts into the counters, once;
+        returned as ``held`` / ``max_expert`` (empty where the model
+        counts none or they were read before)."""
+        if step.counts is None:
+            return {}
+        counts = np.asarray(step.counts)
+        step.counts = None
+        self.metrics.moe_step(
+            step.kind,
+            step.positions * self.moe.top_k * counts.shape[0], counts,
+        )
+        return {"held": int(counts.sum()), "max_expert": int(counts.max())}
 
     def read_expert_counts(self) -> Dict[str, int]:
-        """The waiting counts into the counters, oldest first; the
-        newest step's come back as ``held`` / ``max_expert`` (empty
-        where nothing waited).  ``step`` calls it where it has just
-        fetched its tokens — that program is done, and so is every
-        earlier one — and puts the result on its action span; a reader
-        of ``metrics`` calls it after a window that time cut short,
-        and then waits for the last step's program."""
-        load: Dict[str, int] = {}
-        for kind, positions, counts in self._unread_counts:
-            counts = np.asarray(counts)
-            self.metrics.moe_step(
-                kind, positions * self.moe.top_k * counts.shape[0], counts
-            )
-            load = {"held": int(counts.sum()),
-                    "max_expert": int(counts.max())}
-        self._unread_counts.clear()
-        return load
+        """The counts of the step in flight into the counters (waiting
+        for its program), returned as ``held`` / ``max_expert``; empty
+        where nothing is in flight.  ``step`` reads a step's counts
+        where it waits for that step's tokens, so the counters lag the
+        launches by the one step in flight: a reader of ``metrics``
+        calls this after a window that time cut short.  No token is
+        delivered here."""
+        step = self._inflight
+        return self._count_experts(step) if step is not None else {}
 
     def _after_prefill_dispatch(
         self, g: int, slots: np.ndarray, tokens: np.ndarray,
@@ -1106,54 +1424,6 @@ class Engine:
         ``i`` is slot ``slots[i]``; padded rows have ``n_valid`` 0);
         ``fleet.SpeculativeEngine`` overrides it to teacher-force the
         same prompt chunks into the draft cache."""
-
-    def _run_decode(self) -> None:
-        tl = self.timeline
-        reqs = self.scheduler.decode_ready()
-        tl.annotate(rows=len(reqs))
-        with tl.span("engine.build"):
-            tokens = self._token_buffer("decode")
-            n_valid = np.zeros((self.pool.num_slots,), np.int32)
-            for r in reqs:
-                tokens[r.slot, 0] = self._cur_tok[r.slot]
-                n_valid[r.slot] = 1
-            lengths_in = self._lengths_for_step()
-            tokens_dev = jnp.asarray(tokens)
-            n_valid_dev = jnp.asarray(n_valid)
-        attended = self._attended(self.pool.lengths, n_valid, 1)
-        tl.annotate(rows_read=attended[0], rows_cap=attended[1])
-        t0 = self._rec_clock()
-        tok, cache, lengths_dev, key = self._dispatch(
-            self._decode_fn, self.params, self.pool.cache,
-            lengths_in, tokens_dev, n_valid_dev, self._key,
-        )
-        self.pool.cache = cache
-        self._key = key
-        t1 = self._rec_clock()
-        if isinstance(tok, tuple):
-            tok = self._queue_expert_counts("decode", len(reqs), tok)
-        with tl.span("engine.fetch"):
-            tok_host = np.asarray(tok)      # the ONE host fetch per step
-            load = self.read_expert_counts()
-        if load:
-            tl.annotate(**load)
-        with tl.span("engine.emit", tokens=len(reqs)):
-            self._commit_lengths(lengths_dev, n_valid)
-            self.metrics.step(
-                "decode", len(reqs), self.pool.num_slots,
-                attended=attended,
-            )
-            if self.recorder is not None:
-                for r in reqs:
-                    group = self._decode_groups.get(r.rid)
-                    if group is None:
-                        self._decode_groups[r.rid] = [t0, t1, 1.0]
-                    else:
-                        group[1] = t1
-                        group[2] += 1.0
-            for r in reqs:
-                self.pool.lengths[r.slot] += 1
-                self._emit(r, int(tok_host[r.slot]))
 
     def _emit(self, req: Request, token: int) -> None:
         """Stream one token; per-row termination FREES THE SLOT NOW —
@@ -1209,6 +1479,7 @@ class Engine:
         :func:`torchgpipe_tpu.fleet.migration.migrate`); append back to
         ``_migration_ready`` to re-park one the decode pool cannot take
         yet."""
+        self._settle()
         out = self._migration_ready
         self._migration_ready = []
         return out
@@ -1224,6 +1495,7 @@ class Engine:
             raise ValueError(
                 f"request {req.rid!r} holds no slot — nothing to export"
             )
+        self._settle()
         return kv_cache.slot_rows(self.pool.cache, req.slot)
 
     def complete_migration(self, req: Request) -> None:
@@ -1271,6 +1543,7 @@ class Engine:
                 "ingest_migration is the decode pool's entry point — "
                 f"this engine's role is {self.role!r}"
             )
+        self._settle()
         self._check_rid_free(rid)
         check_tier(tier)
         req = Request(
@@ -1320,6 +1593,7 @@ class Engine:
         self.pool.cache = new_cache
         self.pool.lengths[slot] = req.prompt_len  # shadow miss → upload
         self._cur_tok[slot] = int(last_token)
+        self._tok_dev = None    # the mirror is whole (settled): upload it
         self._rec(
             "req_ingest", rid,
             dur=max(self._rec_clock() - t0, 0.0),
@@ -1332,9 +1606,11 @@ class Engine:
 
     def run(self, max_steps: Optional[int] = None) -> str:
         """Iterate until idle, preempted, or ``max_steps``.  Returns
-        ``'idle'`` | ``'preempted'`` | ``'budget'``."""
+        ``'idle'`` | ``'preempted'`` | ``'budget'``; whichever, the
+        step in flight is settled first, so the caller sees every token
+        of the steps that ran."""
         steps = 0
-        while not self.scheduler.idle:
+        while not self.scheduler.idle or self._inflight is not None:
             if self._preempted():
                 self.drain()
                 return "preempted"
@@ -1342,6 +1618,7 @@ class Engine:
                 break
             steps += 1
             if max_steps is not None and steps >= max_steps:
+                self._settle()
                 return "budget"
         return "idle"
 
@@ -1351,7 +1628,8 @@ class Engine:
 
     def request_drain(self) -> None:
         """Ask the engine to drain at the next iteration boundary (safe
-        from a PreemptionHandler callback or another thread)."""
+        from a PreemptionHandler callback or another thread: it sets a
+        flag, and the drain it asks for settles the step in flight)."""
         self._drain_requested = True
 
     def resume_serving(self) -> None:
@@ -1370,21 +1648,31 @@ class Engine:
         h = self._preemption
         return bool(h is not None and getattr(h, "preempted", False))
 
+    def _unfinished(self) -> List[Request]:
+        """Every request a drain snapshots, the step in flight settled
+        first (a request that ends in it is not one of them).
+        Migration-parked requests (prefill role) are in-flight too:
+        they left the scheduler but not the replica — a drain must
+        snapshot them or a dying prefill replica would strand every
+        prompt caught between completion and handoff."""
+        self._settle()
+        return [*self.scheduler.queue, *self.scheduler.active.values(),
+                *self._migration_ready]
+
+    def unfinished(self) -> List[str]:
+        """The ids of the requests a :meth:`drain` would snapshot now.
+        A step in flight that failed for good re-raises here, once: the
+        engine has then put its requests back among the active ones
+        (:meth:`_abandon`), and the next call lists them."""
+        return [r.rid for r in self._unfinished()]
+
     def drain(self, step_id: Optional[int] = None) -> Dict[str, Any]:
         """Cooperative drain: stop admitting, snapshot every unfinished
         request (original prompt + tokens emitted so far), release all
         slots, and — when a CheckpointManager is wired — persist the
         snapshot.  Returns the snapshot dict."""
+        unfinished = self._unfinished()
         self._draining = True
-        # Migration-parked requests (prefill role) are in-flight too:
-        # they left the scheduler but not the replica — a drain must
-        # snapshot them or a dying prefill replica would strand every
-        # prompt caught between completion and handoff.
-        unfinished = (
-            list(self.scheduler.queue)
-            + list(self.scheduler.active.values())
-            + list(self._migration_ready)
-        )
         tree: Dict[str, Dict[str, np.ndarray]] = {}
         meta: Dict[str, Dict[str, Any]] = {}
         for r in unfinished:

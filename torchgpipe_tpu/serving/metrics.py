@@ -92,6 +92,11 @@ class ServingMetrics:
             "serving_prefill_steps", help="compiled prefill steps run")
         self._c_decode = reg.counter(
             "serving_decode_steps", help="compiled decode steps run")
+        self._c_ahead = reg.counter(
+            "serving_steps_launched_ahead",
+            help="compiled steps launched while the step before them "
+                 "was still in flight (their host work ran behind the "
+                 "device); over all steps: how often the overlap engages")
         self._c_occupied = reg.counter(
             "serving_occupied_slot_steps",
             help="slot-steps doing useful work")
@@ -175,6 +180,7 @@ class ServingMetrics:
     # through the shared facade (obs.registry.counter_property).
     prefill_steps = _counter_property("_c_prefill")
     decode_steps = _counter_property("_c_decode")
+    steps_launched_ahead = _counter_property("_c_ahead")
     occupied_slot_steps = _counter_property("_c_occupied")
     total_slot_steps = _counter_property("_c_total")
     prefill_rows = _counter_property("_c_prefill_rows")
@@ -258,14 +264,19 @@ class ServingMetrics:
     # ------------------------------------------------------------------ #
 
     def step(self, kind: str, active_slots: int, num_slots: int,
-             deferred: int = 0, attended: Tuple[int, int] = (0, 0)) -> None:
-        """One compiled step: ``active_slots`` of the ``num_slots`` rows
+             deferred: int = 0, attended: Tuple[int, int] = (0, 0),
+             ahead: bool = False) -> None:
+        """One compiled step, counted when it is LAUNCHED (``ahead``:
+        while the step before it was still in flight; the tokens it
+        samples are counted when they are delivered, a step later):
+        ``active_slots`` of the ``num_slots`` rows
         its program has did useful work.  A prefill step's program is
         COMPACT (``num_slots`` is its row capacity ``R``, not the
         pool's size) and may leave ``deferred`` pending prompts to the
         next prefill step.  ``attended`` is ``(rows read, row
         capacity)`` of a layer's cache attention in this step
         (``models.generation.attend_rows_counter``)."""
+        self._c_ahead.inc(int(ahead))
         self._c_attend_read.inc(attended[0])
         self._c_attend_capacity.inc(attended[1])
         if kind == "prefill":
@@ -350,6 +361,7 @@ class ServingMetrics:
             "engine_steps": self.engine_steps,
             "prefill_steps": self.prefill_steps,
             "decode_steps": self.decode_steps,
+            "steps_launched_ahead": self.steps_launched_ahead,
             "tokens_out": self.tokens_out,
             "tokens_per_sec": self.tokens_out / elapsed,
             "tokens_per_step": (

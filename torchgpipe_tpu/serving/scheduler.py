@@ -94,6 +94,9 @@ class Request:
     slot: Optional[int] = None
     prefilled: int = 0       # prompt tokens absorbed so far
     generated: List[int] = dataclasses.field(default_factory=list)
+    # Tokens whose step has been LAUNCHED and not yet delivered (the
+    # engine keeps one step in flight: serving/engine.py).
+    in_flight: int = 0
 
     @property
     def prompt_len(self) -> int:
@@ -106,6 +109,13 @@ class Request:
     @property
     def remaining_new(self) -> int:
         return self.max_new_tokens - len(self.generated)
+
+    @property
+    def remaining_launches(self) -> int:
+        """Tokens still to be LAUNCHED: the budget less what has been
+        delivered and what is in flight.  A count the engine knows when
+        it launches a step, so termination by length needs no token."""
+        return self.remaining_new - self.in_flight
 
     def tokens(self) -> List[int]:
         """All tokens this request has produced (across a drain/resume)."""
