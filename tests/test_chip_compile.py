@@ -103,6 +103,31 @@ def _decode_rows(rows, g, compact):
                 ints, ints, ints]
 
 
+# trinity-large.serve-mixed-backlog's pool: 48 query heads on 8 KV heads,
+# a window layer's ring of 4,608 rows (window 4,096 + a chunk, rounded to
+# the kernel's block) and a full layer's 16,384.
+T_H, T_SLOTS, T_ROWS, T_RING, T_LEN = 48, 48, 9, 4608, 16384
+
+
+def _decode_ring(rows, g, compact, ring):
+    """The per-row kernel over a bank of the mixed pool: a window layer's
+    ring (the band's blocks fetched modulo the ring) or a full layer's
+    rows, ``rows`` query rows of ``g`` tokens at their own frontiers."""
+    def fn(q, ck, cv, pos0, lengths, slots):
+        return flash_decode_attention(
+            q, ck, cv, pos0, window=4096 if ring else None, lengths=lengths,
+            slots=slots if compact else None, ring=ring,
+        )
+
+    bank = (T_SLOTS, T_RING if ring else T_LEN, G, D)
+    ints = ((rows,), jnp.int32)
+    return fn, [((rows, g, T_H, D), BF16), (bank, BF16), (bank, BF16),
+                ints, ints, ints]
+
+
+T_RING_BYTES = T_SLOTS * T_RING * G * D * 2
+
+
 def _prefill_attention(s):
     def fn(q, k, v):
         return generation._attend_full(q, k, v, WINDOW)
@@ -133,6 +158,18 @@ CASES = {
         *_decode_rows(ROWS, CHUNK, True), True, BANK_BYTES),
     # chip_smoke.py's engine: 8 rows of 128 tokens (a shorter block).
     "decode-rows-chunk128": (*_decode_rows(8, 128, True), True, BANK_BYTES),
+    # The mixed pool's two kinds of bank under both programs' shapes
+    # (the ring's index map takes the band's blocks modulo the ring).
+    "decode-ring-pool": (
+        *_decode_ring(T_SLOTS, 1, False, True), True, T_RING_BYTES),
+    "decode-ring-compact-32": (
+        *_decode_ring(T_ROWS, 32, True, True), True, T_RING_BYTES),
+    "decode-ring-compact-128": (
+        *_decode_ring(T_ROWS, 128, True, True), True, T_RING_BYTES),
+    "decode-full-16k-pool": (
+        *_decode_ring(T_SLOTS, 1, False, False), True, T_RING_BYTES),
+    "decode-full-16k-compact-128": (
+        *_decode_ring(T_ROWS, 128, True, False), True, T_RING_BYTES),
     # prefill()/generate(): a prompt the 128-blocks do not divide must
     # take the dense path (100 was refused by Mosaic, 200 compiled to a
     # short grid that left the tail rows unwritten), an aligned one the

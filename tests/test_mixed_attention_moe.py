@@ -234,18 +234,43 @@ def test_the_published_record_reads_into_the_period():
         config_from_hf_mixed_moe(types.SimpleNamespace(**dict(vars(hf), mlp_layer_types=["dense"])))
 
 
-def test_generation_and_the_engine_refuse_a_mixed_period_by_name(model):
+@pytest.mark.parametrize("path", ["prefill", "decode_slots", "engine"])
+def test_generation_and_the_engine_serve_a_mixed_period(model, path, monkeypatch):
+    """Since PR 34 a model that mixes layer types is generated from and
+    served (it was refused by name): the window layers' rows in rings of
+    the toy window, YaRN on the full layers, against the plain forward."""
+    from torchgpipe_tpu.models import kv_cache
     from torchgpipe_tpu.serving import Engine
 
+    monkeypatch.setattr(kv_cache, "RING_GRANULE", 4)
     m, flat = model
     cfg, moe = program(m)
-    with pytest.raises(NotImplementedError, match="attn_layers"):
-        generation.generate(cfg, flat, jnp.zeros((1, 4), jnp.int32), max_new_tokens=2)
-    with pytest.raises(NotImplementedError, match="attn_layers"):
-        Engine(cfg, flat, num_slots=2, max_len=16, moe=moe)
+    x, _ = tokens_of(m, rows=2)
+    want = np.asarray(reference_logits(m, flat, x))
+    if path == "prefill":
+        logits, cache = generation.prefill(cfg, flat, x, SEQ + 8, moe=moe)
+        assert kv_cache.bank_rows(cache) == [WINDOW] * 3 + [SEQ + 8]
+        np.testing.assert_allclose(np.asarray(logits), want[:, -1], atol=2e-4)
+    elif path == "decode_slots":
+        cache = generation.init_cache(cfg, 2, SEQ, chunk=4)
+        assert kv_cache.bank_rows(cache) == [12] * 3 + [SEQ]
+        lengths = jnp.zeros((2,), jnp.int32)
+        for at in range(0, SEQ, 4):                     # the rings wrap four times
+            logits, cache, lengths = generation.decode_slots(
+                cfg, flat, x[:, at:at + 4], cache, lengths, jnp.full((2,), 4, jnp.int32),
+                moe=moe)[:3]
+            np.testing.assert_allclose(np.asarray(logits), want[:, at:at + 4], atol=2e-4)
+    else:
+        eng = Engine(cfg, flat, num_slots=2, max_len=SEQ + 8, prefill_chunk=4, moe=moe)
+        rid = eng.submit(np.asarray(x[0, :40]), 6)
+        assert eng.run() == "idle"
+        out = eng.result(rid)
+        full = jnp.concatenate([x[0, :40], jnp.asarray(out)])[None]
+        chose = np.asarray(reference_logits(m, flat, full))[0, 39:-1]
+        assert (chose.max(-1) - chose[np.arange(6), out]).max() < 2e-4
     # One entry is the model-global window, wherever it is written.
     one = dataclasses.replace(cfg, attn_layers=(AttnLayer(WINDOW, 1e4),))
-    assert generation._window(one) == WINDOW
+    assert generation._window(one) == WINDOW and not kv_cache.ring_layer(one, 0)
 
 
 def test_the_steps_held_counts_are_the_layers_own(model):

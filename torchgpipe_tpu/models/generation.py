@@ -42,6 +42,12 @@ Design, TPU-first:
   attention reads from O(max_len) to O(window), bit-equal to the
   masked path (the in-band-by-construction property of the ring makes
   ``p_j >= 0`` the only mask needed).
+* **Window and full layers mixed** (``cfg.attn_layers`` of more than
+  one entry): every entry point reads layer ``i``'s entry
+  (``cfg.attn_layer(i)``: its window, its rope record or none), and the
+  cache holds a ring for each window layer beside ``max_len`` rows for
+  each full one (``kv_cache.layer_rows``); a write lands at ``pos %
+  rows`` and every mask goes by the position a ring row holds.
 
 Sampling: greedy (``temperature=0``) or temperature softmax sampling
 with optional top-k truncation and top-p (nucleus) filtering, driven by
@@ -123,10 +129,16 @@ def _embed(cfg: TransformerConfig, embed_p: Pytree,
     return x
 
 
-def _window(cfg: TransformerConfig) -> Optional[int]:
-    """The window every layer attends in (``_check_decodable`` admits a
-    one-entry attention period only)."""
-    return cfg.attn_layer(0).window
+def _window(cfg: TransformerConfig, layer: int = 0) -> Optional[int]:
+    """The window layer ``layer`` attends in."""
+    return cfg.attn_layer(layer).window
+
+
+def _attn_scope(window: Optional[int]) -> Any:
+    """The scope the operation table tells the two kinds of layer apart
+    by (the training block's names)."""
+    return jax.named_scope("attn.window" if window is not None
+                           else "attn.full")
 
 
 def _w(cfg: TransformerConfig, p: Pytree, key: str) -> jnp.ndarray:
@@ -184,10 +196,12 @@ def _block_qkv(
     p: Pytree,
     x: jnp.ndarray,              # [b, g, dim]
     pos: jnp.ndarray,            # [] int32 first-query position, or [b] per row
+    layer: int = 0,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Shared per-block decode prologue: ln1, q/k/v projections (+LoRA
     deltas, +Qwen2 biases), head reshape, Qwen3 per-head q/k RMSNorm,
-    rope at ``pos``.  ONE body for prefill and the single-token,
+    rope at ``pos`` as layer ``layer``'s entry says (its theta and YaRN
+    record, or no rotation).  ONE body for prefill and the single-token,
     chunked, and slot-masked decode paths — a model-family quirk added
     here reaches all four at once; only where the rows are written
     (``kv_cache``'s two writes) and the attend stay with each caller."""
@@ -211,8 +225,8 @@ def _block_qkv(
     if "qn" in p:  # Qwen3-style per-head q/k RMSNorm, pre-rope
         q = _rms(q, p["qn"], cfg.norm_eps)
         k = _rms(k, p["kn"], cfg.norm_eps)
-    q = _maybe_rope(cfg, q, pos)
-    k = _maybe_rope(cfg, k, pos)
+    q = _maybe_rope(cfg, q, pos, layer)
+    k = _maybe_rope(cfg, k, pos, layer)
     return q, k, v
 
 
@@ -225,23 +239,36 @@ def _block_attn_out(
     valid: Optional[jnp.ndarray] = None,     # [b, g] bool: real tokens
     counts_out: Optional[List[jnp.ndarray]] = None,
 ) -> jnp.ndarray:
-    """Shared per-block decode epilogue: wo projection (+LoRA, +bias),
-    attention residual, ln2 (parallel or sequential residual), MLP
-    residual.  Counterpart of :func:`_block_qkv` (and of
-    ``mla.project`` + ``mla.attend``).  ``valid`` / ``counts_out`` as in
-    :func:`_mlp_out`."""
+    """Shared per-block decode epilogue: the output gate
+    (``cfg.attn_gate``: the heads' output times ``sigmoid(ln1(x) @
+    wg)``), wo projection (+LoRA, +bias), attention residual, ln2
+    (parallel or sequential residual), MLP residual; under
+    ``cfg.sandwich_norm`` each branch's output is normed (``ln1p`` /
+    ``ln2p``) before it joins the stream.  Counterpart of
+    :func:`_block_qkv` (and of ``mla.project`` + ``mla.attend``).
+    ``valid`` / ``counts_out`` as in :func:`_mlp_out`."""
     attn = attn.astype(x.dtype)
+    if cfg.attn_gate:
+        with jax.named_scope("attn.gate"):
+            # ln1(x) is the prologue's: one computation in the program.
+            attn = attn * jax.nn.sigmoid(
+                _block_norm(cfg, p, "ln1", x) @ _w(cfg, p, "wg"))
     o = attn @ _w(cfg, p, "wo")
     if "lora" in p:
         o = o + _lora_delta(cfg, p["lora"], attn, "oa", "ob")
     if "bo" in p:
         o = o + p["bo"]
+    if cfg.sandwich_norm:
+        o = _block_norm(cfg, p, "ln1p", o)
     x_in = x
     x = x + o
     h = _block_norm(
         cfg, p, "ln2", x_in if cfg.parallel_residual else x
     )
-    return x + _mlp_out(cfg, p, h, mlp_layer, valid, counts_out)
+    out = _mlp_out(cfg, p, h, mlp_layer, valid, counts_out)
+    if cfg.sandwich_norm:
+        out = _block_norm(cfg, p, "ln2p", out)
+    return x + out
 
 
 def _decode_step(
@@ -272,6 +299,7 @@ def _decode_step(
     if not ring:
         return _decode_chunk(cfg, block_params, x, cache, mlp_layer)
     _refuse_mla(cfg, "a ring cache")
+    kv_cache.refuse_rings(cfg, "cache_mode='ring' (one ring for all layers)")
     pos = cache.length
     W = _cache_rows(cache)
     new = []
@@ -305,14 +333,15 @@ def _flash_decode_eligible(
 
 
 def attend_rows_counter(
-    cfg: TransformerConfig, cache: Any, rows: int, g: int,
+    cfg: TransformerConfig, cache: Any, rows: int, g: int, layer: int = 0,
 ) -> Any:
     """A function ``(pos0 [rows], n_valid [rows]) -> (rows read, row
-    capacity)`` for the cache attention of ONE layer of a
+    capacity)`` for the cache attention of ONE layer (``layer``: its
+    window, and its banks' own length, a ring's where it holds one) of a
     :func:`decode_slots` call of ``rows`` rows of ``g`` tokens, counted
-    on the HOST (numpy) from the rows' frontiers: capacity is ``rows x
-    max_len``; read is the block-rounded rows the decode kernel fetches
-    (nothing for a row with ``n_valid == 0``) where this platform and
+    on the HOST (numpy) from the rows' frontiers: capacity is ``rows x``
+    the layer's length; read is the block-rounded rows the decode kernel
+    fetches (nothing for a row with ``n_valid == 0``) where this platform and
     these shapes take the kernel, the capacity where the dense path
     runs (off TPU, a latent or an int8 pool, shapes the kernel does not
     tile).  What is decided by platform and shape is decided here,
@@ -323,32 +352,33 @@ def attend_rows_counter(
         _decode_tiling, decode_rows_read,
     )
 
-    max_len = _cache_rows(cache)
+    max_len = kv_cache.bank_rows(cache)[layer]
     cap = rows * max_len
+    window = _window(cfg, layer)
     if (
         cfg.mla is not None
         or jax.devices()[0].platform != "tpu"
         or not _flash_decode_eligible(
-            (rows, g, cfg.n_heads, cfg.head_dim), cache.k[0],
-            _window(cfg), quant=isinstance(cache, QuantKVCache),
+            (rows, g, cfg.n_heads, cfg.head_dim), cache.k[layer],
+            window, quant=isinstance(cache, QuantKVCache),
             per_row=True,
         )
     ):
         return lambda pos0, n_valid: (cap, cap)
-    bank = cache.k[0]
+    bank = cache.k[layer]
     block_k, _ = _decode_tiling(
         g, cfg.n_heads, bank.shape[2], bank.dtype.itemsize, max_len
     )
+    ring = kv_cache.ring_layer(cfg, layer)
     # A band as long as the cache drops no block of any frontier.
-    window = _window(cfg)
-    if window is not None and max_len - window + 1 < block_k:
+    if not ring and window is not None and max_len - window + 1 < block_k:
         window = None
 
     def count(pos0: Any, n_valid: Any) -> Tuple[int, int]:
         pos0 = np.asarray(pos0)
-        live = np.where(
-            np.asarray(n_valid) > 0, np.minimum(pos0 + g, max_len), 0
-        )
+        # A ring is read up to the row's frontier, wherever that lies.
+        end = pos0 + g if ring else np.minimum(pos0 + g, max_len)
+        live = np.where(np.asarray(n_valid) > 0, end, 0)
         return decode_rows_read(pos0, live, window, block_k), cap
 
     return count
@@ -367,6 +397,7 @@ def _attend_chunk(
     seg_k: Optional[jnp.ndarray] = None,    # [b, max_len] cache segments
     slots: Optional[jnp.ndarray] = None,    # [b] — row i reads ck[slots[i]]
     lengths: Optional[jnp.ndarray] = None,  # [b] — cache rows a row needs
+    ring: bool = False,
 ) -> jnp.ndarray:
     """Causal attention of ``g`` consecutive queries against the cache —
     one MXU-friendly einsum instead of g masked cache reads.  Query i
@@ -385,6 +416,11 @@ def _attend_chunk(
     fetches nothing for it and returns zeros); the dense path reads
     every row whatever it says, and a row of length 0 gets garbage that
     its caller never reads.
+
+    ``ring`` (needs ``window``): the ``max_len`` rows of ``ck`` / ``cv``
+    are a RING, position ``p`` in row ``p % max_len``, at least ``window
+    + g - 1`` rows long.  Every mask then goes by the position a row
+    HOLDS: the newest one congruent to it that the chunk has reached.
 
     ``seg_q``/``seg_k`` fold the sequence-packing mask in: query ``i``
     additionally requires ``seg_q[b, i] == seg_k[b, j]`` (the
@@ -433,7 +469,7 @@ def _attend_chunk(
         return flash_decode_attention(
             q, ck, cv, pos0, window=window, k_scale=k_scale,
             v_scale=v_scale, slots=slots, lengths=lengths,
-            interpret=not on_tpu,
+            ring=ring, interpret=not on_tpu,
         )
     if slots is not None:
         # One row, one slot: each row attends over ITS slot's
@@ -447,7 +483,7 @@ def _attend_chunk(
                 _slot_rows(cv, slots[i]), pos0[i:i + 1],
                 _slot_rows(k_scale, slots[i]) if quant else None,
                 _slot_rows(v_scale, slots[i]) if quant else None,
-                window=window,
+                window=window, ring=ring,
             )
             for i in range(q.shape[0])
         ], axis=0)
@@ -469,7 +505,16 @@ def _attend_chunk(
         + jnp.arange(g)[None, :, None]
     )
     idx = jnp.arange(max_len)[None, None, :]      # [1, 1, max_len]
+    if ring:
+        # The position row ``idx`` of the ring holds: the newest one at
+        # or before the chunk's last that is congruent to it (negative:
+        # none yet).  Rows the chunk has not written yet hold a position
+        # out of every query's band, and are labelled past it.
+        last = qpos[:, -1:, :]                    # [B', 1, 1]
+        idx = last - jnp.mod(last - idx, max_len)
     valid = idx <= qpos                           # [B', g, max_len]
+    if ring:
+        valid &= idx >= 0
     if window is not None:
         valid &= idx > qpos - window
     if seg_q is not None:
@@ -493,29 +538,45 @@ def _decode_chunk(
     :func:`_decode_step` (same math per position; ``g=1`` agrees with it
     exactly, tested).  This is what makes speculative verification a
     single MXU matmul per block instead of γ sequential cache reads.
-    Plain and quantized caches; ring caches are not supported (the
-    speculative path that needs chunks rolls positions back, which a
-    ring's slot reuse cannot undo)."""
+    Plain and quantized caches, and the cache of a model that mixes
+    layer types, whose window layers hold rings (``g = 1`` there: the
+    one column lands at ``pos0 % rows``).  ``cache_mode='ring'``'s one
+    ring for all layers is :func:`_decode_step`'s; the speculative path
+    that needs chunks rolls positions back, which a ring's slot reuse
+    cannot undo."""
     g = x.shape[1]
     pos0 = cache.length
     new = []
-    for p, layer in zip(block_params, kv_cache.layers(cache)):
+    for i, (p, layer) in enumerate(zip(block_params, kv_cache.layers(cache))):
         if cfg.mla is not None:
             h = _block_norm(cfg, p, "ln1", x)
             q_nope, q_pe, *rows = mla.project(cfg, p, h, pos0)
             layer = kv_cache.write_columns(layer, rows, pos0)
             attn = mla.attend(cfg, p, q_nope, q_pe, *layer[:2], pos0)
         else:
-            q, *rows = _block_qkv(cfg, p, x, pos0)
-            layer = kv_cache.write_columns(layer, rows, pos0)
+            q, *rows = _block_qkv(cfg, p, x, pos0, i)
+            ring = kv_cache.ring_layer(cfg, i)
+            at = pos0
+            if ring:
+                if g != 1:
+                    raise NotImplementedError(
+                        f"a chunk of {g} tokens at one shared position "
+                        "may straddle a ring's end; window layers' rings "
+                        "take chunks through decode_slots (per-row "
+                        "scatter), one token here"
+                    )
+                at = jnp.mod(pos0, layer[0].shape[1])
+            layer = kv_cache.write_columns(layer, rows, at)
             ck, cv, cks, cvs = layer
             # An int8 layer's banks (cks/cvs non-None) go to the attend
             # AS-IS: the flash decode kernel dequantizes block-wise in
             # VMEM (int8 HBM traffic); the dense path dequantizes at the
             # attend instead.
-            attn = _attend_chunk(
-                q, ck, cv, pos0, _window(cfg), k_scale=cks, v_scale=cvs
-            )
+            with _attn_scope(_window(cfg, i)):
+                attn = _attend_chunk(
+                    q, ck, cv, pos0, _window(cfg, i), k_scale=cks,
+                    v_scale=cvs, ring=ring,
+                )
         x = _block_attn_out(cfg, p, x, attn, mlp_layer)
         new.append(layer)
     return x, kv_cache.rebuild(cache, new, pos0 + g)
@@ -536,11 +597,11 @@ def _slot_rows(bank: jnp.ndarray, slot: jnp.ndarray) -> jnp.ndarray:
     return lax.dynamic_slice_in_dim(bank, slot, 1, axis=0)
 
 
-@functools.partial(jax.jit, static_argnames=("window",))
+@functools.partial(jax.jit, static_argnames=("window", "ring"))
 def _attend_row(
     q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray, pos0: jnp.ndarray,
     k_scale: Optional[jnp.ndarray], v_scale: Optional[jnp.ndarray],
-    *, window: Optional[int],
+    *, window: Optional[int], ring: bool = False,
 ) -> jnp.ndarray:
     """One row of the compact ``decode_slots`` where the kernel does
     not run (off TPU, an int8 pool, shapes it cannot tile): the dense
@@ -551,7 +612,7 @@ def _attend_row(
     every start of an engine; XLA inlines the calls."""
     return _attend_chunk(
         q, ck, cv, pos0, window, use_flash=False,
-        k_scale=k_scale, v_scale=v_scale,
+        k_scale=k_scale, v_scale=v_scale, ring=ring,
     )
 
 
@@ -628,8 +689,13 @@ def decode_slots(
     Plain and quantized caches, and the :class:`LatentCache` of a
     ``cfg.mla`` model (same write and mask rules on its two banks; the
     attend is ``mla.attend`` over the slot's latent rows, absorbed at
-    ``g = 1``); ring caches are not supported (slots recycle by
-    masking, which a ring's position-aliased layout defeats).
+    ``g = 1``), and the cache of a model that mixes layer types
+    (``kv_cache.layer_rows``): a window layer's rows are a ring of at
+    least ``window + g - 1`` rows, written at ``(frontier + j) % rows``
+    and read under the mask of the position each row holds, so a
+    recycled slot's stale rows are as dead there as anywhere (a row
+    holds a position of its new tenant or one before 0).
+    ``cache_mode='ring'``'s one ring for all layers is ``generate``'s.
 
     ``expert_counts=True`` appends a fourth result: ``int32 [expert
     layers, held]``, the tokens this call routed to each expert the
@@ -654,8 +720,12 @@ def decode_slots(
     # position, none for a row that does nothing in this call.
     live = jnp.where(n_valid > 0, jnp.minimum(pos0 + g, L), 0)
     at = kv_cache.scatter_index(slot_of, wpos)
+    # The same two for the rings of a model that mixes layer types:
+    # the frontier itself, which no ring clips, and by ring length the
+    # columns modulo the ring (its own length where masked).
+    ring_live, ring_at = None, {}
     new = []
-    for p, layer in zip(block_p, kv_cache.layers(cache)):
+    for i, (p, layer) in enumerate(zip(block_p, kv_cache.layers(cache))):
         if cfg.mla is not None:
             h = _block_norm(cfg, p, "ln1", x)
             q_nope, q_pe, *rows = mla.project(cfg, p, h, pos0)
@@ -673,8 +743,23 @@ def decode_slots(
             else:
                 attn = mla.attend(cfg, p, q_nope, q_pe, cc, cr, pos0)
         else:
-            q, *rows = _block_qkv(cfg, p, x, pos0)
-            layer = kv_cache.write_scattered(layer, rows, at)
+            q, *rows = _block_qkv(cfg, p, x, pos0, i)
+            window, ring = _window(cfg, i), kv_cache.ring_layer(cfg, i)
+            if ring:
+                R = layer[0].shape[1]
+                if R < min(window + g - 1, L):
+                    raise ValueError(
+                        f"layer {i}'s ring of {R} rows does not hold its "
+                        f"window of {window} beside a chunk of {g}: size "
+                        f"the cache with init_cache(..., chunk={g})"
+                    )
+                if R not in ring_at:
+                    ring_live = jnp.where(n_valid > 0, pos0 + g, 0)
+                    ring_at[R] = kv_cache.scatter_index(
+                        slot_of,
+                        jnp.where(valid, jnp.mod(pos0[:, None] + j, R), R))
+            layer = kv_cache.write_scattered(
+                layer, rows, ring_at[R] if ring else at)
             ck, cv, cks, cvs = layer
             # Each row over ITS slot's rows, the written ones included,
             # up to its own frontier: on a TPU the decode kernel reads
@@ -682,10 +767,12 @@ def decode_slots(
             # whole bank is its operand, nothing of it is sliced or
             # copied) and skips the rows with nothing to do; elsewhere
             # the dense einsum, a row at a time in the compact form.
-            attn = _attend_chunk(
-                q, ck, cv, pos0, _window(cfg), k_scale=cks, v_scale=cvs,
-                slots=slots, lengths=live,
-            )
+            with _attn_scope(window):
+                attn = _attend_chunk(
+                    q, ck, cv, pos0, window, k_scale=cks, v_scale=cvs,
+                    slots=slots, lengths=ring_live if ring else live,
+                    ring=ring,
+                )
         x = _block_attn_out(cfg, p, x, attn, mlp_layer, valid, counts)
         new.append(layer)
     new_lengths = (
@@ -753,15 +840,6 @@ def _check_decodable(cfg: TransformerConfig, positions: int) -> None:
             "the decode paths compute pre-norm blocks; "
             f"norm_position={cfg.norm_position!r} (BERT-class post-norm) "
             "models are encoders — use the training/apply path"
-        )
-    if len(cfg.attn_period) > 1:
-        raise NotImplementedError(
-            "cfg.attn_layers mixes attention layer types by layer "
-            f"({len(cfg.attn_period)} entries): the caches here are one "
-            "kind for every layer, and window layers would need a ring "
-            "beside full layers' rows; such a model trains through "
-            "transformer_block / llama_spmd / llama_moe_spmd and is not "
-            "generated from or served yet"
         )
     _check_max_pos(cfg, positions)
 
@@ -959,12 +1037,18 @@ def prefill(
     use_flash: Optional[bool] = None,
     ring: bool = False,
     kv_quant: bool = False,
+    chunk: int = 1,
 ) -> Tuple[jnp.ndarray, Any]:
     """ONE batched full-sequence pass over the prompt (MXU-friendly, no
     per-token loop): computes each block's K/V for all prompt positions,
     banks them in the cache, and returns (last-position logits
     [b, vocab], cache ready for decode at position s).  ``use_flash``
     as in :func:`_attend_full` (auto: Pallas flash kernel on TPU).
+
+    A model that mixes layer types gets the cache of
+    ``kv_cache.layer_rows``: each window layer banks its last rows into
+    its own ring; ``chunk`` sizes those rings for the longest chunk a
+    later :func:`decode_slots` call will write (``init_cache``'s).
 
     ``ring=True`` (requires ``cfg.attn_window``): the cache is a
     ``[b, attn_window, ...]`` RING per block — only the last ``W``
@@ -975,22 +1059,24 @@ def prefill(
     if s > max_len:
         raise ValueError(f"prompt length {s} exceeds max_len {max_len}")
     _check_decodable(cfg, s)
+    if ring:
+        kv_cache.refuse_rings(cfg, "ring=True (one ring for all layers)")
     if ring and _window(cfg) is None:
         raise ValueError(
             "ring caches hold exactly the attention window: set "
             "cfg.attn_window to use ring=True"
         )
-    W = _window(cfg) if ring else None
-    L = W if ring else max_len
+    L = _window(cfg) if ring else max_len
     mlp_layer = _mlp_layer_for(cfg, moe)
     if ring or kv_quant:
         _refuse_mla(cfg, "a ring or int8 cache")
     cache = (
-        init_quant_cache(cfg, b, L) if kv_quant else init_cache(cfg, b, L)
+        init_quant_cache(cfg, b, L) if kv_quant
+        else init_cache(cfg, b, L, chunk=chunk)
     )
     x = _embed(cfg, embed_p, tokens)
     new = []
-    for p, layer in zip(block_p, kv_cache.layers(cache)):
+    for i, (p, layer) in enumerate(zip(block_p, kv_cache.layers(cache))):
         # The prompt's own rows ARE the rows it attends: one full-
         # sequence attention a block over the s new rows, banked after.
         if cfg.mla is not None:
@@ -998,13 +1084,15 @@ def prefill(
             q_nope, q_pe, *rows = mla.project(cfg, p, h, 0)
             attn = mla.attend(cfg, p, q_nope, q_pe, *rows, 0)
         else:
-            q, *rows = _block_qkv(cfg, p, x, 0)
-            attn = _attend_full(q, *rows, _window(cfg), use_flash)
+            q, *rows = _block_qkv(cfg, p, x, 0, i)
+            with _attn_scope(_window(cfg, i)):
+                attn = _attend_full(q, *rows, _window(cfg, i), use_flash)
         x = _block_attn_out(cfg, p, x, attn, mlp_layer)
-        if ring:
+        W = layer[0].shape[1]
+        if ring or (kv_cache.ring_layer(cfg, i) and s > W):
             # Slot j gets the newest prompt position congruent to j
             # (mod W); never-written slots (s < W) gather garbage that
-            # _attend_ring masks by p_j >= 0.
+            # the attention masks by the position a slot holds (< 0).
             jslots = jnp.arange(W)
             p_j = (s - 1) - jnp.mod((s - 1) - jslots, W)
             idx = jnp.clip(p_j, 0, s - 1)
@@ -1171,6 +1259,9 @@ def generate(
             f"cache_mode must be 'full' or 'ring', got {cache_mode!r}"
         )
     ring = cache_mode == "ring"
+    if ring:
+        kv_cache.refuse_rings(cfg, "cache_mode='ring' (one ring for all "
+                              "layers)")
     if ring and _window(cfg) is None:
         raise ValueError(
             "cache_mode='ring' holds exactly the attention window: set "
@@ -1513,6 +1604,9 @@ def speculative_generate(
     _check_decodable(cfg, total)
     _refuse_mla(cfg, "speculative decoding's rolled-back cache")
     _refuse_mla(draft_cfg, "speculative decoding's rolled-back cache")
+    kv_cache.refuse_rings(cfg, "speculative decoding's rolled-back cache")
+    kv_cache.refuse_rings(
+        draft_cfg, "speculative decoding's rolled-back cache")
     # The draft decodes to the same frontier (its table clamps just as
     # silently — garbage proposals would only collapse the acceptance
     # rate, with no error).

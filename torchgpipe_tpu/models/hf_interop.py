@@ -1590,8 +1590,8 @@ def config_from_hf_latent_moe(
     if select not in _SELECT:
         raise ValueError(
             f"topk_method={select!r} is not computed here "
-            f"({sorted(_SELECT)} are); a grouped or bias-corrected "
-            "selection is one more entry of models.moe._SELECT"
+            f"({sorted(_SELECT)} are); a grouped selection is one "
+            "more entry of models.moe._SELECT"
         )
     if getattr(hf, "attention_bias", False):
         raise ValueError("attention_bias=True: MLA here is bias-free")
@@ -1757,3 +1757,101 @@ def config_from_hf_mixed_moe(hf_config: Any, held: Any = None) -> tuple:
 
 
 __all__ += ["config_from_hf_mixed_moe"]
+
+
+def config_from_hf_afmoe(hf_config: Any, held: Any = None) -> tuple:
+    """(TransformerConfig, MoEConfig) for a published ``model_type:
+    afmoe`` config (Arcee's Trinity family; the key set ``layer_types``,
+    ``sliding_window``, ``num_dense_layers``, ``mup_enabled``,
+    ``num_experts``, ``num_experts_per_tok``, ``num_shared_experts``,
+    ``moe_intermediate_size``, ``score_func``, ``route_norm``,
+    ``route_scale``, ``n_group`` / ``topk_group`` / ``num_limited_groups``).
+    ``hf_config`` is any object with those attributes, as they stand in
+    ``config.json``.
+
+    What the record's keys do not say is the ``afmoe`` block itself: an
+    output gate on the attention (``attn_gate``), q and k RMS-normed per
+    head (``qk_norm``), a norm on each branch's output as well as its
+    input (``sandwich_norm``), sliding layers rotated and windowed and
+    full layers NOT rotated (``layer_types`` becomes the period
+    ``cfg.attn_layers``), the embedding scaled by ``sqrt(hidden_size)``
+    under ``mup_enabled``, and experts chosen by score plus a per-expert
+    bias and weighted by the score alone (``select='bias'``).  The
+    leading ``num_dense_layers`` blocks are dense SwiGLUs of
+    ``intermediate_size``, told apart by their params
+    (``generation._mlp_out``).  ``held=(first, count)`` makes the expert
+    layers one chip's share (``MoEConfig.held``).  Serving path only: the
+    training block refuses the gate and the sandwich norms by name.
+    Grouped selection (``n_group`` or ``num_limited_groups`` other than
+    1), an unknown ``score_func`` and a rope scaling raise instead of
+    being ignored; ``load_balance_coeff`` is a training term and unused."""
+    from torchgpipe_tpu.models.moe import MoEConfig
+    from torchgpipe_tpu.models.transformer import AttnLayer
+
+    hf = hf_config
+    score = getattr(hf, "score_func", "sigmoid")
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(
+            f"score_func={score!r} is not computed here "
+            "('softmax' and 'sigmoid' are)"
+        )
+    for key in ("n_group", "num_limited_groups", "num_expert_groups",
+                "topk_group"):
+        if getattr(hf, key, 1) != 1:
+            raise ValueError(
+                f"{key}={getattr(hf, key)}: a grouped selection is not "
+                "computed here (the bias-corrected top-k runs over all "
+                "experts: 1 group, 1 of them taken)"
+            )
+    if getattr(hf, "rope_scaling", None):
+        raise ValueError(
+            f"rope_scaling={hf.rope_scaling}: the sliding layers rotate "
+            "at rope_theta unscaled here"
+        )
+    if getattr(hf, "attention_bias", False):
+        raise ValueError("attention_bias=True is not read by this importer")
+    dim, inter = hf.hidden_size, hf.intermediate_size
+    theta = float(hf.rope_theta)
+    cfg = TransformerConfig(
+        vocab=hf.vocab_size,
+        dim=dim,
+        n_layers=hf.num_hidden_layers,
+        n_heads=hf.num_attention_heads,
+        n_kv_heads=hf.num_key_value_heads,
+        n_head_dim=int(hf.head_dim),
+        mlp_ratio=3.0 * inter / (2.0 * dim),
+        rope_theta=theta,
+        norm_eps=float(hf.rms_norm_eps),
+        tie_embeddings=bool(getattr(hf, "tie_word_embeddings", False)),
+        qk_norm=True,
+        attn_gate=True,
+        sandwich_norm=True,
+        act=hf.hidden_act,
+        embed_scale=(float(dim) ** 0.5
+                     if getattr(hf, "mup_enabled", False) else None),
+        attn_layers=_attn_period(list(hf.layer_types), {
+            "sliding_attention": AttnLayer(int(hf.sliding_window), theta),
+            "full_attention": AttnLayer(None, theta, rope=False),
+        }),
+    )
+    if cfg.mlp_hidden != inter:
+        raise ValueError(
+            f"intermediate_size={inter} cannot be expressed by this "
+            f"config's 128-aligned SwiGLU formula (got {cfg.mlp_hidden})"
+        )
+    moe = MoEConfig(
+        n_experts=int(hf.num_experts),
+        top_k=int(hf.num_experts_per_tok),
+        dispatch="dropless",
+        scoring=score,
+        norm_topk=bool(getattr(hf, "route_norm", False)),
+        route_scale=float(getattr(hf, "route_scale", 1.0)),
+        n_shared=int(getattr(hf, "num_shared_experts", 0) or 0),
+        expert_hidden=int(hf.moe_intermediate_size),
+        held=None if held is None else (int(held[0]), int(held[1])),
+        select="bias",
+    )
+    return cfg, moe
+
+
+__all__ += ["config_from_hf_afmoe"]

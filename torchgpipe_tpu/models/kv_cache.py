@@ -14,6 +14,16 @@ one *length* axis, the position in the sequence.  Three kinds:
 * :class:`LatentCache` — what latent attention (``cfg.mla``) caches:
   ``ckv [b, L, kv_lora_rank]`` and ``kpe [b, L, qk_rope_head_dim]``.
 
+A model whose layers mix window and full attention (a period of more
+than one ``cfg.attn_layers`` entry) gets banks that differ in LENGTH by
+layer (:func:`layer_rows`): a window layer holds a RING of ``window +
+chunk - 1`` rows (rounded up to the decode kernel's block; position
+``p`` lives in row ``p % rows``, and a chunk's ``chunk`` queries still
+find their whole band beside the rows they write), a full layer
+``max_len`` rows.  Every function here goes by the bank's own length.
+A one-entry period (a model-global window, or none) keeps ``max_len``
+rows in every layer.
+
 Every kind has two CONTENT banks a layer (K and V, or the latent and
 the rotated key head) and, where its rows are quantised, one scale bank
 beside each.  :func:`layers` hands a layer's banks out as ``(a, b,
@@ -99,12 +109,71 @@ def _refuse_mla(cfg: TransformerConfig, what: str) -> None:
         )
 
 
+# A ring's length is a multiple of the decode kernel's largest block
+# (``ops.flash_attention._decode_tiling``: 512 rows), so that the kernel
+# reads a ring at the block it reads a full layer at.
+RING_GRANULE = 512
+
+
+def ring_layer(cfg: TransformerConfig, layer: int) -> bool:
+    """Whether layer ``layer``'s banks are a ring: a window layer of a
+    model that mixes layer types (a one-entry period keeps plain rows;
+    its ring is ``generate(cache_mode='ring')``'s)."""
+    return (len(cfg.attn_period) > 1
+            and cfg.attn_layer(layer).window is not None)
+
+
+def layer_kind(cfg: TransformerConfig, layer: int) -> str:
+    """``'window'`` for a layer that attends in a window (a ring's rows
+    where the model mixes layer types), ``'full'`` for one that attends
+    over its whole context (a latent layer among them): the two kinds
+    the pool's bytes and the attention's rows are counted by."""
+    full = cfg.mla is not None or cfg.attn_layer(layer).window is None
+    return "full" if full else "window"
+
+
+def ring_rows(window: int, chunk: int) -> int:
+    """Rows of a ring for ``window`` under writes of ``chunk`` tokens a
+    call: the band of the chunk's first query and the chunk itself,
+    rounded up to :data:`RING_GRANULE`."""
+    return -(-(window + chunk - 1) // RING_GRANULE) * RING_GRANULE
+
+
+def layer_rows(cfg: TransformerConfig, max_len: int, chunk: int = 1
+               ) -> List[int]:
+    """The length of each layer's banks: ``max_len``, or for a ring
+    layer (:func:`ring_layer`) :func:`ring_rows` where that is less."""
+    return [
+        min(ring_rows(cfg.attn_layer(i).window, chunk), max_len)
+        if ring_layer(cfg, i) else max_len
+        for i in range(cfg.n_layers)
+    ]
+
+
+def refuse_rings(cfg: TransformerConfig, what: str) -> None:
+    """Refuse, by name, what is written for rows that lie at their
+    position: a model that mixes layer types holds its window layers'
+    rows in rings (position ``p`` in row ``p % rows``, older rows
+    overwritten)."""
+    if len(cfg.attn_period) > 1:
+        raise NotImplementedError(
+            f"{what} takes cache rows that lie at their position; this "
+            f"model mixes {len(cfg.attn_period)} attention layer types "
+            "(cfg.attn_layers) and its window layers' rows live in rings "
+            "(kv_cache.layer_rows), which plain prefill / generate / "
+            "decode_slots and serving.Engine's plain pool serve"
+        )
+
+
 def init_cache(
     cfg: TransformerConfig, batch: int, max_len: int,
-    dtype: Optional[jnp.dtype] = None,
+    dtype: Optional[jnp.dtype] = None, chunk: int = 1,
 ) -> Any:
     """Zeroed cache for ``cfg.n_layers`` blocks, as the attention kind
-    says: :class:`KVCache`, or :class:`LatentCache` under ``cfg.mla``."""
+    says: :class:`KVCache`, or :class:`LatentCache` under ``cfg.mla``.
+    ``chunk`` is the most tokens a call will write into a row at once
+    (a serving pool's largest prefill chunk): it sizes the rings of a
+    model that mixes layer types (:func:`layer_rows`) and nothing else."""
     dt = dtype or cfg.dtype
     if cfg.mla is not None:
         m = cfg.mla
@@ -115,10 +184,11 @@ def init_cache(
                  for _ in range(cfg.n_layers)],
             length=jnp.zeros((), jnp.int32),
         )
-    shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
+    shapes = [(batch, rows, cfg.kv_heads, cfg.head_dim)
+              for rows in layer_rows(cfg, max_len, chunk)]
     return KVCache(
-        k=[jnp.zeros(shape, dt) for _ in range(cfg.n_layers)],
-        v=[jnp.zeros(shape, dt) for _ in range(cfg.n_layers)],
+        k=[jnp.zeros(shape, dt) for shape in shapes],
+        v=[jnp.zeros(shape, dt) for shape in shapes],
         length=jnp.zeros((), jnp.int32),
     )
 
@@ -129,6 +199,7 @@ def init_quant_cache(
     """Zeroed int8 KV cache for ``cfg.n_layers`` blocks."""
     shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
     _refuse_mla(cfg, "the int8 QuantKVCache")
+    refuse_rings(cfg, "the int8 QuantKVCache")
     sshape = (batch, cfg.kv_heads, max_len)
     return QuantKVCache(
         k=[jnp.zeros(shape, jnp.int8) for _ in range(cfg.n_layers)],
@@ -154,10 +225,16 @@ def _dequant_rows(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
     return q.astype(jnp.float32) * jnp.transpose(scale, (0, 2, 1))[..., None]
 
 
-def _cache_rows(cache: Any) -> int:
-    """``max_len`` of a cache of any kind."""
+def bank_rows(cache: Any) -> List[int]:
+    """The length of each layer's banks, in block order."""
     field = _bank_fields(cache)[0]
-    return getattr(cache, field)[0].shape[_LENGTH_AXIS[field]]
+    return [bank.shape[_LENGTH_AXIS[field]] for bank in getattr(cache, field)]
+
+
+def _cache_rows(cache: Any) -> int:
+    """``max_len`` of a cache of any kind: its longest layer's length
+    (every layer's, unless window layers hold rings)."""
+    return max(bank_rows(cache))
 
 
 def layers(cache: Any) -> Iterator[Layer]:
@@ -287,17 +364,16 @@ def copy_rows(cache: Any, src: Any, dst: jnp.ndarray, n: jnp.ndarray) -> Any:
     shipped rows (:func:`slot_rows`, of this or of another pool with the
     same :func:`slot_row_specs`).  Rows ``>= n`` of ``dst`` and every
     other slot are untouched.  ``src`` (an index), ``dst`` and ``n`` may
-    be traced values: one fixed-shape program serves every copy."""
-    L = _cache_rows(cache)
-    row_mask = jnp.arange(L) < n          # [L]
+    be traced values: one fixed-shape program serves every copy.  Each
+    bank goes by its own length."""
     shipped = isinstance(src, dict)
 
     def put(f: str, i: int, bank: jnp.ndarray, axis: int) -> jnp.ndarray:
         # A slot's rows have lost the slot axis, so their length axis
         # (and the mask's) sits at ``axis - 1``.
         shape = [1] * (bank.ndim - 1)
-        shape[axis - 1] = L
-        m = row_mask.reshape(shape)
+        shape[axis - 1] = bank.shape[axis]
+        m = (jnp.arange(bank.shape[axis]) < n).reshape(shape)
         row = src[f][i] if shipped else bank[src]
         return bank.at[dst].set(jnp.where(m, row, bank[dst]))
 

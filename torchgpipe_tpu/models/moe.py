@@ -151,7 +151,11 @@ class MoEConfig:
     held: Optional[Tuple[int, int]] = None
     # How the top_k are chosen from the scores (the published
     # ``topk_method``): 'none' / 'greedy' take the k largest over all
-    # experts.  A grouped rule is one more entry of ``_SELECT``.
+    # experts; 'bias' takes the k largest of ``score + b`` (``b`` the
+    # layer's ``router_bias`` [n_experts], a buffer that balancing moves
+    # and no gradient does) and weighs them by the scores WITHOUT ``b``
+    # (DeepSeek-V3's bias-corrected selection, arXiv:2412.19437 section
+    # 2.1.2).  A grouped rule is one more entry of ``_SELECT``.
     select: str = "none"
 
     @property
@@ -245,12 +249,16 @@ def _balance_penalty(
 def _top_k_select(
     probs: jnp.ndarray,
     k: int,
+    bias: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Iterative-argmax top-k routing selection shared by both dispatch
     implementations: per round the highest remaining expert is chosen and
     masked out.  Returns per-round expert indices ``[k, t]``, one-hot masks
-    (list of ``[t, E]``) and gate values ``[k, t]`` (raw softmax probs)."""
-    remaining = probs
+    (list of ``[t, E]``) and gate values ``[k, t]`` (raw softmax probs).
+    With ``bias [E]`` the choice is made on ``probs + bias`` and the gate
+    values are still read from ``probs``: the bias moves WHICH experts a
+    token takes, never how much of each."""
+    remaining = probs if bias is None else probs + bias
     idxs: List[jnp.ndarray] = []
     masks: List[jnp.ndarray] = []
     gates: List[jnp.ndarray] = []
@@ -260,7 +268,10 @@ def _top_k_select(
         idxs.append(idx)
         gates.append(jnp.sum(probs * mask, axis=-1))  # [t]
         masks.append(mask)
-        remaining = remaining * (1.0 - mask)
+        # Scores are positive, so a chosen one is zeroed out of the
+        # running; a biased score may not be, and goes to -inf.
+        remaining = (remaining * (1.0 - mask) if bias is None
+                     else jnp.where(mask > 0, -jnp.inf, remaining))
     return jnp.stack(idxs), masks, jnp.stack(gates)
 
 
@@ -276,8 +287,11 @@ def _gate_denom(gates: jnp.ndarray, k: int,
 
 
 # MoEConfig.select -> selection rule over the scores [t, E]: returns
-# (indices [k, t], one-hot masks, raw gate values [k, t]).
-_SELECT = {"none": _top_k_select, "greedy": _top_k_select}
+# (indices [k, t], one-hot masks, raw gate values [k, t]).  The rules of
+# ``_BIASED`` take the layer's ``router_bias`` third.
+_SELECT = {"none": _top_k_select, "greedy": _top_k_select,
+           "bias": _top_k_select}
+_BIASED = ("bias",)
 
 
 def _scores(moe: MoEConfig, logits: jnp.ndarray) -> jnp.ndarray:
@@ -288,14 +302,16 @@ def _scores(moe: MoEConfig, logits: jnp.ndarray) -> jnp.ndarray:
 
 def _route(
     probs: jnp.ndarray, k: int, moe: Optional[MoEConfig] = None,
+    bias: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, List[jnp.ndarray], jnp.ndarray]:
     """Selection and combine weights under ``moe`` (None: the historical
     plain top-k, normalised when k > 1): per-round expert indices
     ``[k, t]``, one-hot masks, and the weights ``[k, t]`` — the selected
     scores, normalised over the k (``norm_topk``) and scaled
     (``route_scale``)."""
-    select = _SELECT[moe.select if moe is not None else "none"]
-    idxs, masks, raw = select(probs, k)
+    rule = moe.select if moe is not None else "none"
+    idxs, masks, raw = _SELECT[rule](
+        probs, k, *((bias,) if rule in _BIASED else ()))
     gates = raw / _gate_denom(raw, k, None if moe is None else moe.norm_topk)
     if moe is not None and moe.route_scale != 1.0:
         gates = gates * moe.route_scale
@@ -307,6 +323,7 @@ def _top_k_dispatch(
     k: int,
     capacity: int,
     moe: Optional[MoEConfig] = None,
+    bias: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dense dispatch/combine tensors from router probabilities.
 
@@ -316,7 +333,7 @@ def _top_k_dispatch(
     order, k-th choices after all (k-1)-th choices (Switch/GShard order).
     """
     t, E = probs.shape
-    _, masks, gates_kt = _route(probs, k, moe)
+    _, masks, gates_kt = _route(probs, k, moe, bias)
     gates = [gates_kt[kk] for kk in range(k)]
 
     combine = jnp.zeros((t, E, capacity), probs.dtype)
@@ -340,6 +357,7 @@ def _flat_assignment(
     probs: jnp.ndarray,
     k: int,
     moe: Optional[MoEConfig] = None,
+    bias: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Shared routing prologue for the sort-based dispatch paths.
 
@@ -353,7 +371,7 @@ def _flat_assignment(
     ('dropless') paths build on exactly this — their equivalence to the
     dense one-hot path is load-bearing and oracle-tested.
     """
-    idxs, _, gates_kt = _route(probs, k, moe)
+    idxs, _, gates_kt = _route(probs, k, moe, bias)
     experts = idxs.reshape(-1).astype(jnp.int32)  # [kt], k-major
     gates = gates_kt.reshape(-1)
     order = jnp.argsort(experts, stable=True)
@@ -366,6 +384,7 @@ def _sparse_assignment(
     k: int,
     capacity: int,
     moe: Optional[MoEConfig] = None,
+    bias: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Sort-based slot assignment — identical FCFS semantics to
     :func:`_top_k_dispatch` (token order within a choice round, round kk
@@ -379,7 +398,7 @@ def _sparse_assignment(
     """
     t = probs.shape[0]
     kt = k * t
-    experts, gates, order, counts = _flat_assignment(probs, k, moe)
+    experts, gates, order, counts = _flat_assignment(probs, k, moe, bias)
     sorted_e = experts[order]
     starts = jnp.cumsum(counts) - counts  # segment start per expert
     # Position within the expert group IS the dense path's slot number.
@@ -394,6 +413,7 @@ def _dropless_assignment(
     probs: jnp.ndarray,
     k: int,
     moe: Optional[MoEConfig] = None,
+    bias: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Expert-sorted token assignment for the dropless path.
 
@@ -402,7 +422,7 @@ def _dropless_assignment(
     ``group_sizes [E]`` are the ragged segment lengths, and ``gates`` are
     the normalized combine weights in *unsorted* k-major order."""
     t = probs.shape[0]
-    _, gates, order, counts = _flat_assignment(probs, k, moe)
+    _, gates, order, counts = _flat_assignment(probs, k, moe, bias)
     tok = jnp.arange(k * t) % t
     return order, tok[order], counts.astype(jnp.int32), gates
 
@@ -571,6 +591,8 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
             # f32 router: routing decisions are argmaxes over near-ties;
             # keeping them out of bf16 avoids batch-dependent flips.
             "router": _normal(ks[0], (dim, E), std, jnp.float32),
+            **({"router_bias": jnp.zeros((E,), jnp.float32)}
+               if moe.select in _BIASED else {}),
             "w_gate": _normal(ks[1], (n_held, dim, hidden), std, dt),
             "w_up": _normal(ks[2], (n_held, dim, hidden), std, dt),
             "w_down": _normal(
@@ -610,6 +632,10 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
         with jax.named_scope("moe.route"):
             logits = xf.astype(jnp.float32) @ params["router"]  # [t, E]
             probs = _scores(moe, logits)
+            # The selection's bias (``select='bias'``): a buffer, not a
+            # weight, so no gradient reaches it.
+            bias = (lax.stop_gradient(params["router_bias"])
+                    if moe.select in _BIASED else None)
 
         def _finish(y, counts=None):
             """Shared epilogue: the shared expert, reshape + optional
@@ -656,7 +682,8 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
             # segments come first and are the only groups; the rows
             # behind them belong to no group and their gates are 0.
             with jax.named_scope("moe.route"):
-                experts, gates, _, _ = _flat_assignment(probs, K, moe)
+                experts, gates, _, _ = _flat_assignment(
+                    probs, K, moe, bias)
                 tok = jnp.arange(K * t) % t
                 local = experts - first
                 mine = (local >= 0) & (local < n_held)
@@ -683,7 +710,7 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
         )
         if use_sparse:
             experts, gates, keep, slot = _sparse_assignment(
-                probs, K, capacity, moe)
+                probs, K, capacity, moe, bias)
             tok = jnp.arange(K * t) % t
             contrib = xf[tok] * keep[:, None].astype(xf.dtype)
             expert_in = (
@@ -691,7 +718,8 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
                 .at[experts, slot].add(contrib)
             )
         else:
-            combine, dispatch = _top_k_dispatch(probs, K, capacity, moe)
+            combine, dispatch = _top_k_dispatch(
+                probs, K, capacity, moe, bias)
             # Dispatch: [t, E, C] one-hot x [t, d] -> expert buffers [E, C, d].
             expert_in = jnp.einsum(
                 "tec,td->ecd", dispatch.astype(xf.dtype), xf
@@ -749,6 +777,7 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
             "validate_mesh": validate_mesh,
             "param_specs": None if ep is None else {
                 "router": P(),
+                **({"router_bias": P()} if moe.select in _BIASED else {}),
                 "w_gate": P(ep),
                 "w_up": P(ep),
                 "w_down": P(ep),

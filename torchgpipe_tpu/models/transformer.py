@@ -56,11 +56,14 @@ class AttnLayer:
     """The attention of one layer TYPE of a K/V-family model: its window
     (attend iff ``0 <= qpos - kpos < window``; None is full causal
     attention) and its rope record.  ``TransformerConfig.attn_layers``
-    holds one entry a type, in the order the types repeat."""
+    holds one entry a type, in the order the types repeat.  ``rope=False``
+    is a layer whose q and k are NOT rotated (position reaches it through
+    the causal mask and the rotated layers around it)."""
 
     window: Optional[int]
     rope_theta: float
     yarn: Optional[YarnRope] = None
+    rope: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,9 +124,9 @@ class TransformerConfig:
     # None is the one-entry period ``(AttnLayer(attn_window,
     # rope_theta),)``: every reader goes through :meth:`attn_layer`, so a
     # model-global window is the same description, not a second path.
-    # Training path (``transformer_block``); ``models.generation`` and
-    # ``serving.Engine`` refuse a period of more than one entry (window
-    # layers would need a ring beside full layers' rows).
+    # Trained through ``transformer_block``; generated from and served
+    # through a cache whose window layers hold a ring and whose full
+    # layers hold ``max_len`` rows (``models.kv_cache.layer_rows``).
     attn_layers: Optional[Tuple[AttnLayer, ...]] = None
     # Tensor parallelism: name of the mesh axis attention heads and MLP
     # hidden units are sharded over (Megatron-style; see
@@ -138,6 +141,16 @@ class TransformerConfig:
     # Qwen3-style per-head RMSNorm on q and k (params ``qn``/``kn``,
     # [head_dim], applied before rotary).
     qk_norm: bool = False
+    # Output gate on the attention (the ``afmoe`` block): param ``wg``
+    # ``[dim, n_heads * head_dim]``; the heads' output is multiplied by
+    # ``sigmoid(ln1(x) @ wg)`` before ``wo``.  Serving path only: the
+    # training block refuses it by name.
+    attn_gate: bool = False
+    # Sandwich norms: each branch's OUTPUT is normed too before it joins
+    # the residual stream, ``x + ln1p(attn(ln1(x)))`` and ``x +
+    # ln2p(ff(ln2(x)))`` (params ``ln1p`` / ``ln2p``).  Serving path
+    # only: the training block refuses it by name.
+    sandwich_norm: bool = False
     # Explicit per-head dimension (Gemma/Qwen3-class checkpoints where
     # n_heads * head_dim != dim; the attention output projection maps
     # n_heads*head_dim back to dim).  None -> dim // n_heads.
@@ -452,9 +465,9 @@ def _maybe_rope(
     amplitude where the layer's rope record has them, from
     ``cfg.attn_layer(layer)``.  ONE definition shared by the training
     block and every generation path."""
-    if cfg.pos_emb != "rope":
-        return x
     entry = cfg.attn_layer(layer)
+    if cfg.pos_emb != "rope" or not entry.rope:
+        return x
     rot = x.shape[-1] if cfg.rope_pct >= 1.0 else int(
         x.shape[-1] * cfg.rope_pct)
     freqs, amplitude = None, 1.0
@@ -526,6 +539,17 @@ def transformer_block(
             "serving.Engine; block params from models.mla.init_block); "
             "the training block has no MLA forward"
         )
+    for on, what in ((cfg.attn_gate, "attn_gate (the attention's output "
+                      "gate)"),
+                     (cfg.sandwich_norm, "sandwich_norm (a norm on each "
+                      "branch's output)")):
+        if on:
+            raise NotImplementedError(
+                f"{what} is computed on the serving path only "
+                "(models.generation.prefill / decode_slots, "
+                "serving.Engine); the training block does not compute it "
+                "and will not ignore it"
+            )
     dim, hd = cfg.dim, cfg.head_dim
     nh, nkv = cfg.n_heads, cfg.kv_heads
     hidden = cfg.mlp_hidden

@@ -1117,7 +1117,11 @@ def _decode_plan(
     """``(first, last, count)`` per row: the cache blocks a row's
     queries read (the band of the FIRST query opens it, the row's
     length closes it) and how many grid steps the row takes — one at
-    least, in which a row of length 0 writes its zeros."""
+    least, in which a row of length 0 writes its zeros.  Blocks are
+    counted in POSITIONS: block ``j`` is positions ``[j * block_k, (j +
+    1) * block_k)``, which a ring holds in its block ``j % (ring rows
+    // block_k)`` (``lengths`` is then the row's frontier, not clipped
+    to the ring)."""
     last = jnp.maximum(lengths - 1, 0) // block_k
     first = jnp.zeros_like(last)
     if window is not None:
@@ -1434,6 +1438,7 @@ def flash_decode_attention(
                                             # cache row slots[i]
     lengths: Optional[jnp.ndarray] = None,  # [b] int32 — cache rows a
                                             # row reads (0: none)
+    ring: bool = False,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Decode-side flash attention: ``g`` consecutive queries against the
@@ -1461,6 +1466,17 @@ def flash_decode_attention(
     a slot may repeat).  ``lengths`` says how many cache rows a row
     reads where that is not ``pos0+g`` (clipped to ``max_len`` either
     way); a row of length 0 fetches nothing and returns zeros.
+
+    ``ring`` (needs ``window``): the cache's ``s`` rows are a RING,
+    position ``p`` in row ``p % s``, ``s`` at least ``window + g - 1``
+    so that the band of every query of the chunk is in it.  The grid
+    still walks the band's blocks of POSITIONS, first to last; the
+    index map fetches block ``j % (s // block_k)`` of the ring and the
+    mask goes, as ever, by the position a row holds (``j * block_k +``
+    its offset in the block): a ring row that an older or a newer
+    position's block shares is masked in that block's step by the band
+    or by causality.  ``lengths`` is then the row's frontier ``pos0 +
+    g``, not clipped to ``s``.
 
     ``k_scale``/``v_scale`` (both or neither): the cache is int8 with
     per-(position, head) symmetric scales in the QuantKVCache
@@ -1494,6 +1510,13 @@ def flash_decode_attention(
             f"fits {g} queries a row; pass block_k or use the dense path"
         )
     block_k, hw = tiling
+    if ring and (quant or window is None or s < window + g - 1):
+        raise ValueError(
+            f"a ring of {s} rows is read under a window it covers with "
+            f"the chunk (window + g - 1 = "
+            f"{None if window is None else window + g - 1}), bf16 / f32 "
+            "rows only"
+        )
     if quant:
         if slots is not None or lengths is not None or jnp.ndim(pos0):
             raise ValueError(
@@ -1512,21 +1535,21 @@ def flash_decode_attention(
         slots = jnp.arange(b)
     pos0 = jnp.broadcast_to(jnp.asarray(pos0, jnp.int32), (b,))
     lengths = jnp.clip(
-        pos0 + g if lengths is None else lengths, 0, s
+        pos0 + g if lengths is None else lengths, 0, None if ring else s
     ).astype(jnp.int32)
     return _flash_decode_rows(
         q, ck, cv, pos0, lengths, slots.astype(jnp.int32), window=window,
-        block_k=block_k, hw=hw, interpret=interpret,
+        block_k=block_k, hw=hw, ring=ring, interpret=interpret,
     )
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "block_k", "hw", "interpret")
+    jax.jit, static_argnames=("window", "block_k", "hw", "ring", "interpret")
 )
 def _flash_decode_rows(
     q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray, pos0: jnp.ndarray,
     lengths: jnp.ndarray, slots: jnp.ndarray, *, window: Optional[int],
-    block_k: int, hw: int, interpret: bool,
+    block_k: int, hw: int, ring: bool = False, interpret: bool,
 ) -> jnp.ndarray:
     """:func:`flash_decode_attention` over a bf16 / f32 cache, every
     operand per row.  Jitted so that a model's layers (same shapes,
@@ -1541,12 +1564,17 @@ def _flash_decode_rows(
     # rows, nothing of the cache's.
     qf = q.reshape(b, g, groups, hw * r, hd)
     qf = jnp.transpose(qf, (0, 2, 1, 3, 4)).reshape(b, groups, rows, hd)
+    # A band of ``s`` positions that starts inside a block touches one
+    # block of positions more than the ring has.
+    nb = s // block_k
     row, slot, blk, ends = _decode_steps(
-        pos0, lengths, slots, window, block_k, b * (s // block_k)
+        pos0, lengths, slots, window, block_k, b * (nb + 1 if ring else nb)
     )
 
     def kv_im(t: Any, row_ref: Any, slot_ref: Any, blk_ref: Any,
               *_: Any) -> Tuple:
+        if ring:
+            return (slot_ref[t], lax.rem(blk_ref[t], nb), 0, 0)
         return (slot_ref[t], blk_ref[t], 0, 0)
 
     def q_im(t: Any, row_ref: Any, *_: Any) -> Tuple:

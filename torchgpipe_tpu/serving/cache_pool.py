@@ -8,8 +8,10 @@ one page per request: fixed banks of ``num_slots x max_len`` rows per
 layer — a cache of :mod:`torchgpipe_tpu.models.kv_cache` (``KVCache``,
 int8 ``QuantKVCache`` or, for a latent-attention model, ``LatentCache``:
 ``init_cache`` decides by the attention kind) whose batch dim IS the
-slot dim; what a row holds and how its banks are laid out is that
-module's business, not the pool's — a host-side free list handing slots to
+slot dim; what a row holds and how its banks are laid out — a ring of
+``window + chunk - 1`` rows in the window layers of a model that mixes
+layer types, ``max_len`` rows everywhere else — is that module's
+business, not the pool's — a host-side free list handing slots to
 requests and taking them back, and a per-slot ``lengths`` vector (host
 mirror, passed into every compiled step) giving each slot its own
 sequence frontier.
@@ -34,6 +36,7 @@ from typing import Any, Dict, List, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from torchgpipe_tpu.models import kv_cache
 from torchgpipe_tpu.models.kv_cache import init_cache, init_quant_cache
 from torchgpipe_tpu.models.transformer import TransformerConfig
 
@@ -58,6 +61,7 @@ class CachePool:
         *,
         kv_quant: bool = False,
         dtype: Optional[Any] = None,
+        chunk: int = 1,
     ) -> None:
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
@@ -71,10 +75,14 @@ class CachePool:
         self.max_len = max_len
         self.kv_quant = kv_quant
         self.dtype = dtype
+        # The most tokens a step writes into a slot at once: it sizes
+        # the rings of a model that mixes layer types, nothing else.
+        self.chunk = chunk
         self.cache: Any = (
             init_quant_cache(cfg, num_slots, max_len)
             if kv_quant
-            else init_cache(cfg, num_slots, max_len, dtype=dtype)
+            else init_cache(cfg, num_slots, max_len, dtype=dtype,
+                            chunk=chunk)
         )
         self.lengths = np.zeros((num_slots,), np.int32)
         # LIFO free list: the most-recently-freed slot is reused first,
@@ -196,8 +204,18 @@ class CachePool:
 
         return serving_cache_bytes(
             self.cfg, self.num_slots, self.max_len,
-            kv_quant=self.kv_quant, dtype=self.dtype,
+            kv_quant=self.kv_quant, dtype=self.dtype, chunk=self.chunk,
         )
+
+    def bytes_by_kind(self) -> Dict[str, int]:
+        """:meth:`bytes` by the kind of layer that holds them:
+        ``window`` (a layer that attends in a window: a ring's rows in
+        a model that mixes layer types) and ``full``."""
+        out = {"window": 0, "full": 0}
+        for i, banks in enumerate(kv_cache.layers(self.cache)):
+            out[kv_cache.layer_kind(self.cfg, i)] += sum(
+                b.size * b.dtype.itemsize for b in banks if b is not None)
+        return out
 
     def lengths_device(self) -> jnp.ndarray:
         """The per-slot frontier vector as an int32 array for a step.

@@ -262,6 +262,18 @@ class Engine:
                         "latent (generation.LatentCache) — serve it "
                         "with the plain unified engine"
                     )
+        # The same for a model that mixes window and full layers: its
+        # window layers' rows live in rings (``kv_cache.layer_rows``),
+        # and what copies, ships or quantizes rows takes rows that lie
+        # at their position.
+        for on, what in (
+            (kv_quant, "kv_quant (the int8 QuantKVCache)"),
+            (prefix_cache is not None, "a prefix cache (a donor's ring "
+             "has dropped the prefix's rows)"),
+            (role != "unified", f"role={role!r} (KV-row migration)"),
+        ):
+            if on:
+                kv_cache.refuse_rings(cfg, what)
         # Expert layers that are told what they hold (``MoEConfig.held``)
         # report the tokens each held expert received: the step programs
         # then return, in the place of their sampled tokens, the pair
@@ -318,7 +330,7 @@ class Engine:
                 kv_quant=kv_quant, dtype=cache_dtype,
                 param_bytes=tree_bytes(self.params),
                 overhead_bytes=overhead_bytes,
-                donated=donate,
+                donated=donate, chunk=self.prefill_chunk,
             )
             if max_active < 1:
                 raise ValueError(
@@ -331,7 +343,8 @@ class Engine:
             # arrives), so the pool itself is clamped to the cap here.
             num_slots = min(num_slots, max_active)
         self.pool = CachePool(
-            cfg, num_slots, max_len, kv_quant=kv_quant, dtype=cache_dtype
+            cfg, num_slots, max_len, kv_quant=kv_quant, dtype=cache_dtype,
+            chunk=self.prefill_chunk,
         )
         # ``qos`` (serving.qos.QosPolicy) — ONE shared instance across a
         # fleet's engines: tier-ordered admission, per-tenant token
@@ -352,6 +365,7 @@ class Engine:
         self.metrics = metrics or ServingMetrics(
             clock=clock, registry=registry
         )
+        self.metrics.pool_bytes = self.pool.bytes_by_kind()
         self.reporter = reporter
         # ``recorder`` (obs.FlightRecorder) threads a per-request span
         # record through the serving loop: submit/admit, the prefix-
@@ -446,9 +460,19 @@ class Engine:
         if role != "prefill":
             self.trace_counts["decode"] = 0
             self._token_shapes["decode"] = (num_slots, 1)
-        # What a step's attention reads, by its program's (rows, g).
+        # What a step's attention reads, by its program's (rows, g)
+        # and by the kind of layer: the first layer of each kind the
+        # model has stands for its kind (``window``, whose banks are a
+        # ring where the model mixes layer types, and ``full``).
+        kinds: Dict[str, int] = {}
+        for i in range(cfg.n_layers):
+            kinds.setdefault(kv_cache.layer_kind(cfg, i), i)
         self._attend_counters = {
-            shape: attend_rows_counter(self.cfg, self.pool.cache, *shape)
+            shape: {
+                kind: attend_rows_counter(
+                    self.cfg, self.pool.cache, *shape, layer=i)
+                for kind, i in kinds.items()
+            }
             for shape in set(self._token_shapes.values())
         }
         self._build_programs()
@@ -666,16 +690,35 @@ class Engine:
                 "KV-row migration is written for K and V banks; a "
                 "latent-attention pool holds the KV latent"
             )
+        kv_cache.refuse_rings(self.cfg, "KV-row migration (kv_row_specs)")
         return kv_cache.slot_row_specs(self.pool.cache)
 
     def _token_buffer(self, kind: str) -> np.ndarray:
         return np.zeros(self._token_shapes[kind], np.int32)
 
     def _attended(self, pos0: np.ndarray, n_valid: np.ndarray,
-                  g: int) -> Tuple[int, int]:
+                  g: int) -> Dict[str, Tuple[int, int]]:
         """``(rows read, row capacity)`` of a layer's cache attention in
-        the step about to run (``generation.attend_rows_counter``)."""
-        return self._attend_counters[len(n_valid), g](pos0, n_valid)
+        the step about to run (``generation.attend_rows_counter``), by
+        the kind of layer."""
+        return {
+            kind: count(pos0, n_valid)
+            for kind, count in self._attend_counters[len(n_valid), g].items()
+        }
+
+    def _annotate_attended(
+        self, attended: Dict[str, Tuple[int, int]]
+    ) -> None:
+        """The action span's ``rows_read`` / ``rows_cap`` (one layer of
+        each kind, summed) and the same by kind where the model has
+        both (``rows_read_window``, ``rows_cap_full``, ...)."""
+        read, cap = map(sum, zip(*attended.values()))
+        by_kind = {
+            f"rows_{what}_{kind}": v
+            for kind, pair in attended.items()
+            for what, v in zip(("read", "cap"), pair)
+        } if len(attended) > 1 else {}
+        self.timeline.annotate(rows_read=read, rows_cap=cap, **by_kind)
 
     def _lengths_for_step(self) -> jnp.ndarray:
         """The frontier vector for the next compiled step: the previous
@@ -1143,7 +1186,7 @@ class Engine:
                 jnp.asarray(finish), self._key,
             ]
         attended = self._attended(self.pool.lengths[slots], n_valid, g)
-        tl.annotate(rows_read=attended[0], rows_cap=attended[1])
+        self._annotate_attended(attended)
         step = self._launch(
             "prefill", self._prefill_fns[name], args,
             rows=[(r, i) for i, r in enumerate(reqs) if finish[i]],
@@ -1201,7 +1244,7 @@ class Engine:
                 self._tokens_for_step(), jnp.asarray(n_valid), self._key,
             ]
         attended = self._attended(self.pool.lengths, n_valid, 1)
-        tl.annotate(rows_read=attended[0], rows_cap=attended[1])
+        self._annotate_attended(attended)
         step = self._launch(
             "decode", self._decode_fn, args,
             rows=[(r, r.slot) for r in reqs], positions=len(reqs),
@@ -1495,6 +1538,7 @@ class Engine:
             raise ValueError(
                 f"request {req.rid!r} holds no slot — nothing to export"
             )
+        kv_cache.refuse_rings(self.cfg, "KV-row migration (export_kv_rows)")
         self._settle()
         return kv_cache.slot_rows(self.pool.cache, req.slot)
 
@@ -1538,6 +1582,8 @@ class Engine:
         carried token counts against it.  Raises ``RuntimeError`` when
         the pool has no free slot — the router re-parks and retries
         once decode slots free up."""
+        kv_cache.refuse_rings(
+            self.cfg, "KV-row migration (ingest_migration)")
         if self.role != "decode":
             raise ValueError(
                 "ingest_migration is the decode pool's entry point — "

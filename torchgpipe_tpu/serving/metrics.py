@@ -126,6 +126,19 @@ class ServingMetrics:
             "serving_attend_rows_capacity",
             help="rows of every step x max_len; read over capacity is "
                  "the share of the pool a step's attention reads")
+        # The same pair by the kind of layer (one layer of that kind):
+        # ``window`` (its banks a ring where the model mixes layer
+        # types) and ``full``.  The pair above sums one layer of each.
+        self._c_attend_kind = {
+            (kind, what): reg.counter(
+                f"serving_attend_rows_{what}_{kind}",
+                help=f"serving_attend_rows_{what} of one {kind}-"
+                     "attention layer")
+            for kind in ("window", "full") for what in ("read", "capacity")
+        }
+        # Bytes the pool's banks pin, by the same kinds (the engine
+        # sets it once: ``CachePool.bytes_by_kind``).
+        self.pool_bytes: Dict[str, int] = {}
         self._c_tokens = reg.counter(
             "serving_tokens_out", help="tokens emitted")
         self._c_retries = reg.counter(
@@ -264,7 +277,8 @@ class ServingMetrics:
     # ------------------------------------------------------------------ #
 
     def step(self, kind: str, active_slots: int, num_slots: int,
-             deferred: int = 0, attended: Tuple[int, int] = (0, 0),
+             deferred: int = 0,
+             attended: Optional[Dict[str, Tuple[int, int]]] = None,
              ahead: bool = False) -> None:
         """One compiled step, counted when it is LAUNCHED (``ahead``:
         while the step before it was still in flight; the tokens it
@@ -275,10 +289,15 @@ class ServingMetrics:
         pool's size) and may leave ``deferred`` pending prompts to the
         next prefill step.  ``attended`` is ``(rows read, row
         capacity)`` of a layer's cache attention in this step
-        (``models.generation.attend_rows_counter``)."""
+        (``models.generation.attend_rows_counter``) by the kind of
+        layer, ``{'window': (read, capacity), 'full': ...}``, one layer
+        of each kind the model has."""
         self._c_ahead.inc(int(ahead))
-        self._c_attend_read.inc(attended[0])
-        self._c_attend_capacity.inc(attended[1])
+        for layers, (read, cap) in (attended or {}).items():
+            self._c_attend_kind[layers, "read"].inc(read)
+            self._c_attend_kind[layers, "capacity"].inc(cap)
+            self._c_attend_read.inc(read)
+            self._c_attend_capacity.inc(cap)
         if kind == "prefill":
             self._c_prefill.inc()
             self._c_prefill_rows.inc(active_slots)
@@ -375,6 +394,11 @@ class ServingMetrics:
             "prefill_fill_share": self.prefill_fill_share,
             "attend_rows_read": self.attend_rows_read,
             "attend_rows_capacity": self.attend_rows_capacity,
+            "attend_rows_by_kind": {
+                kind: {what: int(self._c_attend_kind[kind, what].value())
+                       for what in ("read", "capacity")}
+                for kind in ("window", "full")},
+            "kv_pool_bytes": dict(self.pool_bytes),
             "retries": self.retries,
             "drains": self.drains,
             "preempted_requests": self.preempted_requests,

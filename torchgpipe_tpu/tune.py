@@ -968,6 +968,7 @@ def serving_cache_bytes(
     *,
     kv_quant: bool = False,
     dtype: Optional[Any] = None,
+    chunk: int = 1,
 ) -> int:
     """Bytes of a ``(num_slots, max_len)`` serving KV-cache pool — the
     same ``eval_shape``-only accounting the training-side probes use (no
@@ -976,7 +977,10 @@ def serving_cache_bytes(
     the HBM the pool will pin, not an estimate.  ``init_cache`` lays a
     latent-attention model's pool out as its latent rows (``cfg.mla``:
     ``(kv_lora_rank + qk_rope_head_dim) * itemsize`` bytes a row a
-    layer, not K and V of ``kv_heads * head_dim``)."""
+    layer, not K and V of ``kv_heads * head_dim``), and the pool of a
+    model that mixes window and full layers by each layer's own length
+    (``kv_cache.layer_rows``: ``chunk``, the pool's largest prefill
+    chunk, sizes the window layers' rings)."""
     from torchgpipe_tpu.models.kv_cache import init_cache, init_quant_cache
 
     if kv_quant:
@@ -985,7 +989,8 @@ def serving_cache_bytes(
         )
     else:
         spec = jax.eval_shape(
-            lambda: init_cache(cfg, num_slots, max_len, dtype=dtype)
+            lambda: init_cache(cfg, num_slots, max_len, dtype=dtype,
+                               chunk=chunk)
         )
     return tree_bytes(spec)
 
@@ -1000,6 +1005,7 @@ def serving_max_slots(
     param_bytes: int = 0,
     overhead_bytes: int = 0,
     donated: bool = False,
+    chunk: int = 1,
 ) -> int:
     """Largest slot count whose KV pool fits ``hbm_budget_bytes`` after
     ``param_bytes`` (the resident weights — ``tree_bytes(params)``) and
@@ -1013,10 +1019,10 @@ def serving_max_slots(
     ``donated=True`` accounts the single aliased copy.  Returns 0 when
     even one slot does not fit (the caller should refuse to build)."""
     one = serving_cache_bytes(
-        cfg, 1, max_len, kv_quant=kv_quant, dtype=dtype
+        cfg, 1, max_len, kv_quant=kv_quant, dtype=dtype, chunk=chunk
     )
     two = serving_cache_bytes(
-        cfg, 2, max_len, kv_quant=kv_quant, dtype=dtype
+        cfg, 2, max_len, kv_quant=kv_quant, dtype=dtype, chunk=chunk
     )
     per_slot = two - one          # bytes strictly linear in slots
     fixed = one - per_slot        # the shared scalar bookkeeping
