@@ -8,6 +8,7 @@ of that.  Shapes are chip_smoke.py's: Mistral-7B widths, bf16.  A compile
 that passes is a compile, not a chip run — nothing executes.
 """
 
+import dataclasses
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
@@ -21,6 +22,7 @@ from torchgpipe_tpu.models import generation
 from torchgpipe_tpu.ops.flash_attention import (
     flash_attention,
     flash_decode_attention,
+    latent_decode_attention,
 )
 
 H, G, D = 32, 8, 128           # Mistral-7B: query heads, KV heads, head dim
@@ -127,6 +129,29 @@ def _decode_ring(rows, g, compact, ring):
 
 T_RING_BYTES = T_SLOTS * T_RING * G * D * 2
 
+# axk1.serve-backlog's pool: 64 heads over a latent of 512 and a shared
+# rotated key head of 64, 128 slots x 4096 rows; the decode program's
+# 128 rows of one token and the prefill program's 25 rows of 32.
+L_H, L_C, L_R, L_SLOTS, L_ROWS = 64, 512, 64, 128, 25
+L_KPE_BYTES = L_SLOTS * MAX_LEN * L_R * 2     # one layer's key head: 64 MiB
+# The same bank rows-major, its 64 padded to a lane tile: what the scatter
+# that writes a step's key heads relays it to and back, twice a layer.
+L_RELAID_BYTES = L_SLOTS * MAX_LEN * 128 * 2
+
+
+def _latent_decode(rows, g, compact):
+    """The latent kernel over the pool's two banks as they lie."""
+    def fn(q_lat, q_pe, ckv, kpe, pos0, lengths, slots):
+        return latent_decode_attention(
+            q_lat, q_pe, ckv, kpe, pos0, sm_scale=0.13, lengths=lengths,
+            slots=slots if compact else None,
+        )
+
+    ints = ((rows,), jnp.int32)
+    return fn, [((rows, g, L_H, L_C), BF16), ((rows, g, L_H, L_R), BF16),
+                ((L_SLOTS, MAX_LEN, L_C), BF16),
+                ((L_SLOTS, MAX_LEN, L_R), BF16), ints, ints, ints]
+
 
 def _prefill_attention(s):
     def fn(q, k, v):
@@ -170,6 +195,15 @@ CASES = {
         *_decode_ring(T_SLOTS, 1, False, False), True, T_RING_BYTES),
     "decode-full-16k-compact-128": (
         *_decode_ring(T_ROWS, 128, True, False), True, T_RING_BYTES),
+    # The latent pool's kernel under both programs' shapes.  The key
+    # head's bank lies positions-minor on the chip (a minor dim of 64
+    # would be padded to a lane tile), and the kernel takes it so: a
+    # ``[block_k, 64]`` tile of it was a relayout copy of the bank, 128
+    # MiB a layer (described-chip compile, PR 35).
+    "latent-decode-pool": (
+        *_latent_decode(L_SLOTS, 1, False), True, L_KPE_BYTES),
+    "latent-decode-compact": (
+        *_latent_decode(L_ROWS, CHUNK, True), True, L_KPE_BYTES),
     # prefill()/generate(): a prompt the 128-blocks do not divide must
     # take the dense path (100 was refused by Mosaic, 200 compiled to a
     # short grid that left the tail rows unwritten), an aligned one the
@@ -248,6 +282,64 @@ def test_decode_slots_compiles_for_v5e(compact, chip, monkeypatch):
     ).compile()
     assert "flash_decode" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < BANK_BYTES
+
+
+@pytest.mark.parametrize("compact", [False, True], ids=["pool", "compact"])
+def test_latent_decode_slots_compiles_for_v5e(compact, chip, monkeypatch):
+    """``decode_slots`` over ``axk1.serve-backlog``'s latent pool (128
+    slots x 4096 rows, donated) at published widths, the dense layer and
+    one expert layer: the decode program and the compact prefill program
+    take the latent kernel once a layer.  The dense path's f32 score
+    planes are gone (524 MB of temporaries at these shapes); what stays
+    is ONE relaid key head's bank, 128 MiB: the scatter writes it
+    rows-major, the program holds it positions-minor, so each layer
+    copies it there and back (the dense program does too; 0.62 ms a
+    layer in both programs of the cell, chip run, PR 35).  The latent
+    bank goes into the kernel as it lies."""
+    import types
+
+    from chipbench import weights_axk1
+    from chipbench.common import HERE, load_json
+    from torchgpipe_tpu.models.hf_interop import config_from_hf_latent_moe
+
+    m = dict(load_json(HERE / "configs" / "axk1.json"), num_hidden_layers=2)
+    hf = dict(m, n_routed_experts=weights_axk1.published(m, "n_routed_experts"))
+    cfg, moe = config_from_hf_latent_moe(
+        types.SimpleNamespace(**hf),
+        held=(m["held_first"], m["n_routed_experts"]),
+    )
+    cfg = dataclasses.replace(cfg, dtype=BF16)
+    monkeypatch.setattr(jax, "devices", lambda *a: [chip])
+    where = SingleDeviceSharding(chip)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where),
+            tree,
+        )
+
+    params = jax.eval_shape(lambda: weights_axk1.make_flat(m, 0))
+    cache = jax.eval_shape(
+        lambda: generation.init_cache(cfg, L_SLOTS, MAX_LEN)
+    )
+    rows, g = (L_ROWS, CHUNK) if compact else (L_SLOTS, 1)
+    ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=where
+    )
+
+    def step(params, cache, lengths, tokens, n_valid, slots):
+        return generation.decode_slots(
+            cfg, params, tokens, cache, lengths, n_valid, moe=moe,
+            slots=slots if compact else None,
+        )
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), ints(L_SLOTS), ints(rows, g),
+        ints(rows), ints(rows),
+    ).compile()
+    text = compiled.as_text()
+    assert "latent_decode" in text and "flash_decode" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * L_RELAID_BYTES
 
 
 def test_mpmd_stored_backward_compiles_for_v5e(chip, monkeypatch):

@@ -817,3 +817,174 @@ def test_decode_tiling_by_shape():
     assert _decode_tiling(1024, 32, 8, 2, 4096) is None
     assert not supports_decode((8, 1024, 32, 128), (8, 4096, 8, 128), None)
     assert _decode_tiling(1, 32, 8, 2, 384) == (128, 8)
+
+
+# --------------------------------------------------------------------- #
+# decode kernel over a latent cache                                      #
+# --------------------------------------------------------------------- #
+
+_LAT_L, _LAT_H, _LAT_C, _LAT_R, _LAT_N = 1024, 8, 128, 16, 16
+
+
+def _latent_model(dtype):
+    """A latent-attention config at the kernel's toy widths and a
+    float32 ``W_kvb`` (the CPU has no batched bf16 product; the banks
+    and the kernel's operands keep ``dtype``)."""
+    from torchgpipe_tpu.models.transformer import (
+        MLAConfig, TransformerConfig,
+    )
+
+    cfg = TransformerConfig(
+        dim=64, n_layers=1, n_heads=_LAT_H, dtype=dtype,
+        mla=MLAConfig(q_lora_rank=8, kv_lora_rank=_LAT_C,
+                      qk_nope_head_dim=_LAT_N, qk_rope_head_dim=_LAT_R,
+                      v_head_dim=_LAT_N),
+    )
+    w = jax.random.normal(
+        jax.random.PRNGKey(3), (_LAT_C, _LAT_H * 2 * _LAT_N), jnp.float32
+    )
+    return cfg, {"wkv_b": w / np.sqrt(_LAT_C)}
+
+
+def _latent_rows(g, block_k, slots_kind):
+    """Rows of one call: frontiers on and off a block edge and at the
+    cache's end, with a row that reads nothing BETWEEN live rows; their
+    slots, the pool's own or a subset of 12 banks in which the dead row
+    and one live row repeat another live row's slot."""
+    lengths = np.array(
+        [g, block_k - 1, 0, block_k, block_k + 1, _LAT_L - g + 1, _LAT_L]
+    )
+    pos0 = np.maximum(lengths - g, 0).astype(np.int32)
+    b = len(lengths)
+    if slots_kind == "pool":
+        return pos0, lengths.astype(np.int32), np.arange(b, dtype=np.int32), b
+    slots = np.random.default_rng(g + block_k).choice(12, b, replace=False)
+    slots[2] = slots[1]      # the dead row repeats a live row's slot
+    slots[4] = slots[3]      # and so does a live one
+    return pos0, lengths.astype(np.int32), slots.astype(np.int32), 12
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("slots_kind", ["pool", "repeated"])
+@pytest.mark.parametrize("block_k", [128, 256, 512])
+@pytest.mark.parametrize("g", [1, 32])
+def test_latent_decode_kernel_matches_mla_attend(g, block_k, slots_kind,
+                                                 dtype):
+    """``latent_decode_attention`` between ``mla.attend``'s own absorbed
+    einsums == ``mla.attend`` (absorbed) over the rows' slots of the
+    same banks, for one token a row and a chunk of 32 (whose 256 query
+    rows a cache row share one fetch of the tile), per-row ``pos0``,
+    frontiers on both sides of a block edge and at the cache's end, at
+    every block size.  A row of length 0 returns zeros, and the live
+    rows come out bit-equal to a call that leaves it out.  bf16 banks
+    against the oracle on the same values in float32: the kernel rounds
+    ``q_lat`` and ``p`` to the banks' type, as ``mla.attend`` does on a
+    bf16 cache."""
+    from torchgpipe_tpu.models import mla
+    from torchgpipe_tpu.ops.flash_attention import latent_decode_attention
+
+    cfg, p = _latent_model(dtype)
+    pos0, lengths, slots, banks = _latent_rows(g, block_k, slots_kind)
+    b = len(pos0)
+    ks = jax.random.split(jax.random.PRNGKey(g + block_k), 4)
+    q_nope = jax.random.normal(ks[0], (b, g, _LAT_H, _LAT_N), jnp.float32)
+    q_pe = jax.random.normal(ks[1], (b, g, _LAT_H, _LAT_R), jnp.float32)
+    q_pe = q_pe.astype(dtype)
+    ckv = jax.random.normal(ks[2], (banks, _LAT_L, _LAT_C), jnp.float32)
+    kpe = jax.random.normal(ks[3], (banks, _LAT_L, _LAT_R), jnp.float32)
+    ckv, kpe = ckv.astype(dtype), kpe.astype(dtype)
+
+    wk, wv = mla.absorbed_halves(cfg, p)
+    q_lat = mla.absorb_queries(q_nope, wk, dtype)
+
+    def kernel(rows):
+        return latent_decode_attention(
+            q_lat[rows], q_pe[rows], ckv, kpe, jnp.asarray(pos0[rows]),
+            sm_scale=mla.score_scale(cfg.mla),
+            slots=None if slots_kind == "pool" and len(rows) == b
+            else jnp.asarray(slots[rows]),
+            lengths=jnp.asarray(lengths[rows]), block_k=block_k,
+            interpret=True,
+        )
+
+    f32 = jnp.float32
+    ref = np.asarray(mla.attend(
+        cfg, p, q_nope, q_pe.astype(f32), ckv[slots].astype(f32),
+        kpe[slots].astype(f32), jnp.asarray(pos0), absorbed=True,
+    ))
+    every = np.arange(b)
+    live = every[lengths > 0]
+    o_lat = kernel(every)
+    assert o_lat.dtype == dtype
+    got = np.asarray(mla.expand_output(o_lat, wv))
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got[live], ref[live], rtol=2e-5, atol=2e-5)
+    else:
+        _close_in_bf16(got[live], ref[live])
+    o_lat = np.asarray(o_lat, np.float32)
+    assert not o_lat[lengths == 0].any()
+    np.testing.assert_array_equal(
+        np.asarray(kernel(live), np.float32), o_lat[live]
+    )
+
+
+@pytest.mark.parametrize("block_k", [128, 256, 512])
+def test_latent_decode_grid_visits_the_rows_the_host_counts(block_k):
+    """``decode_rows_read`` (no window) == the blocks of the latent
+    kernel's grid, frontier by frontier: a live row takes the blocks up
+    to its length, and the one step of a row that reads nothing names
+    the tile already resident (the last live row's slot and last
+    block), so nothing is fetched for it."""
+    from torchgpipe_tpu.ops.flash_attention import (
+        _decode_steps, decode_rows_read,
+    )
+
+    rng = np.random.default_rng(block_k)
+    b, nkb = 24, 4096 // block_k
+    pos0 = rng.integers(0, 4096 - 32, b)
+    lengths = np.where(rng.random(b) < 0.25, 0, pos0 + rng.integers(1, 33, b))
+    lengths[0], lengths[5:7] = 700, 0
+    slots = rng.permutation(64)[:b]
+    row, slot, blk, ends = (np.asarray(a) for a in _decode_steps(
+        jnp.asarray(pos0), jnp.asarray(lengths), jnp.asarray(slots), None,
+        block_k, b * nkb,
+    ))
+    n = int(ends[-1])
+    live = lengths[row[:n]] > 0
+    assert decode_rows_read(pos0, lengths, None, block_k) == (
+        block_k * int(live.sum())
+    )
+    for i in range(b):
+        steps = np.flatnonzero(row[:n] == i)
+        if lengths[i] > 0:
+            blocks = -(-lengths[i] // block_k)
+            assert blk[steps].tolist() == list(range(blocks))
+            assert (slot[steps] == slots[i]).all()
+        else:
+            (t,) = steps
+            assert (slot[t], blk[t]) == (slot[t - 1], blk[t - 1])
+
+
+def test_latent_tiling_by_shape():
+    """Block and query rows a product from the shapes alone (A.X-K1's
+    heads): one token a row puts its 64 heads through one product of a
+    512-block, a chunk of 32 its 2,048 rows 512 at a time; a chunk
+    whose accumulator does not stay resident, and a length no block
+    divides, have no tiling."""
+    from torchgpipe_tpu.ops.flash_attention import (
+        _latent_tiling, latent_decode_attention,
+    )
+
+    assert _latent_tiling(64, 512, 4096) == (512, 64)
+    assert _latent_tiling(32 * 64, 512, 4096) == (512, 512)
+    assert _latent_tiling(6 * 128, 512, 4096) == (512, 256)
+    assert _latent_tiling(5 * 128, 512, 384) == (128, 128)
+    assert _latent_tiling(128 * 64, 512, 4096) is None
+    assert _latent_tiling(64, 512, 1000) is None
+    with pytest.raises(ValueError, match="no latent decode tiling"):
+        latent_decode_attention(
+            jnp.zeros((1, 1, 8, 128)), jnp.zeros((1, 1, 8, 16)),
+            jnp.zeros((1, 1000, 128)), jnp.zeros((1, 1000, 16)),
+            jnp.zeros((1,), jnp.int32), sm_scale=1.0, interpret=True,
+        )

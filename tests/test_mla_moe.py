@@ -101,8 +101,8 @@ def tokens_of(seed, n):
     return np.asarray(jax.random.randint(jax.random.PRNGKey(seed), (n,), 0, TOY["vocab_size"]))
 
 
-def reference_logits(flat, tokens):
-    return ref.ServeReference(TOY, flat, len(tokens), len(tokens)).all_logits(tokens)
+def reference_logits(flat, tokens, m=TOY):
+    return ref.ServeReference(m, flat, len(tokens), len(tokens)).all_logits(tokens)
 
 
 # --------------------------------------------------------------------- #
@@ -236,6 +236,162 @@ def test_engine_serves_the_references_tokens_with_slots_recycled(model, chunk):
     kinds = {k: sum(e.name == "engine." + k for e in actions) for k in ("prefill", "decode")}
     assert {k: eng.metrics.moe_expert_tokens(k)["steps"] for k in kinds} == kinds
     assert sum(e.fields["held"] for e in fetches) == snap["moe_held_assignments"]
+
+
+# --------------------------------------------------------------------- #
+# (b') the latent decode kernel under ``decode_slots``                   #
+# --------------------------------------------------------------------- #
+
+# The toy model at widths the kernel's gate admits: a latent of one lane
+# tile, eight heads (a sublane tile of query rows at one token a row),
+# and a pool of 256 rows a slot (one block).
+KTOY = dict(TOY, kv_lora_rank=128, num_attention_heads=8)
+KLEN = 256
+
+
+@pytest.fixture(scope="module")
+def kernel_model():
+    cfg, moe = configs(KTOY)
+    return cfg, moe, weights_axk1.make_flat(KTOY, 11)
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The gate's platform check answered as on a TPU: the kernel runs
+    (in interpret mode, the platform being the CPU).  Counts the calls
+    that reach it."""
+    from torchgpipe_tpu.models import generation
+    from torchgpipe_tpu.ops import flash_attention
+
+    calls, inner = [], flash_attention.latent_decode_attention
+
+    def counted(q_lat, *args, **kwargs):
+        assert kwargs["interpret"]
+        calls.append(q_lat.shape)
+        return inner(q_lat, *args, **kwargs)
+
+    monkeypatch.setattr(generation, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flash_attention, "latent_decode_attention", counted)
+    return calls
+
+
+def random_pool(cfg, slots, rows, seed=9):
+    """A pool whose every row holds noise: a row the attention must not
+    read, or the write must not touch, shows."""
+    cache = init_cache(cfg, slots, rows)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 2 * cfg.n_layers))
+    noisy = lambda banks: [jax.random.normal(next(keys), a.shape, a.dtype) for a in banks]
+    return cache._replace(ckv=noisy(cache.ckv), kpe=noisy(cache.kpe))
+
+
+@pytest.mark.parametrize("chunk", [4, 8])
+@pytest.mark.parametrize("compact", [True, False], ids=["compact", "pool-wide"])
+def test_decode_slots_through_the_latent_kernel_equals_the_dense_path(
+        kernel_model, chunk, compact, on_tpu, monkeypatch):
+    """``decode_slots`` over a ``LatentCache`` with the kernel under its
+    attention (both forms: the compact prefill rows and the pool-wide
+    decode rows) against the dense path on the same noisy pool and
+    against the plain reference; the slots the script does not write
+    stay bit-identical."""
+    from torchgpipe_tpu.models import generation
+
+    cfg, moe, flat = kernel_model
+    tok = tokens_of(3, 26)
+    pool, lengths = random_pool(cfg, 3, KLEN), jnp.zeros((3,), jnp.int32)
+    got, cache, _ = serve_by_hand(cfg, moe, flat, tok, 17, chunk, compact, pool, lengths, slot=1)
+    steps = -(-17 // chunk) + 9
+    assert len(on_tpu) == steps * cfg.n_layers
+    assert set(on_tpu) == {(2 if compact else 3, chunk, 8, 128), (3, 1, 8, 128)}
+    monkeypatch.setattr(generation, "_on_tpu", lambda: False)
+    dense, dense_cache, _ = serve_by_hand(
+        cfg, moe, flat, tok, 17, chunk, compact, pool, lengths, slot=1)
+    assert len(on_tpu) == steps * cfg.n_layers
+    np.testing.assert_allclose(got, dense, atol=TOL)
+    np.testing.assert_allclose(got, reference_logits(flat, tok, KTOY), atol=TOL)
+    for kernel_bank, dense_bank, before in zip(
+            cache.ckv + cache.kpe, dense_cache.ckv + dense_cache.kpe, pool.ckv + pool.kpe):
+        for slot in (0, 2):
+            assert np.array_equal(np.asarray(kernel_bank[slot]), np.asarray(before[slot]))
+        np.testing.assert_allclose(np.asarray(kernel_bank), np.asarray(dense_bank), atol=TOL)
+
+
+def test_attend_rows_counter_counts_a_latent_pools_blocks(model, kernel_model, monkeypatch):
+    """For a latent pool ``attend_rows_counter`` answers as for a K/V
+    one: the block-rounded rows inside each live row's frontier where
+    the gate admits the shapes on this platform, the capacity where
+    the dense path runs (off a TPU; widths the kernel does not tile)."""
+    from torchgpipe_tpu.models import generation
+    from torchgpipe_tpu.models.generation import attend_rows_counter
+
+    cfg, _, _ = kernel_model
+    cache = init_cache(cfg, 4, 1024)                 # two 512-blocks a slot
+    pos0 = np.array([0, 511, 512, 1000], np.int32)
+    n_valid = np.array([1, 1, 0, 1], np.int32)
+    cap = 4 * 1024
+    assert attend_rows_counter(cfg, cache, 4, 1)(pos0, n_valid) == (cap, cap)
+    monkeypatch.setattr(generation, "_on_tpu", lambda: True)
+    assert attend_rows_counter(cfg, cache, 4, 1)(pos0, n_valid) == (512 + 512 + 0 + 1024, cap)
+    # A chunk of 8: the chunk's last position bounds the read.
+    pos0 = np.array([0, 505, 512, 1016], np.int32)
+    n_valid = np.array([8, 3, 0, 8], np.int32)
+    assert attend_rows_counter(cfg, cache, 4, 8)(pos0, n_valid) == (512 + 1024 + 0 + 1024, cap)
+    # 384 rows go by 128-blocks; 200 rows by none (the dense path).
+    assert attend_rows_counter(cfg, init_cache(cfg, 4, 384), 4, 1)(
+        np.array([0, 127, 128, 383]), n_valid) == (128 + 128 + 0 + 384, 4 * 384)
+    assert attend_rows_counter(cfg, init_cache(cfg, 4, 200), 4, 1)(pos0, n_valid) == (800, 800)
+    # The toy model's latent of 16 is no lane tile: dense on any platform.
+    toy_cfg, _, _ = model
+    assert attend_rows_counter(toy_cfg, init_cache(toy_cfg, 4, 1024), 4, 1)(pos0, n_valid) == (
+        cap, cap)
+
+
+@pytest.mark.parametrize("q_shape,bank_shape,rope_dim,admits", [
+    ((128, 1, 64, 512), (128, 4096, 512), 64, True),      # the cell's decode program
+    ((25, 32, 64, 512), (128, 4096, 512), 64, True),      # and its prefill program
+    ((25, 32, 64, 512), (128, 4000, 512), 64, False),     # a length no block divides
+    ((25, 128, 64, 512), (128, 4096, 512), 64, False),    # an accumulator too large to stay
+    ((128, 1, 64, 512), (128, 128, 512), 64, False),      # a cache too short for a dispatch
+    ((128, 1, 64, 96), (128, 4096, 96), 64, False),       # a latent that is no lane tile
+    ((128, 1, 4, 512), (128, 4096, 512), 64, False),      # query rows under a sublane tile
+    ((128, 1, 64, 512), (128, 4096, 256), 64, False),     # a bank of another width
+], ids=["decode", "prefill", "length", "accumulator", "short", "lanes", "sublanes", "width"])
+def test_the_latent_kernels_gate_by_shape(q_shape, bank_shape, rope_dim, admits):
+    from torchgpipe_tpu.ops.flash_attention import supports_latent_decode
+
+    assert supports_latent_decode(q_shape, bank_shape, rope_dim) is admits
+
+
+@pytest.mark.parametrize("chunk", [4, 8])
+def test_engine_serves_the_references_tokens_through_the_latent_kernel(
+        kernel_model, chunk, on_tpu):
+    """Seven requests through a three-slot engine whose two programs
+    attend through the latent decode kernel (interpret mode): each
+    served token is the reference's best at its position, slots are
+    recycled, and the counters say the attention read the blocks inside
+    the frontiers and not the pool."""
+    cfg, moe, flat = kernel_model
+    eng = Engine(cfg, flat, moe=moe, num_slots=3, max_len=KLEN, prefill_chunk=chunk,
+                 donate=True)
+    rng = np.random.default_rng(chunk)
+    reqs = {f"r{i}": (tokens_of(20 + i, int(rng.integers(3, 22))), int(rng.integers(2, 9)))
+            for i in range(7)}
+    for rid, (prompt, new) in reqs.items():
+        eng.submit(prompt, new, rid=rid)
+    assert eng.run() == "idle"
+    for rid, (prompt, new) in reqs.items():
+        out = eng.result(rid)
+        assert len(out) == new
+        logits = reference_logits(flat, np.concatenate([prompt, out]), KTOY)
+        assert ref.widest_gap(logits[len(prompt) - 1:-1], out) <= TOL
+    assert eng.compile_stats == {"prefill": 1, "decode": 1}
+    # Each program lowered the kernel for its shapes, every layer through one call site.
+    assert len(on_tpu) == 2 * cfg.n_layers
+    assert set(on_tpu) == {(eng.prefill_rows, chunk, 8, 128), (3, 1, 8, 128)}
+    m = eng.metrics
+    steps = m.prefill_steps + m.decode_steps
+    assert 0 < m.attend_rows_read < m.attend_rows_capacity
+    # One block a live row, none for an idle one: every context fits a block.
+    assert m.attend_rows_read % KLEN == 0 and m.attend_rows_read <= 3 * KLEN * steps
 
 
 # --------------------------------------------------------------------- #

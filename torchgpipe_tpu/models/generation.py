@@ -332,6 +332,57 @@ def _flash_decode_eligible(
     )
 
 
+def _on_tpu() -> bool:
+    """Whether the Pallas kernels compile for this platform (off a TPU
+    they run interpreted, for tests)."""
+    return jax.devices()[0].platform == "tpu"
+
+
+def _latent_decode_eligible(
+    q_shape: Tuple[int, ...], ckv: Any, kpe: Any,
+) -> bool:
+    """:func:`_flash_decode_eligible` for a latent pool: whether queries
+    ``[rows, g, H, ...]`` against the banks ``ckv`` / ``kpe`` take the
+    Pallas latent decode kernel (what :func:`_attend_latent` dispatches
+    by and :func:`attend_rows_counter` counts by): a TPU, and shapes
+    the kernel tiles."""
+    from torchgpipe_tpu.ops.flash_attention import supports_latent_decode
+
+    return _on_tpu() and supports_latent_decode(
+        (*q_shape[:3], ckv.shape[2]), ckv.shape, kpe.shape[2]
+    )
+
+
+def _attend_block(
+    cfg: TransformerConfig, cache: Any, rows: int, g: int, layer: int,
+) -> Optional[int]:
+    """The cache block that ``layer``'s attention of a ``decode_slots``
+    call of ``rows`` rows of ``g`` tokens reads by, where this platform
+    and these shapes take a decode kernel; None where the dense path
+    runs."""
+    from torchgpipe_tpu.ops.flash_attention import (
+        _decode_tiling, _latent_tiling,
+    )
+
+    max_len = kv_cache.bank_rows(cache)[layer]
+    if cfg.mla is not None:
+        bank = cache.ckv[layer]
+        if not _latent_decode_eligible(
+            (rows, g, cfg.n_heads), bank, cache.kpe[layer]
+        ):
+            return None
+        return _latent_tiling(g * cfg.n_heads, bank.shape[2], max_len)[0]
+    bank = cache.k[layer]
+    if not _on_tpu() or not _flash_decode_eligible(
+        (rows, g, cfg.n_heads, cfg.head_dim), bank, _window(cfg, layer),
+        quant=isinstance(cache, QuantKVCache), per_row=True,
+    ):
+        return None
+    return _decode_tiling(
+        g, cfg.n_heads, bank.shape[2], bank.dtype.itemsize, max_len
+    )[0]
+
+
 def attend_rows_counter(
     cfg: TransformerConfig, cache: Any, rows: int, g: int, layer: int = 0,
 ) -> Any:
@@ -341,34 +392,22 @@ def attend_rows_counter(
     :func:`decode_slots` call of ``rows`` rows of ``g`` tokens, counted
     on the HOST (numpy) from the rows' frontiers: capacity is ``rows x``
     the layer's length; read is the block-rounded rows the decode kernel
-    fetches (nothing for a row with ``n_valid == 0``) where this platform and
+    (a latent pool's: the latent decode kernel) fetches (nothing for a
+    row with ``n_valid == 0``) where this platform and
     these shapes take the kernel, the capacity where the dense path
-    runs (off TPU, a latent or an int8 pool, shapes the kernel does not
+    runs (off TPU, an int8 pool, shapes the kernel does not
     tile).  What is decided by platform and shape is decided here,
     once; a call is a few numpy operations over ``rows`` ints.  The
     serving engine's ``serving_attend_rows_read`` /
     ``serving_attend_rows_capacity``."""
-    from torchgpipe_tpu.ops.flash_attention import (
-        _decode_tiling, decode_rows_read,
-    )
+    from torchgpipe_tpu.ops.flash_attention import decode_rows_read
 
     max_len = kv_cache.bank_rows(cache)[layer]
     cap = rows * max_len
-    window = _window(cfg, layer)
-    if (
-        cfg.mla is not None
-        or jax.devices()[0].platform != "tpu"
-        or not _flash_decode_eligible(
-            (rows, g, cfg.n_heads, cfg.head_dim), cache.k[layer],
-            window, quant=isinstance(cache, QuantKVCache),
-            per_row=True,
-        )
-    ):
+    block_k = _attend_block(cfg, cache, rows, g, layer)
+    if block_k is None:
         return lambda pos0, n_valid: (cap, cap)
-    bank = cache.k[layer]
-    block_k, _ = _decode_tiling(
-        g, cfg.n_heads, bank.shape[2], bank.dtype.itemsize, max_len
-    )
+    window = _window(cfg, layer)
     ring = kv_cache.ring_layer(cfg, layer)
     # A band as long as the cache drops no block of any frontier.
     if not ring and window is not None and max_len - window + 1 < block_k:
@@ -627,6 +666,58 @@ def _attend_latent_row(
     return mla.attend(cfg, {"wkv_b": wkv_b}, q_nope, q_pe, ckv, kpe, pos0)
 
 
+def _attend_latent(
+    cfg: TransformerConfig,
+    p: Pytree,
+    q_nope: jnp.ndarray,         # [b, g, H, n]
+    q_pe: jnp.ndarray,           # [b, g, H, r] rotated
+    ckv: jnp.ndarray,            # [slots, max_len, c] latent bank
+    kpe: jnp.ndarray,            # [slots, max_len, r]
+    pos0: jnp.ndarray,           # [b] — first query's position
+    slots: Optional[jnp.ndarray] = None,    # [b] — row i reads ckv[slots[i]]
+    lengths: Optional[jnp.ndarray] = None,  # [b] — cache rows a row needs
+) -> jnp.ndarray:
+    """:func:`_attend_chunk` for a latent pool, the one place that
+    decides how ``decode_slots`` attends over it: ``[b, g, H * v]``, row
+    ``i``'s ``g`` queries against the rows of ITS slot (``slots=None``:
+    row ``i`` is slot ``i``).
+
+    On a TPU, for shapes it tiles (:func:`_latent_decode_eligible`), the
+    Pallas latent decode kernel between ``mla.attend``'s own absorbed
+    einsums: it fetches the blocks of each row's slot inside
+    ``lengths`` through its index maps (the banks are its operands as
+    they lie; nothing for a row of length 0, whose output is zeros) and
+    each latent tile once for scores and output alike.  Elsewhere
+    ``mla.attend`` over all ``max_len`` rows whatever ``lengths`` says,
+    a row at a time through a dynamic slice of the banks in the compact
+    form (:func:`_slot_rows`): the off-TPU path, and the oracle."""
+    if _latent_decode_eligible(q_nope.shape, ckv, kpe):
+        from torchgpipe_tpu.ops.flash_attention import (
+            latent_decode_attention,
+        )
+
+        wk, wv = mla.absorbed_halves(cfg, p)
+        with jax.named_scope("mla.scores"):
+            q_lat = mla.absorb_queries(q_nope, wk, ckv.dtype)
+        o_lat = latent_decode_attention(
+            q_lat, q_pe, ckv, kpe, pos0, sm_scale=mla.score_scale(cfg.mla),
+            slots=slots, lengths=lengths,
+            interpret=jax.devices()[0].platform != "tpu",
+        )
+        with jax.named_scope("mla.out"):
+            return mla.expand_output(o_lat, wv)
+    if slots is None:
+        return mla.attend(cfg, p, q_nope, q_pe, ckv, kpe, pos0)
+    return jnp.concatenate([
+        _attend_latent_row(
+            cfg, p["wkv_b"], q_nope[i:i + 1], q_pe[i:i + 1],
+            _slot_rows(ckv, slots[i]), _slot_rows(kpe, slots[i]),
+            pos0[i:i + 1],
+        )
+        for i in range(q_nope.shape[0])
+    ], axis=0)
+
+
 def decode_slots(
     cfg: TransformerConfig,
     params: Pytree,
@@ -688,8 +779,10 @@ def decode_slots(
 
     Plain and quantized caches, and the :class:`LatentCache` of a
     ``cfg.mla`` model (same write and mask rules on its two banks; the
-    attend is ``mla.attend`` over the slot's latent rows, absorbed at
-    ``g = 1``), and the cache of a model that mixes layer types
+    attend is :func:`_attend_latent`: on a TPU the latent decode kernel
+    over the blocks inside each row's frontier, elsewhere ``mla.attend``
+    over all of the slot's latent rows), and the cache of a model that
+    mixes layer types
     (``kv_cache.layer_rows``): a window layer's rows are a ring of at
     least ``window + g - 1`` rows, written at ``(frontier + j) % rows``
     and read under the mask of the position each row holds, so a
@@ -730,18 +823,10 @@ def decode_slots(
             h = _block_norm(cfg, p, "ln1", x)
             q_nope, q_pe, *rows = mla.project(cfg, p, h, pos0)
             layer = kv_cache.write_scattered(layer, rows, at)
-            cc, cr = layer[:2]
-            if compact:
-                attn = jnp.concatenate([
-                    _attend_latent_row(
-                        cfg, p["wkv_b"], q_nope[i:i + 1], q_pe[i:i + 1],
-                        _slot_rows(cc, slots[i]), _slot_rows(cr, slots[i]),
-                        pos0[i:i + 1],
-                    )
-                    for i in range(S)
-                ], axis=0)
-            else:
-                attn = mla.attend(cfg, p, q_nope, q_pe, cc, cr, pos0)
+            attn = _attend_latent(
+                cfg, p, q_nope, q_pe, *layer[:2], pos0, slots=slots,
+                lengths=live,
+            )
         else:
             q, *rows = _block_qkv(cfg, p, x, pos0, i)
             window, ring = _window(cfg, i), kv_cache.ring_layer(cfg, i)

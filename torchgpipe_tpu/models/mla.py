@@ -203,6 +203,44 @@ def _causal(scores: jnp.ndarray, pos0: Any) -> jnp.ndarray:
     return jnp.where(seen[:, None], scores, -jnp.inf)
 
 
+def _wkv_b(cfg: TransformerConfig, p: Pytree) -> jnp.ndarray:
+    """``W_kvb`` as ``[c, H, n + v]``: a head's key half ``[..., :n]``,
+    then its value half."""
+    m = cfg.mla
+    return p["wkv_b"].reshape(
+        m.kv_lora_rank, cfg.n_heads, m.qk_nope_head_dim + m.v_head_dim
+    )
+
+
+def absorbed_halves(
+    cfg: TransformerConfig, p: Pytree,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(W_kvb^K [c, H, n], W_kvb^V [c, H, v])``: what the absorbed
+    form folds into the queries and the output."""
+    w, n = _wkv_b(cfg, p), cfg.mla.qk_nope_head_dim
+    return w[..., :n], w[..., n:]
+
+
+def absorb_queries(
+    q_nope: jnp.ndarray, wk: jnp.ndarray, dtype: Any,
+) -> jnp.ndarray:
+    """The absorbed form's queries: ``q_nope [b, g, H, n]`` through
+    ``W_kvb``'s key half into the latent space, ``[b, g, H, c]`` rounded
+    to ``dtype`` (the cache's)."""
+    return jnp.einsum("bghn,chn->bghc", q_nope, wk,
+                      preferred_element_type=jnp.float32).astype(dtype)
+
+
+def expand_output(o_lat: jnp.ndarray, wv: jnp.ndarray) -> jnp.ndarray:
+    """The absorbed form's epilogue: ``o_lat [b, g, H, c]`` (attention
+    over the latent rows) through ``W_kvb``'s value half, ``[b, g, H *
+    v]`` float32."""
+    out = jnp.einsum("bghc,chv->bghv", o_lat, wv,
+                     preferred_element_type=jnp.float32)
+    b, g, H, v = out.shape
+    return out.reshape(b, g, H * v)
+
+
 def attend(
     cfg: TransformerConfig,
     p: Pytree,
@@ -222,39 +260,37 @@ def attend(
     if absorbed is None:
         absorbed = absorbs(m, g, ckv.shape[1])
     dt, f32 = ckv.dtype, jnp.float32
-    w = p["wkv_b"].reshape(m.kv_lora_rank, H, n + m.v_head_dim)
+    w = _wkv_b(cfg, p)
     wk, wv = w[..., :n], w[..., n:]
     scale = score_scale(m)
     pe = jnp.einsum("bghr,blr->bhgl", q_pe.astype(dt), kpe,
                     preferred_element_type=f32)
     if absorbed:
         with jax.named_scope("mla.scores"):
-            q_lat = jnp.einsum("bghn,chn->bghc", q_nope, wk,
-                               preferred_element_type=f32).astype(dt)
+            q_lat = absorb_queries(q_nope, wk, dt)
             scores = jnp.einsum("bghc,blc->bhgl", q_lat, ckv,
                                 preferred_element_type=f32)
             prob = jax.nn.softmax(_causal((scores + pe) * scale, pos0), -1)
         with jax.named_scope("mla.out"):
             o_lat = jnp.einsum("bhgl,blc->bghc", prob.astype(dt), ckv,
                                preferred_element_type=f32).astype(dt)
-            out = jnp.einsum("bghc,chv->bghv", o_lat, wv,
-                             preferred_element_type=f32)
-    else:
-        with jax.named_scope("mla.expand"):
-            kv = jnp.einsum("blc,chx->blhx", ckv, w,
-                            preferred_element_type=f32).astype(dt)
-        with jax.named_scope("mla.scores"):
-            scores = jnp.einsum("bghn,blhn->bhgl", q_nope.astype(dt),
-                                kv[..., :n], preferred_element_type=f32)
-            prob = jax.nn.softmax(_causal((scores + pe) * scale, pos0), -1)
-        with jax.named_scope("mla.out"):
-            out = jnp.einsum("bhgl,blhv->bghv", prob.astype(dt), kv[..., n:],
-                             preferred_element_type=f32)
+            return expand_output(o_lat, wv)
+    with jax.named_scope("mla.expand"):
+        kv = jnp.einsum("blc,chx->blhx", ckv, w,
+                        preferred_element_type=f32).astype(dt)
+    with jax.named_scope("mla.scores"):
+        scores = jnp.einsum("bghn,blhn->bhgl", q_nope.astype(dt),
+                            kv[..., :n], preferred_element_type=f32)
+        prob = jax.nn.softmax(_causal((scores + pe) * scale, pos0), -1)
+    with jax.named_scope("mla.out"):
+        out = jnp.einsum("bhgl,blhv->bghv", prob.astype(dt), kv[..., n:],
+                         preferred_element_type=f32)
     return out.reshape(b, g, H * m.v_head_dim)
 
 
 __all__ = [
-    "absorbs", "attend", "attn_shapes", "init_block", "project", "rope",
+    "absorb_queries", "absorbed_halves", "absorbs", "attend", "attn_shapes",
+    "expand_output", "init_block", "project", "rope",
     "rope_amplitude", "score_scale", "yarn_amplitude", "yarn_inv_freq",
     "yarn_mscale",
 ]

@@ -1679,3 +1679,265 @@ def _flash_decode_quant(
         k_scale.reshape(b, nkv, nkb, block_k),
         v_scale.reshape(b, nkv, nkb, block_k),
     )
+
+
+# --------------------------------------------------------------------- #
+# decode over a LATENT cache (``models.mla``, absorbed form)            #
+# --------------------------------------------------------------------- #
+
+
+# Query rows a product of the latent kernel takes (a chunk's ``g x H``
+# rows go through a grid step in groups of this many: the f32 score
+# plane of a group at a 512-block is 1 MiB; at 25 rows of 32 x 64
+# heads groups of 128 / 256 / 512 / 1024 read 0.64 / 0.57 / 0.56 / 0.54
+# ms, chip run, PR 35), and the f32 accumulator ``[g x H, c]`` up to
+# which a row's queries stay resident across its blocks (with them
+# their latent queries and the output tile, twice each: 15 MiB of the
+# 48 at a chunk of 32 x 64 heads x 512).
+_LATENT_GROUP_ROWS = 512
+_LATENT_ACC_BYTES = 8 * 1024 * 1024
+
+
+def _latent_tiling(rows: int, c: int, s: int) -> Optional[Tuple[int, int]]:
+    """``(block_k, rq)`` of the latent decode kernel for ``rows`` query
+    rows a cache row (``g`` queries x ``H`` heads), a latent of ``c``
+    and a cache of ``s`` rows: the largest block dividing ``s``, and the
+    query rows a product takes.  None: no block divides ``s``, the rows
+    do not split into whole groups, or their accumulator is too large
+    to stay resident."""
+    block_k = _largest_block(s)
+    if block_k is None or rows * c * 4 > _LATENT_ACC_BYTES:
+        return None
+    if rows <= _LATENT_GROUP_ROWS:
+        return block_k, rows
+    for rq in (_LATENT_GROUP_ROWS, 256, 128):
+        if rows % rq == 0:
+            return block_k, rq
+    return None
+
+
+def supports_latent_decode(
+    q_shape: Tuple[int, ...], bank_shape: Tuple[int, ...], rope_dim: int,
+) -> bool:
+    """Static eligibility for :func:`latent_decode_attention` (the gate
+    of ``models.generation._attend_latent``): latent queries ``[b, g, H,
+    c]`` against a bank ``[slots, s, c]`` with a shared key head of
+    ``rope_dim``.  The latent is whole lane tiles, the query rows of a
+    cache row whole sublane tiles, the cache long enough to be worth a
+    dispatch (:func:`supports_decode`'s floor) and tiled by
+    :func:`_latent_tiling`."""
+    _, g, H, c = q_shape
+    s = bank_shape[1]
+    if c % 128 != 0 or bank_shape[2] != c or rope_dim < 1:
+        return False
+    if (g * H) % 8 != 0 or s < 256:
+        return False
+    return _latent_tiling(g * H, c, s) is not None
+
+
+def _latent_decode_kernel(
+    row_ref: Any,    # [steps] query row of each grid step
+    slot_ref: Any,   # [steps] bank row of its tiles (the index maps')
+    blk_ref: Any,    # [steps] cache block of its tiles
+    end_ref: Any,    # [b] one past a row's last step (running sum)
+    pos_ref: Any,    # [b] first query's position
+    len_ref: Any,    # [b] cache rows the row reads; 0: none
+    ql_ref: Any,     # [1, g*H, c]   queries in the latent space
+    qp_ref: Any,     # [1, g*H, r]   their rotated part
+    ckv_ref: Any,    # [1, block_k, c]  the latent rows: keys AND values
+    kpe_ref: Any,    # [1, r, block_k]  the shared rotated key head,
+                     # positions minor
+    o_ref: Any,      # [1, g*H, c]
+    m_sc: Any,
+    l_sc: Any,
+    acc_sc: Any,
+    *,
+    heads: int,
+    rq: int,
+    block_k: int,
+    sm_scale: float,
+) -> None:
+    """One grid step of latent attention in its absorbed form: one cache
+    block of one query row's slot, ``s = (q_lat . ckv^T + q_pe . kpe^T)
+    * sm_scale`` under the causal mask, online softmax carried in VMEM
+    scratch across the row's steps, ``acc += p . ckv``.  It is attention
+    with ONE key/value head whose key is ``[latent | rotated head]`` and
+    whose value is the latent again: the ``[block_k, c]`` tile is
+    fetched once and feeds both products, for every head.
+
+    The grid is :func:`_decode_kernel`'s flat list of (row, block) steps
+    (:func:`_decode_steps`, no window): a row takes the blocks up to its
+    own frontier and no other.  Row ``x`` of the ``g*H`` is query ``x //
+    heads`` (position ``pos0 + x // heads``), head ``x % heads``; they
+    go through the products ``rq`` at a time, so that a prompt chunk's
+    score plane stays ``[rq, block_k]`` while its 2,048 rows share the
+    one fetch of the tile.  Arithmetic is ``mla.attend``'s: operands in
+    the cache's type, ``p`` rounded to it, f32 accumulation and softmax
+    (:func:`_dot_tile`)."""
+    t = pl.program_id(0)
+    i = row_ref[t]
+    jb = blk_ref[t]
+    end = end_ref[i]
+    start = jnp.where(i > 0, end_ref[jnp.maximum(i - 1, 0)], 0)
+    pos0 = pos_ref[i]
+    rows = acc_sc.shape[0]
+
+    @pl.when(t == start)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, _NEG)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    @pl.when(len_ref[i] > 0)
+    def _body():
+        ckv, kpe = ckv_ref[0], kpe_ref[0]
+        col = jb * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+        for lo in range(0, rows, rq):
+            grp = pl.ds(lo, rq)
+            x = lo + lax.broadcasted_iota(jnp.int32, (rq, 1), 0)
+            valid = col <= pos0 + x // heads
+            s = (
+                _dot_tile(ql_ref[0, grp], ckv, _NT)
+                + _dot_tile(qp_ref[0, grp], kpe, _NN)
+            ) * sm_scale
+            s = jnp.where(valid, s, _NEG)
+            m_prev = m_sc[grp]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_sc[grp] = l_sc[grp] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_sc[grp] = acc_sc[grp] * corr + _dot_tile(p, ckv, _NN)
+            m_sc[grp] = m_new
+
+    @pl.when(t == end - 1)
+    def _finish():
+        # A row that read nothing has l == 0: zeros, not 0/0.
+        l = l_sc[...]
+        o_ref[0] = jnp.where(
+            l > 0, acc_sc[...] / jnp.where(l > 0, l, 1.0), 0.0
+        ).astype(o_ref.dtype)
+
+
+def latent_decode_attention(
+    q_lat: jnp.ndarray,          # [b, g, H, c] — queries through W_kvb^K
+    q_pe: jnp.ndarray,           # [b, g, H, r] — rotated, positions
+                                 # pos0..pos0+g-1
+    ckv: jnp.ndarray,            # [slots, max_len, c] latent cache
+    kpe: jnp.ndarray,            # [slots, max_len, r] shared key head
+    pos0: jnp.ndarray,           # [] or [b] int32 — first query's position
+    *,
+    sm_scale: float,
+    slots: Optional[jnp.ndarray] = None,    # [b] — row i reads bank row
+                                            # slots[i]
+    lengths: Optional[jnp.ndarray] = None,  # [b] — cache rows a row
+                                            # reads (0: none)
+    block_k: Optional[int] = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Decode-side attention over the LIVE PREFIX of a latent cache
+    (``models.kv_cache.LatentCache``): the Pallas twin of the absorbed
+    ``models.mla.attend`` between its two einsums — ``o_lat [b, g, H,
+    c]`` in the cache's type, the softmax of ``(q_lat . ckv^T + q_pe .
+    kpe^T) * sm_scale`` over each row's own cache rows times ``ckv``.
+    ``q_lat = q_nope . W_kvb^K`` and ``o_lat . W_kvb^V`` stay the
+    caller's einsums.
+
+    Rows, slots and lengths are :func:`flash_decode_attention`'s: row
+    ``i`` reads bank row ``slots[i]`` (the two banks as they lie,
+    through the index maps: no slice, no gather; a slot may repeat) up
+    to ``lengths[i]`` rows (``pos0 + g`` where not given, clipped to
+    ``max_len``), block by block, and a row of length 0 fetches nothing
+    and returns zeros.  The queries take the cache's type, as
+    ``mla.attend`` rounds them."""
+    b, g, H, c = q_lat.shape
+    s = ckv.shape[1]
+    if block_k is not None and s % block_k != 0:
+        raise ValueError(f"cache length {s} not divisible by {block_k}")
+    tiling = _latent_tiling(g * H, c, s)
+    if tiling is None:
+        raise ValueError(
+            f"no latent decode tiling for {g} x {H} query rows of {c} "
+            f"against {s} cache rows; use the dense path (mla.attend)"
+        )
+    if slots is None:
+        if ckv.shape[0] != b:
+            raise ValueError(
+                f"{b} query rows against {ckv.shape[0]} cache rows: pass "
+                "slots"
+            )
+        slots = jnp.arange(b)
+    pos0 = jnp.broadcast_to(jnp.asarray(pos0, jnp.int32), (b,))
+    lengths = jnp.clip(
+        pos0 + g if lengths is None else lengths, 0, s
+    ).astype(jnp.int32)
+    return _latent_decode_rows(
+        q_lat.astype(ckv.dtype), q_pe.astype(kpe.dtype), ckv, kpe, pos0,
+        lengths, slots.astype(jnp.int32), sm_scale=float(sm_scale),
+        block_k=block_k or tiling[0], rq=tiling[1], interpret=interpret,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("sm_scale", "block_k", "rq", "interpret")
+)
+def _latent_decode_rows(
+    q_lat: jnp.ndarray, q_pe: jnp.ndarray, ckv: jnp.ndarray,
+    kpe: jnp.ndarray, pos0: jnp.ndarray, lengths: jnp.ndarray,
+    slots: jnp.ndarray, *, sm_scale: float, block_k: int, rq: int,
+    interpret: bool,
+) -> jnp.ndarray:
+    """:func:`latent_decode_attention`, every operand per row.  Jitted
+    for :func:`_flash_decode_rows`' reason: a model's layers lower the
+    kernel once."""
+    b, g, H, c = q_lat.shape
+    s, r = ckv.shape[1], kpe.shape[2]
+    rows = g * H
+    row, slot, blk, ends = _decode_steps(
+        pos0, lengths, slots, None, block_k, b * (s // block_k)
+    )
+
+    def bank_im(t: Any, row_ref: Any, slot_ref: Any, blk_ref: Any,
+                *_: Any) -> Tuple:
+        return (slot_ref[t], blk_ref[t], 0)
+
+    def head_im(t: Any, row_ref: Any, slot_ref: Any, blk_ref: Any,
+                *_: Any) -> Tuple:
+        return (slot_ref[t], 0, blk_ref[t])
+
+    def q_im(t: Any, row_ref: Any, *_: Any) -> Tuple:
+        return (row_ref[t], 0, 0)
+
+    out = pl.pallas_call(
+        functools.partial(
+            _latent_decode_kernel, heads=H, rq=rq, block_k=block_k,
+            sm_scale=sm_scale,
+        ),
+        name="latent_decode",
+        out_shape=jax.ShapeDtypeStruct((b, rows, c), ckv.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(ends[-1],),
+            in_specs=[
+                pl.BlockSpec((1, rows, c), q_im),
+                pl.BlockSpec((1, rows, r), q_im),
+                pl.BlockSpec((1, block_k, c), bank_im),
+                pl.BlockSpec((1, r, block_k), head_im),
+            ],
+            out_specs=pl.BlockSpec((1, rows, c), q_im),
+            scratch_shapes=[
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, c), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_DECODE_VMEM_BYTES,
+        ),
+        interpret=interpret,
+    )(
+        row, slot, blk, ends, pos0, lengths,
+        q_lat.reshape(b, rows, c), q_pe.reshape(b, rows, r), ckv,
+        jnp.swapaxes(kpe, 1, 2),
+    )
+    return out.reshape(b, g, H, c)
