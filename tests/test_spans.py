@@ -3,6 +3,7 @@ the spans inside ``Engine.step``, the ``step`` span of the SPMD train step
 with the schedule it was built with, the named scopes of the compiled step
 and the names of the flash kernels."""
 
+import gc
 import glob
 import os
 
@@ -22,6 +23,7 @@ from torchgpipe_tpu.ops.flash_attention import (
 from torchgpipe_tpu.parallel import interleaved, zerobubble
 from torchgpipe_tpu.serving import Engine
 from torchgpipe_tpu.spmd import SpmdGPipe, make_mesh, schedule_shape
+from torchgpipe_tpu.utils import tracing
 from torchgpipe_tpu.utils.tracing import Timeline, default_timeline
 
 CFG = TransformerConfig(vocab=64, dim=32, n_layers=2, n_heads=4, n_kv_heads=2)
@@ -122,7 +124,153 @@ def test_span_lands_in_the_profilers_trace(tmp_path):
 
 def test_default_timeline_is_one_bounded_ring():
     assert default_timeline() is default_timeline()
-    assert default_timeline().capacity >= 5 * 7 * 1000    # 44 s at 5 x 22 steps/s
+    # A 44 s window of 2.4 ms steps at 7 spans a step.
+    assert default_timeline().capacity >= 7 * 44 / 2.4e-3
+
+
+def test_mark_takes_the_next_seq_and_the_open_span_as_parent():
+    tl = Timeline()
+    t = tracing.time.perf_counter()
+    tl.mark("alone", t, t + 0.5, why="x")
+    with tl.span("outer") as outer:
+        with tl.span("inner"):
+            pass
+        tl.mark("late", t + 1.0, t + 1.25)
+    alone, inner, late, got = tl.events
+    assert (alone.name, alone.seq, alone.parent) == ("alone", 0, -1)
+    assert alone.fields == {"why": "x"} and alone.duration == pytest.approx(0.5)
+    assert alone.t_start == pytest.approx(t - tl._t0)
+    assert (outer.seq, inner.seq, late.seq) == (1, 2, 3)      # the order they came
+    assert (late.parent, late.fields, got.name) == (1, None, "outer")
+    assert late.duration == pytest.approx(0.25)
+
+
+def test_wrapped_ring_with_marks_answers_since_truthfully():
+    """A mark lands at once and the span open around it later, with the
+    smaller ``seq``: what was pushed out is told by ``seq``, not by place."""
+    tl = Timeline(capacity=3)
+    t = tracing.time.perf_counter()
+    with tl.span("s0"):                 # seq 0: lands after marks 1 and 2
+        tl.mark("m1", t, t)
+        tl.mark("m2", t, t)
+    assert [e.seq for e in tl.since(0)] == [1, 2, 0]
+    tl.mark("m3", t, t)                 # pushes m1 (seq 1) out, not s0
+    assert tl.since(0) is None and tl.since(1) is None
+    assert [e.seq for e in tl.since(2)] == [2, 3]
+    assert [e.name for e in tl.events] == ["m2", "s0", "m3"]
+
+
+# --------------------------------------------------------------------- #
+# time the program did not choose to spend                              #
+# --------------------------------------------------------------------- #
+
+
+def _children(span, name, **fields):
+    """The ``name`` events under ``span`` that carry ``fields`` (a test
+    process makes collections and compiles of its own now and then)."""
+    return [e for e in default_timeline().events
+            if e.name == name and e.parent == span.seq
+            and all(e.fields[k] == v for k, v in fields.items())]
+
+
+def test_forced_collection_is_a_child_of_the_span_it_fell_into():
+    with default_timeline().span("probe.gc") as probe:
+        gc.collect()
+    (event,) = _children(probe, "gc.collect", generation=2)
+    assert event.fields["collected"] >= 0
+    mine = next(e for e in default_timeline().events if e.seq == probe.seq)
+    assert mine.t_start <= event.t_start < event.t_end <= mine.t_end
+
+
+def test_full_collection_lands_in_the_profilers_trace(tmp_path):
+    """A generation-2 collection holds a ``TraceAnnotation`` open from its
+    start to its stop: a profile shows it on the device trace's clock."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        gc.collect()
+        gc.collect(0)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True
+    )
+    data = jax.profiler.ProfileData.from_file(path)
+    found = [
+        e for plane in data.planes for line in plane.lines
+        for e in line.events if e.name == "gc.collect"
+    ]
+    assert len(found) == 1 and found[0].duration_ns > 0
+
+
+def test_short_young_collection_leaves_nothing():
+    gc.collect()                        # so that the young one has no work
+    with default_timeline().span("probe.gc0") as probe:
+        gc.collect(0)
+    assert _children(probe, "gc.collect", generation=0) == []
+
+
+def test_long_young_collection_is_marked(monkeypatch):
+    monkeypatch.setattr(tracing._ProcessMarks, "GC_FLOOR_S", 0.0)
+    with default_timeline().span("probe.gc0") as probe:
+        gc.collect(0)
+    assert len(_children(probe, "gc.collect", generation=0)) == 1
+
+
+def test_installing_twice_records_once():
+    callbacks = list(gc.callbacks)
+    tracing._PROCESS_MARKS.install()
+    assert gc.callbacks == callbacks
+    with default_timeline().span("probe.gc") as probe:
+        gc.collect()
+        jax.jit(lambda x: x - 3.0)(jnp.ones((5,)))
+    assert len(_children(probe, "gc.collect", generation=2)) == 1
+    assert len(_children(probe, "xla.compile", fun="jit(<lambda>)",
+                         phase="backend")) == 1
+
+
+def test_timeline_of_ones_own_records_neither():
+    tl = Timeline()
+    with tl.span("probe"):
+        gc.collect()
+        jax.jit(lambda x: x - 4.0)(jnp.ones((5,)))
+    assert [e.name for e in tl.events] == ["probe"]
+
+
+def test_fresh_jit_leaves_its_phases_under_the_span_and_a_second_call_none():
+    def fresh_probe(x):
+        return x * 2.0 + 1.0
+
+    fn = jax.jit(fresh_probe)
+    x = jnp.ones((3, 7))
+    with default_timeline().span("probe.jit") as first:
+        fn(x)
+    with default_timeline().span("probe.jit") as second:
+        fn(x)
+    by_phase = {}
+    for e in _children(first, "xla.compile"):
+        by_phase.setdefault(e.fields["phase"], []).append(e)
+    assert set(by_phase) == {"trace", "lower", "backend"}
+    assert "fresh_probe" in [e.fields["fun"] for e in by_phase["trace"]]
+    (lower,), (backend,) = by_phase["lower"], by_phase["backend"]
+    assert lower.fields["fun"] == backend.fields["fun"] == "jit(fresh_probe)"
+    assert backend.fields["cache_hit"] in (0, 1) and "cache_hit" not in lower.fields
+    assert all(e.duration > 0 and e.t_end <= backend.t_end for e in by_phase["trace"])
+    assert _children(second, "xla.compile") == []
+
+
+def test_backend_phase_says_whether_it_was_a_load_from_the_cache():
+    tl = Timeline()
+    marks = tracing._ProcessMarks(tl)           # not installed: driven by hand
+    backend = "/jax/core/compile/backend_compile_duration"
+    marks._on_event("/jax/compilation_cache/compile_requests_use_cache")
+    marks._on_duration(backend, 2.0, fun_name="jit(cold)")
+    marks._on_event(marks.CACHE_HIT)
+    marks._on_duration("/jax/compilation_cache/cache_retrieval_time_sec", 0.1)
+    marks._on_duration(backend, 0.25, fun_name="jit(warm)")
+    marks._on_duration(backend, 1.0, fun_name="jit(cold_again)")
+    assert [(e.fields["fun"], e.fields["cache_hit"], round(e.duration, 6))
+            for e in tl.events] == [
+        ("jit(cold)", 0, 2.0), ("jit(warm)", 1, 0.25), ("jit(cold_again)", 0, 1.0)]
 
 
 # --------------------------------------------------------------------- #
@@ -210,6 +358,34 @@ def test_idle_iteration_records_nothing(flat_params):
     assert list(eng.timeline.events) == []
 
 
+def test_admit_span_carries_the_state_admission_left_behind(flat_params):
+    """``queued`` / ``free`` / ``slots`` are the scheduler's and the pool's
+    own numbers where admission ends, step by step; a backlog that empties
+    shows room and nobody waiting."""
+    eng = _engine(flat_params)
+    inner, seen = eng.scheduler.next_action, []
+
+    def probed():
+        action = inner()
+        if action is not None:
+            seen.append({"queued": len(eng.scheduler.queue),
+                         "free": eng.pool.num_free,
+                         "slots": eng.pool.num_slots})
+        return action
+
+    eng.scheduler.next_action = probed
+    events = _serve(eng, n=9)           # nine requests through four slots
+    admits = [e.fields for e in events if e.name == "engine.admit"]
+    assert len(admits) == len(seen) > 9
+    for fields, want in zip(admits, seen):
+        assert {k: fields[k] for k in want} == want and "admitted" in fields
+    assert admits[0]["queued"] == 5 and admits[0]["free"] == 0
+    assert all(a["slots"] == 4 for a in admits)
+    assert max(a["queued"] for a in admits) == 5
+    last = admits[-1]
+    assert last["queued"] == 0 and last["free"] > 0
+
+
 def test_engine_records_into_the_default_timeline(flat_params):
     eng = Engine(CFG, flat_params, num_slots=4, max_len=32, prefill_chunk=4)
     assert eng.timeline is default_timeline()
@@ -262,10 +438,17 @@ def test_step_span_carries_the_schedule_it_was_built_with(
     params = pipe.init(jax.random.PRNGKey(1), x)
     opt = optax.sgd(1e-2)
     step = pipe.make_train_step(opt, donate=False)
-    before = len(default_timeline().events)
+    def spans():
+        return [e for e in default_timeline().events if e.name == "step"]
+
+    before = len(spans())
     step(params, pipe.place_tree(opt.init(params)), x, x)
     span = default_timeline().events[-1]      # no tracer: the default ring
-    assert len(default_timeline().events) == before + 1
+    assert len(spans()) == before + 1
+    # The first call compiled inside the span: its phases are its children.
+    assert {"trace", "lower", "backend"} <= {
+        e.fields["phase"] for e in default_timeline().events
+        if e.name == "xla.compile" and e.parent == span.seq}
     assert span.name == "step" and span.stage == -1 and span.duration > 0
     assert span.fields == schedule_shape(schedule, n, m)
     if schedule == "fill_drain":

@@ -1000,7 +1000,12 @@ class Engine:
                     admit_span.drop()
                     step_span.drop()
                 else:
-                    tl.annotate(admitted=admitted)
+                    # What admission left behind: who still waits, and
+                    # the room there is for them.
+                    tl.annotate(admitted=admitted,
+                                queued=len(self.scheduler.queue),
+                                free=self.pool.num_free,
+                                slots=self.pool.num_slots)
             if action is not None:
                 # The action's span opens as soon as the action is
                 # known, before its program runs; ``_run_*`` add ``rows``

@@ -32,6 +32,11 @@ a bounded ring that is always on::
         with tracer.span("engine.admit"):
             ...
             tracer.annotate(admitted=2)
+
+Time the program did not choose to spend lands in the default timeline
+too, as closed events (``Timeline.mark``): ``gc.collect`` for a
+collection of the interpreter's, ``xla.compile`` for each phase of a
+compile (:class:`_ProcessMarks`), each the child of the span it fell into.
 """
 
 from __future__ import annotations
@@ -39,6 +44,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import gc
 import itertools
 import threading
 import time
@@ -181,6 +187,19 @@ class Timeline:
         if stack:
             stack[-1]._late = dict(stack[-1]._late or (), **fields)
 
+    def mark(self, name: str, t_start: float, t_end: float,
+             **fields: Any) -> None:
+        """Append one CLOSED event, for work whose end is the first the
+        program hears of it (a collection, a compile): what a span's
+        exit appends, with the next ``seq`` and, as ``parent``, the span
+        open on the calling thread.  ``t_start`` and ``t_end`` are
+        ``time.perf_counter()`` readings."""
+        stack = self._stack()
+        self._append(TimelineEvent(
+            name, -1, -1, t_start - self._t0, t_end - self._t0,
+            next(self._seq), stack[-1].seq if stack else -1, fields or None,
+        ))
+
     def since(self, seq: int) -> Optional[List[TimelineEvent]]:
         """The spans whose ``seq`` is at least ``seq``, oldest first, or
         ``None`` where a bounded timeline has pushed one of them out."""
@@ -301,16 +320,106 @@ class Timeline:
 
 # The process-wide default: what ``serving.Engine`` and ``SpmdGPipe`` (with
 # no ``tracer=``) record into.  Always on, so that a step that stalls
-# outside any profiler session still names its phase.  Sized for a 44 s
-# window at five times the measured serving step rate (22 steps/s x 7
-# spans a step, PERF.md section 5): 34k events; about 16 MiB when full.
-DEFAULT_CAPACITY = 65536
+# outside any profiler session still names its phase.  Sized from the
+# fastest serving step on record (``step_wall_ms.backlog`` 7.43 ms: ledger,
+# PR 35, ``mistral-7b.serve-backlog``): 135 steps/s x 7 spans a step is
+# 41.5k events in a 44 s window; 131,072 hold a window of steps down to
+# 2.4 ms (set-up's events go first).  About 32 MiB when full.
+DEFAULT_CAPACITY = 131072
 _DEFAULT = Timeline(capacity=DEFAULT_CAPACITY)
 
 
 def default_timeline() -> Timeline:
     """The one bounded, always-on timeline of this process."""
     return _DEFAULT
+
+
+class _ProcessMarks:
+    """The two process-wide sources of ``Timeline.mark``: time the program
+    did not choose to spend, recorded where it fell.
+
+    ``gc.collect`` (from ``gc.callbacks``): a generation-2 collection, and
+    any collection of ``GC_FLOOR_S`` or more, with ``generation`` and
+    ``collected``.  A generation-2 collection also holds a
+    ``TraceAnnotation`` open from start to stop, so a profile shows it on
+    the device trace's clock.  A short young collection, the common case,
+    costs two clock reads and a compare and leaves nothing.
+
+    ``xla.compile`` (from ``jax.monitoring``'s duration events): one event
+    a phase of a compile, with ``phase`` (``trace`` / ``lower`` /
+    ``backend``) and ``fun``; on ``backend`` also ``cache_hit``, 1 where
+    the program was loaded from the persistent cache and not compiled.
+    jax reports a phase when it ends, so the start is the end less the
+    seconds reported.  jax traces a ``jit`` called inside a ``jit`` inside
+    the outer trace: the inner event lies within the outer one's interval,
+    and a sum over events counts that time twice.
+    """
+
+    GC_FLOOR_S = 1e-3
+    PHASES = {
+        "/jax/core/compile/jaxpr_trace_duration": "trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+        "/jax/core/compile/backend_compile_duration": "backend",
+    }
+    CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self, timeline: Timeline) -> None:
+        self._tl = timeline
+        self._installed = False
+        self._gc_start = 0.0
+        self._gc_ann: Optional[jax.profiler.TraceAnnotation] = None
+        # Whether the backend phase now under way on a thread found its
+        # program in the persistent cache.
+        self._cache = threading.local()
+
+    def install(self) -> None:
+        """Start recording.  A second call changes nothing."""
+        if self._installed:
+            return
+        self._installed = True
+        gc.callbacks.append(self._on_gc)
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            if info["generation"] == 2:
+                self._gc_ann = jax.profiler.TraceAnnotation(
+                    "gc.collect", generation=2)
+                self._gc_ann.__enter__()
+            self._gc_start = time.perf_counter()
+            return
+        t_end = time.perf_counter()
+        ann, self._gc_ann = self._gc_ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        elif t_end - self._gc_start < self.GC_FLOOR_S:
+            return
+        self._tl.mark("gc.collect", self._gc_start, t_end,
+                      generation=info["generation"],
+                      collected=info["collected"])
+
+    def _on_event(self, event: str, **_: Any) -> None:
+        if event == self.CACHE_HIT:
+            self._cache.hit = True
+
+    def _on_duration(self, event: str, secs: float, **kwargs: Any) -> None:
+        phase = self.PHASES.get(event)
+        if phase is None:
+            return
+        t_end = time.perf_counter()
+        fields = {"phase": phase, "fun": kwargs.get("fun_name")}
+        if phase == "backend":
+            fields["cache_hit"] = int(getattr(self._cache, "hit", False))
+            self._cache.hit = False
+        self._tl.mark("xla.compile", t_end - secs, t_end, **fields)
+
+
+# Installed with the ring they record into, when this module is first
+# imported: set-up's compiles come before the first engine is built.
+_PROCESS_MARKS = _ProcessMarks(_DEFAULT)
+_PROCESS_MARKS.install()
 
 
 @contextlib.contextmanager
