@@ -10,6 +10,7 @@ that passes is a compile, not a chip run — nothing executes.
 
 import dataclasses
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
@@ -84,6 +85,14 @@ def _decode(quant, window):
     if quant:
         shapes += [((1, G, MAX_LEN), jnp.float32)] * 2
     return fn, shapes
+
+
+def _row_scatters(text, rows, width):
+    """The compiled program's scatters into ``[rows, width]``: the shape of
+    the expert layer's token rows.  The KV banks' writes are scatters too,
+    so the shape decides, not the word."""
+    shape = re.compile(rf"= \w+\[{rows},{width}\]\S* scatter\(")
+    return [line for line in text.splitlines() if shape.search(line)]
 
 
 SLOTS, ROWS, CHUNK = 64, 12, 32    # mistral-7b.serve-backlog's pool
@@ -295,7 +304,9 @@ def test_latent_decode_slots_compiles_for_v5e(compact, chip, monkeypatch):
     rows-major, the program holds it positions-minor, so each layer
     copies it there and back (the dense program does too; 0.62 ms a
     layer in both programs of the cell, chip run, PR 35).  The latent
-    bank goes into the kernel as it lies."""
+    bank goes into the kernel as it lies.  The served expert sum moves its
+    rows by gathers alone: no scatter-add into the token rows (the
+    parent's combine, 1.59 ms a layer at prefill, chip run, PR 35)."""
     import types
 
     from chipbench import weights_axk1
@@ -339,6 +350,7 @@ def test_latent_decode_slots_compiles_for_v5e(compact, chip, monkeypatch):
     ).compile()
     text = compiled.as_text()
     assert "latent_decode" in text and "flash_decode" not in text
+    assert not _row_scatters(text, rows * g, cfg.dim)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * L_RELAID_BYTES
 
 

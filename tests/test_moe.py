@@ -927,6 +927,196 @@ def test_dropless_assignment_counts_and_k_major_order():
     )
 
 
+# --- the served dropless sum combines by gathers (PR 37) ----------------- #
+
+
+def _dropless_args(k, held, masked, t=12, d=8, h=6):
+    """The dropless expert sum's arguments as ``moe_mlp`` routes ``t``
+    tokens of ``k`` distinct choices among 16 experts: ``held`` computes
+    experts 4..9 here (else all 16), ``masked`` leaves every third
+    position out.  Returns the differentiable arguments (the gates in
+    expert order last), ``(tok_sorted, group_sizes)`` and the sort key."""
+    E = 16
+    first, n_held = (4, 6) if held else (0, E)
+    ks = jax.random.split(jax.random.PRNGKey(k * 4 + 2 * held + masked), 6)
+    experts = jnp.argsort(jax.random.uniform(ks[0], (t, E)), axis=1)[:, :k]
+    local = experts.T.reshape(-1) - first  # k-major
+    mine = (local >= 0) & (local < n_held)
+    if masked:
+        mine = mine & jnp.tile(jnp.arange(t) % 3 != 0, k)
+    key = jnp.where(mine, local, n_held)
+    order = jnp.argsort(key, stable=True)
+    gs = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
+    gates = jax.random.uniform(ks[5], (k * t,))
+    diff = (
+        jax.random.normal(ks[1], (t, d)),
+        jax.random.normal(ks[2], (n_held, d, h)) * d ** -0.5,
+        jax.random.normal(ks[3], (n_held, d, h)) * d ** -0.5,
+        jax.random.normal(ks[4], (n_held, h, d)) * h ** -0.5,
+        jnp.where(mine, gates, 0.0)[order],
+    )
+    return diff, (order % t, gs), key
+
+
+def _scatter_oracle(xf, w_gate, w_up, w_down, gate_sorted, tok_sorted,
+                    group_sizes):
+    """The dropless expert sum as PR 36 wrote it: a gather of the
+    expert-sorted rows and a scatter-add combine (the CPU's grouped
+    product writes zeros to the rows of no group)."""
+    xs = xf[tok_sorted]
+    hs = (jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, group_sizes))
+          * jax.lax.ragged_dot(xs, w_up, group_sizes))
+    ys = jax.lax.ragged_dot(hs, w_down, group_sizes)
+    return jnp.zeros(xf.shape, ys.dtype).at[tok_sorted].add(
+        ys * gate_sorted[:, None])
+
+
+def _sum_and_grads(fn, diff, cot):
+    y, vjp = jax.vjp(fn, *diff)
+    return y, vjp(cot)
+
+
+@pytest.mark.parametrize("k", [2, 8])
+@pytest.mark.parametrize("held,masked", [(False, False), (True, False),
+                                         (False, True), (True, True)],
+                         ids=["all", "held", "valid", "held-valid"])
+def test_dropless_expert_sum_matches_scatter_oracle(k, held, masked):
+    """The dropless expert sum equals the scatter form served (under
+    ``held`` or a ``valid`` mask the gather combine: back to assignment
+    order by the inverse sort, the k choices summed in float32) and in
+    the gradient of every differentiable argument, on the path
+    ``moe_mlp`` takes: ``_held_expert_sum`` under ``held`` or a mask, the
+    plain ``_expert_sum`` otherwise."""
+    from torchgpipe_tpu.models import moe
+
+    diff, route, key = _dropless_args(k, held, masked)
+    if held or masked:
+        fn = lambda *a: moe._held_expert_sum(*a, *route, key)  # noqa: E731
+    else:
+        fn = lambda *a: moe._expert_sum(*a, *route)  # noqa: E731
+    cot = jax.random.normal(jax.random.PRNGKey(9), diff[0].shape)
+    want = _sum_and_grads(lambda *a: _scatter_oracle(*a, *route), diff, cot)
+    _assert_trees_close(_sum_and_grads(fn, diff, cot), want,
+                        rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(fn(*diff)), np.asarray(want[0]),
+                               rtol=1e-5, atol=1e-6)
+
+
+def _stale_ragged_dot(real):
+    """``real`` (a grouped product) as the TPU's kernel leaves it: rows
+    of no group hold whatever was in memory, here NaN, in the result and
+    in the transposed product's result alike."""
+
+    def stale(a, gs):
+        return jnp.where(jnp.arange(a.shape[0])[:, None] < jnp.sum(gs),
+                         a, jnp.nan)
+
+    @jax.custom_vjp
+    def ragged_dot(x, w, gs):
+        return stale(real(x, w, gs), gs)
+
+    def fwd(x, w, gs):
+        return ragged_dot(x, w, gs), (x, w, gs)
+
+    def bwd(res, g):
+        x, w, gs = res
+        _, vjp = jax.vjp(lambda x, w: real(x, w, gs), x, w)
+        dx, dw = vjp(g)
+        return stale(dx, gs), dw, None
+
+    ragged_dot.defvjp(fwd, bwd)
+    return ragged_dot
+
+
+@pytest.mark.parametrize("held,masked", [(True, False), (False, True),
+                                         (True, True)],
+                         ids=["held", "valid", "held-valid"])
+def test_dropless_expert_sum_ignores_stale_rows(held, masked, monkeypatch):
+    """Rows past ``sum(group_sizes)`` of every grouped product and of its
+    transposes hold NaN (what the TPU may leave there: ``PERF.md`` §6,
+    PR 32 (2)): the served gather combine and the trained scatter form
+    keep them out of the output and of every gradient, which equal the
+    oracle run on clean products."""
+    from torchgpipe_tpu.models import moe
+
+    diff, route, key = _dropless_args(8, held, masked)
+    assert int(jnp.sum(route[1])) < key.shape[0]
+    cot = jax.random.normal(jax.random.PRNGKey(9), diff[0].shape)
+    want = _sum_and_grads(lambda *a: _scatter_oracle(*a, *route), diff, cot)
+    monkeypatch.setattr(moe.lax, "ragged_dot",
+                        _stale_ragged_dot(jax.lax.ragged_dot))
+    got = _sum_and_grads(
+        lambda *a: moe._held_expert_sum(*a, *route, key), diff, cot)
+    served = moe._held_expert_sum(*diff, *route, key)
+    assert all(bool(jnp.isfinite(g).all())
+               for g in jax.tree.leaves((got, served)))
+    _assert_trees_close(got, want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(served), np.asarray(want[0]),
+                               rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("held,masked", [(True, False), (False, True)],
+                         ids=["held", "valid"])
+def test_served_dropless_sum_lowers_without_scatter(held, masked):
+    """The held expert sum as served (undifferentiated) holds no scatter:
+    its combine gathers back by the inverse sort.  PR 36's held one, the
+    combine's scatter-add."""
+    from torchgpipe_tpu.models import moe
+
+    diff, route, key = _dropless_args(8, held, masked)
+    text = jax.jit(lambda *d: moe._held_expert_sum(*d, *route, key)).lower(
+        *diff).as_text()
+    assert "stablehlo.dot_general" in text and "stablehlo.scatter" not in text
+
+
+# sha256 of ``jit(value_and_grad(...)).lower(...).as_text()`` of a dropless
+# expert layer's loss, pinned on the PARENT commit (fcb42e2, PR 36; jax
+# 0.9.0, CPU): every differentiated dropless path (training) keeps PR 36's
+# scatter form, because the gather form read a wrong loss in
+# ``mellum2.train-4x8192`` on the chip (PR 37; ``PERF.md`` section 7).
+PARENT_GRAD_PROGRAMS = {
+    "plain": "75f395c0fe9fa9c518ba3b039fe79605ad9192cccda3facb59bf4245169fe9a3",
+    "held": "ff5757b7217f6731c1a242cf01f207b14e2645c8d3c8d14690b40b1b5c6d3efc",
+    "valid": "dc6d18e22ed09e3d48584c70383bc5d19341db881c2d752433eb311895d398c9",
+}
+
+
+@pytest.mark.parametrize("form", sorted(PARENT_GRAD_PROGRAMS))
+def test_differentiated_dropless_layer_lowers_as_the_parent(form):
+    """A dropless expert layer's loss and gradient (no ``held``; ``held``;
+    a ``valid`` mask) lower byte-identically to PR 36's: the gather
+    combine is the served forward's alone."""
+    import hashlib
+
+    cfg = _cfg()
+    layer = moe_mlp(cfg, MoEConfig(n_experts=8, top_k=2, dispatch="dropless",
+                                   held=(2, 4) if form == "held" else None))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, cfg.dim))
+    params, _ = layer.init(
+        jax.random.PRNGKey(0), jax.ShapeDtypeStruct(x.shape, x.dtype))
+    valid = (jnp.arange(16).reshape(2, 8) % 3 != 0) if form == "valid" else None
+
+    def loss(p, x):
+        y, _ = layer.meta["forward_counts"](p, x, valid)
+        return jnp.sum(y ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_GRAD_PROGRAMS[form]
+
+
+@pytest.mark.parametrize("t,k,n", [(12, 2, 3), (800, 8, 12), (576, 4, 32)])
+def test_inverse_order_is_the_sorts(t, k, n):
+    """``_inverse_order`` (a cumulative sum over the key's one-hot, no
+    second sort) inverts the stable sort of the keys."""
+    from torchgpipe_tpu.models import moe
+
+    key = jax.random.randint(jax.random.PRNGKey(t), (k * t,), 0, n + 1)
+    order = np.argsort(np.asarray(key), kind="stable")
+    inv = np.asarray(moe._inverse_order(key, n))
+    np.testing.assert_array_equal(order[inv], np.arange(k * t))
+
+
 def test_router_stats_counts_selections_pre_capacity():
     """`router_stats` load is the PRE-capacity selection fraction: a
     router that sends everything to expert 0 reports load[0] == 1.0 and

@@ -438,14 +438,32 @@ def _expert_ffn(expert_in: jnp.ndarray, params: Pytree) -> jnp.ndarray:
     return jnp.einsum("ech,ehd->ecd", h, params["w_down"])
 
 
+def _inverse_order(key: jnp.ndarray, n: int) -> jnp.ndarray:
+    """The inverse of ``argsort(key, stable=True)`` for keys in ``[0, n]``
+    (assignment -> its expert-sorted position) with no second sort and no
+    scatter: where key ``key[j]``'s group starts plus the number of earlier
+    assignments with that key, a cumulative sum over the key's one-hot."""
+    hit = key[:, None] == jnp.arange(n + 1)  # [kt, n + 1]
+    rank = jnp.cumsum(hit, axis=0, dtype=jnp.int32)
+    starts = jnp.cumsum(rank[-1]) - rank[-1]
+    return jnp.sum(jnp.where(hit, rank - 1 + starts, 0), axis=1)
+
+
 def _expert_sum(
     xf: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
     w_down: jnp.ndarray, gate_sorted: jnp.ndarray, tok_sorted: jnp.ndarray,
     group_sizes: jnp.ndarray, zero: Optional[str] = None,
+    inv: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """The dropless path's expert sum ``[t, d]``: the expert-sorted rows
     ``xf[tok_sorted]`` through the SwiGLU as three grouped products over
-    ``group_sizes`` and, times their gates, added back to their tokens.
+    ``group_sizes`` and, times their gates, added back to their tokens:
+    by a scatter-add, or, given ``inv`` (:func:`_inverse_order` of the
+    k-major assignments' sort), by a gather back to assignment order and
+    a float32 sum of the k choices (the served forward only: 1.59 ->
+    0.12 ms a layer at A.X-K1's prefill, chip run, PR 37; under
+    differentiation it read a wrong loss in ``mellum2.train-4x8192``,
+    ``PERF.md`` section 7).
 
     Rows behind the last group (``held``: an absent expert's; a masked
     position's) belong to no group and have gate 0.  The TPU's grouped
@@ -467,6 +485,10 @@ def _expert_sum(
     xs = xf[tok_sorted]  # [kt, d] expert-sorted
     h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
     ys = grouped(h, w_down)
+    if inv is not None:  # the rows of no group are selected out, as below
+        gates = gate_sorted[inv][:, None]
+        y = jnp.where(gates != 0.0, ys[inv], 0.0).astype(jnp.float32) * gates
+        return y.reshape(-1, *xf.shape).sum(0).astype(ys.dtype)
     if zero == "result":
         ys = jnp.where(gate_sorted[:, None] != 0.0, ys, 0.0)
     return (
@@ -478,7 +500,7 @@ def _expert_sum(
 
 @jax.custom_vjp
 def _held_expert_sum(xf, w_gate, w_up, w_down, gate_sorted, tok_sorted,
-                     group_sizes):
+                     group_sizes, key):
     """:func:`_expert_sum` where rows lie in no group, with its own
     backward: the forward zeroes what it must and keeps nothing but its
     arguments; the backward recomputes the sum with every product zeroed
@@ -487,13 +509,16 @@ def _held_expert_sum(xf, w_gate, w_up, w_down, gate_sorted, tok_sorted,
     (``[k*t, d]``, three quarters of them in no group at a quarter of the
     experts held, and their products) would otherwise be alive at once:
     19.4 of 15.75 GiB at 8 layers x 65,536 rows (described-chip compile,
-    PR 32)."""
+    PR 32).  Undifferentiated (served) it combines by gathers, by ``key``'s
+    inverse order; differentiated, forward and backward scatter."""
+    inv = _inverse_order(key, group_sizes.shape[0])
     return _expert_sum(xf, w_gate, w_up, w_down, gate_sorted, tok_sorted,
-                       group_sizes, zero="result")
+                       group_sizes, zero="result", inv=inv)
 
 
 def _held_expert_sum_fwd(*args):
-    return _held_expert_sum(*args), args
+    args = args[:-1]  # the key: only the served combine reads it
+    return _expert_sum(*args, zero="result"), args
 
 
 def _held_expert_sum_bwd(args, g):
@@ -505,7 +530,7 @@ def _held_expert_sum_bwd(args, g):
     _, vjp = jax.vjp(
         lambda *d: _expert_sum(*d, tok_sorted, group_sizes, zero="all"),
         *diff)
-    return (*vjp(g), None, None)
+    return (*vjp(g), None, None, None)
 
 
 _held_expert_sum.defvjp(_held_expert_sum_fwd, _held_expert_sum_bwd)
@@ -696,10 +721,11 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
                 tok_sorted = tok[order]
                 gate_sorted = jnp.where(mine, gates, 0.0)[order]
             ragged = moe.held is not None or valid is not None
-            with jax.named_scope("moe.experts"):
-                y = (_held_expert_sum if ragged else _expert_sum)(
-                    xf, params["w_gate"], params["w_up"], params["w_down"],
+            args = (xf, params["w_gate"], params["w_up"], params["w_down"],
                     gate_sorted, tok_sorted, group_sizes)
+            with jax.named_scope("moe.experts"):
+                y = _held_expert_sum(*args, key) if ragged else _expert_sum(
+                    *args)
             return _finish(y, group_sizes)
         # Dense one-hot einsum dispatch materializes [t, E, C] tensors; past
         # ~16M elements (64MB f32) the sort-based scatter/gather path wins on
