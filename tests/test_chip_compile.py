@@ -440,3 +440,58 @@ def test_mixed_attention_expert_train_step_compiles_for_v5e(chip, monkeypatch):
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "ragged-dot"):
         assert kernel in text
     assert compiled.memory_analysis().peak_memory_in_bytes < 15.75 * 2 ** 30
+
+
+@pytest.mark.parametrize("compact", [False, True], ids=["decode", "prefill"])
+def test_hybrid_state_pool_programs_fit_the_v5e(compact, chip, monkeypatch):
+    """``nemotron3-nano.serve-deep-backlog``'s two programs at published
+    widths (EMEMEMEM*, 64 of 128 experts held, 65,536 rows of vocabulary)
+    over its pool of 512 slots x 4096, donated: four mixer layers' float32
+    states (4 GiB) beside the attention layer's rows (2 GiB).  The decode
+    program (one token a slot) and the compact prefill program (102 rows
+    of 64, the head at each row's sampled position, as the engine runs
+    it) take the decode kernel, and each peaks under the chip's 15.75 GiB:
+    12.8 and 13.2 GiB of 5.90 GiB of weights, 6.07 of pool and their
+    temporaries (printed)."""
+    from chipbench import weights_nemotron
+    from chipbench.builders import engine_nemotron
+    from chipbench.run import make_cell
+    from torchgpipe_tpu.serving.engine import prefill_rows_for
+
+    cell = make_cell("nemotron3-nano.serve-deep-backlog", 1, 44.0, False)
+    m, sv = cell.config, cell.config["serve"]
+    cfg, moe = engine_nemotron.program_config(cell)
+    monkeypatch.setattr(jax, "devices", lambda *a: [chip])
+    where = SingleDeviceSharding(chip)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where),
+            tree,
+        )
+
+    slots, g = sv["num_slots"], sv["prefill_chunk"]
+    params = jax.eval_shape(lambda: weights_nemotron.make_flat(m, 0))
+    cache = jax.eval_shape(
+        lambda: generation.init_cache(cfg, slots, sv["max_len"]))
+    rows, g = (prefill_rows_for(slots), g) if compact else (slots, 1)
+    ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=where
+    )
+
+    def step(params, cache, lengths, tokens, n_valid, slots):
+        last = jnp.clip(n_valid - 1, 0, g - 1)
+        return generation.decode_slots(
+            cfg, params, tokens, cache, lengths, n_valid, moe=moe,
+            slots=slots if compact else None, expert_counts=True,
+            logits_at=last if compact else None,
+        )
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), ints(slots), ints(rows, g),
+        ints(rows), ints(rows),
+    ).compile()
+    assert "flash_decode" in compiled.as_text()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    print(f"peak {peak / 2 ** 30:.3f} GiB")
+    assert peak < 15.75 * 2 ** 30

@@ -334,13 +334,16 @@ AXK1 = {"hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
 # sha256 of ``jit(...).lower(...).as_text()`` of the two step programs of an
 # engine of 4 slots x 64 rows, chunk 8, donating, on the PARENT commit
 # (b4f9e18, PR 33; the same script run in a clone of it, jax 0.9.0, CPU).
+# The prefill programs re-pinned where the chunked prefill came to run the
+# head at each row's sampled position alone, with no per-position grid
+# (``decode_slots(logits_at=)``): the decode programs are as they were.
 PARENT_PROGRAMS = {
-    "mistral-7b": ("e6061d6d1fc023bb95b556bfed5ab50643a7d0b9c8713c4544a527a9abe7e171",
+    "mistral-7b": ("2ef42b7a580b7787410b630aa47410ab65e364387eccf81f3ea8260dee9ec4be",
                    "196be28c92b082268abba9b6136f3a7ae8e74c51bc0eb78a44ccf0f160c13d7c"),
     # Re-pinned by PR 37: the served expert sum's combine gathers back by
     # the inverse sort and sums the k choices in float32 where it
     # scatter-added, so both programs lower anew.
-    "axk1": ("6ad6274fdf2a248beb4e4eaf8f2e79c4c4d139b7caefb269bc9060f0322b506a",
+    "axk1": ("2079a677a63773c81bc046c551168b0a78cebe1f85367dd6ecba63f85ec5b91f",
              "c60b8f8734c87ca39534b5078db09cc6e0d1d2cc832f8f9bb7fa3cd32a9fe966"),
 }
 

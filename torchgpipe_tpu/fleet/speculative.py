@@ -126,10 +126,11 @@ class SpeculativeEngine(Engine):
                 "rejected draft; a latent-attention model's pool holds "
                 "the KV latent — serve it with the plain Engine"
             )
-        from torchgpipe_tpu.models.kv_cache import refuse_rings
+        from torchgpipe_tpu.models.kv_cache import refuse_rings, refuse_state
 
         for c in (cfg, draft_cfg):
             refuse_rings(c, "speculative decoding's rolled-back rows")
+            refuse_state(c, "speculative decoding's rolled-back rows")
         self.gamma = int(gamma)
         self.draft_cfg = draft_cfg
         self.draft_params = list(draft_params)

@@ -260,6 +260,8 @@ def _block_attn_out(
         o = o + p["bo"]
     if cfg.sandwich_norm:
         o = _block_norm(cfg, p, "ln1p", o)
+    if cfg.layer_pattern is not None:   # an attention-only layer
+        return x + o
     x_in = x
     x = x + o
     h = _block_norm(
@@ -364,15 +366,16 @@ def _attend_block(
         _decode_tiling, _latent_tiling,
     )
 
-    max_len = kv_cache.bank_rows(cache)[layer]
+    cache, bank_i = kv_cache.attention_view(cfg, cache, layer)
+    max_len = kv_cache.bank_rows(cache)[bank_i]
     if cfg.mla is not None:
-        bank = cache.ckv[layer]
+        bank = cache.ckv[bank_i]
         if not _latent_decode_eligible(
-            (rows, g, cfg.n_heads), bank, cache.kpe[layer]
+            (rows, g, cfg.n_heads), bank, cache.kpe[bank_i]
         ):
             return None
         return _latent_tiling(g * cfg.n_heads, bank.shape[2], max_len)[0]
-    bank = cache.k[layer]
+    bank = cache.k[bank_i]
     if not _on_tpu() or not _flash_decode_eligible(
         (rows, g, cfg.n_heads, cfg.head_dim), bank, _window(cfg, layer),
         quant=isinstance(cache, QuantKVCache), per_row=True,
@@ -402,7 +405,8 @@ def attend_rows_counter(
     ``serving_attend_rows_capacity``."""
     from torchgpipe_tpu.ops.flash_attention import decode_rows_read
 
-    max_len = kv_cache.bank_rows(cache)[layer]
+    view, bank_i = kv_cache.attention_view(cfg, cache, layer)
+    max_len = kv_cache.bank_rows(view)[bank_i]
     cap = rows * max_len
     block_k = _attend_block(cfg, cache, rows, g, layer)
     if block_k is None:
@@ -583,6 +587,8 @@ def _decode_chunk(
     ring for all layers is :func:`_decode_step`'s; the speculative path
     that needs chunks rolls positions back, which a ring's slot reuse
     cannot undo."""
+    kv_cache.refuse_state(cfg, "the shared-frontier decode (generate, "
+                          "speculative verification)")
     g = x.shape[1]
     pos0 = cache.length
     new = []
@@ -718,6 +724,36 @@ def _attend_latent(
     ], axis=0)
 
 
+def _mixer_layer(
+    cfg: TransformerConfig, p: Pytree, x: jnp.ndarray, tail: jnp.ndarray,
+    state: jnp.ndarray, slots: Optional[jnp.ndarray], n_valid: jnp.ndarray,
+    pos0: jnp.ndarray,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``(x + mixer(ln1(x)), tails, states)`` of one mixer layer of a
+    :func:`decode_slots` call: ``tail`` / ``state`` are the layer's banks
+    over the pool's slots, handed back with the rows' new ones in their
+    slots (the compact form scatters them; a padded row, ``n_valid =
+    0``, writes nothing, as its K/V rows do not)."""
+    from torchgpipe_tpu.models import ssm
+
+    # The barriers keep one layer's state rows alive at a time: without
+    # them the compiler gathers every layer's rows early and scatters
+    # them late, holding 0.4 GiB of float32 rows a mixer layer at once
+    # (the compact prefill program of the cell's pool, described-chip
+    # compile).
+    x, tail, state = lax.optimization_barrier((x, tail, state))
+    rows = (slice(None) if slots is None else slots)
+    out, new_tail, new_state = ssm.mixer(
+        cfg, p, _block_norm(cfg, p, "ln1", x), tail[rows], state[rows],
+        n_valid, pos0 == 0)
+    if slots is not None:
+        dst = jnp.where(n_valid > 0, slots, tail.shape[0])
+        new_tail = tail.at[dst].set(new_tail, mode="drop")
+        new_state = state.at[dst].set(new_state, mode="drop")
+    return lax.optimization_barrier(
+        (x + out.astype(x.dtype), new_tail, new_state))
+
+
 def decode_slots(
     cfg: TransformerConfig,
     params: Pytree,
@@ -728,6 +764,7 @@ def decode_slots(
     moe: Optional[Any] = None,
     slots: Optional[jnp.ndarray] = None,  # [R] int32 — row i IS slot slots[i]
     expert_counts: bool = False,
+    logits_at: Optional[jnp.ndarray] = None,  # [S] int32 — one position a row
 ) -> Tuple:
     """The SLOT-MASKED decode step: ``g`` tokens per slot through all
     blocks, each slot at its OWN position ``lengths[i]``, with row
@@ -790,6 +827,17 @@ def decode_slots(
     holds a position of its new tenant or one before 0).
     ``cache_mode='ring'``'s one ring for all layers is ``generate``'s.
 
+    A hybrid model (``cfg.layer_pattern``) walks its pattern: an
+    attention layer is the above without a feed-forward, an expert
+    layer a feed-forward alone, and a mixer layer (``models.ssm.mixer``)
+    continues each row's conv tail and recurrent state of its
+    :class:`~.kv_cache.HybridCache` from the row's slot: zero where the
+    row's frontier is 0 (a new tenant), untouched where the row does
+    nothing (``n_valid = 0``; a padded row writes nothing back either).
+
+    ``logits_at`` (a position ``< g`` a row) computes the head at that
+    position of each row alone: the logits are then ``[S, 1, vocab]``.
+
     ``expert_counts=True`` appends a fourth result: ``int32 [expert
     layers, held]``, the tokens this call routed to each expert the
     layer holds (``MoEConfig.held``; masked positions not counted), in
@@ -798,7 +846,9 @@ def decode_slots(
     embed_p, block_p, head_p = _split_params(cfg, params)
     mlp_layer = _mlp_layer_for(cfg, moe)
     S, g = tokens.shape          # rows of THIS call (R under ``slots``)
-    L = _cache_rows(cache)
+    hybrid = isinstance(cache, kv_cache.HybridCache)
+    kv = cache.kv if hybrid else cache
+    L = _cache_rows(kv)
     counts: Optional[List[jnp.ndarray]] = [] if expert_counts else None
     compact = slots is not None
     slot_of = slots if compact else jnp.arange(S)       # [S] row -> slot
@@ -818,7 +868,22 @@ def decode_slots(
     # columns modulo the ring (its own length where masked).
     ring_live, ring_at = None, {}
     new = []
-    for i, (p, layer) in enumerate(zip(block_p, kv_cache.layers(cache))):
+    banks = kv_cache.layers(kv)
+    states = iter(zip(cache.conv, cache.ssm)) if hybrid else None
+    new_conv, new_ssm = [], []
+    for i, p in enumerate(block_p):
+        kind = cfg.layer_type(i)
+        if kind == "mixer":
+            x, tail, st = _mixer_layer(cfg, p, x, *next(states), slots,
+                                       n_valid, pos0)
+            new_conv.append(tail)
+            new_ssm.append(st)
+            continue
+        if kind == "experts":
+            h = _block_norm(cfg, p, "ln1", x)
+            x = x + _mlp_out(cfg, p, h, mlp_layer, valid, counts)
+            continue
+        layer = next(banks)
         if cfg.mla is not None:
             h = _block_norm(cfg, p, "ln1", x)
             q_nope, q_pe, *rows = mla.project(cfg, p, h, pos0)
@@ -864,7 +929,11 @@ def decode_slots(
         lengths.at[slots].add(n_valid) if compact else lengths + n_valid
     )
     length = jnp.sum(new_lengths).astype(jnp.int32)  # schema slot only
-    out_cache = kv_cache.rebuild(cache, new, length)
+    out_cache = kv_cache.rebuild(kv, new, length)
+    if hybrid:
+        out_cache = kv_cache.HybridCache(out_cache, new_conv, new_ssm, length)
+    if logits_at is not None:
+        x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
     out = (_logits(cfg, head_p, x), out_cache, new_lengths)
     if expert_counts:
         if not counts:
@@ -1151,6 +1220,17 @@ def prefill(
             "ring caches hold exactly the attention window: set "
             "cfg.attn_window to use ring=True"
         )
+    if cfg.ssm is not None:
+        if ring or kv_quant:
+            kv_cache.refuse_state(cfg, "a ring or int8 cache")
+        # A hybrid model's prompt is one decode_slots call from an empty
+        # cache: the mixer layers' chunked form from a zero state.
+        logits, cache, _ = decode_slots(
+            cfg, params, tokens, init_cache(cfg, b, max_len),
+            jnp.zeros((b,), jnp.int32), jnp.full((b,), s, jnp.int32),
+            moe=moe, logits_at=jnp.full((b,), s - 1, jnp.int32))
+        return logits[:, 0], cache._replace(
+            length=jnp.asarray(s, jnp.int32))
     L = _window(cfg) if ring else max_len
     mlp_layer = _mlp_layer_for(cfg, moe)
     if ring or kv_quant:
@@ -1498,6 +1578,7 @@ def beam_search(
     total = _total_len(s, max_new_tokens, max_len)
     _check_decodable(cfg, total)
     _refuse_mla(cfg, "beam search's reordered cache")
+    kv_cache.refuse_state(cfg, "beam search's reordered cache")
     embed_p, block_p, head_p = _split_params(cfg, params)
     mlp_layer = _mlp_layer_for(cfg, moe)
     logits0, cache = prefill(cfg, params, prompt, total, moe=moe)
@@ -1688,6 +1769,7 @@ def speculative_generate(
     total = _total_len(s, T, max_len)
     _check_decodable(cfg, total)
     _refuse_mla(cfg, "speculative decoding's rolled-back cache")
+    kv_cache.refuse_state(cfg, "speculative decoding's rolled-back cache")
     _refuse_mla(draft_cfg, "speculative decoding's rolled-back cache")
     kv_cache.refuse_rings(cfg, "speculative decoding's rolled-back cache")
     kv_cache.refuse_rings(

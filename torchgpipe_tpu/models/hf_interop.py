@@ -1855,3 +1855,110 @@ def config_from_hf_afmoe(hf_config: Any, held: Any = None) -> tuple:
 
 
 __all__ += ["config_from_hf_afmoe"]
+
+
+def config_from_hf_nemotron_h(hf_config: Any, held: Any = None) -> tuple:
+    """(TransformerConfig, MoEConfig) for a published ``model_type:
+    nemotron_h`` config with experts (NVIDIA's Nemotron-3-Nano family:
+    the key set ``hybrid_override_pattern``, ``mamba_num_heads``,
+    ``mamba_head_dim``, ``n_groups``, ``ssm_state_size``,
+    ``conv_kernel``, ``chunk_size``, ``n_routed_experts``,
+    ``num_experts_per_tok``, ``moe_intermediate_size``,
+    ``moe_shared_expert_intermediate_size``, ``norm_topk_prob``,
+    ``routed_scaling_factor``).  ``hf_config`` is any object with those
+    attributes, as they stand in ``config.json``.
+
+    A layer is ONE of a Mamba-2 mixer (``M``: ``cfg.ssm``, its inner
+    width ``mamba_num_heads x mamba_head_dim``; ``expand`` is not read),
+    an expert layer (``E``) or attention (``*``), each ``x + F(norm(x))``
+    (``cfg.layer_pattern``).  Attention is causal and full and rotates
+    nothing (the family's attention has no rotary embedding;
+    ``rope_theta`` is not read).  Experts are ungated ``relu2`` (routed
+    and shared alike, the shared expert of its own width), chosen by
+    sigmoid score plus ``e_score_correction_bias`` (the layer's
+    ``router_bias``) and weighted by the score, normalised and scaled.
+    ``held=(first, count)`` makes the expert layers one chip's share
+    (``MoEConfig.held``).  Serving path only: the training block refuses
+    the pattern by name.  A pattern letter other than ``M``, ``E`` and
+    ``*`` (the family's dense ``-`` among them), grouped selection, an
+    activation other than the record's and a projection bias raise
+    instead of being ignored."""
+    from torchgpipe_tpu.models.moe import MoEConfig
+    from torchgpipe_tpu.models.transformer import (
+        LAYER_LETTERS, AttnLayer, SSMConfig,
+    )
+
+    hf = hf_config
+    pattern = str(hf.hybrid_override_pattern)
+    unknown = sorted(set(pattern) - set(LAYER_LETTERS))
+    if unknown:
+        raise ValueError(
+            f"hybrid_override_pattern letters {unknown} are not computed "
+            f"here ({sorted(LAYER_LETTERS)} are)"
+        )
+    for key, want in (("mlp_hidden_act", "relu2"),
+                      ("mamba_hidden_act", "silu"),
+                      ("attention_bias", False), ("mlp_bias", False),
+                      ("mamba_proj_bias", False), ("use_bias", False),
+                      ("n_group", 1), ("topk_group", 1),
+                      ("residual_in_fp32", False), ("use_conv_bias", True)):
+        if getattr(hf, key, want) != want:
+            raise ValueError(
+                f"{key}={getattr(hf, key)!r} is not computed here "
+                f"({want!r} is)"
+            )
+    limit = list(getattr(hf, "time_step_limit", None) or (0.0, None))
+    if limit[0] or limit[1] is not None:
+        raise ValueError(
+            f"time_step_limit={limit}: the step is not clipped here "
+            "((0, None), or none given, is computed)"
+        )
+    ssm = SSMConfig(
+        n_heads=int(hf.mamba_num_heads),
+        head_dim=int(hf.mamba_head_dim),
+        n_groups=int(hf.n_groups),
+        state=int(hf.ssm_state_size),
+        conv_kernel=int(hf.conv_kernel),
+        chunk=int(getattr(hf, "chunk_size", 128)),
+    )
+    if ssm.n_heads % ssm.n_groups:
+        raise ValueError(
+            f"mamba_num_heads={ssm.n_heads} is not a multiple of "
+            f"n_groups={ssm.n_groups}"
+        )
+    eps = float(getattr(hf, "layer_norm_epsilon",
+                        getattr(hf, "norm_eps", 1e-5)))
+    cfg = TransformerConfig(
+        vocab=hf.vocab_size,
+        dim=hf.hidden_size,
+        n_layers=hf.num_hidden_layers,
+        n_heads=hf.num_attention_heads,
+        n_kv_heads=hf.num_key_value_heads,
+        n_head_dim=int(hf.head_dim),
+        rope_theta=float(getattr(hf, "rope_theta", 10000.0)),
+        norm_eps=eps,
+        tie_embeddings=bool(getattr(hf, "tie_word_embeddings", False)),
+        attn_layers=(AttnLayer(None, float(getattr(hf, "rope_theta",
+                                                   10000.0)), rope=False),),
+        layer_pattern=pattern,
+        ssm=ssm if "M" in pattern else None,
+    )
+    cfg.validate_arch()
+    moe = MoEConfig(
+        n_experts=int(hf.n_routed_experts),
+        top_k=int(hf.num_experts_per_tok),
+        dispatch="dropless",
+        scoring="sigmoid",
+        norm_topk=bool(getattr(hf, "norm_topk_prob", False)),
+        route_scale=float(getattr(hf, "routed_scaling_factor", 1.0)),
+        n_shared=int(getattr(hf, "n_shared_experts", 0) or 0),
+        expert_hidden=int(hf.moe_intermediate_size),
+        shared_hidden=int(hf.moe_shared_expert_intermediate_size),
+        held=None if held is None else (int(held[0]), int(held[1])),
+        select="bias",
+        act="relu2",
+    )
+    return cfg, moe
+
+
+__all__ += ["config_from_hf_nemotron_h"]

@@ -24,6 +24,15 @@ find their whole band beside the rows they write), a full layer
 A one-entry period (a model-global window, or none) keeps ``max_len``
 rows in every layer.
 
+A hybrid model whose mixer layers keep a recurrent state (``cfg.ssm``,
+``models.ssm``) gets a :class:`HybridCache`: its attention layers' K/V
+rows as a :class:`KVCache` (``kv``), and beside them, a mixer layer
+each, a state that does NOT grow with the context: the conv's last
+inputs (``conv``) and the float32 state (``ssm``).  Nothing of it lies
+at a position, so what takes rows at their position (the merges and
+copies below, int8 rows, a prefix's rows, migration, rollback) refuses
+it by name (:func:`refuse_state`).
+
 Every kind has two CONTENT banks a layer (K and V, or the latent and
 the rotated key head) and, where its rows are quantised, one scale bank
 beside each.  :func:`layers` hands a layer's banks out as ``(a, b,
@@ -76,6 +85,20 @@ class LatentCache(NamedTuple):
     length: jnp.ndarray     # [] int32 — tokens already cached
 
 
+class HybridCache(NamedTuple):
+    """The cache of a hybrid model (``cfg.layer_pattern`` with mixer
+    layers, ``cfg.ssm``): the attention layers' rows, and a mixer layer
+    each a slot's conv tail and recurrent state (``models.ssm``).  A
+    row of the state is a SLOT, not a position: the state sums the whole
+    context, so a new tenant starts it from zero (the mixer reads zero
+    where a row's frontier is 0) and a no-op row leaves it untouched."""
+
+    kv: KVCache             # the attention ('*') layers' K/V, layer order
+    conv: List[jnp.ndarray]  # each [b, conv_kernel - 1, conv_dim]
+    ssm: List[jnp.ndarray]   # each f32 [b, n_heads, head_dim, state]
+    length: jnp.ndarray      # [] int32 — tokens already cached
+
+
 class QuantKVCache(NamedTuple):
     """int8 K/V buffers with per-(position, kv-head) scales — half the
     cache HBM footprint/traffic of bf16 and a quarter of f32; see
@@ -113,6 +136,50 @@ def _refuse_mla(cfg: TransformerConfig, what: str) -> None:
 # (``ops.flash_attention._decode_tiling``: 512 rows), so that the kernel
 # reads a ring at the block it reads a full layer at.
 RING_GRANULE = 512
+
+
+def refuse_state(cfg: TransformerConfig, what: str) -> None:
+    """Refuse, by name, what is written for cache rows that lie at their
+    position where the model's mixer layers keep a recurrent state
+    (``cfg.ssm``): a slot's state sums its whole context, so it cannot
+    be cut at a position, copied in part, rolled back or quantized a row
+    at a time."""
+    if cfg.ssm is not None:
+        raise NotImplementedError(
+            f"{what} takes cache rows that lie at their position; this "
+            "model's mixer layers ('M' of cfg.layer_pattern) keep a "
+            "recurrent state a slot (kv_cache.HybridCache) that sums the "
+            "whole context and has no row at a position: prefill / "
+            "decode_slots and serving.Engine's plain pool serve it"
+        )
+
+
+def attention_view(cfg: TransformerConfig, cache: Any,
+                   layer: int) -> Tuple[Any, int]:
+    """``(cache, index)``: the cache whose banks layer ``layer``'s
+    attention reads and the index of its banks there (a hybrid model's
+    attention layers are counted among themselves)."""
+    if not isinstance(cache, HybridCache):
+        return cache, layer
+    return cache.kv, cfg.layer_pattern[:layer].count("*")
+
+
+def bytes_by_kind(cfg: TransformerConfig, cache: Any) -> Dict[str, int]:
+    """The bytes ``cache``'s banks pin by the kind of layer that holds
+    them: ``window`` and ``full`` (:func:`layer_kind`), and ``state``,
+    a hybrid model's mixer layers' tails and states."""
+    out = {"window": 0, "full": 0}
+    kv = cache
+    if isinstance(cache, HybridCache):
+        kv = cache.kv
+        out["state"] = sum(b.size * b.dtype.itemsize
+                           for b in (*cache.conv, *cache.ssm))
+    attn = [i for i in range(cfg.n_layers)
+            if cfg.layer_type(i) in ("block", "attention")]
+    for i, banks in zip(attn, layers(kv)):
+        out[layer_kind(cfg, i)] += sum(
+            b.size * b.dtype.itemsize for b in banks if b is not None)
+    return out
 
 
 def ring_layer(cfg: TransformerConfig, layer: int) -> bool:
@@ -175,6 +242,20 @@ def init_cache(
     (a serving pool's largest prefill chunk): it sizes the rings of a
     model that mixes layer types (:func:`layer_rows`) and nothing else."""
     dt = dtype or cfg.dtype
+    if cfg.ssm is not None:
+        from torchgpipe_tpu.models import ssm
+
+        n_attn = cfg.layer_pattern.count("*")
+        rows = (batch, max_len, cfg.kv_heads, cfg.head_dim)
+        states = [ssm.init_state(cfg, batch, dt)
+                  for _ in range(cfg.layer_pattern.count("M"))]
+        return HybridCache(
+            kv=KVCache(k=[jnp.zeros(rows, dt) for _ in range(n_attn)],
+                       v=[jnp.zeros(rows, dt) for _ in range(n_attn)],
+                       length=jnp.zeros((), jnp.int32)),
+            conv=[c for c, _ in states], ssm=[st for _, st in states],
+            length=jnp.zeros((), jnp.int32),
+        )
     if cfg.mla is not None:
         m = cfg.mla
         return LatentCache(
@@ -200,6 +281,7 @@ def init_quant_cache(
     shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
     _refuse_mla(cfg, "the int8 QuantKVCache")
     refuse_rings(cfg, "the int8 QuantKVCache")
+    refuse_state(cfg, "the int8 QuantKVCache")
     sshape = (batch, cfg.kv_heads, max_len)
     return QuantKVCache(
         k=[jnp.zeros(shape, jnp.int8) for _ in range(cfg.n_layers)],
@@ -226,7 +308,10 @@ def _dequant_rows(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
 
 
 def bank_rows(cache: Any) -> List[int]:
-    """The length of each layer's banks, in block order."""
+    """The length of each layer's banks, in block order (a hybrid
+    cache's: of its attention layers)."""
+    if isinstance(cache, HybridCache):
+        cache = cache.kv
     field = _bank_fields(cache)[0]
     return [bank.shape[_LENGTH_AXIS[field]] for bank in getattr(cache, field)]
 
@@ -317,10 +402,20 @@ def write_scattered(layer: Layer, rows: Tuple, at: ScatterIndex) -> Layer:
     return _write(layer, rows, put, put_scales)
 
 
+def _refuse_hybrid(cache: Any) -> None:
+    if isinstance(cache, HybridCache):
+        raise NotImplementedError(
+            "a HybridCache's mixer layers keep a state a slot, not rows "
+            "at positions: rows cannot be merged, copied or shipped "
+            "apart from it (kv_cache.refuse_state)"
+        )
+
+
 def _map_banks(cache: Any, fn: Any) -> Any:
     """A cache of ``cache``'s kind and length whose every bank is
     ``fn(field, layer, bank, the bank's length axis)``, field by field
     in layout order."""
+    _refuse_hybrid(cache)
     return type(cache)(
         *(
             [fn(f, i, bank, _LENGTH_AXIS[f])
@@ -382,6 +477,7 @@ def copy_rows(cache: Any, src: Any, dst: jnp.ndarray, n: jnp.ndarray) -> Any:
 
 def slot_rows(cache: Any, slot: Any) -> Dict[str, List[jnp.ndarray]]:
     """One slot's rows of every bank, slot axis sliced away, by field."""
+    _refuse_hybrid(cache)
     return {
         f: [bank[slot] for bank in getattr(cache, f)]
         for f in _bank_fields(cache)

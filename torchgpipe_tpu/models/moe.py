@@ -157,6 +157,13 @@ class MoEConfig:
     # (DeepSeek-V3's bias-corrected selection, arXiv:2412.19437 section
     # 2.1.2).  A grouped rule is one more entry of ``_SELECT``.
     select: str = "none"
+    # An expert's feed-forward: 'swiglu' (``down(silu(gate u) * up u)``)
+    # or 'relu2', ungated (``down(relu(up u) ** 2)``: Nemotron-H's; no
+    # ``w_gate``), for the routed and the shared experts alike.  'relu2'
+    # is computed on the dropless path.
+    act: str = "swiglu"
+    # Width of the shared expert; None -> n_shared * the expert width.
+    shared_hidden: Optional[int] = None
 
     @property
     def held_range(self) -> Tuple[int, int]:
@@ -449,8 +456,19 @@ def _inverse_order(key: jnp.ndarray, n: int) -> jnp.ndarray:
     return jnp.sum(jnp.where(hit, rank - 1 + starts, 0), axis=1)
 
 
+def _ffn(x: jnp.ndarray, w_gate: Optional[jnp.ndarray], w_up: jnp.ndarray,
+         w_down: jnp.ndarray, product: Any = jnp.matmul) -> jnp.ndarray:
+    """One expert's feed-forward through ``product`` (a plain or a
+    grouped product): the SwiGLU, or with no ``w_gate`` the ungated
+    ``down(relu(up x) ** 2)`` (``MoEConfig.act='relu2'``)."""
+    if w_gate is None:
+        return product(jnp.square(jax.nn.relu(product(x, w_up))), w_down)
+    return product(
+        jax.nn.silu(product(x, w_gate)) * product(x, w_up), w_down)
+
+
 def _expert_sum(
-    xf: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
+    xf: jnp.ndarray, w_gate: Optional[jnp.ndarray], w_up: jnp.ndarray,
     w_down: jnp.ndarray, gate_sorted: jnp.ndarray, tok_sorted: jnp.ndarray,
     group_sizes: jnp.ndarray, zero: Optional[str] = None,
     inv: Optional[jnp.ndarray] = None,
@@ -483,8 +501,7 @@ def _expert_sum(
         return jnp.where(in_group, lax.ragged_dot(x, w, group_sizes), 0.0)
 
     xs = xf[tok_sorted]  # [kt, d] expert-sorted
-    h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
-    ys = grouped(h, w_down)
+    ys = _ffn(xs, w_gate, w_up, w_down, grouped)
     if inv is not None:  # the rows of no group are selected out, as below
         gates = gate_sorted[inv][:, None]
         y = jnp.where(gates != 0.0, ys[inv], 0.0).astype(jnp.float32) * gates
@@ -554,6 +571,10 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
             f"MoEConfig.scoring={moe.scoring!r}: expected 'softmax' or "
             "'sigmoid'"
         )
+    if moe.act not in ("swiglu", "relu2"):
+        raise ValueError(
+            f"MoEConfig.act={moe.act!r}: expected 'swiglu' or 'relu2'"
+        )
     if moe.select not in _SELECT:
         raise ValueError(
             f"MoEConfig.select={moe.select!r}: the selection rules "
@@ -576,6 +597,12 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
                 "router='topk'"
             )
     dropless = moe.dispatch == "dropless" or moe.held is not None
+    if moe.act == "relu2" and not dropless:
+        raise ValueError(
+            "act='relu2' experts are computed on the dropless path: "
+            "dispatch='dropless' or held=(first, count)"
+        )
+    gated = moe.act == "swiglu"
     if moe.dispatch not in ("auto", "dense", "sparse", "dropless"):
         raise ValueError(
             "MoEConfig.dispatch must be 'auto'|'dense'|'sparse'|'dropless'"
@@ -624,12 +651,16 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
                 ks[3], (n_held, hidden, dim), hidden ** -0.5, dt),
         }
         if moe.n_shared:
-            sh, kss = moe.n_shared * hidden, jax.random.split(ks[0], 4)
+            sh = moe.shared_hidden or moe.n_shared * hidden
+            kss = jax.random.split(ks[0], 4)
             params["shared"] = {
                 "w_gate": _normal(kss[1], (dim, sh), std, dt),
                 "w_up": _normal(kss[2], (dim, sh), std, dt),
                 "w_down": _normal(kss[3], (sh, dim), sh ** -0.5, dt),
             }
+        if not gated:
+            del params["w_gate"]
+            params.get("shared", {}).pop("w_gate", None)
         return params, ()
 
     def apply(params, state, x, *, rng=None, train=True):
@@ -669,9 +700,8 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
             if moe.n_shared:
                 with jax.named_scope("moe.shared"):
                     sp = params["shared"]
-                    y = y + (
-                        jax.nn.silu(xf @ sp["w_gate"]) * (xf @ sp["w_up"])
-                    ) @ sp["w_down"]
+                    y = y + _ffn(xf, sp.get("w_gate"), sp["w_up"],
+                                 sp["w_down"])
             y = y.reshape(b, s, d).astype(x.dtype)
             if moe.balance_weight > 0.0 and train:
                 _, _, aux = _balance_penalty(probs, E, K)
@@ -721,8 +751,8 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
                 tok_sorted = tok[order]
                 gate_sorted = jnp.where(mine, gates, 0.0)[order]
             ragged = moe.held is not None or valid is not None
-            args = (xf, params["w_gate"], params["w_up"], params["w_down"],
-                    gate_sorted, tok_sorted, group_sizes)
+            args = (xf, params.get("w_gate"), params["w_up"],
+                    params["w_down"], gate_sorted, tok_sorted, group_sizes)
             with jax.named_scope("moe.experts"):
                 y = _held_expert_sum(*args, key) if ragged else _expert_sum(
                     *args)

@@ -93,6 +93,42 @@ class MLAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """A Mamba-2 mixer (Dao & Gu, arXiv:2405.21060) as Nemotron-H's
+    record states it: ``n_heads`` heads of ``head_dim``, their
+    ``n_groups`` groups of B and C of ``state`` each (head ``h`` reads
+    group ``h // (n_heads // n_groups)``), a causal depthwise conv of
+    ``conv_kernel`` taps over ``[x | B | C]``, and the gated norm over
+    groups of ``d_inner // n_groups``.  What a slot keeps of it
+    (``models.kv_cache.HybridCache``) is the conv's last ``conv_kernel -
+    1`` inputs and the f32 state ``[n_heads, head_dim, state]``, neither
+    of which grows with the context.  ``chunk`` is the length of a block
+    of the chunked (SSD) form a prompt is absorbed in; it changes the
+    order of the sums, not the mathematics."""
+
+    n_heads: int
+    head_dim: int
+    n_groups: int
+    state: int
+    conv_kernel: int = 4
+    chunk: int = 128
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of the conv: x, then B and C of every group."""
+        return self.d_inner + 2 * self.n_groups * self.state
+
+
+# The letters of a hybrid layer pattern (``TransformerConfig.layer_pattern``):
+# the one thing a layer computes besides its pre-norm and residual.
+LAYER_LETTERS = {"M": "mixer", "E": "experts", "*": "attention"}
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 32000
     dim: int = 512
@@ -229,6 +265,23 @@ class TransformerConfig:
     # (``models.generation``, ``serving.Engine``): the training block
     # refuses it.
     mla: Optional[MLAConfig] = None
+    # A hybrid model (Nemotron-H's ``hybrid_override_pattern``): one
+    # letter a layer of :data:`LAYER_LETTERS`, each layer ``x + F(norm(x))``
+    # with ``F`` ONE of a Mamba-2 mixer (``M``, of ``ssm``), an expert
+    # feed-forward (``E``) or attention (``*``): nothing pairs attention
+    # with a feed-forward.  None: every layer is attention then a
+    # feed-forward.  Serving path only (``models.generation``,
+    # ``serving.Engine``): the training block refuses it.
+    layer_pattern: Optional[str] = None
+    ssm: Optional[SSMConfig] = None
+
+    def layer_type(self, layer: int) -> str:
+        """What layer ``layer`` computes: ``'block'`` (attention, then a
+        feed-forward), or under a ``layer_pattern`` its letter's
+        ``'mixer'`` / ``'experts'`` / ``'attention'``."""
+        if self.layer_pattern is None:
+            return "block"
+        return LAYER_LETTERS[self.layer_pattern[layer]]
 
     @property
     def attn_kind(self) -> str:
@@ -305,6 +358,26 @@ class TransformerConfig:
                 "attn_layers is a non-empty period of AttnLayer entries "
                 "and carries the windows itself: leave attn_window None"
             )
+        if self.layer_pattern is not None:
+            unknown = sorted(set(self.layer_pattern) - set(LAYER_LETTERS))
+            if unknown:
+                raise ValueError(
+                    f"layer_pattern letters {unknown}: a layer is one of "
+                    f"{LAYER_LETTERS}"
+                )
+            if len(self.layer_pattern) != self.n_layers:
+                raise ValueError(
+                    f"layer_pattern has {len(self.layer_pattern)} letters "
+                    f"for n_layers={self.n_layers}"
+                )
+            if ("M" in self.layer_pattern) != (self.ssm is not None):
+                raise ValueError(
+                    "a layer_pattern with mixer layers ('M') needs cfg.ssm, "
+                    "and cfg.ssm needs them"
+                )
+        elif self.ssm is not None:
+            raise ValueError("cfg.ssm describes the 'M' layers of a "
+                             "layer_pattern: set one")
         if not 0.0 < self.rope_pct <= 1.0:
             raise ValueError(f"rope_pct={self.rope_pct} must be in (0, 1]")
         if self.rope_pct < 1.0 and int(self.head_dim * self.rope_pct) % 2:
@@ -538,6 +611,14 @@ def transformer_block(
             "only (models.generation.prefill / decode_slots, "
             "serving.Engine; block params from models.mla.init_block); "
             "the training block has no MLA forward"
+        )
+    if cfg.layer_pattern is not None:
+        raise NotImplementedError(
+            f"layer_pattern={cfg.layer_pattern!r} (mixer-only, expert-only "
+            "and attention-only layers; the Mamba-2 mixer's state) is "
+            "computed on the serving path only (models.generation.prefill "
+            "/ decode_slots, serving.Engine); the training block has no "
+            "mixer forward or backward"
         )
     for on, what in ((cfg.attn_gate, "attn_gate (the attention's output "
                       "gate)"),

@@ -21,7 +21,13 @@ masking — every attention read masks cache rows ``> length``, and decode
 writes land exactly at ``length``, so a recycled slot can never see its
 previous tenant's K/V, scales included (the bitwise slot-reuse test in
 ``tests/test_serving.py`` pins this for the int8 cache, where a stale
-*scale* would corrupt every row it spans).
+*scale* would corrupt every row it spans).  A hybrid model's recurrent
+state (``kv_cache.HybridCache``) is not masked by position: it sums the
+whole context.  Its recycling needs no device work either: admission
+sets the slot's frontier to 0, and a row at frontier 0 reads a zero
+state and conv tail in the step program (``models.ssm.mixer``), so the
+last tenant's state is never read (``ServingMetrics.state_zeroed_slots``
+counts those admissions).
 
 Sizing: :func:`torchgpipe_tpu.tune.serving_cache_bytes` accounts the
 pool via ``eval_shape`` (no allocation);
@@ -210,12 +216,9 @@ class CachePool:
     def bytes_by_kind(self) -> Dict[str, int]:
         """:meth:`bytes` by the kind of layer that holds them:
         ``window`` (a layer that attends in a window: a ring's rows in
-        a model that mixes layer types) and ``full``."""
-        out = {"window": 0, "full": 0}
-        for i, banks in enumerate(kv_cache.layers(self.cache)):
-            out[kv_cache.layer_kind(self.cfg, i)] += sum(
-                b.size * b.dtype.itemsize for b in banks if b is not None)
-        return out
+        a model that mixes layer types), ``full``, and a hybrid model's
+        recurrent ``state`` (``kv_cache.bytes_by_kind``)."""
+        return kv_cache.bytes_by_kind(self.cfg, self.cache)
 
     def lengths_device(self) -> jnp.ndarray:
         """The per-slot frontier vector as an int32 array for a step.

@@ -274,6 +274,20 @@ class Engine:
         ):
             if on:
                 kv_cache.refuse_rings(cfg, what)
+        # And for a hybrid model whose mixer layers keep a recurrent
+        # state a slot (``kv_cache.HybridCache``): it has no row at a
+        # position to copy, ship or quantize, and a preempted stream
+        # would re-absorb its tokens in prompt chunks where it went one
+        # token a step — the state's sums in another order, so not the
+        # bitwise resumption preemption promises.
+        for on, what in (
+            (kv_quant, "kv_quant (the int8 QuantKVCache)"),
+            (prefix_cache is not None, "a prefix cache (RadixPrefixCache)"),
+            (role != "unified", f"role={role!r} (KV-row migration)"),
+            (qos is not None, "QoS preemption (qos=)"),
+        ):
+            if on:
+                kv_cache.refuse_state(cfg, what)
         # Expert layers that are told what they hold (``MoEConfig.held``)
         # report the tokens each held expert received: the step programs
         # then return, in the place of their sampled tokens, the pair
@@ -366,6 +380,9 @@ class Engine:
             clock=clock, registry=registry
         )
         self.metrics.pool_bytes = self.pool.bytes_by_kind()
+        # A slot's recurrent state, every mixer layer (0 without one).
+        self._slot_state_bytes = self.metrics.pool_bytes.get(
+            "state", 0) // num_slots
         self.reporter = reporter
         # ``recorder`` (obs.FlightRecorder) threads a per-request span
         # record through the serving loop: submit/admit, the prefix-
@@ -466,7 +483,8 @@ class Engine:
         # ring where the model mixes layer types, and ``full``).
         kinds: Dict[str, int] = {}
         for i in range(cfg.n_layers):
-            kinds.setdefault(kv_cache.layer_kind(cfg, i), i)
+            if cfg.layer_type(i) in ("block", "attention"):
+                kinds.setdefault(kv_cache.layer_kind(cfg, i), i)
         self._attend_counters = {
             shape: {
                 kind: attend_rows_counter(
@@ -512,21 +530,29 @@ class Engine:
             # ``lengths`` comes back advanced ON DEVICE (at ``slots``,
             # by the rows each consumed): the next step reuses the
             # array instead of re-uploading the host mirror.
+            last = jnp.clip(n_valid - 1, 0, g - 1)
+            # The chunked prefill samples at each row's last valid
+            # position and nothing else: the head runs there alone
+            # (``logits_at``; at R x g positions its float32 logits
+            # were 1.6 GiB of a 102 x 64 step over 65,536 rows of
+            # vocabulary, described-chip compile).  Speculative
+            # decoding's verify pass (``cur_tok`` None) takes every
+            # position's: its per-POSITION greedy tokens [rows, g],
+            # what the target model would emit after consuming each
+            # input position, are the acceptance oracle
+            # (fleet/speculative.py).
+            verify = cur_tok is None
             logits, cache, lengths, *held = decode_slots(
                 cfg, params, tokens, cache, lengths, n_valid, moe=moe,
                 slots=slots, expert_counts=self._expert_counts,
+                logits_at=None if verify else last,
             )
-            last = jnp.clip(n_valid - 1, 0, g - 1)
-            row_logits = jnp.take_along_axis(
+            row_logits = (jnp.take_along_axis(
                 logits, last[:, None, None], axis=1
-            )[:, 0]
+            ) if verify else logits)[:, 0]
             tok, key = self._sample_row(row_logits, key)
-            # Per-POSITION greedy tokens [rows, g]: what the target
-            # model would emit after consuming each input position.
-            # Chunked prefill ignores it (an output nobody fetches);
-            # for speculative decoding's verify pass the grid is the
-            # acceptance oracle (fleet/speculative.py).
-            grid = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            grid = (jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    if verify else None)
             if cur_tok is not None:
                 # A row whose prompt completes here (``finish``: the
                 # host knows it from counts) hands its first token to
@@ -691,6 +717,7 @@ class Engine:
                 "latent-attention pool holds the KV latent"
             )
         kv_cache.refuse_rings(self.cfg, "KV-row migration (kv_row_specs)")
+        kv_cache.refuse_state(self.cfg, "KV-row migration (kv_row_specs)")
         return kv_cache.slot_row_specs(self.pool.cache)
 
     def _token_buffer(self, kind: str) -> np.ndarray:
@@ -719,6 +746,19 @@ class Engine:
             for what, v in zip(("read", "cap"), pair)
         } if len(attended) > 1 else {}
         self.timeline.annotate(rows_read=read, rows_cap=cap, **by_kind)
+
+    def _state_moved(self, pos0: np.ndarray, n_valid: np.ndarray) -> int:
+        """Recurrent-state bytes the step about to run reads and writes
+        (a hybrid model's; 0 otherwise): a row that runs writes its
+        slot's tails and states and reads them unless its frontier is
+        0.  Annotated on the action span (``state_bytes``)."""
+        if not self._slot_state_bytes:
+            return 0
+        runs = n_valid > 0
+        moved = int(runs.sum() + (runs & (pos0 > 0)).sum())
+        moved *= self._slot_state_bytes
+        self.timeline.annotate(state_bytes=moved)
+        return moved
 
     def _lengths_for_step(self) -> jnp.ndarray:
         """The frontier vector for the next compiled step: the previous
@@ -1078,6 +1118,8 @@ class Engine:
         ``emitted_prefix`` extended — exactly the drain/restore schema,
         per-request.  Greedy decode is prefix-deterministic, so the
         resumed stream is bitwise the unpreempted one."""
+        kv_cache.refuse_state(self.cfg, "preempt_request (a bitwise "
+                              "resumption from re-absorbed tokens)")
         self._settle()
         req = self.scheduler.active.get(rid)
         if req is None:
@@ -1112,6 +1154,10 @@ class Engine:
         """Per-admission hook: prefix-cache consult here; subclasses
         extend (``fleet.SpeculativeEngine`` resets the recycled slot's
         draft frontier)."""
+        if self._slot_state_bytes:
+            # The slot's state is zero for this tenant: its first chunk
+            # runs at frontier 0, where the mixers read zeros.
+            self.metrics.state_zeroed_slots += 1
         if self.recorder is not None:
             times = self.metrics.requests.get(req.rid)
             wait = times.queue_wait if times is not None else None
@@ -1192,6 +1238,7 @@ class Engine:
             ]
         attended = self._attended(self.pool.lengths[slots], n_valid, g)
         self._annotate_attended(attended)
+        moved = self._state_moved(self.pool.lengths[slots], n_valid)
         step = self._launch(
             "prefill", self._prefill_fns[name], args,
             rows=[(r, i) for i, r in enumerate(reqs) if finish[i]],
@@ -1212,7 +1259,7 @@ class Engine:
             self._lengths_shadow = self._lengths_shadow + shadow
             self.metrics.step(
                 "prefill", len(reqs), cap, deferred=deferred,
-                attended=attended, ahead=ahead,
+                attended=attended, ahead=ahead, state_bytes=moved,
             )
             for i, r in enumerate(reqs):
                 take = int(n_valid[i])
@@ -1250,6 +1297,7 @@ class Engine:
             ]
         attended = self._attended(self.pool.lengths, n_valid, 1)
         self._annotate_attended(attended)
+        moved = self._state_moved(self.pool.lengths, n_valid)
         step = self._launch(
             "decode", self._decode_fn, args,
             rows=[(r, r.slot) for r in reqs], positions=len(reqs),
@@ -1259,7 +1307,7 @@ class Engine:
             self._lengths_shadow = self._lengths_shadow + n_valid
             self.metrics.step(
                 "decode", len(reqs), self.pool.num_slots,
-                attended=attended, ahead=ahead,
+                attended=attended, ahead=ahead, state_bytes=moved,
             )
             for r in reqs:
                 self.pool.lengths[r.slot] += 1
@@ -1690,6 +1738,8 @@ class Engine:
         recovered (SLO-degraded) replica into rotation — the compiled
         programs and pool are unchanged, so serving resumes without a
         rebuild."""
+        kv_cache.refuse_state(self.cfg, "resume_serving (drained streams "
+                              "resumed bitwise from re-absorbed tokens)")
         self._draining = False
         self._drain_requested = False
 

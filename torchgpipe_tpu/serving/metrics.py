@@ -136,6 +136,21 @@ class ServingMetrics:
                      "attention layer")
             for kind in ("window", "full") for what in ("read", "capacity")
         }
+        # A hybrid model's recurrent state (``kv_cache.HybridCache``):
+        # the bytes of it each kind of step reads and writes (a row that
+        # runs writes its slot's state, and reads it unless its frontier
+        # is 0), and the slots whose state admission zeroed.
+        self._c_state_bytes = {
+            kind: reg.counter(
+                f"serving_state_bytes_{kind}",
+                help=f"recurrent-state bytes (conv tails and states of "
+                     f"every mixer layer) the {kind} steps read and wrote")
+            for kind in ("prefill", "decode")
+        }
+        self._c_state_zeroed = reg.counter(
+            "serving_state_zeroed_slots",
+            help="admissions whose slot's recurrent state starts from "
+                 "zero (its last tenant's state is never read)")
         # Bytes the pool's banks pin, by the same kinds (the engine
         # sets it once: ``CachePool.bytes_by_kind``).
         self.pool_bytes: Dict[str, int] = {}
@@ -178,7 +193,8 @@ class ServingMetrics:
                    for what, text in (
                        ("max", "the fullest held expert's tokens"),
                        ("mean", "the mean held expert's tokens"),
-                       ("steps", "1 (steps with expert counts)"))]
+                       ("steps", "1 (steps with expert counts)"),
+                       ("touched", "the held experts given a token"))]
             for kind in ("prefill", "decode")
         }
         self._h_ttft = reg.histogram(
@@ -211,6 +227,7 @@ class ServingMetrics:
     migrations_in = _counter_property("_c_migr_in")
     moe_routed_assignments = _counter_property("_c_moe_routed")
     moe_held_assignments = _counter_property("_c_moe_held")
+    state_zeroed_slots = _counter_property("_c_state_zeroed")
 
     # ------------------------------------------------------------------ #
     # request lifecycle                                                  #
@@ -279,7 +296,7 @@ class ServingMetrics:
     def step(self, kind: str, active_slots: int, num_slots: int,
              deferred: int = 0,
              attended: Optional[Dict[str, Tuple[int, int]]] = None,
-             ahead: bool = False) -> None:
+             ahead: bool = False, state_bytes: int = 0) -> None:
         """One compiled step, counted when it is LAUNCHED (``ahead``:
         while the step before it was still in flight; the tokens it
         samples are counted when they are delivered, a step later):
@@ -291,8 +308,10 @@ class ServingMetrics:
         capacity)`` of a layer's cache attention in this step
         (``models.generation.attend_rows_counter``) by the kind of
         layer, ``{'window': (read, capacity), 'full': ...}``, one layer
-        of each kind the model has."""
+        of each kind the model has.  ``state_bytes``: the recurrent
+        state the step reads and writes (a hybrid model's)."""
         self._c_ahead.inc(int(ahead))
+        self._c_state_bytes[kind].inc(state_bytes)
         for layers, (read, cap) in (attended or {}).items():
             self._c_attend_kind[layers, "read"].inc(read)
             self._c_attend_kind[layers, "capacity"].inc(cap)
@@ -314,18 +333,21 @@ class ServingMetrics:
         received."""
         self._c_moe_routed.inc(routed)
         self._c_moe_held.inc(int(counts.sum()))
-        peak, mean, steps = self._moe_load[kind]
+        peak, mean, steps, touched = self._moe_load[kind]
         peak.inc(float(counts.max()))
         mean.inc(float(counts.mean()))
         steps.inc()
+        touched.inc(int((counts > 0).sum()))
 
     def moe_expert_tokens(self, kind: str) -> Dict[str, float]:
         """Per ``kind`` step ('prefill' | 'decode'): the fullest and the
         mean held expert's tokens a step (0 before any such step), and
-        the ``steps`` they are means over."""
-        peak, mean, steps = (c.value() for c in self._moe_load[kind])
+        the ``steps`` they are means over, and the held experts a step
+        gave a token (``touched``, over every expert layer)."""
+        peak, mean, steps, touched = (c.value() for c in self._moe_load[kind])
         n = max(steps, 1)
-        return {"max": peak / n, "mean": mean / n, "steps": steps}
+        return {"max": peak / n, "mean": mean / n, "steps": steps,
+                "touched": touched / n}
 
     def drained(self, unfinished: int) -> None:
         self._c_drains.inc()
@@ -399,6 +421,9 @@ class ServingMetrics:
                        for what in ("read", "capacity")}
                 for kind in ("window", "full")},
             "kv_pool_bytes": dict(self.pool_bytes),
+            "state_bytes": {k: c.value()
+                            for k, c in self._c_state_bytes.items()},
+            "state_zeroed_slots": self.state_zeroed_slots,
             "retries": self.retries,
             "drains": self.drains,
             "preempted_requests": self.preempted_requests,
