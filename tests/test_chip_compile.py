@@ -25,6 +25,7 @@ from torchgpipe_tpu.ops.flash_attention import (
     flash_decode_attention,
     latent_decode_attention,
 )
+from torchgpipe_tpu.ops.grouped_matmul import grouped_matmul
 
 H, G, D = 32, 8, 128           # Mistral-7B: query heads, KV heads, head dim
 SEQ, WINDOW, MAX_LEN = 4096, 4096, 4096
@@ -85,6 +86,16 @@ def _decode(quant, window):
     if quant:
         shapes += [((1, G, MAX_LEN), jnp.float32)] * 2
     return fn, shapes
+
+
+def _calls(text, kernel):
+    """The instructions of a compiled program named after ``kernel`` (a
+    Pallas kernel's name, or the compiler's ``ragged-dot``).  The
+    program's source-frame tables are not searched: a lowering the
+    compiler cached for another program of the same process (a cumulative
+    sum over as many elements, say) brings that program's function names
+    along."""
+    return re.findall(rf"%{re.escape(kernel)}[\w.-]* = ", text)
 
 
 def _row_scatters(text, rows, width):
@@ -169,6 +180,17 @@ def _prefill_attention(s):
     return fn, _qkv(s)
 
 
+def _grouped(m, k, n, groups):
+    def fn(x, w, group_sizes):
+        return grouped_matmul(x, w, group_sizes)
+
+    return fn, [((m, k), BF16), ((groups, k, n), BF16), ((groups,), jnp.int32)]
+
+
+def _bank_bytes(m, k, n, groups):
+    return groups * k * n * 2
+
+
 # name -> (fn, argument (shape, dtype)s, kernel expected in the executable)
 CASES = {
     "flash-fwd": (*_flash(SEQ, window=WINDOW), True),
@@ -220,6 +242,23 @@ CASES = {
     "prefill-attention-100": (*_prefill_attention(100), False),
     "prefill-attention-200": (*_prefill_attention(200), False),
     "prefill-attention-256": (*_prefill_attention(256), True),
+    # The served expert layers' grouped products (rows, width in, width
+    # out, held experts), each under a bank's bytes of temporaries: the
+    # bank goes in as it lies.  Nemotron's up bank, 1,856 wide, is stored
+    # with its 2,688 minor; the compiler's ragged-dot took a relaid copy
+    # of it, 638 MB a call (a described-chip compile of that product).
+    **{
+        f"grouped-{name}": (*_grouped(*shape), True, _bank_bytes(*shape))
+        for name, shape in {
+            "nemotron-decode-up": (3072, 2688, 1856, 64),
+            "nemotron-prefill-up": (39168, 2688, 1856, 64),
+            "nemotron-prefill-down": (39168, 1856, 2688, 64),
+            "axk1-decode-gate": (1024, 7168, 2048, 12),
+            "axk1-prefill-down": (6400, 2048, 7168, 12),
+            # 192 rows: padded to the row tile.
+            "trinity-decode-up": (192, 3072, 3072, 32),
+        }.items()
+    },
 }
 
 
@@ -349,7 +388,10 @@ def test_latent_decode_slots_compiles_for_v5e(compact, chip, monkeypatch):
         ints(rows), ints(rows),
     ).compile()
     text = compiled.as_text()
-    assert "latent_decode" in text and "flash_decode" not in text
+    assert _calls(text, "latent_decode") and not _calls(text, "flash_decode")
+    # The expert sum's grouped products are the Pallas kernel, not the
+    # compiler's ragged-dot.
+    assert _calls(text, "grouped_matmul") and not _calls(text, "ragged-dot")
     assert not _row_scatters(text, rows * g, cfg.dim)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * L_RELAID_BYTES
 
@@ -451,8 +493,9 @@ def test_hybrid_state_pool_programs_fit_the_v5e(compact, chip, monkeypatch):
     program (one token a slot) and the compact prefill program (102 rows
     of 64, the head at each row's sampled position, as the engine runs
     it) take the decode kernel, and each peaks under the chip's 15.75 GiB:
-    12.8 and 13.2 GiB of 5.90 GiB of weights, 6.07 of pool and their
-    temporaries (printed)."""
+    12.3 and 13.1 GiB of 5.90 GiB of weights, 6.07 of pool and their
+    temporaries (printed; the decode program's read 12.8 while the up
+    bank was relaid)."""
     from chipbench import weights_nemotron
     from chipbench.builders import engine_nemotron
     from chipbench.run import make_cell
@@ -491,7 +534,14 @@ def test_hybrid_state_pool_programs_fit_the_v5e(compact, chip, monkeypatch):
         on_chip(params), on_chip(cache), ints(slots), ints(rows, g),
         ints(rows), ints(rows),
     ).compile()
-    assert "flash_decode" in compiled.as_text()
+    text = compiled.as_text()
+    assert "flash_decode" in text
+    # The grouped products are the Pallas kernel, and the up bank, which
+    # the chip stores with its 2,688 minor, goes in as it lies: no relaid
+    # copy of it (the compiler's grouped product took one a layer and
+    # program, 2 ms each on a v5e).
+    assert _calls(text, "grouped_matmul") and not _calls(text, "ragged-dot")
+    assert not re.search(r"= bf16\[64,2688,1856\]\S* copy\(", text)
     peak = compiled.memory_analysis().peak_memory_in_bytes
     print(f"peak {peak / 2 ** 30:.3f} GiB")
     assert peak < 15.75 * 2 ** 30

@@ -1105,6 +1105,58 @@ def test_differentiated_dropless_layer_lowers_as_the_parent(form):
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_GRAD_PROGRAMS[form]
 
 
+@pytest.mark.parametrize("act", ["swiglu", "relu2"])
+def test_served_held_sum_through_the_kernel(act, monkeypatch):
+    """The served held sum with its grouped products through the Pallas
+    ``grouped_matmul`` (what a TPU runs: ``_on_tpu`` patched, so the
+    kernel runs interpreted) equals its ``lax.ragged_dot`` form within
+    bf16's rounding, for SwiGLU and for relu² experts (no ``w_gate``)."""
+    from torchgpipe_tpu.models import moe
+
+    diff, route, key = _dropless_args(8, True, True, t=24, d=16, h=12)
+    x, w_gate, w_up, w_down, gates = diff
+    weights = [w.astype(jnp.bfloat16) for w in (x, w_gate, w_up, w_down)]
+    if act == "relu2":
+        weights[1] = None
+    args = (*weights, gates, *route, key)
+    want = moe._held_expert_sum(*args)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    text = str(jax.make_jaxpr(moe._held_expert_sum)(*args))
+    assert "pallas_call" in text and "ragged_dot" not in text
+    got = moe._held_expert_sum(*args)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("form", ["held", "valid"])
+def test_differentiated_dropless_layer_keeps_ragged_dot(form, monkeypatch):
+    """Where the served forward takes the Pallas kernel (``_on_tpu``
+    patched), ``jax.grad`` through a dropless expert layer (``held``; a
+    ``valid`` mask) still computes every product by ``lax.ragged_dot``:
+    the kernel is the undifferentiated forward's alone."""
+    from torchgpipe_tpu.models import moe
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    cfg = _cfg()
+    layer = moe_mlp(cfg, MoEConfig(n_experts=8, top_k=2, dispatch="dropless",
+                                   held=(2, 4) if form == "held" else None))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, cfg.dim))
+    params, _ = layer.init(
+        jax.random.PRNGKey(0), jax.ShapeDtypeStruct(x.shape, x.dtype))
+    valid = (jnp.arange(16).reshape(2, 8) % 3 != 0) if form == "valid" else None
+
+    def loss(p, x):
+        y, _ = layer.meta["forward_counts"](p, x, valid)
+        return jnp.sum(y ** 2)
+
+    trained = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x))
+    assert "ragged_dot" in trained and "pallas_call" not in trained
+    served = str(jax.make_jaxpr(loss)(params, x))
+    assert "pallas_call" in served and "ragged_dot" not in served
+
+
 @pytest.mark.parametrize("t,k,n", [(12, 2, 3), (800, 8, 12), (576, 4, 32)])
 def test_inverse_order_is_the_sorts(t, k, n):
     """``_inverse_order`` (a cumulative sum over the key's one-hot, no

@@ -64,6 +64,8 @@ from torchgpipe_tpu.models.transformer import (
     token_embedding,
     transformer_block,
 )
+from torchgpipe_tpu.ops.grouped_matmul import grouped_matmul
+from torchgpipe_tpu.ops.grouped_matmul import tiles as grouped_matmul_tiles
 from torchgpipe_tpu.parallel.ring_attention import axis_bound
 
 
@@ -467,6 +469,29 @@ def _ffn(x: jnp.ndarray, w_gate: Optional[jnp.ndarray], w_up: jnp.ndarray,
         jax.nn.silu(product(x, w_gate)) * product(x, w_up), w_down)
 
 
+def _on_tpu() -> bool:
+    """Whether the served grouped products compile the Pallas kernel (off
+    a TPU they are ``lax.ragged_dot``; a test patches this to run the
+    kernel interpreted)."""
+    return jax.devices()[0].platform == "tpu"
+
+
+def _served_product(x: jnp.ndarray, w: jnp.ndarray,
+                    group_sizes: jnp.ndarray) -> jnp.ndarray:
+    """The served expert sum's grouped product: on a TPU, where it tiles
+    the shape, :func:`~torchgpipe_tpu.ops.grouped_matmul.grouped_matmul`
+    (tiles from the shape, each bank read in the layout the chip stores
+    it); else ``lax.ragged_dot``.  Either leaves the rows of no group as
+    they come."""
+    itemsize = jnp.dtype(jnp.result_type(x, w)).itemsize
+    if _on_tpu() and grouped_matmul_tiles(
+            *x.shape, w.shape[2], w.shape[0], itemsize) is not None:
+        return grouped_matmul(
+            x, w, group_sizes,
+            interpret=jax.devices()[0].platform != "tpu")
+    return lax.ragged_dot(x, w, group_sizes)
+
+
 def _expert_sum(
     xf: jnp.ndarray, w_gate: Optional[jnp.ndarray], w_up: jnp.ndarray,
     w_down: jnp.ndarray, gate_sorted: jnp.ndarray, tok_sorted: jnp.ndarray,
@@ -483,6 +508,11 @@ def _expert_sum(
     differentiation it read a wrong loss in ``mellum2.train-4x8192``,
     ``PERF.md`` section 7).
 
+    Which grouped product runs: given ``inv`` (the served forward, never
+    differentiated) :func:`_served_product`, on a TPU the Pallas kernel
+    ``grouped_matmul``; otherwise, every product under ``jax.grad`` among
+    them, ``lax.ragged_dot``, the compiler's grouped product.
+
     Rows behind the last group (``held``: an absent expert's; a masked
     position's) belong to no group and have gate 0.  The TPU's grouped
     product leaves such rows of its RESULT as they were in memory, in the
@@ -495,6 +525,8 @@ def _expert_sum(
         jnp.arange(tok_sorted.shape[0]) < jnp.sum(group_sizes))[:, None]
 
     def grouped(x, w):
+        if inv is not None:
+            return _served_product(x, w, group_sizes)
         if zero != "all":
             return lax.ragged_dot(x, w, group_sizes)
         x = jnp.where(in_group, x, 0.0)
@@ -527,7 +559,9 @@ def _held_expert_sum(xf, w_gate, w_up, w_down, gate_sorted, tok_sorted,
     experts held, and their products) would otherwise be alive at once:
     19.4 of 15.75 GiB at 8 layers x 65,536 rows (described-chip compile,
     PR 32).  Undifferentiated (served) it combines by gathers, by ``key``'s
-    inverse order; differentiated, forward and backward scatter."""
+    inverse order, and its grouped products are :func:`_served_product`'s
+    (on a TPU the Pallas kernel); differentiated, forward and backward
+    scatter, and every product is ``lax.ragged_dot``."""
     inv = _inverse_order(key, group_sizes.shape[0])
     return _expert_sum(xf, w_gate, w_up, w_down, gate_sorted, tok_sorted,
                        group_sizes, zero="result", inv=inv)
@@ -728,9 +762,10 @@ def moe_mlp(cfg: TransformerConfig, moe: MoEConfig, *, name: str = "moe") -> Lay
         if dropless:
             # Megablocks-style dropless experts: sort the k*t assignments
             # by expert and run the SwiGLU as grouped matmuls over the
-            # ragged segments (lax.ragged_dot → TPU grouped-matmul
-            # lowering).  No capacity, no drops, no [E, C, d] buffers —
-            # work is exactly k*t rows however unbalanced the router is.
+            # ragged segments (``_expert_sum``: lax.ragged_dot, or served
+            # on a TPU the Pallas grouped_matmul).  No capacity, no drops,
+            # no [E, C, d] buffers — work is exactly k*t rows however
+            # unbalanced the router is.
             # Under ``held`` the sort key is the LOCAL expert id, with
             # every assignment to an absent expert (or of a masked
             # position) keyed past the last held one: the held experts'
