@@ -484,26 +484,17 @@ def test_mixed_attention_expert_train_step_compiles_for_v5e(chip, monkeypatch):
     assert compiled.memory_analysis().peak_memory_in_bytes < 15.75 * 2 ** 30
 
 
-@pytest.mark.parametrize("compact", [False, True], ids=["decode", "prefill"])
-def test_hybrid_state_pool_programs_fit_the_v5e(compact, chip, monkeypatch):
-    """``nemotron3-nano.serve-deep-backlog``'s two programs at published
-    widths (EMEMEMEM*, 64 of 128 experts held, 65,536 rows of vocabulary)
-    over its pool of 512 slots x 4096, donated: four mixer layers' float32
-    states (4 GiB) beside the attention layer's rows (2 GiB).  The decode
-    program (one token a slot) and the compact prefill program (102 rows
-    of 64, the head at each row's sampled position, as the engine runs
-    it) take the decode kernel, and each peaks under the chip's 15.75 GiB:
-    12.3 and 13.1 GiB of 5.90 GiB of weights, 6.07 of pool and their
-    temporaries (printed; the decode program's read 12.8 while the up
-    bank was relaid)."""
-    from chipbench import weights_nemotron
-    from chipbench.builders import engine_nemotron
+def _pool_program(workload, builder, weights, compact, chip, monkeypatch):
+    """A hybrid serving cell's decode program (one token a slot) or compact
+    prefill program (``prefill_rows_for`` rows of ``prefill_chunk``, the
+    head at each row's sampled position, as the engine runs it), compiled
+    for the described chip over its pool, donated, at published widths."""
     from chipbench.run import make_cell
     from torchgpipe_tpu.serving.engine import prefill_rows_for
 
-    cell = make_cell("nemotron3-nano.serve-deep-backlog", 1, 44.0, False)
+    cell = make_cell(workload, 1, 44.0, False)
     m, sv = cell.config, cell.config["serve"]
-    cfg, moe = engine_nemotron.program_config(cell)
+    cfg, moe = builder.program_config(cell)
     monkeypatch.setattr(jax, "devices", lambda *a: [chip])
     where = SingleDeviceSharding(chip)
 
@@ -514,7 +505,7 @@ def test_hybrid_state_pool_programs_fit_the_v5e(compact, chip, monkeypatch):
         )
 
     slots, g = sv["num_slots"], sv["prefill_chunk"]
-    params = jax.eval_shape(lambda: weights_nemotron.make_flat(m, 0))
+    params = jax.eval_shape(lambda: weights.make_flat(m, 0))
     cache = jax.eval_shape(
         lambda: generation.init_cache(cfg, slots, sv["max_len"]))
     rows, g = (prefill_rows_for(slots), g) if compact else (slots, 1)
@@ -530,10 +521,30 @@ def test_hybrid_state_pool_programs_fit_the_v5e(compact, chip, monkeypatch):
             logits_at=last if compact else None,
         )
 
-    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+    return jax.jit(step, donate_argnums=(1,)).lower(
         on_chip(params), on_chip(cache), ints(slots), ints(rows, g),
         ints(rows), ints(rows),
     ).compile()
+
+
+@pytest.mark.parametrize("compact", [False, True], ids=["decode", "prefill"])
+def test_hybrid_state_pool_programs_fit_the_v5e(compact, chip, monkeypatch):
+    """``nemotron3-nano.serve-deep-backlog``'s two programs at published
+    widths (EMEMEMEM*, 64 of 128 experts held, 65,536 rows of vocabulary)
+    over its pool of 512 slots x 4096, donated: four mixer layers' float32
+    states (4 GiB) beside the attention layer's rows (2 GiB).  The decode
+    program (one token a slot) and the compact prefill program (102 rows
+    of 64, the head at each row's sampled position, as the engine runs
+    it) take the decode kernel, and each peaks under the chip's 15.75 GiB:
+    12.3 and 13.1 GiB of 5.90 GiB of weights, 6.07 of pool and their
+    temporaries (printed; the decode program's read 12.8 while the up
+    bank was relaid)."""
+    from chipbench import weights_nemotron
+    from chipbench.builders import engine_nemotron
+
+    compiled = _pool_program("nemotron3-nano.serve-deep-backlog",
+                             engine_nemotron, weights_nemotron, compact, chip,
+                             monkeypatch)
     text = compiled.as_text()
     assert "flash_decode" in text
     # The grouped products are the Pallas kernel, and the up bank, which
@@ -545,3 +556,27 @@ def test_hybrid_state_pool_programs_fit_the_v5e(compact, chip, monkeypatch):
     peak = compiled.memory_analysis().peak_memory_in_bytes
     print(f"peak {peak / 2 ** 30:.3f} GiB")
     assert peak < 15.75 * 2 ** 30
+
+
+@pytest.mark.parametrize("compact", [False, True], ids=["decode", "prefill"])
+def test_delta_rule_pool_programs_fit_the_v5e(compact, chip, monkeypatch):
+    """``solar-open2.serve-deep-backlog``'s two programs at published widths
+    (*E KE KE KE, 40 of 320 experts held, 24,576 rows of vocabulary) over
+    its pool of 256 slots x 4096, donated: three delta-rule layers' float32
+    states (3 GiB) and tails beside the full layer's rows (4 GiB).  The
+    decode program and the compact prefill program (51 rows of 64) take
+    the decode kernel and the grouped-product kernel, and each peaks under
+    15 GiB, the most the configuration lets its programs take (13.48 and
+    14.80 GiB of 6.16 GiB of weights, 7.11 of pool and their temporaries;
+    at chunks of 128 the prefill program needs 16.12 GiB and is refused)."""
+    from chipbench import weights_solar
+    from chipbench.builders import engine_solar
+
+    compiled = _pool_program("solar-open2.serve-deep-backlog", engine_solar,
+                             weights_solar, compact, chip, monkeypatch)
+    text = compiled.as_text()
+    assert _calls(text, "flash_decode") and _calls(text, "grouped_matmul")
+    assert not _calls(text, "ragged-dot")
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    print(f"peak {peak / 2 ** 30:.3f} GiB")
+    assert peak < 15 * 2 ** 30

@@ -74,14 +74,14 @@ teacher-forced test) holds when capacity admits every token
 from __future__ import annotations
 
 import functools
-from typing import Any, List, NamedTuple, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from torchgpipe_tpu.models import kv_cache, mla
+from torchgpipe_tpu.models import kv_cache, mla, recurrent
 from torchgpipe_tpu.models.kv_cache import (  # noqa: F401 (re-exported)
     KVCache,
     LatentCache,
@@ -725,17 +725,17 @@ def _attend_latent(
 
 
 def _mixer_layer(
-    cfg: TransformerConfig, p: Pytree, x: jnp.ndarray, tail: jnp.ndarray,
-    state: jnp.ndarray, slots: Optional[jnp.ndarray], n_valid: jnp.ndarray,
-    pos0: jnp.ndarray,
+    cfg: TransformerConfig, letter: str, p: Pytree, x: jnp.ndarray,
+    tail: jnp.ndarray, state: jnp.ndarray, slots: Optional[jnp.ndarray],
+    n_valid: jnp.ndarray, pos0: jnp.ndarray,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """``(x + mixer(ln1(x)), tails, states)`` of one mixer layer of a
-    :func:`decode_slots` call: ``tail`` / ``state`` are the layer's banks
-    over the pool's slots, handed back with the rows' new ones in their
-    slots (the compact form scatters them; a padded row, ``n_valid =
-    0``, writes nothing, as its K/V rows do not)."""
-    from torchgpipe_tpu.models import ssm
-
+    :func:`decode_slots` call, ``letter`` its letter of the pattern (its
+    mixer is ``recurrent.MIXERS[letter]``'s): ``tail`` / ``state`` are
+    the layer's banks over the pool's slots, handed back with the rows'
+    new ones in their slots (the compact form scatters them; a padded
+    row, ``n_valid = 0``, writes nothing, as its K/V rows do not)."""
+    mixer = recurrent.MIXERS[letter].impl().mixer
     # The barriers keep one layer's state rows alive at a time: without
     # them the compiler gathers every layer's rows early and scatters
     # them late, holding 0.4 GiB of float32 rows a mixer layer at once
@@ -743,7 +743,7 @@ def _mixer_layer(
     # compile).
     x, tail, state = lax.optimization_barrier((x, tail, state))
     rows = (slice(None) if slots is None else slots)
-    out, new_tail, new_state = ssm.mixer(
+    out, new_tail, new_state = mixer(
         cfg, p, _block_norm(cfg, p, "ln1", x), tail[rows], state[rows],
         n_valid, pos0 == 0)
     if slots is not None:
@@ -829,11 +829,12 @@ def decode_slots(
 
     A hybrid model (``cfg.layer_pattern``) walks its pattern: an
     attention layer is the above without a feed-forward, an expert
-    layer a feed-forward alone, and a mixer layer (``models.ssm.mixer``)
-    continues each row's conv tail and recurrent state of its
-    :class:`~.kv_cache.HybridCache` from the row's slot: zero where the
-    row's frontier is 0 (a new tenant), untouched where the row does
-    nothing (``n_valid = 0``; a padded row writes nothing back either).
+    layer a feed-forward alone, and a mixer layer (its letter's of
+    ``models.recurrent.MIXERS``) continues each row's conv
+    tail and recurrent state of its :class:`~.kv_cache.HybridCache` from
+    the row's slot: zero where the row's frontier is 0 (a new tenant),
+    untouched where the row does nothing (``n_valid = 0``; a padded row
+    writes nothing back either).
 
     ``logits_at`` (a position ``< g`` a row) computes the head at that
     position of each row alone: the logits are then ``[S, 1, vocab]``.
@@ -869,15 +870,22 @@ def decode_slots(
     ring_live, ring_at = None, {}
     new = []
     banks = kv_cache.layers(kv)
-    states = iter(zip(cache.conv, cache.ssm)) if hybrid else None
-    new_conv, new_ssm = [], []
+    # A hybrid cache's tails and states, by the letter of their layers.
+    mixers = recurrent.MIXERS
+    states = {letter: iter(zip(getattr(cache, m.tails),
+                               getattr(cache, m.states)))
+              for letter, m in mixers.items()} if hybrid else {}
+    new_states: Dict[str, List[jnp.ndarray]] = {
+        f: [] for m in mixers.values() for f in (m.tails, m.states)}
     for i, p in enumerate(block_p):
         kind = cfg.layer_type(i)
         if kind == "mixer":
-            x, tail, st = _mixer_layer(cfg, p, x, *next(states), slots,
+            letter = cfg.layer_pattern[i]
+            x, tail, st = _mixer_layer(cfg, letter, p, x,
+                                       *next(states[letter]), slots,
                                        n_valid, pos0)
-            new_conv.append(tail)
-            new_ssm.append(st)
+            new_states[mixers[letter].tails].append(tail)
+            new_states[mixers[letter].states].append(st)
             continue
         if kind == "experts":
             h = _block_norm(cfg, p, "ln1", x)
@@ -931,7 +939,8 @@ def decode_slots(
     length = jnp.sum(new_lengths).astype(jnp.int32)  # schema slot only
     out_cache = kv_cache.rebuild(kv, new, length)
     if hybrid:
-        out_cache = kv_cache.HybridCache(out_cache, new_conv, new_ssm, length)
+        out_cache = kv_cache.HybridCache(kv=out_cache, length=length,
+                                         **new_states)
     if logits_at is not None:
         x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
     out = (_logits(cfg, head_p, x), out_cache, new_lengths)
@@ -1220,7 +1229,7 @@ def prefill(
             "ring caches hold exactly the attention window: set "
             "cfg.attn_window to use ring=True"
         )
-    if cfg.ssm is not None:
+    if cfg.keeps_state:
         if ring or kv_quant:
             kv_cache.refuse_state(cfg, "a ring or int8 cache")
         # A hybrid model's prompt is one decode_slots call from an empty

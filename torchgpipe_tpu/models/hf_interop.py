@@ -1962,3 +1962,103 @@ def config_from_hf_nemotron_h(hf_config: Any, held: Any = None) -> tuple:
 
 
 __all__ += ["config_from_hf_nemotron_h"]
+
+
+# Solar-Open2's full layers stand one in four, from layer 0 (its 3:1 of
+# delta-rule to full layers); ``gqa_interval`` is not read.
+SOLAR_GQA_PERIOD = 4
+
+
+def config_from_hf_solar_open2(hf_config: Any, held: Any = None) -> tuple:
+    """(TransformerConfig, MoEConfig) for a published ``model_type:
+    solar_open2`` config (Upstage's Solar-Open2: the key set
+    ``gqa_layers``, ``use_gqa_gate``, ``use_rope``,
+    ``linear_attn_config`` {``num_heads``, ``head_dim``,
+    ``num_kv_heads``, ``short_conv_kernel_size``}, ``kda_use_full_proj``,
+    ``kda_allow_neg_eigval``, ``n_routed_experts``, ``n_shared_experts``,
+    ``num_experts_per_tok``, ``moe_intermediate_size``,
+    ``norm_topk_prob``, ``routed_scaling_factor``,
+    ``first_k_dense_replace``).  ``hf_config`` is any object with those
+    attributes, as they stand in ``config.json``.
+
+    A published layer is a mixer, then experts, each ``x + F(norm(x))``:
+    two letters of ``cfg.layer_pattern``, ``*E`` for a layer of
+    ``gqa_layers`` (gated GQA, causal and full, nothing rotated) and
+    ``KE`` for the others (the gated delta-rule mixer of
+    ``cfg.linear_attn``, ``models.kda``; its low-rank projections of
+    ``head_dim``).  Experts are SwiGLU, chosen by sigmoid score plus
+    ``e_score_correction_bias`` (the layer's ``router_bias``) and
+    weighted by the score, normalised and scaled, beside one shared
+    expert of ``n_shared_experts x moe_intermediate_size``.
+    ``held=(first, count)`` makes the expert layers one chip's share
+    (``MoEConfig.held``).  Serving path only: the training block refuses
+    the pattern by name.  ``kda_use_full_proj: true`` (full-rank decay
+    and gate projections), ``use_rope: true``, leading dense layers, a
+    ``num_kv_heads`` other than ``num_heads`` and ``gqa_layers`` off the
+    one-in-four pattern raise instead of being ignored."""
+    from torchgpipe_tpu.models.moe import MoEConfig
+    from torchgpipe_tpu.models.transformer import AttnLayer, LinearAttnConfig
+
+    hf = hf_config
+    la = dict(hf.linear_attn_config)
+    n = int(hf.num_hidden_layers)
+    for key, want in (("kda_use_full_proj", False), ("use_rope", False),
+                      ("first_k_dense_replace", 0)):
+        if getattr(hf, key, want) != want:
+            raise ValueError(
+                f"{key}={getattr(hf, key)!r} is not computed here "
+                f"({want!r} is)"
+            )
+    full = sorted(int(i) for i in hf.gqa_layers)
+    if full != list(range(0, n, SOLAR_GQA_PERIOD)):
+        raise ValueError(
+            f"gqa_layers={full} is off the pattern computed here: one full "
+            f"layer in {SOLAR_GQA_PERIOD}, from layer 0, of "
+            f"num_hidden_layers={n} ({list(range(0, n, SOLAR_GQA_PERIOD))})"
+        )
+    heads = int(la["num_heads"])
+    if la.get("num_kv_heads") not in (None, heads):
+        raise ValueError(
+            f"linear_attn_config num_kv_heads={la['num_kv_heads']} is not "
+            f"computed here (None, or num_heads={heads}, is)"
+        )
+    head_dim = int(la["head_dim"])
+    pattern = "".join("*E" if i in full else "KE" for i in range(n))
+    theta = float(getattr(hf, "rope_theta", 10000.0))
+    cfg = TransformerConfig(
+        vocab=hf.vocab_size,
+        dim=hf.hidden_size,
+        n_layers=len(pattern),
+        n_heads=hf.num_attention_heads,
+        n_kv_heads=hf.num_key_value_heads,
+        n_head_dim=int(hf.head_dim),
+        rope_theta=theta,
+        norm_eps=float(getattr(hf, "rms_norm_eps", 1e-5)),
+        tie_embeddings=bool(getattr(hf, "tie_word_embeddings", False)),
+        attn_gate=bool(getattr(hf, "use_gqa_gate", False)),
+        attn_layers=(AttnLayer(None, theta, rope=False),),
+        layer_pattern=pattern,
+        linear_attn=LinearAttnConfig(
+            n_heads=heads,
+            head_dim=head_dim,
+            conv_kernel=int(la.get("short_conv_kernel_size", 4)),
+            neg_eigval=bool(getattr(hf, "kda_allow_neg_eigval", False)),
+        ),
+    )
+    cfg.validate_arch()
+    moe = MoEConfig(
+        n_experts=int(hf.n_routed_experts),
+        top_k=int(hf.num_experts_per_tok),
+        dispatch="dropless",
+        scoring="sigmoid",
+        norm_topk=bool(getattr(hf, "norm_topk_prob", False)),
+        route_scale=float(getattr(hf, "routed_scaling_factor", 1.0)),
+        n_shared=int(getattr(hf, "n_shared_experts", 0) or 0),
+        expert_hidden=int(hf.moe_intermediate_size),
+        held=None if held is None else (int(held[0]), int(held[1])),
+        select="bias",
+    )
+    return cfg, moe
+
+
+__all__ += ["config_from_hf_solar_open2"]

@@ -24,14 +24,18 @@ find their whole band beside the rows they write), a full layer
 A one-entry period (a model-global window, or none) keeps ``max_len``
 rows in every layer.
 
-A hybrid model whose mixer layers keep a recurrent state (``cfg.ssm``,
-``models.ssm``) gets a :class:`HybridCache`: its attention layers' K/V
-rows as a :class:`KVCache` (``kv``), and beside them, a mixer layer
-each, a state that does NOT grow with the context: the conv's last
-inputs (``conv``) and the float32 state (``ssm``).  Nothing of it lies
-at a position, so what takes rows at their position (the merges and
-copies below, int8 rows, a prefix's rows, migration, rollback) refuses
-it by name (:func:`refuse_state`).
+A hybrid model whose mixer layers keep a recurrent state (``M``
+layers of ``cfg.ssm``, ``models.ssm``; ``K`` layers of
+``cfg.linear_attn``, ``models.kda``) gets a :class:`HybridCache`: its
+attention layers' K/V rows as a :class:`KVCache` (``kv``), and beside
+them, a mixer layer each, a state that does NOT grow with the context:
+the conv's last inputs and the float32 state, in the fields of the
+mixer that keeps them (``recurrent.MIXERS``: ``conv`` / ``ssm`` for the
+Mamba-2 layers, ``kda_conv`` / ``kda`` for the delta-rule layers), each
+of the shape its own mixer's ``init_state`` gives.  Nothing of it lies at a position, so
+what takes rows at their position (the merges and copies below, int8
+rows, a prefix's rows, migration, rollback) refuses it by name
+(:func:`refuse_state`).
 
 Every kind has two CONTENT banks a layer (K and V, or the latent and
 the rotated key head) and, where its rows are quantised, one scale bank
@@ -57,6 +61,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from torchgpipe_tpu.models.recurrent import MIXERS
 from torchgpipe_tpu.models.transformer import TransformerConfig
 
 # One layer's banks: the two content banks, then their scale banks
@@ -87,15 +92,21 @@ class LatentCache(NamedTuple):
 
 class HybridCache(NamedTuple):
     """The cache of a hybrid model (``cfg.layer_pattern`` with mixer
-    layers, ``cfg.ssm``): the attention layers' rows, and a mixer layer
-    each a slot's conv tail and recurrent state (``models.ssm``).  A
-    row of the state is a SLOT, not a position: the state sums the whole
-    context, so a new tenant starts it from zero (the mixer reads zero
-    where a row's frontier is 0) and a no-op row leaves it untouched."""
+    layers that keep a state, ``cfg.keeps_state``): the attention
+    layers' rows, and a mixer layer each a slot's conv tail and
+    recurrent state, in the fields of the mixer that keeps them
+    (``recurrent.MIXERS``: the Mamba-2 ``M`` layers' of ``models.ssm``,
+    the delta-rule ``K`` layers' of ``models.kda``; each field in layer
+    order among its own letter).  A row of the state is a SLOT, not a position: the state
+    sums the whole context, so a new tenant starts it from zero (the
+    mixer reads zero where a row's frontier is 0) and a no-op row leaves
+    it untouched."""
 
-    kv: KVCache             # the attention ('*') layers' K/V, layer order
-    conv: List[jnp.ndarray]  # each [b, conv_kernel - 1, conv_dim]
-    ssm: List[jnp.ndarray]   # each f32 [b, n_heads, head_dim, state]
+    kv: KVCache              # the attention ('*') layers' K/V, layer order
+    conv: List[jnp.ndarray]  # 'M': each [b, conv_kernel - 1, conv_dim]
+    ssm: List[jnp.ndarray]   # 'M': each f32 [b, n_heads, head_dim, state]
+    kda_conv: List[jnp.ndarray]  # 'K': each [b, conv_kernel - 1, 3 H d]
+    kda: List[jnp.ndarray]   # 'K': each f32 [b, H, d, d]
     length: jnp.ndarray      # [] int32 — tokens already cached
 
 
@@ -141,15 +152,16 @@ RING_GRANULE = 512
 def refuse_state(cfg: TransformerConfig, what: str) -> None:
     """Refuse, by name, what is written for cache rows that lie at their
     position where the model's mixer layers keep a recurrent state
-    (``cfg.ssm``): a slot's state sums its whole context, so it cannot
-    be cut at a position, copied in part, rolled back or quantized a row
-    at a time."""
-    if cfg.ssm is not None:
+    (``cfg.keeps_state``): a slot's state sums its whole context, so it
+    cannot be cut at a position, copied in part, rolled back or
+    quantized a row at a time."""
+    if cfg.keeps_state:
+        letters = sorted(set(cfg.layer_pattern) & set(MIXERS))
         raise NotImplementedError(
             f"{what} takes cache rows that lie at their position; this "
-            "model's mixer layers ('M' of cfg.layer_pattern) keep a "
-            "recurrent state a slot (kv_cache.HybridCache) that sums the "
-            "whole context and has no row at a position: prefill / "
+            f"model's mixer layers ({letters} of cfg.layer_pattern) keep "
+            "a recurrent state a slot (kv_cache.HybridCache) that sums "
+            "the whole context and has no row at a position: prefill / "
             "decode_slots and serving.Engine's plain pool serve it"
         )
 
@@ -167,13 +179,17 @@ def attention_view(cfg: TransformerConfig, cache: Any,
 def bytes_by_kind(cfg: TransformerConfig, cache: Any) -> Dict[str, int]:
     """The bytes ``cache``'s banks pin by the kind of layer that holds
     them: ``window`` and ``full`` (:func:`layer_kind`), and ``state``,
-    a hybrid model's mixer layers' tails and states."""
+    a hybrid model's mixer layers' tails and states, every mixer's by
+    its own shape."""
     out = {"window": 0, "full": 0}
     kv = cache
     if isinstance(cache, HybridCache):
         kv = cache.kv
-        out["state"] = sum(b.size * b.dtype.itemsize
-                           for b in (*cache.conv, *cache.ssm))
+        out["state"] = sum(
+            b.size * b.dtype.itemsize
+            for mixer in MIXERS.values()
+            for b in (*getattr(cache, mixer.tails),
+                      *getattr(cache, mixer.states)))
     attn = [i for i in range(cfg.n_layers)
             if cfg.layer_type(i) in ("block", "attention")]
     for i, banks in zip(attn, layers(kv)):
@@ -242,19 +258,20 @@ def init_cache(
     (a serving pool's largest prefill chunk): it sizes the rings of a
     model that mixes layer types (:func:`layer_rows`) and nothing else."""
     dt = dtype or cfg.dtype
-    if cfg.ssm is not None:
-        from torchgpipe_tpu.models import ssm
-
+    if cfg.keeps_state:
         n_attn = cfg.layer_pattern.count("*")
         rows = (batch, max_len, cfg.kv_heads, cfg.head_dim)
-        states = [ssm.init_state(cfg, batch, dt)
-                  for _ in range(cfg.layer_pattern.count("M"))]
+        banks = {}
+        for letter, mixer in MIXERS.items():
+            states = [mixer.impl().init_state(cfg, batch, dt)
+                      for _ in range(cfg.layer_pattern.count(letter))]
+            banks[mixer.tails] = [c for c, _ in states]
+            banks[mixer.states] = [st for _, st in states]
         return HybridCache(
             kv=KVCache(k=[jnp.zeros(rows, dt) for _ in range(n_attn)],
                        v=[jnp.zeros(rows, dt) for _ in range(n_attn)],
                        length=jnp.zeros((), jnp.int32)),
-            conv=[c for c, _ in states], ssm=[st for _, st in states],
-            length=jnp.zeros((), jnp.int32),
+            length=jnp.zeros((), jnp.int32), **banks,
         )
     if cfg.mla is not None:
         m = cfg.mla

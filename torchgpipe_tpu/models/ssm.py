@@ -27,12 +27,10 @@ blocks of ``cfg.ssm.chunk`` positions in which the sums run as products
 and the state passes from block to block: a chunk boundary changes the
 order of the sums and nothing else.
 
-Two rules make a pooled slot's state safe (``models.kv_cache.
-HybridCache``): a row whose frontier is 0 (a new tenant: admission gave
-it the slot) reads a zero state and tail, whatever its slot's last
-tenant left; and a masked position is a no-op, its ``dt`` 0 (so ``a = 1``
-and nothing enters ``S``) and its input never in the tail, so a row that
-does nothing this call hands back its state and tail bit-untouched.
+The two rules that make a pooled slot's state safe are
+``models.recurrent``'s (a new tenant reads zero state and tail, a masked
+position is a no-op); here a masked position's ``dt`` is 0, so ``a = 1``
+and nothing enters ``S``.
 """
 
 from __future__ import annotations
@@ -43,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from torchgpipe_tpu.models import recurrent
+from torchgpipe_tpu.models.recurrent import entering as _entering
 from torchgpipe_tpu.models.transformer import SSMConfig, TransformerConfig
 
 Pytree = Any
@@ -60,32 +60,9 @@ def init_state(cfg: TransformerConfig, rows: int,
 
 def _conv(s: SSMConfig, p: Pytree, xbc: jnp.ndarray, tail: jnp.ndarray,
           n_valid: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``(silu(conv(xbc) + b) f32 [b, g, conv_dim], the new tail)``: the
-    conv over ``[tail ; xbc]``; the new tail is the ``K - 1`` inputs
-    that end at the row's last VALID position (the old tail itself for a
-    row with ``n_valid = 0``)."""
-    g, k = xbc.shape[1], s.conv_kernel
-    ext = jnp.concatenate([tail, xbc.astype(tail.dtype)], axis=1)
-    w = p["conv_w"].astype(jnp.float32)
-    acc = sum(ext[:, j:j + g].astype(jnp.float32) * w[j] for j in range(k))
-    acc = acc + p["conv_b"].astype(jnp.float32)
-    new_tail = jnp.take_along_axis(ext, _tail_at(n_valid, k)[:, :, None], axis=1)
-    return jax.nn.silu(acc), new_tail
-
-
-def _tail_at(n_valid: jnp.ndarray, k: int) -> jnp.ndarray:
-    """Where in ``[tail ; chunk]`` a row's new tail lies: the ``k - 1``
-    inputs that end at its last VALID position, so a padded position's
-    input never enters it."""
-    return n_valid[:, None] + jnp.arange(k - 1)[None, :]
-
-
-def _entering(fresh: jnp.ndarray, tail: jnp.ndarray,
-              state: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The tail and state a row continues from: zero for a new tenant
-    (``fresh``), whatever its slot's last tenant left."""
-    return (jnp.where(fresh[:, None, None], 0, tail).astype(tail.dtype),
-            jnp.where(fresh[:, None, None, None], 0.0, state))
+    """``(silu(conv(xbc) + b) f32 [b, g, conv_dim], the new tail)``
+    (``recurrent.conv`` with the layer's taps and bias)."""
+    return recurrent.conv(xbc, tail, p["conv_w"], p["conv_b"], n_valid)
 
 
 def _steps(dt: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
@@ -188,7 +165,7 @@ def mixer(
     tail_in, state_in = _entering(fresh, tail, state)
     with jax.named_scope("ssm.conv"):
         xbc, new_tail = _conv(s, p, xbc, tail_in, n_valid)
-        new_tail = jnp.where(keep[:, None, None], new_tail, tail)
+        new_tail = recurrent.kept(keep, new_tail, tail)
     with jax.named_scope("ssm.scan"):
         x = xbc[..., :s.d_inner].reshape(b, g, G, R, s.head_dim)
         B = xbc[..., s.d_inner:s.d_inner + G * s.state].reshape(b, g, G, s.state)
@@ -203,8 +180,7 @@ def mixer(
         else:
             y, st = _ssd(s.chunk, x, dt, a_log, B, C, st)
         y = y + p["D"].reshape(G, R)[:, :, None] * x
-        new_state = jnp.where(keep[:, None, None, None],
-                              st.reshape(state.shape), state)
+        new_state = recurrent.kept(keep, st.reshape(state.shape), state)
     with jax.named_scope("ssm.out"):
         y = _gated_norm(s, y.reshape(b, g, s.d_inner), z, p["norm"],
                         cfg.norm_eps)

@@ -27,6 +27,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from torchgpipe_tpu.layers import Layer, chain
+from torchgpipe_tpu.models.recurrent import MIXERS
 from torchgpipe_tpu.parallel import attention
 from torchgpipe_tpu.parallel.ring_attention import axis_bound
 from torchgpipe_tpu.parallel.tensor import (
@@ -123,9 +124,41 @@ class SSMConfig:
         return self.d_inner + 2 * self.n_groups * self.state
 
 
+@dataclasses.dataclass(frozen=True)
+class LinearAttnConfig:
+    """A gated delta-rule mixer with per-channel decay (Kimi Delta
+    Attention, arXiv:2510.26692; ``models.kda``) as Solar-Open2's
+    ``linear_attn_config`` states it: ``n_heads`` heads of ``head_dim``
+    for q, k and v alike, each through a causal depthwise conv of
+    ``conv_kernel`` taps, the decay and the output gate through low-rank
+    projections (their width is that of the weights), ``neg_eigval`` the
+    factor 2 on ``beta`` that lets the transition's eigenvalue along
+    ``k`` reach (-1, 1).  What a slot keeps of it
+    (``models.kv_cache.HybridCache``) is the convs' last ``conv_kernel -
+    1`` inputs and the f32 state ``[n_heads, head_dim, head_dim]``,
+    neither of which grows with the context."""
+
+    n_heads: int
+    head_dim: int
+    conv_kernel: int = 4
+    neg_eigval: bool = True
+
+    @property
+    def width(self) -> int:
+        """Channels of q, of k and of v: ``n_heads * head_dim``."""
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of the conv: q, k and v side by side."""
+        return 3 * self.width
+
+
 # The letters of a hybrid layer pattern (``TransformerConfig.layer_pattern``):
-# the one thing a layer computes besides its pre-norm and residual.
-LAYER_LETTERS = {"M": "mixer", "E": "experts", "*": "attention"}
+# the one thing a layer computes besides its pre-norm and residual.  Every
+# mixer that keeps a state a slot (``recurrent.MIXERS``) is a ``'mixer'``.
+LAYER_LETTERS = {**{letter: "mixer" for letter in MIXERS},
+                 "E": "experts", "*": "attention"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -267,13 +300,16 @@ class TransformerConfig:
     mla: Optional[MLAConfig] = None
     # A hybrid model (Nemotron-H's ``hybrid_override_pattern``): one
     # letter a layer of :data:`LAYER_LETTERS`, each layer ``x + F(norm(x))``
-    # with ``F`` ONE of a Mamba-2 mixer (``M``, of ``ssm``), an expert
+    # with ``F`` ONE of a Mamba-2 mixer (``M``, of ``ssm``), a gated
+    # delta-rule mixer (``K``, of ``linear_attn``), an expert
     # feed-forward (``E``) or attention (``*``): nothing pairs attention
-    # with a feed-forward.  None: every layer is attention then a
+    # with a feed-forward, so a published layer of attention and experts
+    # is two letters.  None: every layer is attention then a
     # feed-forward.  Serving path only (``models.generation``,
     # ``serving.Engine``): the training block refuses it.
     layer_pattern: Optional[str] = None
     ssm: Optional[SSMConfig] = None
+    linear_attn: Optional[LinearAttnConfig] = None
 
     def layer_type(self, layer: int) -> str:
         """What layer ``layer`` computes: ``'block'`` (attention, then a
@@ -282,6 +318,13 @@ class TransformerConfig:
         if self.layer_pattern is None:
             return "block"
         return LAYER_LETTERS[self.layer_pattern[layer]]
+
+    @property
+    def keeps_state(self) -> bool:
+        """Whether some layer keeps a recurrent state a slot (a letter of
+        ``recurrent.MIXERS`` in the pattern)."""
+        return self.layer_pattern is not None and any(
+            c in MIXERS for c in self.layer_pattern)
 
     @property
     def attn_kind(self) -> str:
@@ -370,14 +413,26 @@ class TransformerConfig:
                     f"layer_pattern has {len(self.layer_pattern)} letters "
                     f"for n_layers={self.n_layers}"
                 )
-            if ("M" in self.layer_pattern) != (self.ssm is not None):
+            for letter, mixer in MIXERS.items():
+                if ((letter in self.layer_pattern)
+                        != (getattr(self, mixer.config) is not None)):
+                    raise ValueError(
+                        f"a layer_pattern with mixer layers ({letter!r}) "
+                        f"needs cfg.{mixer.config}, and cfg.{mixer.config} "
+                        "needs them"
+                    )
+            la = self.linear_attn
+            if la is not None and la.conv_kernel < 1:
                 raise ValueError(
-                    "a layer_pattern with mixer layers ('M') needs cfg.ssm, "
-                    "and cfg.ssm needs them"
+                    f"linear_attn conv_kernel={la.conv_kernel} must be at "
+                    "least 1"
                 )
-        elif self.ssm is not None:
-            raise ValueError("cfg.ssm describes the 'M' layers of a "
-                             "layer_pattern: set one")
+        else:
+            for letter, mixer in MIXERS.items():
+                if getattr(self, mixer.config) is not None:
+                    raise ValueError(
+                        f"cfg.{mixer.config} describes the {letter!r} layers "
+                        "of a layer_pattern: set one")
         if not 0.0 < self.rope_pct <= 1.0:
             raise ValueError(f"rope_pct={self.rope_pct} must be in (0, 1]")
         if self.rope_pct < 1.0 and int(self.head_dim * self.rope_pct) % 2:
@@ -615,10 +670,10 @@ def transformer_block(
     if cfg.layer_pattern is not None:
         raise NotImplementedError(
             f"layer_pattern={cfg.layer_pattern!r} (mixer-only, expert-only "
-            "and attention-only layers; the Mamba-2 mixer's state) is "
-            "computed on the serving path only (models.generation.prefill "
-            "/ decode_slots, serving.Engine); the training block has no "
-            "mixer forward or backward"
+            "and attention-only layers; the Mamba-2 and delta-rule mixers' "
+            "state) is computed on the serving path only "
+            "(models.generation.prefill / decode_slots, serving.Engine); "
+            "the training block has no mixer forward or backward"
         )
     for on, what in ((cfg.attn_gate, "attn_gate (the attention's output "
                       "gate)"),
